@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Perf smoke: the whole-query single-dispatch contract, enforced.
 
-All 22 TPC-H queries at SF0.05 (CPU backend — the contract is about
-dispatch STRUCTURE, not device speed) must, at steady state:
+All 22 TPC-H queries at SF0.05 (CPU backend by default — the contract
+is about dispatch STRUCTURE, not device speed) must, at steady state:
 
   * cross the host<->device boundary at most twice:
     phase `dispatches` <= 2 and `syncs` <= 1 per query
@@ -40,8 +40,9 @@ import sys
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
-# structure gate, not a speed gate: never burn a TPU grant on it
 os.environ.setdefault("TIDB_TPU_LOCKRANK", "1")   # lock-rank sanitizer armed
+# a structure gate: the CPU backend unless a platform is asked for by
+# name (PERF_SF=1 JAX_PLATFORMS=tpu runs the same budget on the chip)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 if os.environ.get("PERF_MESH") == "1" and \
         "xla_force_host_platform_device_count" not in \
@@ -86,9 +87,10 @@ def run(queries=None, sf=None, max_dispatches=None, max_syncs=None,
                     "(set XLA_FLAGS=--xla_force_host_platform_device_"
                     "count=8 before jax imports)"]
 
+    import jax
     tk = TestKit()
-    print(f"# perf_smoke: sf={sf} queries={len(queries)} "
-          f"mesh={'on' if mesh else 'off'} "
+    print(f"# perf_smoke: backend={jax.default_backend()} sf={sf} "
+          f"queries={len(queries)} mesh={'on' if mesh else 'off'} "
           f"budget: dispatches<={max_dispatches} syncs<={max_syncs} "
           f"upload_bytes==0", file=out)
     load_tpch(tk, sf=sf, seed=42)
@@ -106,8 +108,12 @@ def run(queries=None, sf=None, max_dispatches=None, max_syncs=None,
     finally:
         tk.domain.copr.use_device = True
 
+    import time
     for q in queries:                    # warmup: compiles + uploads +
-        tk.must_query(ALL_QUERIES[q])    # learned shuffle capacities
+        t0 = time.time()                 # learned shuffle capacities
+        tk.must_query(ALL_QUERIES[q])
+        print(f"# warmup {q}: {time.time() - t0:.1f}s", file=out,
+              flush=True)
 
     def _mpp_marks(m):
         return (m.get("fused_pipeline_mpp_hit", 0),

@@ -162,11 +162,9 @@ def test_all_queries_device_vs_host(tk, qname):
     assert r_dev == r_host
 
 
-# queries whose joins must ride the fused device pipeline; a routing
-# regression (silent fall-off to the host join) fails here, not just in
-# the benchmark (VERDICT r2: "no test asserts fused_pipeline_error == 0")
-FUSED_QUERIES = ["q2", "q3", "q4", "q5", "q7", "q8", "q9", "q10", "q11",
-                 "q12", "q13", "q14", "q16", "q17", "q19", "q21", "q22"]
+# a routing regression (silent fall-off to the host join) fails here,
+# not just in the benchmark
+from tidb_tpu.bench.tpch import FUSED_QUERIES
 
 
 def test_fused_routing_pinned(tk):

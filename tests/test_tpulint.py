@@ -249,7 +249,7 @@ def test_purity_clean_kernel_and_shard_map():
     src = """
         import jax
         import jax.numpy as jnp
-        from ..utils.jaxcfg import compat_shard_map as shard_map
+        from jax import shard_map
 
         def frag(a, b):
             local = {}
@@ -264,7 +264,7 @@ def test_purity_clean_kernel_and_shard_map():
 
 def test_purity_shard_map_target_checked():
     src = """
-        from ..utils.jaxcfg import compat_shard_map as shard_map
+        from jax import shard_map
 
         def frag(a):
             print(a)

@@ -6,11 +6,22 @@ from __future__ import annotations
 import argparse
 
 
+def backend_line() -> str:
+    """The jax backend every device fragment will run on, as jax
+    reports it — named at start-up so a CPU run is never mistaken for
+    a chip run."""
+    import jax
+    devs = jax.devices()
+    return (f"backend {devs[0].platform} ({devs[0].device_kind}) "
+            f"x{len(devs)}")
+
+
 def repl(domain):
     from .session import Session
     sess = Session(domain)
     sess.vars.current_db = "test"
-    print("tidb_tpu SQL shell (embedded store). \\q to quit.")
+    print(f"tidb_tpu SQL shell (embedded store, {backend_line()}). "
+          "\\q to quit.")
     buf = ""
     while True:
         try:
@@ -54,7 +65,7 @@ def main(argv=None):
     ap.add_argument("--data-dir", default=None,
                     help="persist commits to a WAL in this directory")
     ap.add_argument("--cpu", action="store_true",
-                    help="force the jax CPU backend (no TPU init)")
+                    help="force the jax CPU backend")
     ap.add_argument("--tls-cert", default=None,
                     help="PEM certificate enabling TLS on the wire")
     ap.add_argument("--tls-key", default=None)
@@ -69,7 +80,8 @@ def main(argv=None):
         from .server import Server
         srv = Server(domain, port=args.port, tls_cert=args.tls_cert,
                      tls_key=args.tls_key).start()
-        print(f"listening on 127.0.0.1:{srv.port} (MySQL protocol)")
+        print(f"listening on 127.0.0.1:{srv.port} (MySQL protocol), "
+              f"{backend_line()}")
         if args.status_port >= 0:
             from .server.status import start_status_server
             try:
@@ -88,6 +100,8 @@ def main(argv=None):
             srv.shutdown()
         return
     if args.execute:
+        import sys
+        print(f"# {backend_line()}", file=sys.stderr)
         from .session import Session
         sess = Session(domain)
         sess.vars.current_db = "test"

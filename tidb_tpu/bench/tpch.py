@@ -274,9 +274,9 @@ def load_tpch(tk, sf: float = 0.01, seed: int = 7, skip_tables=()):
             "l_orderkey": l_orderkey,
             "l_partkey": rng.integers(1, n_part + 1, n_li).astype(np.int64),
             "l_suppkey": rng.integers(1, n_supp + 1, n_li).astype(np.int64),
-            "l_linenumber": np.concatenate(
-                [np.arange(1, k + 1) for k in nl_per]).astype(np.int64)
-            if n_ord < 200_000 else np.ones(n_li, dtype=np.int64),
+            # 1..k within each order, at every scale
+            "l_linenumber": np.arange(n_li, dtype=np.int64) - np.repeat(
+                np.cumsum(nl_per) - nl_per, nl_per).astype(np.int64) + 1,
             "l_quantity": quantity,
             "l_extendedprice": extprice,
             "l_discount": rng.integers(0, 11, n_li).astype(np.int64),
@@ -595,3 +595,9 @@ ALL_QUERIES = {
     "q14": Q14, "q15": Q15, "q16": Q16, "q17": Q17, "q18": Q18, "q19": Q19,
     "q20": Q20, "q21": Q21, "q22": Q22,
 }
+
+# queries whose joins must ride the fused device pipeline
+# (tests/test_tpch.py pins the routing; chip_smoke.py requires it of the
+# ones it runs)
+FUSED_QUERIES = ["q2", "q3", "q4", "q5", "q7", "q8", "q9", "q10", "q11",
+                 "q12", "q13", "q14", "q16", "q17", "q19", "q21", "q22"]

@@ -2158,8 +2158,12 @@ class HashJoinExec(Executor):
             return Chunk.empty(out_fts)
 
         mode = str(self.ctx.sv.get("tidb_join_exec"))
-        use_device = (mode == "device" or
-                      (mode == "auto" and _backend_is_accel()))
+        # copr.use_device = False is the host twin every device result
+        # is compared with: like the sort and window branches, the
+        # device probe stays out of it (on an accelerator "auto" would
+        # otherwise put device joins inside the host reference)
+        use_device = self.ctx.copr.use_device and (
+            mode == "device" or (mode == "auto" and _backend_is_accel()))
         if use_device and not naaj and bv.dtype == np.int64 \
                 and pv.dtype == np.int64 and not plan.other_conds:
             from ..utils import device_guard

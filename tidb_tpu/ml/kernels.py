@@ -33,8 +33,13 @@ def forward_xp(xp, X, weights, biases):
     the final width is 1, else [n, out]."""
     h = X
     last = len(weights) - 1
+    # the parity contract is float32: on a TPU the default float32
+    # matmul is a single bf16 MXU pass (0.1 absolute error against the
+    # numpy twin on a 4-16-1 MLP), so the device chain asks for full
+    # precision. numpy has no such knob, and the CPU backend ignores it.
+    prec = {"precision": jax.lax.Precision.HIGHEST} if xp is jnp else {}
     for i, (W, b) in enumerate(zip(weights, biases)):
-        h = h @ xp.asarray(W, dtype=xp.float32) \
+        h = xp.matmul(h, xp.asarray(W, dtype=xp.float32), **prec) \
             + xp.asarray(b, dtype=xp.float32)
         if i != last:
             h = xp.maximum(h, xp.float32(0.0))

@@ -29,7 +29,7 @@ from ..utils import jaxcfg  # noqa: F401
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.jaxcfg import compat_shard_map as shard_map
+from jax import shard_map
 
 from ..expression import EvalCtx, eval_expr, eval_bool_mask
 from ..expression.vec import materialize_nulls

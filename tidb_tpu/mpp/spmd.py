@@ -24,7 +24,7 @@ from ..utils import jaxcfg  # noqa: F401
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ..utils.jaxcfg import compat_shard_map as shard_map
+from jax import shard_map
 
 from ..expression import EvalCtx, eval_expr, eval_bool_mask
 from ..expression.vec import materialize_nulls
@@ -171,7 +171,7 @@ def run_dag_spmd(domain, dag, mesh, local_cap, n_groups=None,
                             if bound[ix][1] is not None),
          _arg_sig(flat_args)), build)
     # supervised mesh launch: the worker control plane (cluster/worker
-    # spmd_frag) calls this NAKED — without the guard a dropped grant
+    # spmd_frag) calls this NAKED — without the guard a device lost
     # mid-collective is an unclassified worker crash instead of a
     # retryable error the coordinator can reason about
     # fallback_is_host=False: a degrade here propagates to the

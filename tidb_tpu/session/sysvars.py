@@ -71,10 +71,10 @@ from ..utils import env_int as _env_int  # shared with storage lock knobs
 
 
 def _jax_cache_dir_default() -> str:
-    """The ACTUAL persistent-cache directory ('' = disabled or
-    degraded). Read from jaxcfg when it is already loaded — its
-    persistent_cache_dir is None when setup failed (read-only home) or
-    was disabled, and SHOW VARIABLES must report that reality. Via
+    """The persistent-cache directory IN FORCE ('' = degraded). Read
+    from jaxcfg when it is already loaded — its persistent_cache_dir
+    is None when setup failed (unwritable directory), and SHOW
+    VARIABLES must report that reality. Via
     sys.modules only: this module stays jax-import-free. When jaxcfg
     loads later it publishes the real outcome into this var itself
     (jaxcfg._publish_cache_sysvar)."""
@@ -82,7 +82,7 @@ def _jax_cache_dir_default() -> str:
     jc = sys.modules.get("tidb_tpu.utils.jaxcfg")
     if jc is not None:
         return getattr(jc, "persistent_cache_dir", None) or ""
-    # jaxcfg not loaded yet: report the env intent; the publish hook
+    # jaxcfg not loaded yet: report the resolution; the publish hook
     # overwrites it with the configured outcome at jaxcfg import
     from ..utils import resolve_jax_cache_dir
     return resolve_jax_cache_dir()

@@ -4,8 +4,8 @@ The walk builds:
   * parent pointers (ancestor queries for "am I inside a guarded
     lambda / a `with lock:` block / a traced function");
   * an import alias table (`import jax`, `from ..utils import
-    device_guard`, `from ..utils.jaxcfg import compat_shard_map as
-    shard_map`) so rules match *resolved* dotted names, not spellings;
+    device_guard`, `from jax import shard_map`) so rules match
+    *resolved* dotted names, not spellings;
   * node indexes (calls, function defs, module-level assignments,
     global/nonlocal statements) so each rule iterates a pre-filtered
     list instead of re-walking the tree;

@@ -2,10 +2,10 @@
 device_guard.guarded_dispatch.
 
 PR 1's contract (utils/device_guard.py): invoking a compiled kernel is
-a remote call against an unreliable accelerator — grant loss, HBM
+a call against a device that can fail — a lost device connection, HBM
 exhaustion, wedged kernels. A naked invocation turns any of those into
 a statement error or a hung process instead of a supervised
-retry/degrade (the BENCH_TPU_SF10 q21 stall, BENCH_r05 q12 rc=124).
+retry/degrade.
 
 What counts as a jitted callable (per-file, alias-tracked):
   * `@jax.jit` / `@functools.partial(jax.jit, ...)` decorated defs;

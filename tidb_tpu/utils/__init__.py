@@ -79,14 +79,15 @@ _LRU_MISS = object()
 
 
 def resolve_jax_cache_dir() -> str:
-    """Persistent XLA compile-cache directory precedence (jax-import
-    free — shared by jaxcfg's setup and the sysvar registry so the two
-    resolutions can't drift): TIDB_TPU_JAX_CACHE_DIR, else
-    JAX_COMPILATION_CACHE_DIR, else ~/.cache/tidb_tpu/xla; '' means
-    explicitly disabled."""
-    d = os.environ.get("TIDB_TPU_JAX_CACHE_DIR")
-    if d is None:
-        d = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
-            os.path.join(os.path.expanduser("~"), ".cache", "tidb_tpu",
-                         "xla")
-    return d
+    """THE persistent XLA compile-cache directory (jax-import free —
+    shared by jaxcfg's setup and the sysvar registry so the two
+    resolutions can't drift): JAX_COMPILATION_CACHE_DIR where it is
+    set, else <checkout>/.cache/jax computed from this package's
+    location. The path is part of nothing process-local (no $HOME, pid
+    or time), so every process of one checkout shares one cache."""
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if d:
+        return d
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(checkout, ".cache", "jax")
