@@ -32,8 +32,11 @@ query must have dispatched to the device. Any failed check prints which
 and exits nonzero; a process-wide timer prints the statement in flight
 and exits nonzero, so the script cannot hang.
 
-The last stdout line is one JSON object. Its wall seconds are SMOKE
-TIMINGS (cold includes compiles) — not benchmark results.
+The last stdout line is exactly the object the driver reads,
+{"ok": true, "device": {"platform", "kind", "count"}}, the device as jax
+reports it. The line before it, `# smoke summary: {...}`, carries the
+queries run and the wall seconds for load, cold pass and warm pass:
+SMOKE TIMINGS (cold includes compiles) — not benchmark results.
 """
 import argparse
 import json
@@ -432,12 +435,13 @@ def run(jax, sf):
     print(f"# persistent compile cache: {xla}", flush=True)
     for k, v in counters.items():
         check(v == 0, f"{k} == {v}: a dispatch degraded ({counters})")
-    return {"ok": True, "device": device, "sf": sf,
-            "queries": list(QUERIES),
-            "smoke_timings_s": {"load": round(load_s, 2),
-                                "cold_pass": round(cold_s, 2),
-                                "warm_pass": round(warm_s, 2)},
-            "delta_applied": applied, "xla_cache": xla}
+    summary = {"sf": sf, "queries": list(QUERIES),
+               "smoke_timings_s": {"load": round(load_s, 2),
+                                   "cold_pass": round(cold_s, 2),
+                                   "warm_pass": round(warm_s, 2)},
+               "delta_applied": applied, "xla_cache": xla}
+    print(f"# smoke summary: {json.dumps(summary)}", flush=True)
+    return device
 
 
 def main(argv=None):
@@ -451,8 +455,9 @@ def main(argv=None):
     os.environ.setdefault("TIDB_TPU_JAX_CACHE_MIN_COMPILE_SECS", "0")
     arm_deadline()
     jax = require_tpu()
-    result = run(jax, args.sf)
-    print(json.dumps(result), flush=True)
+    device = run(jax, args.sf)
+    # the driver's contract: exactly these keys, and nothing after it
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

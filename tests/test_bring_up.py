@@ -151,9 +151,16 @@ def test_chip_smoke_passes_when_nothing_degrades(tmp_path):
              {"JAX_PLATFORMS": "cpu",
               "JAX_COMPILATION_CACHE_DIR": str(tmp_path)}, timeout=600)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-2000:]
-    last = json.loads(r.stdout.strip().splitlines()[-1])
-    assert last["ok"] is True and last["device"]["count"] == 1
-    assert last["delta_applied"] > 0
+    lines = r.stdout.strip().splitlines()
+    # the last line is the driver's contract: these keys and no others
+    assert json.loads(lines[-1]) == {
+        "ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    tag = "# smoke summary: "
+    assert lines[-2].startswith(tag)
+    summary = json.loads(lines[-2][len(tag):])
+    assert summary["delta_applied"] > 0
+    assert set(summary["smoke_timings_s"]) == {"load", "cold_pass",
+                                               "warm_pass"}
 
 
 def test_chip_smoke_fails_on_an_injected_device_degrade(tmp_path):
