@@ -54,6 +54,49 @@ def test_wire_error_and_sessions(server):
         c2.close()
 
 
+def test_wire_warning_count_rides_eof_and_ok(server):
+    """A statement's warning count is in the result set's EOF packets
+    and in the OK packet: a client sees a device degrade (9013) without
+    a SHOW WARNINGS round trip."""
+    from tidb_tpu.utils import device_guard, failpoint
+    c = MiniClient(server.port, db="test")
+
+    def eof_counts(sql):
+        """The warning counts of the two EOF packets of a result set."""
+        c.io.reset_seq()
+        c.io.write_packet(bytes([P.COM_QUERY]) + sql.encode())
+        counts = []
+        while len(counts) < 2:
+            pkt = c.io.read_packet()
+            assert pkt[0] != 0xFF, pkt
+            if pkt[0] == 0xFE and len(pkt) < 9:
+                counts.append(struct.unpack_from("<H", pkt, 1)[0])
+        return counts
+
+    try:
+        c.query("create table ww (a int primary key, b int, c int)")
+        r = c.query("insert into ww values " + ",".join(
+            f"({i}, {i % 7}, {i % 13})" for i in range(400)))
+        assert r["affected"] == 400 and c.warnings == 0  # OK packet
+        q = "select b, sum(c) from ww group by b order by b"
+        clean = c.query(q)
+        assert c.warnings == 0
+        failpoint.enable("device_guard/copr/agg", "error:compile")
+        try:
+            degraded = c.query(q)
+            assert c.warnings == 1
+            assert eof_counts(q) == [1, 1]
+        finally:
+            failpoint.disable_all()
+            device_guard.reset()
+        assert degraded["rows"] == clean["rows"]
+        shown = c.query("show warnings")["rows"]
+        assert [w[1] for w in shown] == ["9013"], shown
+        assert eof_counts(q) == [0, 0]                  # reset per statement
+    finally:
+        c.close()
+
+
 def test_status_port(server):
     import json
     import urllib.request

@@ -31,6 +31,7 @@ from ..utils import memory as _memory
 from ..utils import phase
 from ..utils import device_guard
 from ..utils import metrics as _metrics
+from ..utils import tracing as _tracing
 from ..errors import TiDBError
 from ..chunk.device import shape_bucket
 from ..chunk.column import Column
@@ -44,7 +45,7 @@ class _KernelCache(dict):
     coprocessor_cache.go metrics; surfaced per-operator by
     EXPLAIN ANALYZE's backend column). Every inserted kernel is
     wrapped with phase accounting (utils/phase.py): dispatch counts
-    and per-kind time feed the bench sidecar artifact."""
+    and enqueue time, and the `dispatch` span with the key's kind."""
 
     def __init__(self):
         super().__init__()
@@ -227,18 +228,20 @@ class CoprExecutor:
             tbl = self.engine.table(dag.table_info)
             if dag.table_info.id < 0:
                 read_ts = None              # session temp table: read latest
-            # incremental HTAP (copr/delta.py): fold committed deltas
-            # into the resident buffers FIRST — patched entries advance
-            # their version in place and survive the sweep below —
-            # then drop whatever is still stale (derived entries:
-            # validity masks, luts; and unpatchable buffers). Without
-            # the fold this sweep was a full drop-and-reupload per
-            # DML commit.
-            self.delta.refresh(tbl, ectx)
-            self._dev_store.invalidate(tbl.uid, tbl.version)
-        arrays, valid = tbl.snapshot(
-            [cid for cid in (self._cid(dag, sc) for sc in dag.cols)
-             if cid != -1], read_ts)
+        with phase.bind_span():
+            if dag.table_info.id > -1000:
+                # incremental HTAP (copr/delta.py): fold committed
+                # deltas into the resident buffers FIRST — patched
+                # entries advance their version in place and survive
+                # the sweep below — then drop whatever is still stale
+                # (derived entries: validity masks, luts; and
+                # unpatchable buffers). Without the fold this sweep was
+                # a full drop-and-reupload per DML commit.
+                self.delta.refresh(tbl, ectx)
+                self._dev_store.invalidate(tbl.uid, tbl.version)
+            arrays, valid = tbl.snapshot(
+                [cid for cid in (self._cid(dag, sc) for sc in dag.cols)
+                 if cid != -1], read_ts)
         n = len(valid)          # snapshot length, not live tbl.n
         if overlay:
             arrays, valid, n = self._apply_overlay(dag, tbl, arrays, valid,
@@ -294,8 +297,6 @@ class CoprExecutor:
                 # supervised mesh dispatch: retryable classes retry with
                 # backoff, anything else degrades to None so the
                 # single-chip path (which always works) takes over
-                from ..utils import tracing as _tracing
-                t_mpp = time.perf_counter()
                 with _tracing.span("mpp_dispatch",
                                    table=dag.table_info.name, rows=n):
                     res = device_guard.guarded_dispatch(
@@ -308,9 +309,6 @@ class CoprExecutor:
                         fallback_is_host=False)
                     if res is None:
                         _tracing.tag(degraded=1)
-                if res is not None:
-                    _metrics.MPP_DISPATCH_SECONDS.observe(
-                        time.perf_counter() - t_mpp)
             except TiDBError:
                 raise                       # kill/quota: statement error
             except Exception:               # noqa: BLE001
@@ -501,8 +499,9 @@ class CoprExecutor:
             sl = slice(start, min(start + step, n))
             m = sl.stop - sl.start
             cap = shape_bucket(m)
-            cols = self._bind_cols(dag, tbl, arrays, sl, handles,
-                                   cacheable=(n == tbl.n))
+            with phase.bind_span():
+                cols = self._bind_cols(dag, tbl, arrays, sl, handles,
+                                       cacheable=(n == tbl.n))
             v = valid[sl]
             if dag.aggs or dag.group_items:
                 res = device_guard.guarded_dispatch(
@@ -518,35 +517,33 @@ class CoprExecutor:
                     site="copr/topn", ectx=ectx, domain=dom,
                     host_fallback=lambda: self._topn_host(dag, cols, v,
                                                           m))
-                chunk_cols = []
-                for sc in dag.cols:
-                    data, nulls, sdict = cols[sc.col.idx]
-                    chunk_cols.append(Column(
-                        sc.col.ft, data[idx],
-                        None if nulls is None else nulls[idx], sdict))
-                out.append(Chunk(chunk_cols))
+                with _tracing.span("consume"):
+                    out.append(self._gather_chunk(dag, cols, idx))
                 continue
             mask = device_guard.guarded_dispatch(
                 lambda: self._run_filter_partition(dag, tbl, cols, v,
                                                    m, cap),
                 site="copr/filter", ectx=ectx, domain=dom)
-            idx = np.nonzero(np.asarray(mask)[:m])[0]
-            if dag.limit >= 0:
-                remain = dag.limit - produced
-                if remain <= 0:
-                    break
-                idx = idx[:remain]
-            produced += len(idx)
-            chunk_cols = []
-            for sc in dag.cols:
-                data, nulls, sdict = cols[sc.col.idx]
-                chunk_cols.append(Column(
-                    sc.col.ft, data[idx],
-                    None if nulls is None else nulls[idx], sdict))
-            out.append(Chunk(chunk_cols))
+            with _tracing.span("consume"):
+                idx = np.nonzero(np.asarray(mask)[:m])[0]
+                if dag.limit >= 0:
+                    remain = dag.limit - produced
+                    if remain <= 0:
+                        break
+                    idx = idx[:remain]
+                produced += len(idx)
+                out.append(self._gather_chunk(dag, cols, idx))
             if 0 <= dag.limit <= produced:
                 break
         return out
+
+    @staticmethod
+    def _gather_chunk(dag, cols, idx):
+        """The host rows `idx` of a partition's bound columns."""
+        return Chunk([Column(sc.col.ft, data[idx],
+                             None if nulls is None else nulls[idx], sdict)
+                      for sc in dag.cols
+                      for data, nulls, sdict in (cols[sc.col.idx],)])
 
     def _pad_upload(self, cols, v, m, cap, bind_keys=None):
         jcols = {}
@@ -725,34 +722,35 @@ class CoprExecutor:
         # priming the cache poisoned the outer query's columns)
         cid_of_idx = {sc.col.idx: self._cid(dag, sc) for sc in dag.cols}
         from .delta import append_key
-        args = []
-        has_nulls = {}
-        epoch = tbl.gc_epoch
-        for k in names:
-            data, nulls, sdict = cols[k]
-            cid = cid_of_idx.get(k, -1)
-            kind = "h" if cid == -1 else "d"
-            args.append(self._dev_put_append(
-                append_key(tbl.uid, "mppcol", cid, kind, epoch,
-                           (ndev,), padded),
-                data, n, padded, tbl.uid, tbl.version, epoch, 0, None,
-                mesh=mesh, spec="sharded"))
-            has_nulls[k] = nulls is not None
-            if nulls is not None:
+        with phase.bind_span():
+            args = []
+            has_nulls = {}
+            epoch = tbl.gc_epoch
+            for k in names:
+                data, nulls, sdict = cols[k]
+                cid = cid_of_idx.get(k, -1)
+                kind = "h" if cid == -1 else "d"
                 args.append(self._dev_put_append(
-                    append_key(tbl.uid, "mppcol", cid, "n", epoch,
+                    append_key(tbl.uid, "mppcol", cid, kind, epoch,
                                (ndev,), padded),
-                    nulls, n, padded, tbl.uid, tbl.version, epoch, 0,
-                    None, pad_fill=True, mesh=mesh, spec="sharded"))
-        # the MVCC validity mask is version+snapshot-keyed (same policy
-        # as _upload_dim's ts_keyed entries): within one (version,
-        # read_ts) it is immutable, so it stays resident too — the old
-        # raw device_put here was an uncounted warm re-upload per
-        # statement
-        args.append(self._dev_put_sharded(
-            (tbl.uid, "mppvalid", tbl.version, read_ts, ndev, padded),
-            valid[:n], mesh, padded, pad_fill=False, uid=tbl.uid,
-            version=tbl.version))
+                    data, n, padded, tbl.uid, tbl.version, epoch, 0, None,
+                    mesh=mesh, spec="sharded"))
+                has_nulls[k] = nulls is not None
+                if nulls is not None:
+                    args.append(self._dev_put_append(
+                        append_key(tbl.uid, "mppcol", cid, "n", epoch,
+                                   (ndev,), padded),
+                        nulls, n, padded, tbl.uid, tbl.version, epoch, 0,
+                        None, pad_fill=True, mesh=mesh, spec="sharded"))
+            # the MVCC validity mask is version+snapshot-keyed (same policy
+            # as _upload_dim's ts_keyed entries): within one (version,
+            # read_ts) it is immutable, so it stays resident too — the old
+            # raw device_put here was an uncounted warm re-upload per
+            # statement
+            args.append(self._dev_put_sharded(
+                (tbl.uid, "mppvalid", tbl.version, read_ts, ndev, padded),
+                valid[:n], mesh, padded, pad_fill=False, uid=tbl.uid,
+                version=tbl.version))
         key = self._cache_key(dag, tbl, "mpp", padded,
                               (tuple(strides), ndev,
                                tuple(sorted(has_nulls.items()))))
@@ -764,7 +762,8 @@ class CoprExecutor:
         res = kern(*args)
         from ..mpp.exec import exchange_observed, tree_nbytes
         exchange_observed("passthrough", tree_nbytes(res))
-        return [_compact_dense(dag, res, strides, kd, sd)]
+        with _tracing.span("consume"):
+            return [_compact_dense(dag, res, strides, kd, sd)]
 
     def _cache_key(self, dag, tbl, kind, cap, extra=()):
         dict_vers = tuple(sorted(
@@ -782,7 +781,7 @@ class CoprExecutor:
         sdicts = {k: c[2] for k, c in cols.items()}
         filters = list(dag.filters)
         if kern is None:
-            def _filter_body(jc, vv):
+            def tidb_filter(jc, vv):
                 full = {k: (d, nl, sdicts[k]) for k, (d, nl) in jc.items()}
                 ctx = EvalCtx(jnp, cap, full, host=False)
                 mask = vv
@@ -793,19 +792,22 @@ class CoprExecutor:
             # _pad_upload every call, never pooled): donate its HBM
             dn = jaxcfg.donation_argnums(1)
             kern = jaxcfg.guard_donation(
-                jax.jit(_filter_body, donate_argnums=dn), dn)
+                jax.jit(tidb_filter, donate_argnums=dn), dn)
             kern = self._kernel_cache.put(key, kern)
-        jcols, vv = self._pad_upload(cols, v, m, cap)
+        with phase.bind_span():
+            jcols, vv = self._pad_upload(cols, v, m, cap)
         jc = {k: (d, nl) for k, (d, nl, _) in jcols.items()}
-        mask = host_array(prefetch(kern(jc, vv)))
-        # host-only filters applied on host afterwards
-        if dag.host_filters:
-            ctx = EvalCtx(np, m, cols, host=True)
-            hm = mask[:m].copy()
-            for f in dag.host_filters:
-                hm &= np.asarray(eval_bool_mask(ctx, f))
-            return hm
-        return mask
+        res = prefetch(kern(jc, vv))
+        with _tracing.span("consume"):
+            mask = host_array(res)
+            # host-only filters applied on host afterwards
+            if dag.host_filters:
+                ctx = EvalCtx(np, m, cols, host=True)
+                hm = mask[:m].copy()
+                for f in dag.host_filters:
+                    hm &= np.asarray(eval_bool_mask(ctx, f))
+                return hm
+            return mask
 
     def _run_topn_partition(self, dag, tbl, cols, v, m, cap):
         """Fused filter + device top-k over the single sort key; returns
@@ -821,7 +823,7 @@ class CoprExecutor:
         if kern is None:
             filters = list(dag.filters)
 
-            def _topn_body(jc, vv):
+            def tidb_topn(jc, vv):
                 full = {kk: (d, nl, sdicts[kk]) for kk, (d, nl) in jc.items()}
                 ctx = EvalCtx(jnp, cap, full, host=False)
                 mask = vv
@@ -850,20 +852,28 @@ class CoprExecutor:
                 return top_idx, cnt
             dn = jaxcfg.donation_argnums(1)
             kern = jaxcfg.guard_donation(
-                jax.jit(_topn_body, donate_argnums=dn), dn)
+                jax.jit(tidb_topn, donate_argnums=dn), dn)
             kern = self._kernel_cache.put(key, kern)
-        jcols, vv = self._pad_upload(cols, v, m, cap)
-        jc = {kk: (d, nl) for kk, (d, nl, _) in jcols.items()}
-        if dag.host_filters:
-            ctx = EvalCtx(np, m, cols, host=True)
-            hm = np.ones(m, dtype=bool)
-            for f in dag.host_filters:
-                hm &= np.asarray(eval_bool_mask(ctx, f))
-            hmp = np.concatenate([hm, np.zeros(cap - m, dtype=bool)]) \
-                if m != cap else hm
-            vv = vv & jnp.asarray(hmp)
+        with phase.bind_span():
+            jcols, vv = self._pad_upload(cols, v, m, cap)
+            jc = {kk: (d, nl) for kk, (d, nl, _) in jcols.items()}
+            vv = self._and_host_filters(dag, cols, vv, m, cap)
         top_idx, cnt = prefetch(kern(jc, vv))
-        return host_array(top_idx)[:host_int(cnt)]
+        with _tracing.span("consume"):
+            return host_array(top_idx)[:host_int(cnt)]
+
+    @staticmethod
+    def _and_host_filters(dag, cols, vv, m, cap):
+        """The uploaded validity mask AND the host-only filters."""
+        if not dag.host_filters:
+            return vv
+        ctx = EvalCtx(np, m, cols, host=True)
+        hm = np.ones(m, dtype=bool)
+        for f in dag.host_filters:
+            hm &= np.asarray(eval_bool_mask(ctx, f))
+        hmp = np.concatenate([hm, np.zeros(cap - m, dtype=bool)]) \
+            if m != cap else hm
+        return vv & jnp.asarray(hmp)
 
     def _topn_host(self, dag, cols, v, m):
         (expr, desc), k = dag.topn
@@ -910,6 +920,7 @@ class CoprExecutor:
                  tuple(a.fingerprint() for a in dag.aggs))
         group_bucket = max(group_bucket, self._host_cache.get(gbkey, 0))
         impl_key = ("aggimpl",) + gbkey
+        retries = 0     # re-dispatches the learned lowering forced
         while True:
             impl = self._host_cache.get(impl_key) or _segment_impl()
             kd, sd = capture_agg_dicts(dag, cols)
@@ -937,40 +948,39 @@ class CoprExecutor:
                     kern = _build_agg_kernel(dag, cols, cap, group_bucket,
                                              impl)
                     kern = self._kernel_cache.put(key, kern)
-            jcols, vv = self._pad_upload(cols, v, m, cap)
-            jc = {k: (d, nl) for k, (d, nl, _) in jcols.items()}
-            if dag.host_filters:
-                ctx = EvalCtx(np, m, cols, host=True)
-                hm = np.ones(m, dtype=bool)
-                for f in dag.host_filters:
-                    hm &= np.asarray(eval_bool_mask(ctx, f))
-                hmp = np.concatenate([hm, np.zeros(cap - m, dtype=bool)]) \
-                    if m != cap else hm
-                vv = vv & jnp.asarray(hmp)
+            with phase.bind_span():
+                jcols, vv = self._pad_upload(cols, v, m, cap)
+                jc = {k: (d, nl) for k, (d, nl, _) in jcols.items()}
+                vv = self._and_host_filters(dag, cols, vv, m, cap)
             res = prefetch(kern(jc, vv))
-            if strides is not None:
-                return _compact_dense(dag, res, strides, kd, sd)
-            ngroups = host_int(res["ngroups"])
-            if impl == "runs" and ngroups > max(_RUNS_DEGRADE_MIN, m // 4):
-                # keys uncorrelated with storage order: runs exploded
-                # into ~per-row partials. Pin this (table, group, agg)
-                # shape to the sorted lowering (one partial per group)
-                # before the regrow loop learns the inflated bucket.
-                self._host_cache[impl_key] = "sorted"
-                continue
-            if ngroups > group_bucket:
-                group_bucket = shape_bucket(ngroups)
-                self._host_cache[gbkey] = group_bucket
-                continue
-            return PartialAggResult(
-                ngroups=ngroups,
-                keys=[host_array(k)[:ngroups] for k in res["keys"]],
-                key_nulls=[host_array(kn)[:ngroups]
-                           for kn in res["key_nulls"]],
-                states=[[host_array(s)[:ngroups] for s in st]
-                        for st in res["states"]],
-                key_dicts=kd, state_dicts=sd,
-            )
+            with _tracing.span("consume", retries=retries):
+                if strides is not None:
+                    return _compact_dense(dag, res, strides, kd, sd)
+                ngroups = host_int(res["ngroups"])
+                if impl == "runs" and \
+                        ngroups > max(_RUNS_DEGRADE_MIN, m // 4):
+                    # keys uncorrelated with storage order: runs
+                    # exploded into ~per-row partials. Pin this (table,
+                    # group, agg) shape to the sorted lowering (one
+                    # partial per group) before the regrow loop learns
+                    # the inflated bucket.
+                    self._host_cache[impl_key] = "sorted"
+                    retries += 1
+                    continue
+                if ngroups > group_bucket:
+                    group_bucket = shape_bucket(ngroups)
+                    self._host_cache[gbkey] = group_bucket
+                    retries += 1
+                    continue
+                return PartialAggResult(
+                    ngroups=ngroups,
+                    keys=[host_array(k)[:ngroups] for k in res["keys"]],
+                    key_nulls=[host_array(kn)[:ngroups]
+                               for kn in res["key_nulls"]],
+                    states=[[host_array(s)[:ngroups] for s in st]
+                            for st in res["states"]],
+                    key_dicts=kd, state_dicts=sd,
+                )
 
 
 class PartialAggResult:
@@ -1666,7 +1676,7 @@ def _build_dense_agg_kernel(dag, sample_cols, cap, sizes):
     group_items = list(dag.group_items)
     aggs = list(dag.aggs)
 
-    def _dense_body(jc, vv):
+    def tidb_agg_dense(jc, vv):
         full = {k: (d, nl, sdicts[k]) for k, (d, nl) in jc.items()}
         ctx = EvalCtx(jnp, cap, full, host=False)
         mask = vv
@@ -1675,7 +1685,7 @@ def _build_dense_agg_kernel(dag, sample_cols, cap, sizes):
         return dense_agg_body(ctx, mask, group_items, aggs, sizes, cap)
     dn = jaxcfg.donation_argnums(1)
     return jaxcfg.guard_donation(
-        jax.jit(_dense_body, donate_argnums=dn), dn)
+        jax.jit(tidb_agg_dense, donate_argnums=dn), dn)
 
 
 def _psum_first(lv, lc, axis):
@@ -1736,7 +1746,7 @@ def _build_dense_agg_kernel_mpp(dag, sample_cols, local_cap, sizes, mesh,
     for s, _off in sizes:
         nslots *= s
 
-    def frag(*flat):
+    def tidb_mpp_agg_dense(*flat):
         cols = {}
         i = 0
         for k in names:
@@ -1767,7 +1777,7 @@ def _build_dense_agg_kernel_mpp(dag, sample_cols, local_cap, sizes, mesh,
         return psum_dense_result(local, aggs, "dp")
 
     nargs = sum(1 + (1 if has_nulls[k] else 0) for k in names) + 1
-    fn = shard_map(frag, mesh=mesh,
+    fn = shard_map(tidb_mpp_agg_dense, mesh=mesh,
                    in_specs=tuple(P("dp") for _ in range(nargs)),
                    out_specs={"present": P(),
                               "states": [[P() for _ in range(
@@ -1815,7 +1825,7 @@ def _build_agg_kernel(dag, sample_cols, cap, group_bucket, impl=None):
     group_items = list(dag.group_items)
     aggs = list(dag.aggs)
 
-    def _agg_body(jc, vv):
+    def tidb_agg_sort(jc, vv):
         full = {k: (d, nl, sdicts[k]) for k, (d, nl) in jc.items()}
         ctx = EvalCtx(jnp, cap, full, host=False)
         mask = vv
@@ -1825,7 +1835,7 @@ def _build_agg_kernel(dag, sample_cols, cap, group_bucket, impl=None):
                              group_bucket, impl=impl)
     dn = jaxcfg.donation_argnums(1)
     return jaxcfg.guard_donation(
-        jax.jit(_agg_body, donate_argnums=dn), dn)
+        jax.jit(tidb_agg_sort, donate_argnums=dn), dn)
 
 
 def sort_agg_body(ctx, mask, group_items, aggs, cap, group_bucket,

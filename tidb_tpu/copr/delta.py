@@ -91,13 +91,13 @@ def _build_fold_kernel(out_shardings=None):
     matching the output tuple) pins mesh placement for sharded/
     replicated groups."""
 
-    def fold(bufs, upds, offs):
+    def tidb_delta_fold(bufs, upds, offs):
         return tuple(jax.lax.dynamic_update_slice(b, u, (o,))
                      for b, u, o in zip(bufs, upds, offs))
 
     if out_shardings is not None:
-        return jax.jit(fold, out_shardings=out_shardings)
-    return jax.jit(fold)
+        return jax.jit(tidb_delta_fold, out_shardings=out_shardings)
+    return jax.jit(tidb_delta_fold)
 
 
 class DeltaMaintainer:

@@ -36,6 +36,8 @@ from ..utils.fetch import prefetch, host_array, host_int
 from ..utils import failpoint
 from ..utils import jaxcfg
 from ..utils import memory as _memory
+from ..utils import phase
+from ..utils import tracing as _tracing
 
 _POS_DENSE_MAX = 1 << 22
 
@@ -602,10 +604,13 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None):
     pre = bool(meta.get("pre"))
     args = {"cols": {}}
     if meta.get("pack") is not None:
+        # small host values ride the kernel call as numpy operands:
+        # jnp.asarray of a scalar or a list is a device program of its
+        # own (`jit_convert_element_type`) on every statement
         los, spans, strides = meta["pack"]
-        args["plo"] = jnp.asarray(los, dtype=jnp.int64)
-        args["pspan"] = jnp.asarray(spans, dtype=jnp.int64)
-        args["pstride"] = jnp.asarray(strides, dtype=jnp.int64)
+        args["plo"] = np.asarray(los, dtype=np.int64)
+        args["pspan"] = np.asarray(spans, dtype=np.int64)
+        args["pstride"] = np.asarray(strides, dtype=np.int64)
     if not pre:
         # prefiltered semi dims fold visibility+filters into the lut at
         # meta time; the kernel never reads valid/cols for them — don't
@@ -616,7 +621,7 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None):
         lcap = shape_bucket(len(meta["lut"]))
         args["lut"] = put("lut", meta["lut"], len(meta["lut"]), lcap,
                           fill=n, ts_keyed=True)
-        args["lo"] = jnp.asarray(meta["lo"], dtype=jnp.int64)
+        args["lo"] = np.asarray(meta["lo"], dtype=np.int64)
     else:
         ns = meta["n_sorted"]
         scap = shape_bucket(ns)
@@ -868,130 +873,142 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
         cols = {k: (d, nl, fact_sdicts[k]) for k, (d, nl) in fjc.items()}
         ctx = EvalCtx(jnp, cap, cols, host=False)
         mask = fvv
-        for f in fact_filters:
-            mask = mask & eval_bool_mask(ctx, f)
+        # the stages' named scopes cost nothing at run time: they put
+        # the stage into each HLO instruction's metadata, for whoever
+        # reads a profile of a `jit_tidb_fused_<kind>` program
+        with jax.named_scope("scan_filter"):
+            for f in fact_filters:
+                mask = mask & eval_bool_mask(ctx, f)
         if ecap is not None:
-            csum0 = jnp.cumsum(mask.astype(jnp.int64))
-            fnvalid = csum0[cap - 1]
-            src = jnp.searchsorted(
-                csum0, jnp.arange(1, ecap + 1, dtype=jnp.int64))
-            src = jnp.minimum(src, cap - 1)
-            cols = {k: (d[src], None if nl is None else nl[src], sd)
-                    for k, (d, nl, sd) in cols.items()}
-            cap = ecap
-            mask = jnp.arange(ecap, dtype=jnp.int64) < fnvalid
-            ctx = EvalCtx(jnp, cap, cols, host=False)
+            with jax.named_scope("compact"):
+                csum0 = jnp.cumsum(mask.astype(jnp.int64))
+                fnvalid = csum0[cap - 1]
+                src = jnp.searchsorted(
+                    csum0, jnp.arange(1, ecap + 1, dtype=jnp.int64))
+                src = jnp.minimum(src, cap - 1)
+                cols = {k: (d[src], None if nl is None else nl[src], sd)
+                        for k, (d, nl, sd) in cols.items()}
+                cap = ecap
+                mask = jnp.arange(ecap, dtype=jnp.int64) < fnvalid
+                ctx = EvalCtx(jnp, cap, cols, host=False)
         elif want_fnvalid:
             fnvalid = jnp.sum(mask.astype(jnp.int64))
         dim_pos = {}
         for dim_i, (dim, da, dcap, dn, dsn, layout) in enumerate(
                 zip(dims, dargs, dim_caps, dim_ns, dim_sns, dim_layouts)):
-            pre = bool(dim_pres[dim_i]) if dim_i < len(dim_pres) else False
-            if pre:
-                dmask = None       # filters/visibility folded at meta
-                                   # time (prefiltered semi dims)
-            else:
-                dcols = {}
-                for idx, (jd, jn) in da["cols"].items():
-                    dcols[idx] = (jd, jn, layout[idx][1])
-                dctx = EvalCtx(jnp, dcap, dcols, host=False)
-                dmask = da["valid"]
-                for f in dim.dag.filters:
-                    dmask = dmask & eval_bool_mask(dctx, f)
-            if dim.extra_keys:
-                # composite key: pack probes with the build-side layout;
-                # out-of-range components force a miss (a clipped index
-                # could otherwise alias a live packed key)
-                pv = jnp.zeros(cap, dtype=jnp.int64)
-                pnm = jnp.zeros(cap, dtype=bool)
-                inb_pack = jnp.ones(cap, dtype=bool)
-                for ki, (_, pe) in enumerate(dim.all_keys()):
-                    v, nl, _ = eval_expr(ctx, pe)
-                    if np.isscalar(v) or getattr(v, "ndim", 1) == 0:
-                        v = jnp.full(cap, v)
-                    v = v.astype(jnp.int64)
-                    pnm = pnm | materialize_nulls(ctx, nl)
-                    idx = v - da["plo"][ki]
-                    inb_pack = inb_pack & (idx >= 0) & \
-                        (idx < da["pspan"][ki])
-                    idx = jnp.clip(idx, 0, da["pspan"][ki] - 1)
-                    pv = pv + idx * da["pstride"][ki]
-                pnm = pnm | ~inb_pack
-            else:
-                pv, pnl, _ = eval_expr(ctx, dim.probe_expr)
-                if np.isscalar(pv) or getattr(pv, "ndim", 1) == 0:
-                    pv = jnp.full(cap, pv)
-                pv = pv.astype(jnp.int64)
-                pnm = materialize_nulls(ctx, pnl)
-            if "lut" in da:
-                # dense key domain: the join is ONE gather
-                lsize = da["lut"].shape[0]
-                idx = pv - da["lo"]
-                inb = (idx >= 0) & (idx < lsize)
-                pos = da["lut"][jnp.clip(idx, 0, lsize - 1)]
-                pos = jnp.minimum(pos, dcap - 1)
-                hit = inb & (da["lut"][jnp.clip(idx, 0, lsize - 1)] < dn) \
-                    & ~pnm
-                if dmask is not None:
-                    hit = hit & dmask[pos]
-            else:
-                scap = da["sk"].shape[0]
-                loc = jnp.searchsorted(da["sk"], pv)
-                locc = jnp.minimum(loc, scap - 1)
-                pos = da["ord"][locc]
-                hit = (da["sk"][locc] == pv) & ~pnm & (loc < dsn)
-                if dmask is not None:
-                    hit = hit & dmask[pos]
-            if dim.join_type == "left":
-                # preserved side: misses keep the row, payload is NULL
-                for idx, (jd, jn) in da["cols"].items():
-                    g = jd[pos]
-                    gn = ~hit if jn is None else (~hit | jn[pos])
-                    cols[idx] = (g, gn, layout[idx][1])
-            elif dim.join_type == "anti":
-                # NOT EXISTS: keep only rows with NO match (NULL probe
-                # keys never match, so they survive — EXISTS-derived
-                # anti semantics; null-aware NOT IN never plans here)
-                mask = mask & ~hit
-            else:
-                mask = mask & hit
-                if dim.join_type != "semi":
+            with jax.named_scope("dim_probe"):
+                pre = bool(dim_pres[dim_i]) if dim_i < len(dim_pres) else False
+                if pre:
+                    dmask = None       # filters/visibility folded at meta
+                                       # time (prefiltered semi dims)
+                else:
+                    dcols = {}
+                    for idx, (jd, jn) in da["cols"].items():
+                        dcols[idx] = (jd, jn, layout[idx][1])
+                    dctx = EvalCtx(jnp, dcap, dcols, host=False)
+                    dmask = da["valid"]
+                    for f in dim.dag.filters:
+                        dmask = dmask & eval_bool_mask(dctx, f)
+                if dim.extra_keys:
+                    # composite key: pack probes with the build-side layout;
+                    # out-of-range components force a miss (a clipped index
+                    # could otherwise alias a live packed key)
+                    pv = jnp.zeros(cap, dtype=jnp.int64)
+                    pnm = jnp.zeros(cap, dtype=bool)
+                    inb_pack = jnp.ones(cap, dtype=bool)
+                    for ki, (_, pe) in enumerate(dim.all_keys()):
+                        v, nl, _ = eval_expr(ctx, pe)
+                        if np.isscalar(v) or getattr(v, "ndim", 1) == 0:
+                            v = jnp.full(cap, v)
+                        v = v.astype(jnp.int64)
+                        pnm = pnm | materialize_nulls(ctx, nl)
+                        idx = v - da["plo"][ki]
+                        inb_pack = inb_pack & (idx >= 0) & \
+                            (idx < da["pspan"][ki])
+                        idx = jnp.clip(idx, 0, da["pspan"][ki] - 1)
+                        pv = pv + idx * da["pstride"][ki]
+                    pnm = pnm | ~inb_pack
+                else:
+                    pv, pnl, _ = eval_expr(ctx, dim.probe_expr)
+                    if np.isscalar(pv) or getattr(pv, "ndim", 1) == 0:
+                        pv = jnp.full(cap, pv)
+                    pv = pv.astype(jnp.int64)
+                    pnm = materialize_nulls(ctx, pnl)
+                if "lut" in da:
+                    # dense key domain: the join is ONE gather
+                    lsize = da["lut"].shape[0]
+                    idx = pv - da["lo"]
+                    inb = (idx >= 0) & (idx < lsize)
+                    pos = da["lut"][jnp.clip(idx, 0, lsize - 1)]
+                    pos = jnp.minimum(pos, dcap - 1)
+                    hit = inb & (da["lut"][jnp.clip(idx, 0, lsize - 1)] < dn) \
+                        & ~pnm
+                    if dmask is not None:
+                        hit = hit & dmask[pos]
+                else:
+                    scap = da["sk"].shape[0]
+                    loc = jnp.searchsorted(da["sk"], pv)
+                    locc = jnp.minimum(loc, scap - 1)
+                    pos = da["ord"][locc]
+                    hit = (da["sk"][locc] == pv) & ~pnm & (loc < dsn)
+                    if dmask is not None:
+                        hit = hit & dmask[pos]
+                if dim.join_type == "left":
+                    # preserved side: misses keep the row, payload is NULL
                     for idx, (jd, jn) in da["cols"].items():
                         g = jd[pos]
-                        gn = jn[pos] if jn is not None else None
+                        gn = ~hit if jn is None else (~hit | jn[pos])
                         cols[idx] = (g, gn, layout[idx][1])
-            dim_pos[dim_i] = jnp.minimum(pos, dn - 1)
-            ctx = EvalCtx(jnp, cap, cols, host=False)
-        for f in post:
-            mask = mask & eval_bool_mask(ctx, f)
+                elif dim.join_type == "anti":
+                    # NOT EXISTS: keep only rows with NO match (NULL probe
+                    # keys never match, so they survive — EXISTS-derived
+                    # anti semantics; null-aware NOT IN never plans here)
+                    mask = mask & ~hit
+                else:
+                    mask = mask & hit
+                    if dim.join_type != "semi":
+                        for idx, (jd, jn) in da["cols"].items():
+                            g = jd[pos]
+                            gn = jn[pos] if jn is not None else None
+                            cols[idx] = (g, gn, layout[idx][1])
+                dim_pos[dim_i] = jnp.minimum(pos, dn - 1)
+                ctx = EvalCtx(jnp, cap, cols, host=False)
+        with jax.named_scope("scan_filter"):
+            for f in post:
+                mask = mask & eval_bool_mask(ctx, f)
         if agg_kind == "posdense":
             pos_dims, nslots = agg_param
-            slot = jnp.zeros(cap, dtype=jnp.int64)
-            for di in pos_dims:
-                slot = slot * dim_ns[di] + dim_pos[di]
-            slot = jnp.where(mask, slot, nslots)
-            res = dense_agg_states(ctx, mask, aggs, slot, nslots, cap)
+            with jax.named_scope("group_agg"):
+                slot = jnp.zeros(cap, dtype=jnp.int64)
+                for di in pos_dims:
+                    slot = slot * dim_ns[di] + dim_pos[di]
+                slot = jnp.where(mask, slot, nslots)
+                res = dense_agg_states(ctx, mask, aggs, slot, nslots,
+                                       cap)
             if ecap is not None or want_fnvalid:
                 res["fnvalid"] = fnvalid
             return res
         if agg_kind == "dense":
-            res = dense_agg_body(ctx, mask, group_items, aggs, agg_param,
-                                 cap)
+            with jax.named_scope("group_agg"):
+                res = dense_agg_body(ctx, mask, group_items, aggs,
+                                     agg_param, cap)
             if ecap is not None or want_fnvalid:
                 res["fnvalid"] = fnvalid
             return res
         if agg_kind == "onehot":
             (scap_oh,) = agg_param
             sargs = dargs[len(dims)]
-            res = _de.onehot_agg_body(ctx, mask, group_items, aggs,
-                                      cap, scap_oh, sargs)
-            res["nvalid"] = jnp.sum(mask.astype(jnp.int64))
+            with jax.named_scope("group_agg"):
+                res = _de.onehot_agg_body(ctx, mask, group_items, aggs,
+                                          cap, scap_oh, sargs)
+                res["nvalid"] = jnp.sum(mask.astype(jnp.int64))
             if ecap is not None or want_fnvalid:
                 res["fnvalid"] = fnvalid
             return res
         gb, agg_impl, topn, ccap = agg_param
-        csum = jnp.cumsum(mask.astype(jnp.int64))
-        nvalid = csum[cap - 1]
+        with jax.named_scope("compact"):
+            csum = jnp.cumsum(mask.astype(jnp.int64))
+            nvalid = csum[cap - 1]
         if ccap is not None:
             # compact-then-aggregate (selective pipelines, the
             # Q18/Q21 class): the sort-based agg pays O(cap log cap)
@@ -1002,23 +1019,27 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
             # policy) — and aggregate that. The caller verifies
             # nvalid <= ccap (an overflow regrows the bucket and
             # reruns, the group_bucket retry pattern).
-            src = jnp.searchsorted(
-                csum, jnp.arange(1, ccap + 1, dtype=jnp.int64))
-            src = jnp.minimum(src, cap - 1)
-            ok = jnp.arange(ccap, dtype=jnp.int64) < nvalid
-            ccols = {}
-            for cidx, (d, nl, sd) in cols.items():
-                ccols[cidx] = (d[src],
-                               None if nl is None else nl[src], sd)
-            cctx = EvalCtx(jnp, ccap, ccols, host=False)
-            res = sort_agg_body(cctx, ok, group_items, aggs, ccap, gb,
-                                impl=agg_impl)
+            with jax.named_scope("compact"):
+                src = jnp.searchsorted(
+                    csum, jnp.arange(1, ccap + 1, dtype=jnp.int64))
+                src = jnp.minimum(src, cap - 1)
+                ok = jnp.arange(ccap, dtype=jnp.int64) < nvalid
+                ccols = {}
+                for cidx, (d, nl, sd) in cols.items():
+                    ccols[cidx] = (d[src],
+                                   None if nl is None else nl[src], sd)
+                cctx = EvalCtx(jnp, ccap, ccols, host=False)
+            with jax.named_scope("group_agg"):
+                res = sort_agg_body(cctx, ok, group_items, aggs, ccap,
+                                    gb, impl=agg_impl)
         else:
-            res = sort_agg_body(ctx, mask, group_items, aggs, cap,
-                                gb, impl=agg_impl)
+            with jax.named_scope("group_agg"):
+                res = sort_agg_body(ctx, mask, group_items, aggs, cap,
+                                    gb, impl=agg_impl)
         res["nvalid"] = nvalid
         if topn is not None:
-            res = _topn_select(res, aggs, topn, gb)
+            with jax.named_scope("topn"):
+                res = _topn_select(res, aggs, topn, gb)
         if ecap is not None or want_fnvalid:
             res["fnvalid"] = fnvalid
         return res
@@ -1036,6 +1057,7 @@ def _build_fused_kernel(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
     # _pad_upload every call; dim args and fact columns ride the
     # resident pool and must never be donated
     dn = jaxcfg.donation_argnums(1)
+    jaxcfg.name_program(body, "fused_" + agg_kind)
     return jaxcfg.guard_donation(jax.jit(body, donate_argnums=dn), dn)
 
 
@@ -1071,6 +1093,7 @@ def _build_fused_kernel_mpp(plan, local_cap, fact_sdicts, dim_caps,
         out_spec = P()
     else:
         out_spec = P("dp")
+    jaxcfg.name_program(frag, "mpp_fused_" + agg_kind)
     fn = shard_map(frag, mesh=mesh, in_specs=(P("dp"), P("dp"), P()),
                    out_specs=out_spec, check_vma=False)
     return jax.jit(fn)
@@ -1370,9 +1393,12 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         _memory.set_current(prev)
 
 
-def _fused_partials_inner(copr, plan, read_ts, mesh=None,
-                          bcast_threshold=1 << 20, ctx=None,
-                          delta_rows=None, dead_handles=None):
+def _bind_tables(copr, plan, read_ts, ctx):
+    """The statement's first `bind`: fold committed deltas into the
+    resident buffers of the fact table and every dimension, build or
+    find each dimension's metadata, snapshot the fact columns.
+    -> (fact_tbl, dim_metas, fact_version, fact_arrays, fact_valid), or
+    the answer itself ([] for no rows, None for runtime-ineligible)."""
     engine = copr.engine
     fact_tbl = engine.table(plan.fact_dag.table_info)
     # incremental HTAP: fold committed deltas into resident buffers
@@ -1428,6 +1454,17 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
         [cid for cid in (_cid_of(plan.fact_dag, sc)
                          for sc in plan.fact_dag.cols) if cid != -1],
         read_ts)
+    return fact_tbl, dim_metas, fact_version, fact_arrays, fact_valid
+
+
+def _fused_partials_inner(copr, plan, read_ts, mesh=None,
+                          bcast_threshold=1 << 20, ctx=None,
+                          delta_rows=None, dead_handles=None):
+    with phase.bind_span():
+        bound = _bind_tables(copr, plan, read_ts, ctx)
+    if not isinstance(bound, tuple):
+        return bound
+    fact_tbl, dim_metas, fact_version, fact_arrays, fact_valid = bound
     n = len(fact_valid)
     if n == 0 and not delta_rows:
         return []
@@ -1453,14 +1490,15 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
 
     # upload dims once (shared across fact partitions)
     dim_args, dim_layouts, dim_caps, dim_ns, dim_sns = [], [], [], [], []
-    for dim, meta in zip(plan.dims, dim_metas):
-        dcap = shape_bucket(meta["n"])
-        da, layout = _upload_dim(copr, dim, meta, dcap, read_ts, mesh)
-        dim_args.append(da)
-        dim_layouts.append(layout)
-        dim_caps.append(dcap)
-        dim_ns.append(meta["n"])
-        dim_sns.append(meta["n_sorted"])
+    with phase.bind_span() if plan.dims else _tracing.NO_SPAN:
+        for dim, meta in zip(plan.dims, dim_metas):
+            dcap = shape_bucket(meta["n"])
+            da, layout = _upload_dim(copr, dim, meta, dcap, read_ts, mesh)
+            dim_args.append(da)
+            dim_layouts.append(layout)
+            dim_caps.append(dcap)
+            dim_ns.append(meta["n"])
+            dim_sns.append(meta["n_sorted"])
     dim_pres = tuple(bool(m.get("pre")) for m in dim_metas)
 
     # 1-row host ctx over ALL pipeline columns: learn output dicts and
@@ -1599,9 +1637,10 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
         for start in range(0, n, step):
             sl = slice(start, min(start + step, n))
             pm = sl.stop - sl.start
-            pcols = copr._bind_cols(plan.fact_dag, fact_tbl, fact_arrays,
-                                    sl, handles,
-                                    cacheable=(n == fact_tbl.n))
+            with phase.bind_span():
+                pcols = copr._bind_cols(plan.fact_dag, fact_tbl,
+                                        fact_arrays, sl, handles,
+                                        cacheable=(n == fact_tbl.n))
             # capture this partition's device-cache keys: the pipelined
             # loop dispatches the NEXT partition (overwriting
             # copr._bind_keys) before this one's consume-time retries
@@ -1676,8 +1715,9 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
                 tuple(dim_ns), tuple(dim_sns), tuple(dim_layouts),
                 agg_kind, agg_param, dim_pres, ecap=ecap)
             kern = copr._kernel_cache.put(key, kern)
-        fjc_full, fvv = copr._pad_upload(cols, v, m, cap,
-                                         bind_keys=bind_keys)
+        with phase.bind_span():
+            fjc_full, fvv = copr._pad_upload(cols, v, m, cap,
+                                             bind_keys=bind_keys)
         fjc = {k: (d, nl) for k, (d, nl, _) in fjc_full.items()}
         kargs = dim_args
         oh_table = None
@@ -1708,8 +1748,20 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
         return res, cap, agg_kind, agg_param, ecap, oh_table
 
     def _consume_part(state, cols, v, m, bind_keys):
+        """Validate one partition's run against the learned lowering
+        parameters and turn it into host partials. A policy that says
+        "retry" re-dispatches this partition inside the span: its
+        `bind`/`dispatch` children are the cost of the retry."""
+        with _tracing.span("consume") as sp:
+            retries = _consume_until_valid(state, cols, v, m, bind_keys)
+            if sp is not None:
+                sp.attrs["retries"] = retries
+
+    def _consume_until_valid(state, cols, v, m, bind_keys):
         nonlocal group_bucket
+        retries = -1
         while True:
+            retries += 1
             res, cap, agg_kind, agg_param, ecap, oh_table = state
             # early-compaction policy: learn the survivor bucket on
             # first sight, regrow + rerun on overflow (fnvalid is the
@@ -1722,10 +1774,10 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
             if pos_spec is not None:
                 out.append(_compact_pos_dense(plan, res, pos_spec[0],
                                               pos_spec[1], dim_metas, sd))
-                return
+                return retries
             if sizes is not None:
                 out.append(_compact_dense(shim, res, sizes, kd, sd))
-                return
+                return retries
             if agg_kind == "onehot":
                 if host_int(res["miss"]) > 0:
                     # new/changed keys since the table was learned:
@@ -1747,7 +1799,7 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
                     keys=[k.copy() for k in OH["key_vals"]],
                     key_nulls=[kn.copy() for kn in OH["key_nulls"]],
                     states=states, key_dicts=kd, state_dicts=sd))
-                return
+                return retries
             ngroups = host_int(res["ngroups"])
             if _compact_policy(copr, compk, agg_param[3],
                                host_int(res["nvalid"]), cap) == "retry":
@@ -1802,7 +1854,7 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
                 out.append(PartialAggResult(
                     ngroups=ncand, keys=ckeys, key_nulls=cnulls,
                     states=cstates, key_dicts=kd, state_dicts=sd))
-                return
+                return retries
             ks = [host_array(k)[:ngroups] for k in res["keys"]]
             kns = [host_array(kn)[:ngroups] for kn in res["key_nulls"]]
             sts = [[host_array(s)[:ngroups] for s in st]
@@ -1824,7 +1876,7 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
             out.append(PartialAggResult(
                 ngroups=ngroups, keys=ks, key_nulls=kns, states=sts,
                 key_dicts=kd, state_dicts=sd))
-            return
+            return retries
 
     # partition pipelining: partition i+1's padding/upload/dispatch is
     # issued BEFORE partition i's results are consumed, so the fixed
@@ -2026,36 +2078,38 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
     # on-mesh instead of re-keying every `lane` rows (copr/delta.py)
     padded = ((shape_bucket(n) + lane - 1) // lane) * lane
     local = padded // ndev
-    cols = copr._bind_cols(plan.fact_dag, fact_tbl, fact_arrays,
-                           slice(0, n), handles)
-    fjc = {}
-    ver = fact_tbl.version
-    epoch = fact_tbl.gc_epoch
-    for sc in plan.fact_dag.cols:
-        cid = _cid_of(plan.fact_dag, sc)
-        data, nulls, _sd = cols[sc.col.idx]
-        jd = copr._dev_put_append(
-            append_key(fact_tbl.uid, "mppf",
-                       cid, "h" if cid == -1 else "d", epoch, (ndev,),
-                       padded),
-            data, n, padded, fact_tbl.uid, ver, epoch, 0, None,
-            mesh=mesh, spec="sharded")
-        jn = None
-        if nulls is not None:
-            jn = copr._dev_put_append(
-                append_key(fact_tbl.uid, "mppf", cid, "n", epoch,
-                           (ndev,), padded),
-                nulls, n, padded, fact_tbl.uid, ver, epoch, 0, None,
-                pad_fill=True, mesh=mesh, spec="sharded")
-        fjc[sc.col.idx] = (jd, jn)
-    # the fact validity mask is (version, read_ts)-immutable: residency
-    # (same contract as the sharded columns above) instead of a raw
-    # device_put, which re-uploaded it warm on every statement
-    fvv = copr._dev_put_sharded(
-        (fact_tbl.uid, "mppfv", ver, read_ts, ndev, padded),
-        fact_valid[:n], mesh, padded, pad_fill=False, uid=fact_tbl.uid,
-        version=ver)
+    with phase.bind_span():
+        cols = copr._bind_cols(plan.fact_dag, fact_tbl, fact_arrays,
+                               slice(0, n), handles)
+        fjc = {}
+        ver = fact_tbl.version
+        epoch = fact_tbl.gc_epoch
+        for sc in plan.fact_dag.cols:
+            cid = _cid_of(plan.fact_dag, sc)
+            data, nulls, _sd = cols[sc.col.idx]
+            jd = copr._dev_put_append(
+                append_key(fact_tbl.uid, "mppf",
+                           cid, "h" if cid == -1 else "d", epoch, (ndev,),
+                           padded),
+                data, n, padded, fact_tbl.uid, ver, epoch, 0, None,
+                mesh=mesh, spec="sharded")
+            jn = None
+            if nulls is not None:
+                jn = copr._dev_put_append(
+                    append_key(fact_tbl.uid, "mppf", cid, "n", epoch,
+                               (ndev,), padded),
+                    nulls, n, padded, fact_tbl.uid, ver, epoch, 0, None,
+                    pad_fill=True, mesh=mesh, spec="sharded")
+            fjc[sc.col.idx] = (jd, jn)
+        # the fact validity mask is (version, read_ts)-immutable: residency
+        # (same contract as the sharded columns above) instead of a raw
+        # device_put, which re-uploaded it warm on every statement
+        fvv = copr._dev_put_sharded(
+            (fact_tbl.uid, "mppfv", ver, read_ts, ndev, padded),
+            fact_valid[:n], mesh, padded, pad_fill=False, uid=fact_tbl.uid,
+            version=ver)
     compk = ("fcompact", fact_tbl.gc_epoch) + gbkey
+    retries = 0     # re-dispatches the learned lowering forced
     while True:
         if pos_spec is not None:
             agg_kind = "posdense"
@@ -2088,44 +2142,48 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
         # mesh (the result tree is already global); the sort layout
         # ships per-shard partials to the coordinator in one fetch
         exchange_observed("passthrough", tree_nbytes(res))
-        if pos_spec is not None:
-            return [_compact_pos_dense(plan, res, pos_spec[0],
-                                       pos_spec[1], dim_metas, sd)]
-        if sizes is not None:
-            return [_compact_dense(shim, res, sizes, kd, sd)]
-        ngroups_arr = host_array(res["ngroups"])     # [ndev]
-        ng_max = int(ngroups_arr.max())
-        if _compact_policy(copr, compk, agg_param[3],
-                           int(host_array(res["nvalid"]).max()),
-                           local) == "retry":
-            continue
-        if agg_param[1] == "runs" and \
-                ng_max > max(_de._RUNS_DEGRADE_MIN, local // 4):
-            # unclustered group keys on this shard layout: pin to the
-            # sorted lowering before learning an inflated bucket
-            copr._host_cache[("aggimpl", fact_tbl.gc_epoch) + gbkey] = \
-                "sorted"
-            continue
-        if ng_max > group_bucket:
-            group_bucket = shape_bucket(ng_max)
-            copr._host_cache[gbkey] = group_bucket
-            continue
-        # unstack the per-shard partials
-        out = []
-        for si in range(ndev):
-            ng = int(ngroups_arr[si])
-            if ng <= 0:
+        with _tracing.span("consume", retries=retries):
+            if pos_spec is not None:
+                return [_compact_pos_dense(plan, res, pos_spec[0],
+                                           pos_spec[1], dim_metas, sd)]
+            if sizes is not None:
+                return [_compact_dense(shim, res, sizes, kd, sd)]
+            ngroups_arr = host_array(res["ngroups"])     # [ndev]
+            ng_max = int(ngroups_arr.max())
+            if _compact_policy(copr, compk, agg_param[3],
+                               int(host_array(res["nvalid"]).max()),
+                               local) == "retry":
+                retries += 1
                 continue
-            sl = slice(si * group_bucket, (si + 1) * group_bucket)
-            out.append(PartialAggResult(
-                ngroups=ng,
-                keys=[host_array(k)[sl][:ng] for k in res["keys"]],
-                key_nulls=[host_array(kn)[sl][:ng]
-                           for kn in res["key_nulls"]],
-                states=[[host_array(s)[sl][:ng] for s in st]
-                        for st in res["states"]],
-                key_dicts=kd, state_dicts=sd))
-        return out
+            if agg_param[1] == "runs" and \
+                    ng_max > max(_de._RUNS_DEGRADE_MIN, local // 4):
+                # unclustered group keys on this shard layout: pin to the
+                # sorted lowering before learning an inflated bucket
+                copr._host_cache[("aggimpl", fact_tbl.gc_epoch) + gbkey] = \
+                    "sorted"
+                retries += 1
+                continue
+            if ng_max > group_bucket:
+                group_bucket = shape_bucket(ng_max)
+                copr._host_cache[gbkey] = group_bucket
+                retries += 1
+                continue
+            # unstack the per-shard partials
+            out = []
+            for si in range(ndev):
+                ng = int(ngroups_arr[si])
+                if ng <= 0:
+                    continue
+                sl = slice(si * group_bucket, (si + 1) * group_bucket)
+                out.append(PartialAggResult(
+                    ngroups=ng,
+                    keys=[host_array(k)[sl][:ng] for k in res["keys"]],
+                    key_nulls=[host_array(kn)[sl][:ng]
+                               for kn in res["key_nulls"]],
+                    states=[[host_array(s)[sl][:ng] for s in st]
+                            for st in res["states"]],
+                    key_dicts=kd, state_dicts=sd))
+            return out
 
 
 def _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap, dim_caps,

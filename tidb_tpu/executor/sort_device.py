@@ -35,7 +35,7 @@ def _float_to_ordered_int(a: np.ndarray) -> np.ndarray:
 
 
 @jax.jit
-def _lexsort_kernel(keys):
+def tidb_lexsort(keys):
     # keys[0] is the primary key; lexsort wants it LAST. jit's own
     # cache specializes per (len(keys), cap) signature.
     return jnp.lexsort(tuple(reversed(keys)))
@@ -65,5 +65,5 @@ def device_sort_permutation(keys, n):
     # whole function in guarded_dispatch(site="sort") with the host
     # np.lexsort twin — a second in-module guard would double-retry
     # tpulint: disable=unguarded-dispatch
-    order = np.asarray(_lexsort_kernel([jnp.asarray(k) for k in dk]))
+    order = np.asarray(tidb_lexsort([jnp.asarray(k) for k in dk]))
     return order[:n]

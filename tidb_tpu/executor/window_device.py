@@ -57,7 +57,7 @@ def _build_kernel(name, nkeys, npart, has_order, cap, val_float,
     offset (a long-lived server would otherwise compile and pin a
     kernel per user-supplied constant)."""
 
-    def kern(keys, vals, ok, default, shift):
+    def tidb_window(keys, vals, ok, default, shift):
         order = jnp.lexsort(tuple(reversed(keys)))
         sk = [k[order] for k in keys]
         svals = vals[order]
@@ -152,7 +152,7 @@ def _build_kernel(name, nkeys, npart, has_order, cap, val_float,
         rnulls = jnp.zeros(cap, dtype=bool).at[order].set(onulls)
         return res, rnulls
 
-    return jax.jit(kern)
+    return jax.jit(tidb_window)
 
 
 def run_window_device(name, key_arrays, n_part_keys, has_order, svals,

@@ -55,12 +55,12 @@ def build_forward_kernel(nlayers: int):
     once, never per statement), so one compiled kernel serves every
     snapshot of the table at the same (cap, nf, layer-dims) shape."""
 
-    def kern(X, *params):
+    def tidb_ml_forward(X, *params):
         ws = params[:nlayers]
         bs = params[nlayers:]
         return forward_xp(jnp, X, ws, bs)
 
-    return jax.jit(kern)
+    return jax.jit(tidb_ml_forward)
 
 
 def host_forward(X, weights, biases) -> np.ndarray:

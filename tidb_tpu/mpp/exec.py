@@ -169,7 +169,7 @@ def mpp_global_sum(mesh: Mesh, cols_sharded: dict, sdicts: dict,
     in_specs.append(P(axis))
 
     def build():
-        def frag(*vals):
+        def tidb_mpp_gsum(*vals):
             local_n = vals[0].shape[0]
             cols = {}
             i = 0
@@ -193,7 +193,8 @@ def mpp_global_sum(mesh: Mesh, cols_sharded: dict, sdicts: dict,
             cnt = jax.lax.psum(jnp.sum(mask.astype(jnp.int64)), axis)
             return tuple(outs) + (cnt,)
 
-        fn = shard_map(frag, mesh=mesh, in_specs=tuple(in_specs),
+        fn = shard_map(tidb_mpp_gsum, mesh=mesh,
+                       in_specs=tuple(in_specs),
                        out_specs=tuple(P() for _ in
                                        range(len(sum_exprs) + 1)),
                        check_vma=False)
@@ -235,7 +236,7 @@ def mpp_filter_agg(mesh: Mesh, key_arr, val_arr, valid, n_groups: int,
     Returns (sums[n_groups], counts[n_groups]) replicated."""
 
     def build():
-        def frag(keys, vals, ok):
+        def tidb_mpp_fagg(keys, vals, ok):
             seg = jnp.clip(keys, 0, n_groups - 1)
             sums = jax.ops.segment_sum(jnp.where(ok, vals, 0), seg,
                                        num_segments=n_groups)
@@ -243,7 +244,7 @@ def mpp_filter_agg(mesh: Mesh, key_arr, val_arr, valid, n_groups: int,
                                        num_segments=n_groups)
             return jax.lax.psum(sums, axis), jax.lax.psum(cnts, axis)
 
-        fn = shard_map(frag, mesh=mesh,
+        fn = shard_map(tidb_mpp_fagg, mesh=mesh,
                        in_specs=(P(axis), P(axis), P(axis)),
                        out_specs=(P(), P()), check_vma=False)
         return jax.jit(fn)
@@ -390,7 +391,7 @@ def mpp_shuffle_join_agg(mesh: Mesh, probe_keys, probe_vals, probe_valid,
             return (fk.reshape(-1), [fv.reshape(-1) for fv in fvs],
                     fo.reshape(-1), local_max)
 
-        def frag(pk, pok, bk, bp, bok, *pvs):
+        def tidb_mpp_shuf(pk, pok, bk, bp, bok, *pvs):
             pk2, pv2s, pok2, pmax = exchange(pk, list(pvs), pok)
             bk2, (bp2,), bok2, bmax = exchange(bk, [bp], bok)
             # exact global capacity bound, computed where the data is:
@@ -418,7 +419,7 @@ def mpp_shuffle_join_agg(mesh: Mesh, probe_keys, probe_vals, probe_valid,
                                        num_segments=n_groups)
             return sums + (jax.lax.psum(cnts, axis), needed)
 
-        fn = shard_map(frag, mesh=mesh,
+        fn = shard_map(tidb_mpp_shuf, mesh=mesh,
                        in_specs=tuple(P(axis) for _ in range(5 + nvals)),
                        out_specs=tuple(P() for _ in range(nvals + 2)),
                        check_vma=False)

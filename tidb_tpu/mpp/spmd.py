@@ -98,7 +98,7 @@ def run_dag_spmd(domain, dag, mesh, local_cap, n_groups=None,
             raise ValueError(f"agg {a.name} not supported in SPMD "
                              f"fragment yet")
 
-    def frag(valid_l, *flat):
+    def tidb_mpp_spmd(valid_l, *flat):
         cols = {}
         i = 0
         for ix in idxs:
@@ -153,7 +153,8 @@ def run_dag_spmd(domain, dag, mesh, local_cap, n_groups=None,
     nouts = len(aggs) + 1
 
     def build():
-        fn = shard_map(frag, mesh=mesh, in_specs=tuple(in_specs),
+        fn = shard_map(tidb_mpp_spmd, mesh=mesh,
+                       in_specs=tuple(in_specs),
                        out_specs=tuple(P() for _ in range(nouts)),
                        check_vma=False)
         return jax.jit(fn)

@@ -30,7 +30,7 @@ _I64_MAX = np.iinfo(np.int64).max
 
 
 @functools.partial(jax.jit, static_argnames=())
-def _phase1(bk, bvalid, pk, pvalid):
+def tidb_join_probe(bk, bvalid, pk, pvalid):
     skey = jnp.where(bvalid, bk, _I64_MAX)
     border = jnp.argsort(skey)
     sbk = skey[border]
@@ -42,7 +42,7 @@ def _phase1(bk, bvalid, pk, pvalid):
 
 def _phase2(out_cap):
     @jax.jit
-    def expand(counts, lo, border, total):
+    def tidb_join_expand(counts, lo, border, total):
         starts = jnp.cumsum(counts) - counts
         r = jnp.arange(out_cap)
         valid = r < total
@@ -52,7 +52,7 @@ def _phase2(out_cap):
         j = r - starts[pi]
         bpos = border[jnp.clip(lo[pi] + j, 0, border.shape[0] - 1)]
         return pi, bpos, valid
-    return expand
+    return tidb_join_expand
 
 
 _EXPAND_CACHE: dict = {}
@@ -75,7 +75,7 @@ def device_join_index(bk: np.ndarray, bnull: np.ndarray,
     # device_join_index in guarded_dispatch(site="join") with the host
     # hash-join fallback on DeviceDegradedError
     # tpulint: disable=unguarded-dispatch
-    counts, lo, border = _phase1(bkd, bvd, pkd, pvd)
+    counts, lo, border = tidb_join_probe(bkd, bvd, pkd, pvd)
     if semi_only:
         return np.asarray(counts)[:npr] > 0, None
     total = int(jnp.sum(counts))
@@ -87,7 +87,7 @@ def device_join_index(bk: np.ndarray, bnull: np.ndarray,
         if expand is None:
             expand = _phase2(out_cap)
             _EXPAND_CACHE[(out_cap, cp)] = expand
-    # same supervision as _phase1 above (guarded at the executors site)
+    # same supervision as tidb_join_probe above (guarded at the executors site)
     # tpulint: disable=unguarded-dispatch
     pi, bpos, valid = expand(counts, lo, border,
                              jnp.asarray(total, dtype=jnp.int64))

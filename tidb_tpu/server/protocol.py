@@ -56,6 +56,7 @@ class PacketIO:
     def __init__(self, sock):
         self.sock = sock
         self.seq = 0
+        self.sent = 0           # bytes written, headers included
 
     def read_packet(self) -> bytes:
         out = b""
@@ -83,6 +84,7 @@ class PacketIO:
             hdr = struct.pack("<I", len(part))[:3] + bytes([self.seq])
             self.seq = (self.seq + 1) & 0xFF
             self.sock.sendall(hdr + part)
+            self.sent += 4 + len(part)
             if len(part) < MAX_PACKET:
                 return
 

@@ -9,6 +9,7 @@ import threading
 
 from ..session import Session, Domain
 from ..errors import TiDBError
+from ..utils import tracing as _tracing
 from . import protocol as P
 
 
@@ -120,69 +121,70 @@ class Server:
                 pass
 
     def _command_loop(self, sess: Session, io: P.PacketIO):
+        tracer = self.domain.tracer
         while True:
             io.reset_seq()
-            pkt = io.read_packet()
-            if not pkt:
+            pkt = io.read_packet()      # blocks: outside every span
+            if not pkt or pkt[0] == P.COM_QUIT:
                 return
-            cmd = pkt[0]
-            if cmd == P.COM_QUIT:
-                return
-            if cmd == P.COM_PING:
+            # the root of the connection thread's trace, from the
+            # packet read to the reply's last byte written. Unsampled:
+            # the statement inside knows its type and upgrades it
+            with tracer.span("command", conn_id=sess.conn_id,
+                             cmd=pkt[0]):
+                self._command(sess, io, pkt)
+
+    def _command(self, sess: Session, io: P.PacketIO, pkt: bytes):
+        cmd = pkt[0]
+        if cmd == P.COM_PING:
+            io.write_packet(P.ok_packet())
+        elif cmd == P.COM_INIT_DB:
+            dbname = pkt[1:].decode()
+            try:
+                sess.execute(f"use `{dbname}`")
                 io.write_packet(P.ok_packet())
-                continue
-            if cmd == P.COM_INIT_DB:
-                dbname = pkt[1:].decode()
-                try:
-                    sess.execute(f"use `{dbname}`")
-                    io.write_packet(P.ok_packet())
-                except TiDBError as e:
-                    io.write_packet(P.err_packet(e.code, e.sqlstate, e.msg))
-                continue
-            if cmd == P.COM_FIELD_LIST:
+            except TiDBError as e:
+                io.write_packet(P.err_packet(e.code, e.sqlstate, e.msg))
+        elif cmd == P.COM_FIELD_LIST:
+            io.write_packet(P.eof_packet())
+        elif cmd == P.COM_QUERY:
+            sql = pkt[1:].decode("utf-8", "surrogateescape")
+            self._handle_query(sess, io, sql)
+        elif cmd == P.COM_STMT_PREPARE:
+            sql = pkt[1:].decode("utf-8", "surrogateescape")
+            try:
+                sid, n_params = sess.prepare_wire(sql)
+            except TiDBError as e:
+                io.write_packet(P.err_packet(e.code, e.sqlstate, e.msg))
+                return
+            io.write_packet(P.stmt_prepare_ok(sid, 0, n_params))
+            for _ in range(n_params):
+                io.write_packet(P.column_def("?"))
+            if n_params:
                 io.write_packet(P.eof_packet())
-                continue
-            if cmd == P.COM_QUERY:
-                sql = pkt[1:].decode("utf-8", "surrogateescape")
-                self._handle_query(sess, io, sql)
-                continue
-            if cmd == P.COM_STMT_PREPARE:
-                sql = pkt[1:].decode("utf-8", "surrogateescape")
-                try:
-                    sid, n_params = sess.prepare_wire(sql)
-                except TiDBError as e:
-                    io.write_packet(P.err_packet(e.code, e.sqlstate, e.msg))
-                    continue
-                io.write_packet(P.stmt_prepare_ok(sid, 0, n_params))
-                for _ in range(n_params):
-                    io.write_packet(P.column_def("?"))
-                if n_params:
-                    io.write_packet(P.eof_packet())
-                continue
-            if cmd == P.COM_STMT_EXECUTE:
-                sid = int.from_bytes(pkt[1:5], "little")
-                entry = sess.stmt_handles.get(sid)
-                if entry is None:
-                    io.write_packet(P.err_packet(1243, "HY000",
-                                                 "Unknown stmt handler"))
-                    continue
-                n_params = entry[1]
-                try:
-                    _, params = P.parse_execute_params(pkt[1:], n_params)
-                    rs = sess.execute_wire(sid, params)
-                except TiDBError as e:
-                    io.write_packet(P.err_packet(e.code, e.sqlstate, e.msg))
-                    continue
-                except Exception as e:              # noqa: BLE001
-                    io.write_packet(P.err_packet(1105, "HY000",
-                                                 str(e)[:400]))
-                    continue
-                self._write_resultset(io, rs, binary=True)
-                continue
-            if cmd == P.COM_STMT_CLOSE:
-                sid = int.from_bytes(pkt[1:5], "little")
-                sess.close_wire(sid)
-                continue
+        elif cmd == P.COM_STMT_EXECUTE:
+            sid = int.from_bytes(pkt[1:5], "little")
+            entry = sess.stmt_handles.get(sid)
+            if entry is None:
+                io.write_packet(P.err_packet(1243, "HY000",
+                                             "Unknown stmt handler"))
+                return
+            n_params = entry[1]
+            try:
+                _, params = P.parse_execute_params(pkt[1:], n_params)
+                rs = sess.execute_wire(sid, params)
+            except TiDBError as e:
+                io.write_packet(P.err_packet(e.code, e.sqlstate, e.msg))
+                return
+            except Exception as e:              # noqa: BLE001
+                io.write_packet(P.err_packet(1105, "HY000",
+                                             str(e)[:400]))
+                return
+            self._write_resultset(sess, io, rs, binary=True)
+        elif cmd == P.COM_STMT_CLOSE:
+            sid = int.from_bytes(pkt[1:5], "little")
+            sess.close_wire(sid)
+        else:
             io.write_packet(P.err_packet(1047, "08S01", "unknown command"))
 
     def _handle_query(self, sess: Session, io: P.PacketIO, sql: str):
@@ -194,22 +196,34 @@ class Server:
         except Exception as e:   # internal error -> protocol error packet
             io.write_packet(P.err_packet(1105, "HY000", str(e)[:400]))
             return
-        self._write_resultset(io, rs, binary=False)
+        self._write_resultset(sess, io, rs, binary=False)
 
-    def _write_resultset(self, io, rs, binary):
-        if not rs.names:
-            io.write_packet(P.ok_packet(
-                affected=rs.affected, last_insert_id=rs.last_insert_id))
-            return
-        io.write_packet(P.lenenc_int(len(rs.names)))
-        for name in rs.names:
-            io.write_packet(P.column_def(name))
-        io.write_packet(P.eof_packet())
-        enc = P.binary_row if binary else P.text_row
-        for ch in rs.chunks:
-            for i in range(len(ch)):
-                io.write_packet(enc(ch.row_py(i)))
-        io.write_packet(P.eof_packet())
+    def _write_resultset(self, sess, io, rs, binary):
+        # the statement's warning count rides the OK/EOF packets, so a
+        # client sees e.g. 9013 (device degrade) without a SHOW
+        # WARNINGS round trip
+        warnings = min(len(sess.vars.warnings), 0xFFFF)
+        with _tracing.span("wire_write") as sp:
+            sent0 = io.sent
+            rows = 0
+            if not rs.names:
+                io.write_packet(P.ok_packet(
+                    affected=rs.affected,
+                    last_insert_id=rs.last_insert_id, warnings=warnings))
+            else:
+                io.write_packet(P.lenenc_int(len(rs.names)))
+                for name in rs.names:
+                    io.write_packet(P.column_def(name))
+                io.write_packet(P.eof_packet(warnings=warnings))
+                enc = P.binary_row if binary else P.text_row
+                for ch in rs.chunks:
+                    for i in range(len(ch)):
+                        io.write_packet(enc(ch.row_py(i)))
+                    rows += len(ch)
+                io.write_packet(P.eof_packet(warnings=warnings))
+            if sp is not None:
+                sp.attrs["rows"] = rows
+                sp.attrs["bytes"] = io.sent - sent0
 
 
 def serve(port=4000):

@@ -191,7 +191,7 @@ class Domain:
         # sql -> parsed stmt list. Bounded LRU: ad-hoc SQL churn (every
         # bench/ORM statement is unique text) used to grow the old dict
         # without limit between 512-clears on ONE call path while
-        # _parse_one_cached inserted uncapped on another
+        # _parse_cached inserted uncapped on another
         self.ast_cache = LRUCache(512)
         self.digest_cache = LRUCache(1024)  # sql -> (normalized, digest)
         # fast-path schema fence: any commit touching the meta

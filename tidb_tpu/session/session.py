@@ -16,6 +16,7 @@ from .sysvars import SessionVars
 from .domain import Domain
 from .ddl import DDLExecutor
 from . import fastpath as _fastpath
+from ..utils import tracing as _tracing
 
 
 class ResultSet:
@@ -172,13 +173,7 @@ class Session:
         rs = _fastpath.try_execute(self, sql, params)
         if rs is not None:
             return rs
-        # AST cache: same reuse contract as prepared statements (the
-        # planner treats parsed trees as read-only); bounded LRU
-        dom = self.domain
-        stmts = dom.ast_cache.get(sql)
-        if stmts is None:
-            stmts = parse(sql)
-            dom.ast_cache.put(sql, stmts)
+        stmts = self._parse_cached(sql)
         result = ResultSet()
         cache_key_ok = len(stmts) == 1   # multi-stmt text can't key the cache
         for stmt in stmts:
@@ -265,7 +260,12 @@ class Session:
                 samp = random.random() < rate
         with self.domain.tracer.span("statement", conn_id=self.conn_id,
                                      sampled=samp,
-                                     stmt=type(stmt).__name__):
+                                     stmt=type(stmt).__name__) as sp:
+            if samp and sp is not None and sp.depth > 0 and \
+                    _phase.depth() == 1:
+                # not the root: the wire's `command` span opened the
+                # trace unsampled, before the statement type was known
+                self.domain.tracer.mark_sampled()
             try:
                 rs = self._dispatch(stmt, params)
                 self._observe(stmt, sql, start, ok=True, rgroup=rg)
@@ -697,8 +697,8 @@ class Session:
             for tn in stmt.tables:
                 db = tn.db or self.vars.current_db
                 tbl = self.domain.infoschema().table_by_name(db, tn.name)
-                rs = self._exec_select(self._parse_one_cached(
-                    f"select * from `{db}`.`{tn.name}`"), None)
+                rs = self._exec_select(self._parse_cached(
+                    f"select * from `{db}`.`{tn.name}`")[0], None)
                 crc = 0
                 for row in rs.rows:
                     crc = zlib.crc32(repr(row).encode(), crc)
@@ -1234,13 +1234,21 @@ class Session:
             f.write(buf.getvalue())
         return path
 
-    def _parse_one_cached(self, sql):
-        from ..parser import parse
+    def _parse_cached(self, sql):
+        """AST cache: same reuse contract as prepared statements (the
+        planner treats parsed trees as read-only); bounded LRU. The
+        parser runs before any `statement` span opens, so its span is
+        live only under a root of the caller's (the wire's `command`)."""
+        from ..utils import metrics as metrics_util
         stmts = self.domain.ast_cache.get(sql)
         if stmts is None:
-            stmts = parse(sql)
+            metrics_util.AST_CACHE.labels("miss").inc()
+            with _tracing.span("parse"):
+                stmts = parse(sql)
             self.domain.ast_cache.put(sql, stmts)
-        return stmts[0]
+        else:
+            metrics_util.AST_CACHE.labels("hit").inc()
+        return stmts
 
     def _plan_cache_key(self, sql_key):
         # any session var that changes plan SHAPE or semantics must key

@@ -112,6 +112,7 @@ class MiniClient:
         self.sock = socket.create_connection(("127.0.0.1", port),
                                              timeout=timeout)
         self.io = P.PacketIO(self.sock)
+        self.warnings = 0
         greeting = self.io.read_packet()
         if greeting[0] != 10:
             raise RuntimeError(f"not a handshake v10 greeting: {greeting!r}")
@@ -150,7 +151,8 @@ class MiniClient:
     def query(self, sql):
         """-> {"affected": n} for an OK packet, else {"cols", "rows"};
         a server ERR packet raises RuntimeError("server error <code>:
-        ...")."""
+        ..."). `self.warnings` is the statement's warning count as the
+        OK packet or the closing EOF packet carried it."""
         P = self._P
         self.io.reset_seq()
         self.io.write_packet(bytes([P.COM_QUERY]) + sql.encode())
@@ -161,6 +163,8 @@ class MiniClient:
                                f"{first[9:].decode(errors='replace')}")
         if first[0] == 0x00:
             affected, pos = self._read_lenenc(first, 1)
+            _last_id, pos = self._read_lenenc(first, pos)
+            _status, self.warnings = struct.unpack_from("<HH", first, pos)
             return {"affected": affected}
         ncols, _ = self._read_lenenc(first, 0)
         cols = []
@@ -181,6 +185,7 @@ class MiniClient:
         while True:
             pkt = self.io.read_packet()
             if pkt[0] == 0xFE and len(pkt) < 9:
+                self.warnings = struct.unpack_from("<H", pkt, 1)[0]
                 break
             row = []
             pos = 0

@@ -38,6 +38,7 @@ from . import failpoint
 from . import memory as _memory
 from . import metrics as _metrics
 from . import phase as _phase
+from . import tracing as _tracing
 from .logutil import log
 from ..errors import TiDBError, DeviceUnavailableError
 from . import lockrank
@@ -314,10 +315,15 @@ def _with_watchdog(fn, timeout_ms: int, site: str):
     # a dispatch moved onto the watchdog worker must keep charging its
     # upload bytes to the statement that asked for them
     mem_tracker = _memory.current_tracker()
+    # and so is the trace context: spans the worker opens (bind,
+    # dispatch) finish into a private list, folded into the statement's
+    # trace below like the stats, and dropped with an abandoned worker
+    handed = _tracing.handoff()
 
     def run():
         _phase.adopt(worker_stats)
         _memory.set_current(mem_tracker)
+        box["spans"] = _tracing.adopt(handed)
         try:
             box["v"] = fn()
         except BaseException as e:      # noqa: BLE001
@@ -333,6 +339,7 @@ def _with_watchdog(fn, timeout_ms: int, site: str):
             f"device dispatch at {site} exceeded {timeout_ms}ms watchdog")
     for k, v in worker_stats.items():
         _phase.add(k, v)
+    _tracing.absorb(box.get("spans"))
     if "e" in box:
         raise box["e"]
     return box.get("v")
@@ -439,7 +446,6 @@ def guarded_dispatch(fn, *, site: str, ectx=None, domain=None,
 
     attempts = 0
     pressure_evicted = False
-    from . import tracing as _tracing
     while True:
         if ectx is not None:
             ectx.check_killed()

@@ -101,6 +101,18 @@ def _publish_cache_sysvar():
 _publish_cache_sysvar()
 
 
+def name_program(fn, family: str):
+    """Give `fn` the stable name `tidb_<family>` before it is jitted:
+    jit calls the program `jit_tidb_<family>`, which is how a profile's
+    `XLA Modules` line and the compile cache know it. The name is part
+    of the persistent cache's key, so `family` comes only from what
+    already distinguishes programs (a kernel-cache key's kind), never
+    from a table, a literal, a shape or a seed. Where the family is
+    fixed, the `def` itself carries the name. -> fn"""
+    fn.__name__ = fn.__qualname__ = "tidb_" + family
+    return fn
+
+
 def donation_enabled() -> bool:
     """Donate per-dispatch scratch buffers? auto = real accelerators
     only (CPU PJRT ignores donation and warns per compile)."""

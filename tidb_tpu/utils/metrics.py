@@ -534,9 +534,11 @@ def _check_histograms(families, errors):
 # ---- Top SQL ---------------------------------------------------------
 
 def phase_device_ms(ph: dict) -> float:
-    """Device time of a phase snapshot in ms (snap() already converts
-    `*_s` keys to ms): kernel dispatch + XLA compile. THE definition of
-    'device time' — statements_summary and Top SQL must agree."""
+    """Enqueue + compile time of a phase snapshot in ms (snap() already
+    converts `*_s` keys to ms), NOT device time: the host's time inside
+    kernel calls, which return before the device finishes. Device time
+    is the profiler trace's. statements_summary and Top SQL's
+    `sum_device_ms` both mean this number."""
     ph = ph or {}
     return ph.get("dispatch_s", 0.0) + ph.get("compile_s", 0.0)
 
@@ -761,6 +763,10 @@ PLAN_CACHE = REGISTRY.counter(
     "instance plan cache): hit=planner skipped, miss=planned then "
     "cached, uncacheable=planned, not cacheable (plan-time data "
     "dependence or unsupported fast-path shape)", ("outcome",))
+AST_CACHE = REGISTRY.counter(
+    "tidb_tpu_ast_cache_total",
+    "AST-cache lookups of statement texts by outcome (hit=parser "
+    "skipped, miss=parsed then cached)", ("outcome",))
 WAL_GROUP_COMMIT_SIZE = REGISTRY.histogram(
     "tidb_tpu_wal_group_commit_size",
     "Commit frames made durable per WAL group-commit sync (leader "
@@ -788,9 +794,12 @@ COPR_DISPATCH_SECONDS = REGISTRY.histogram(
     "tidb_tpu_copr_dispatch_seconds",
     "Coprocessor (sub)DAG execution latency by serving backend",
     ("backend",))
-MPP_DISPATCH_SECONDS = REGISTRY.histogram(
-    "tidb_tpu_mpp_dispatch_seconds",
-    "Multi-chip MPP dispatch latency (mesh fan-out + merge)")
+SPAN_SECONDS = REGISTRY.histogram(
+    "tidb_tpu_span_seconds",
+    "Inclusive duration of every utils/tracing span by span name "
+    "(parse / plan / execute / dispatch / fetch / wire_write ...), "
+    "sampled or not",
+    ("span",))
 MPP_EXCHANGE = REGISTRY.counter(
     "tidb_tpu_mpp_exchange_total",
     "MPP exchanges lowered to on-mesh collectives by exchange type "

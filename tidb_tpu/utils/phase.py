@@ -18,22 +18,22 @@ to their own digest (Top SQL) instead of blurring into whichever
 statement folds first. Nested internal SQL runs on its outer
 statement's thread and accumulates into it by design (see
 stmt_enter/depth). A worker thread doing a statement's dispatch on its
-behalf (device_guard's watchdog) calls adopt(current()) to record into
-the owning statement's dict.
+behalf (device_guard's watchdog) adopt()s a private dict that the owner
+folds into its own when the dispatch ends inside its budget.
 
 Timing a dispatch measures the *call* (async on TPU: the host returns
-before the kernel finishes). With TIDB_TPU_PHASE_SYNC=1 each kernel
-call blocks until its outputs are ready, attributing true device time
-per kernel kind — a diagnostic mode; it serializes the host/device
-overlap the production path relies on, so bench numbers must come from
-a non-sync run.
+before the kernel finishes): `dispatch_s` is enqueue time, the wait
+shows up in `fetch_s`/`sync_s`, and device time is the profiler
+trace's. The two central wrappers also open the `dispatch` and `fetch`
+spans (utils/tracing), so every kernel and every wait for the device
+lands in the statement's trace, and in the profiler's as
+`tidb:dispatch` / `tidb:fetch` segments, with no per-operator code.
 """
-import os
 import threading
 import time
 
+from . import tracing as _tracing
 
-SYNC = os.environ.get("TIDB_TPU_PHASE_SYNC") == "1"
 _TLS = threading.local()
 
 
@@ -102,6 +102,22 @@ def snap():
     return out
 
 
+def _awaits_device(arr) -> bool:
+    """True while the array's program has not finished: materialising
+    it now blocks the host on the device, and that wait is what the
+    `fetch` span is for. A ready array costs only its copy to the host,
+    mostly prefetched: it gets no span (the time stays in the enclosing
+    span; `fetch_s`/`sync_s` count it all the same), which keeps a
+    statement's span count near its dispatch count instead of its
+    output-array count."""
+    return not arr.is_ready()
+
+
+def _fetch_span(arr):
+    return _tracing.span("fetch") if _awaits_device(arr) \
+        else _tracing.NO_SPAN
+
+
 def _install_fetch_timer():
     """Time every device->host materialization centrally by wrapping
     jax.Array's host-conversion dunders: __array__ (bulk fetches via
@@ -125,10 +141,14 @@ def _install_fetch_timer():
     orig_array = ArrayImpl.__array__
 
     def timed_array(self, *a, **kw):
-        t0 = time.perf_counter()
-        out = orig_array(self, *a, **kw)
-        add("fetch_s", time.perf_counter() - t0)
-        add("fetch_bytes", getattr(out, "nbytes", 0))
+        with _fetch_span(self) as sp:
+            t0 = time.perf_counter()
+            out = orig_array(self, *a, **kw)
+            add("fetch_s", time.perf_counter() - t0)
+            nbytes = getattr(out, "nbytes", 0)
+            if sp is not None:
+                sp.attrs["bytes"] = nbytes
+        add("fetch_bytes", nbytes)
         inc("fetches")
         return out
 
@@ -140,9 +160,12 @@ def _install_fetch_timer():
             continue
 
         def timed_scalar(self, _orig=orig):
-            t0 = time.perf_counter()
-            out = _orig(self)
-            add("sync_s", time.perf_counter() - t0)
+            with _fetch_span(self) as sp:
+                t0 = time.perf_counter()
+                out = _orig(self)
+                add("sync_s", time.perf_counter() - t0)
+                if sp is not None:
+                    sp.attrs["bytes"] = self.dtype.itemsize
             inc("syncs")
             return out
 
@@ -158,30 +181,52 @@ except Exception as _e:                             # noqa: BLE001
           "fetch_s/sync_s will be absent", file=_sys.stderr)
 
 
+class bind_span:
+    """The `bind` span: the host readying a kernel's operands (delta
+    fold, snapshot, column binding, padding and upload). At close it
+    carries what the statement's upload counters grew by inside it."""
+
+    __slots__ = ("_cm", "_sp", "_stats", "_bytes", "_hits")
+
+    def __enter__(self):
+        self._cm = _tracing.span("bind")
+        sp = self._sp = self._cm.__enter__()
+        if sp is not None:
+            d = self._stats = _cur()
+            self._bytes = d.get("upload_bytes", 0)
+            self._hits = d.get("upload_hits", 0)
+        return sp
+
+    def __exit__(self, *exc):
+        sp = self._sp
+        if sp is not None:
+            d = self._stats
+            sp.attrs["upload_bytes"] = d.get("upload_bytes", 0) - \
+                self._bytes
+            sp.attrs["pool_hits"] = d.get("upload_hits", 0) - self._hits
+        return self._cm.__exit__(*exc)
+
+
 def timed_kernel(kind, fn):
-    """Wrap a compiled kernel callable with dispatch accounting.
-    First call is recorded separately (it pays the XLA trace+compile)."""
+    """Wrap a compiled kernel callable with dispatch accounting and the
+    `dispatch` span (the enqueue; `kind` is the cache key's). The first
+    call is recorded separately (it pays the XLA trace+compile)."""
     state = {"first": True}
 
     def wrapped(*args, **kw):
-        t0 = time.perf_counter()
-        out = fn(*args, **kw)
-        if SYNC:
-            try:
-                import jax
-                jax.block_until_ready(out)
-            except Exception:           # noqa: BLE001
-                pass
-        dt = time.perf_counter() - t0
+        with _tracing.span("dispatch", kind=kind) as sp:
+            t0 = time.perf_counter()
+            out = fn(*args, **kw)
+            dt = time.perf_counter() - t0
+            first, state["first"] = state["first"], False
+            if first and sp is not None:
+                sp.attrs["first"] = 1
         inc("dispatches")
-        if state["first"]:
-            state["first"] = False
+        if first:
             inc("kernel_builds")
             add("compile_s", dt)
-            add(f"compile_{kind}_s", dt)
         else:
             add("dispatch_s", dt)
-            add(f"k_{kind}_s", dt)
         return out
 
     wrapped.__wrapped__ = fn

@@ -26,15 +26,58 @@ thread-local "active tracer" installed by the innermost open root
 span, so deep subsystems with no Domain reference (the WAL writer,
 device_guard's retry loop, admission queues) can record spans without
 plumbing a tracer through every constructor. With no active tracer on
-the thread they are exact no-ops."""
+the thread they are exact no-ops.
+
+One span feeds three sinks (docs/OBSERVABILITY.md "Distributed
+tracing"): the flight-recorder buffer above; the registry histogram
+`tidb_tpu_span_seconds{span}`, observed with the span's inclusive
+duration at close; and, while a `jax.profiler` session is active, the
+profiler's trace, as FLAT SELF-TIME SEGMENTS: each thread keeps at most
+one `TraceAnnotation` open, named `tidb:<span>` after its innermost
+open span. Opening a child closes the parent's annotation, closing the
+child reopens it, so a thread's line in the trace is a non-overlapping
+sequence that says what the thread was doing at each instant, and the
+sum of a name's segments is that span's self time with no tree to
+rebuild. The annotations sit on the host plane beside whatever else
+the process annotates, on the profiler's clock."""
 from __future__ import annotations
 
 import collections
-import contextlib
 import itertools
 import threading
 import time
 from typing import NamedTuple
+
+from . import metrics as _metrics
+
+# the profiler sink, resolved at the first span (importing this module
+# must not import jax): jax.profiler.TraceAnnotation, or False where
+# jax cannot be imported
+_ANNOTATION = None
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except Exception:                           # noqa: BLE001
+            _ANNOTATION = False
+    return _ANNOTATION
+
+
+def _segment(tls, name):
+    """Make `tidb:<name>` this thread's one open profiler annotation
+    (none for name None). A flag check when no session is active."""
+    open_ = getattr(tls, "ann", None)
+    if open_ is not None:
+        open_.__exit__(None, None, None)
+        tls.ann = None
+    ann = _ANNOTATION or _annotation()
+    if name and ann and ann.is_enabled():
+        tls.ann = ann("tidb:" + name)
+        tls.ann.__enter__()
 
 
 class SpanEvent(NamedTuple):
@@ -111,10 +154,17 @@ def _render_attrs(attrs: dict) -> str:
 
 
 class _Span:
-    __slots__ = ("name", "depth", "start", "attrs", "conn_id",
-                 "span_id", "parent_id")
+    """An open span, and its own context manager: `with tracer.span(..)
+    as sp` binds it, leaving the block closes it. (A class, not a
+    generator: a statement opens dozens of spans, and two generator
+    frames a span cost more than the span.)"""
 
-    def __init__(self, name, depth, attrs, conn_id, span_id, parent_id):
+    __slots__ = ("name", "depth", "start", "attrs", "conn_id",
+                 "span_id", "parent_id", "tracer", "parent", "state",
+                 "prev_active")
+
+    def __init__(self, name, depth, attrs, conn_id, span_id, parent_id,
+                 tracer=None, parent=None, state=None, prev_active=None):
         self.name = name
         self.depth = depth
         self.start = time.perf_counter()
@@ -122,6 +172,32 @@ class _Span:
         self.conn_id = conn_id
         self.span_id = span_id
         self.parent_id = parent_id
+        self.tracer = tracer
+        self.parent = parent            # None: this span is the root
+        self.state = state
+        self.prev_active = prev_active  # roots: the slot to restore
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.tracer._close(self)
+        return False
+
+
+class _NoSpan:
+    """What span() hands back when nothing records: binds None."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+NO_SPAN = _NoSpan()
 
 
 class _TraceState:
@@ -189,6 +265,32 @@ class Tracer:
         else:
             self.recorder.record_many(events)
 
+    # ---- a worker thread on the statement's behalf ---------------------
+
+    def handoff(self):
+        """-> what a worker thread needs to record spans under this
+        thread's innermost open span (device_guard's watchdog runs a
+        statement's dispatch on a thread of its own), or None with no
+        span open."""
+        sp = getattr(self._tls, "cur", None)
+        state = getattr(self._tls, "state", None)
+        if sp is None or state is None:
+            return None
+        return (self, state.trace_id, sp.span_id, sp.depth, sp.conn_id)
+
+    def adopt(self, handoff) -> list:
+        """On the worker thread: spans opened here become children of
+        the handed-off span, in its trace, and finish into the returned
+        private list. The owner absorb()s the list when the work ends
+        inside its budget; an abandoned worker's spans go nowhere."""
+        _self, trace_id, span_id, depth, conn_id = handoff
+        state = _TraceState(trace_id, False)
+        self._tls.state = state
+        # a parent that is not None, so spans here are never roots
+        self._tls.cur = _Span(None, depth, {}, conn_id, span_id, "")
+        _ACTIVE.tracer = self
+        return state.buf
+
     # ---- trace state introspection -----------------------------------
 
     def current_context(self):
@@ -225,24 +327,21 @@ class Tracer:
 
     # ---- spans -------------------------------------------------------
 
-    @contextlib.contextmanager
     def span(self, name: str, conn_id: int | None = None,
              sampled: bool | None = None, trace_id: str | None = None,
              **attrs):
-        """Record a span. Nesting is per-thread; the outermost span on
+        """Open a span; a context manager that binds it (or None when
+        the tracer is off). Nesting is per-thread; the outermost span on
         a thread is the trace ROOT: it mints (or adopts, under
         install_remote) the trace_id and owns the sampled decision —
         `sampled` / `trace_id` are honored only there. Child spans
         inherit conn_id and parent linkage automatically."""
         if not self.enabled:
-            yield None
-            return
+            return NO_SPAN
         tls = self._tls
         parent = getattr(tls, "cur", None)
-        root = parent is None
         prev_active = None
-        remote = None
-        if root:
+        if parent is None:
             remote = getattr(tls, "remote", None)
             if remote is not None:
                 state = _TraceState(remote["trace_id"],
@@ -265,30 +364,36 @@ class Tracer:
                 conn_id = parent.conn_id
             depth = parent.depth + 1
         sp = _Span(name, depth, attrs, conn_id, self._new_id("s"),
-                   parent_id)
+                   parent_id, self, parent, state, prev_active)
         tls.cur = sp
-        try:
-            yield sp
-        finally:
-            tls.cur = parent
-            dur_ms = (time.perf_counter() - sp.start) * 1000.0
-            state.buf.append(SpanEvent(
-                time.time(), sp.conn_id, sp.depth, name, dur_ms,
-                _render_attrs(sp.attrs), state.trace_id, sp.span_id,
-                sp.parent_id, self.worker))
-            if root:
-                tls.state = None
-                _ACTIVE.tracer = prev_active
-                if remote is not None:
-                    # hand the whole subtree to the RPC reply; a
-                    # sampled remote trace ALSO lands in this worker's
-                    # own ring (locally inspectable mid-flight)
-                    remote["events"].extend(state.buf)
-                    if state.sampled:
-                        self.recorder.record_many(state.buf)
-                elif state.sampled:
+        _segment(tls, name)
+        return sp
+
+    def _close(self, sp):
+        tls, parent, state = self._tls, sp.parent, sp.state
+        tls.cur = parent
+        # an adopted parent (name None) is open on its own thread
+        _segment(tls, None if parent is None else parent.name)
+        dur_s = time.perf_counter() - sp.start
+        _metrics.SPAN_SECONDS.labels(sp.name).observe(dur_s)
+        state.buf.append(SpanEvent(
+            time.time(), sp.conn_id, sp.depth, sp.name, dur_s * 1000.0,
+            _render_attrs(sp.attrs), state.trace_id, sp.span_id,
+            sp.parent_id, self.worker))
+        if parent is None:
+            tls.state = None
+            _ACTIVE.tracer = sp.prev_active
+            remote = state.remote
+            if remote is not None:
+                # hand the whole subtree to the RPC reply; a
+                # sampled remote trace ALSO lands in this worker's
+                # own ring (locally inspectable mid-flight)
+                remote["events"].extend(state.buf)
+                if state.sampled:
                     self.recorder.record_many(state.buf)
-                # unsampled local trace: buffer dropped, ring untouched
+            elif state.sampled:
+                self.recorder.record_many(state.buf)
+            # unsampled local trace: buffer dropped, ring untouched
 
     def tag(self, **attrs):
         """Attach attributes to the innermost open span (e.g. the slow
@@ -334,19 +439,35 @@ def set_thread_context(ctx) -> None:
     _ACTIVE.ctx = ctx
 
 
-@contextlib.contextmanager
 def span(name: str, **attrs):
-    """Record a child span on this thread's active tracer; exact no-op
-    when none is active (background threads, untraced fast path)."""
+    """Open a child span on this thread's active tracer; binds None and
+    records nothing when none is active (background threads, untraced
+    fast path)."""
     t = getattr(_ACTIVE, "tracer", None)
     if t is None:
-        yield None
-        return
-    with t.span(name, **attrs) as sp:
-        yield sp
+        return NO_SPAN
+    return t.span(name, **attrs)
 
 
 def tag(**attrs) -> None:
     t = getattr(_ACTIVE, "tracer", None)
     if t is not None:
         t.tag(**attrs)
+
+
+def handoff():
+    """Tracer.handoff() of this thread's active tracer, or None."""
+    t = getattr(_ACTIVE, "tracer", None)
+    return t.handoff() if t is not None else None
+
+
+def adopt(handed) -> list:
+    """Tracer.adopt() on a worker thread; [] for a None handoff."""
+    return handed[0].adopt(handed) if handed is not None else []
+
+
+def absorb(events) -> None:
+    """Fold a worker's finished spans into this thread's open trace."""
+    t = getattr(_ACTIVE, "tracer", None)
+    if t is not None and events:
+        t.absorb(events)

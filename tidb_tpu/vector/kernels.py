@@ -61,13 +61,13 @@ def build_topk_kernel(metric: str, kcap: int):
     keys <= -inf mark dead padding the host must drop, keys == +inf
     mark NULL rows (ordered first, ASC semantics)."""
 
-    def kern(mat, valid, q):
+    def tidb_vec_topk(mat, valid, q):
         d = _distances_xp(jnp, mat, q, metric)
         key = _select_key_xp(jnp, d, valid)
         vals, idx = jax.lax.top_k(key, kcap)
         return vals, idx.astype(jnp.int32)
 
-    return jax.jit(kern)
+    return jax.jit(tidb_vec_topk)
 
 
 def build_ivf_score_kernel(metric: str, kcap: int):
@@ -76,14 +76,14 @@ def build_ivf_score_kernel(metric: str, kcap: int):
     host->device per query) and top-k them. cand is padded with 0s;
     cvalid gates padding and MVCC-dead rows off."""
 
-    def kern(mat, cand, cvalid, q):
+    def tidb_vec_ivf_score(mat, cand, cvalid, q):
         sub = jnp.take(mat, cand, axis=0)
         d = _distances_xp(jnp, sub, q, metric)
         key = _select_key_xp(jnp, d, cvalid)
         vals, pos = jax.lax.top_k(key, kcap)
         return vals, jnp.take(cand, pos).astype(jnp.int32)
 
-    return jax.jit(kern)
+    return jax.jit(tidb_vec_ivf_score)
 
 
 def build_kmeans_step():
@@ -91,7 +91,7 @@ def build_kmeans_step():
     distance form) + one-hot segment means — both MXU contractions.
     Empty clusters keep their previous centroid."""
 
-    def step(mat, valid, cent):
+    def tidb_vec_kmeans_step(mat, valid, cent):
         # zero the dead/NULL (NaN) rows BEFORE the segment matmul:
         # their one-hot weight is 0, but 0 * NaN = NaN and one poisoned
         # row would NaN every centroid
@@ -105,17 +105,17 @@ def build_kmeans_step():
         return jnp.where(cnts[:, None] > 0,
                          sums / jnp.maximum(cnts, 1.0)[:, None], cent)
 
-    return jax.jit(step)
+    return jax.jit(tidb_vec_kmeans_step)
 
 
 def build_assign_kernel():
     """Nearest-centroid id per row (posting-list construction and the
     incremental delta fold)."""
 
-    def kern(mat, cent):
+    def tidb_vec_assign(mat, cent):
         return jnp.argmin(_pair_d2(mat, cent), axis=1).astype(jnp.int32)
 
-    return jax.jit(kern)
+    return jax.jit(tidb_vec_assign)
 
 
 def _pair_d2(mat, cent):

@@ -250,10 +250,10 @@ class VectorRuntime:
             if kern is None:
                 shard = getattr(dev, "sharding", None)
 
-                def f(buf, u, off):
+                def tidb_vec_fold(buf, u, off):
                     return jax.lax.dynamic_update_slice(buf, u, (off, 0))
-                jf = jax.jit(f, out_shardings=shard) if shard is not None \
-                    else jax.jit(f)
+                jf = jax.jit(tidb_vec_fold, out_shardings=shard) \
+                    if shard is not None else jax.jit(tidb_vec_fold)
                 kern = kc.put(ck, jf)
             return kern(dev, upd, np.int64(rows))
 
