@@ -6,8 +6,9 @@ directly, on the chip:
 
     python benchmarks/microbench_tpu.py [section ...]
 
-Sections: io, reduce, group, sort, scatter (scatter is the slowest to
-COMPILE on a TPU — run it last, with a long timeout).
+Sections: io, reduce, group, sort by default; probe, sort4m, mxu and
+scatter by name (scatter is the slowest to COMPILE on a TPU — run it
+last, with a long timeout).
 
 Design inputs these numbers feed (copr/dag_exec.py lowering choice):
 - dispatch+fetch round-trip floor
@@ -117,6 +118,27 @@ def main(sections):
         idx4 = jnp.asarray(rng.integers(0, 1 << 21, n4), dtype=jnp.int64)
         bench("gather 4M from 2M lut", jax.jit(lambda lu, i: lu[i]),
               lut, idx4)
+        # the same gather by physical type (ROADMAP S1(c): is a 64-bit
+        # gather two 32-bit ones?). The "sum" rows reduce on the device,
+        # so the sample is the gather and not the 4-32 MB download; the
+        # "sorted idx" rows read the table in storage order, as a probe
+        # by a clustered key (l_orderkey into orders) does
+        lut32, idx32 = lut.astype(jnp.int32), idx4.astype(jnp.int32)
+        lutb = (lut & 1).astype(bool)
+        sidx4, sidx32 = jnp.sort(idx4), jnp.sort(idx32)
+        gsum = jax.jit(lambda lu, i: jnp.sum(lu[i].astype(jnp.int32)))
+        bench("gather 4M from 2M lut int32 (table and idx)",
+              jax.jit(lambda lu, i: lu[i]), lut32, idx32)
+        bench("gather 4M from 2M lut bool (int32 idx)",
+              jax.jit(lambda lu, i: lu[i]), lutb, idx32)
+        bench("read+sum 4M int32 (the floor under the sum rows)",
+              jax.jit(lambda i: jnp.sum(i)), idx32)
+        bench("gather+sum 4M int64", gsum, lut, idx4)
+        bench("gather+sum 4M int64 table, int32 idx", gsum, lut, idx32)
+        bench("gather+sum 4M int32", gsum, lut32, idx32)
+        bench("gather+sum 4M bool", gsum, lutb, idx32)
+        bench("gather+sum 4M int64, sorted idx", gsum, lut, sidx4)
+        bench("gather+sum 4M int32, sorted idx", gsum, lut32, sidx32)
         skeys = jnp.asarray(np.sort(rng.choice(1 << 24, 1 << 21,
                                                replace=False)),
                             dtype=jnp.int64)

@@ -57,10 +57,10 @@ def test_fused_topn_candidates_match_host(runs_impl):
     calls = {"n": 0, "sizes": []}
     orig = pl._topn_select
 
-    def spy(res, aggs, topn, bucket):
+    def spy(res, aggs, topn, bucket, *group_metric):
         calls["n"] += 1
         calls["sizes"].append(topn[3])
-        return orig(res, aggs, topn, bucket)
+        return orig(res, aggs, topn, bucket, *group_metric)
     pl._topn_select = spy
     try:
         dev = tk.must_query(TOPN_SQL).rows
@@ -122,9 +122,9 @@ def test_fused_topn_disabled_after_degrade_pin(runs_impl, monkeypatch):
     calls = {"n": 0}
     orig = pl._topn_select
 
-    def spy(res, aggs, topn, bucket):
+    def spy(res, aggs, topn, bucket, *group_metric):
         calls["n"] += 1
-        return orig(res, aggs, topn, bucket)
+        return orig(res, aggs, topn, bucket, *group_metric)
     pl._topn_select = spy
     try:
         dev = tk.must_query(sql).rows       # degrades mid-loop

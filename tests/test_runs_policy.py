@@ -6,12 +6,14 @@ scatter-free kernels (reduce / broadcast-compare / contiguous-run
 partials) stay covered in CI. See dag_exec._segment_impl for the
 measured numbers behind the policy.
 """
+import jax
 import numpy as np
 import pytest
 
 import tidb_tpu.copr.dag_exec as de
+import tidb_tpu.copr.pipeline as pl
 from tidb_tpu.testkit import TestKit
-from tidb_tpu.bench.tpch import load_tpch, QUERIES
+from tidb_tpu.bench.tpch import load_tpch, ALL_QUERIES, QUERIES
 
 
 @pytest.fixture
@@ -30,22 +32,27 @@ def tk():
     return tk
 
 
-@pytest.mark.parametrize("q", ["q1", "q3", "q5", "q6"])
-def test_tpch_headline_runs_vs_host(tk, runs_impl, q):
+def _dev_vs_host(tk, sql, runs=1):
     tk.domain.copr.use_device = True
-    dev = tk.must_query(QUERIES[q]).rows
+    dev = [tk.must_query(sql).rows for _ in range(runs)][-1]
     tk.domain.copr.use_device = False
     try:
-        host = tk.must_query(QUERIES[q]).rows
+        host = tk.must_query(sql).rows
     finally:
         tk.domain.copr.use_device = True
-    assert len(dev) == len(host)
+    assert len(dev) == len(host) and len(dev) > 0
     for rd, rh in zip(dev, host):
         for a, b in zip(rd, rh):
             if isinstance(a, float) or isinstance(b, float):
                 np.testing.assert_allclose(float(a), float(b), rtol=1e-9)
             else:
-                assert a == b, (q, rd, rh)
+                assert a == b, (sql, rd, rh)
+    return dev
+
+
+@pytest.mark.parametrize("q", ["q1", "q3", "q5", "q6"])
+def test_tpch_headline_runs_vs_host(tk, runs_impl, q):
+    _dev_vs_host(tk, QUERIES[q])
 
 
 def test_first_row_skips_empty_partials(runs_impl):
@@ -105,3 +112,266 @@ def test_unclustered_group_by_runs(runs_impl):
         assert int(row[4]) == int(vs[m].max())
         np.testing.assert_allclose(float(row[5]),
                                    float((vs[m] / 7.0).mean()), rtol=1e-9)
+
+
+# ---- position-grouped runs lowering ("posruns", copr/pipeline.py) ----
+
+@pytest.fixture(scope="module")
+def tkp():
+    """d: 200 rows, 91 names (NULLs among them; several rows a name), 7
+    grps. f: runs of 15 rows a d_id, every 40th row a d_id no d has (a
+    miss in the middle of a run), d_id 40..44 absent from d (whole runs
+    miss). g: the same runs without the mid-run misses, so g.d_id is
+    stored in order (what the device top-n asks of its anchor)."""
+    tk = TestKit()
+    tk.must_exec("create table d (id int primary key, name varchar(16), "
+                 "grp int, val int)")
+    tk.must_exec("create table d2 (id int primary key, tag int)")
+    tk.must_exec("create table f (k int primary key, d_id int, "
+                 "amt decimal(10,2), q int)")
+    tk.must_exec("create table g (k int primary key, d_id int, "
+                 "amt decimal(10,2), q int)")
+    tk.must_exec("insert into d values " + ",".join(
+        "(%d, %s, %d, %d)" % (i, "null" if i % 11 == 0 else f"'n{i % 90}'",
+                              i % 7, (i * 37) % 1000)
+        for i in range(1, 206) if not 40 <= i < 45))
+    tk.must_exec("insert into d2 values " + ",".join(
+        f"({i}, {i % 3})" for i in range(1, 101)))
+    rng = np.random.RandomState(11)
+    frows, grows = [], []
+    for k in range(3000):
+        d_id = k // 15 + 1
+        row = (rng.randint(1, 99999) / 100.0, rng.randint(0, 100))
+        grows.append("(%d, %d, %s, %d)" % ((k, d_id) + row))
+        frows.append("(%d, %d, %s, %d)" % (
+            (k, 9999 if k % 40 == 7 else d_id) + row))
+    tk.must_exec("insert into f values " + ",".join(frows))
+    tk.must_exec("insert into g values " + ",".join(grows))
+    return tk
+
+
+@pytest.fixture
+def kinds(monkeypatch):
+    """[(agg_kind, agg_param, build args, call shapes)] of every fused
+    kernel built while the fixture is live."""
+    seen = []
+    orig = pl._build_fused_kernel
+
+    def spy(*a, **k):
+        kern = orig(*a, **k)
+        rec = [a[7], a[8], (a, k), None]
+        seen.append(rec)
+
+        def call(fjc, fvv, kargs):
+            if rec[3] is None:
+                rec[3] = jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(np.shape(x),
+                                                   np.asarray(x).dtype),
+                    (fjc, fvv, kargs))
+            return kern(fjc, fvv, kargs)
+        return call
+    monkeypatch.setattr(pl, "_build_fused_kernel", spy)
+    return seen
+
+
+def _posruns_count(tk):
+    return tk.domain.metrics.get("fused_posruns_agg", 0)
+
+
+_AGGS = "sum(f.amt), count(*), min(f.q), max(f.q), avg(f.amt)"
+_SYN = {
+    # NULL names; several d rows a name: positions differ, values merge
+    "null_and_equal_payload":
+        f"select d.name, {_AGGS} from f, d where f.d_id = d.id "
+        "group by d.name order by d.name",
+    "equal_payload_few_groups":
+        f"select d.grp, {_AGGS} from f, d where f.d_id = d.id "
+        "group by d.grp order by d.grp",
+    "probe_key_and_payload":
+        f"select f.d_id, d.name, d.val, {_AGGS} from f, d "
+        "where f.d_id = d.id and f.q < 90 "
+        "group by f.d_id, d.name, d.val order by f.d_id",
+    "two_position_dims":
+        f"select d.name, d2.tag, {_AGGS} from f, d, d2 "
+        "where f.d_id = d.id and f.q = d2.id "
+        "group by d.name, d2.tag order by d.name, d2.tag",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SYN))
+def test_posruns_synthetic_vs_host(tkp, runs_impl, kinds, case):
+    before = _posruns_count(tkp)
+    _dev_vs_host(tkp, _SYN[case])
+    assert _posruns_count(tkp) > before
+    assert {k[0] for k in kinds} == {"posruns"}
+
+
+@pytest.mark.parametrize("q", ["q3", "q10", "q18"])
+def test_posruns_tpch_vs_host(tk, runs_impl, kinds, q):
+    before = _posruns_count(tk)
+    tk.domain.copr._kernel_cache.clear()
+    # at SF0.003 no order passes q18's HAVING of 300; 244 pass 200
+    _dev_vs_host(tk, ALL_QUERIES[q].replace("> 300", "> 200"))
+    assert _posruns_count(tk) > before
+    # q18's subquery groups by a fact column alone: today's kind
+    want = {"posruns", "sort"} if q == "q18" else {"posruns"}
+    assert {k[0] for k in kinds} == want
+
+
+def test_posruns_group_straddles_partitions(tkp, runs_impl, kinds):
+    """1000-row partitions cut runs of 15: both halves' partials merge."""
+    copr = tkp.domain.copr
+    old = copr.device_rows
+    copr.device_rows = 1000
+    before = _posruns_count(tkp)
+    try:
+        _dev_vs_host(tkp, _SYN["probe_key_and_payload"])
+    finally:
+        copr.device_rows = old
+    assert _posruns_count(tkp) == before + 3
+    assert {k[0] for k in kinds} == {"posruns"}
+
+
+def test_posruns_uncommitted_insert_overlay(tkp, runs_impl):
+    """The transaction's rows ride the same kernel as one more
+    partition; one lands in a group the snapshot has, one in a new one
+    (d_id 200 has no committed f row), one misses."""
+    sql = _SYN["probe_key_and_payload"]
+    base = _dev_vs_host(tkp, sql)
+    tkp.must_exec("begin")
+    try:
+        tkp.must_exec("insert into f values (90001, 3, 5.00, 1), "
+                      "(90002, 205, 7.00, 2), (90003, 42, 1.00, 3)")
+        before = _posruns_count(tkp)
+        got = _dev_vs_host(tkp, sql)
+        assert _posruns_count(tkp) == before + 2
+    finally:
+        tkp.must_exec("rollback")
+    assert len(got) == len(base) + 1
+    assert _dev_vs_host(tkp, sql) == base
+
+
+def test_posruns_late_compaction(tkp, runs_impl, kinds):
+    """A selective dimension filter leaves under an eighth of the
+    partition: the second run gathers survivors (positions beside the
+    columns) before the run extraction."""
+    sql = (f"select f.d_id, d.name, {_AGGS} from f, d "
+           "where f.d_id = d.id and d.val < 100 "
+           "group by f.d_id, d.name order by f.d_id")
+    _dev_vs_host(tkp, sql, runs=2)
+    assert [k[0] for k in kinds] == ["posruns", "posruns"]
+    assert kinds[0][1][3] is None and isinstance(kinds[1][1][3], int)
+
+
+@pytest.mark.parametrize("order, kind", [("s desc", "agg"),
+                                         ("d.val desc", "group"),
+                                         ("g.d_id", "group")])
+def test_posruns_device_topn(tkp, runs_impl, kinds, order, kind):
+    """The candidate cut runs on the position-grouped partials too; an
+    ordering group item is read at bucket width from its dimension."""
+    sql = ("select g.d_id, d.val, sum(g.amt) s from g, d "
+           f"where g.d_id = d.id group by g.d_id, d.val order by {order} "
+           "limit 5")
+    _dev_vs_host(tkp, sql)
+    assert [k[0] for k in kinds] == ["posruns"]
+    topn = kinds[0][1][2]
+    assert topn is not None and topn[0] == kind
+
+
+_OLD_KIND = {
+    "fact_column":
+        "select f.q, d.grp, count(*) from f, d where f.d_id = d.id "
+        "group by f.q, d.grp order by f.q, d.grp",
+    "expression_over_dim_column":
+        "select d.val + 1, count(*) from f, d where f.d_id = d.id "
+        "group by d.val + 1 order by 1",
+    "left_dim":
+        "select d.val, count(*) from f left join d on f.d_id = d.id "
+        "group by d.val order by d.val",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_OLD_KIND))
+def test_posruns_not_taken(tkp, runs_impl, kinds, case):
+    before = _posruns_count(tkp)
+    _dev_vs_host(tkp, _OLD_KIND[case])
+    assert _posruns_count(tkp) == before
+    assert "posruns" not in {k[0] for k in kinds}
+
+
+def test_posruns_yields_to_pinned_sorted(runs_impl, kinds, monkeypatch):
+    """Positions scattered over storage order: the first partition's
+    partials exceed the degrade limit, the shape is pinned to "sorted"
+    and that run and every later one take today's kind."""
+    monkeypatch.setattr(de, "_RUNS_DEGRADE_MIN", 8)
+    tk = TestKit()
+    tk.must_exec("create table d (id int primary key, val int)")
+    tk.must_exec("create table f (k int primary key, d_id int, q int)")
+    tk.must_exec("insert into d values " + ",".join(
+        f"({i}, {i * 3})" for i in range(1, 101)))
+    rng = np.random.RandomState(2)
+    tk.must_exec("insert into f values " + ",".join(
+        f"({k}, {rng.randint(1, 101)}, {k % 9})" for k in range(800)))
+    sql = ("select d.val, count(*), sum(f.q) from f, d "
+           "where f.d_id = d.id group by d.val order by d.val")
+    _dev_vs_host(tk, sql, runs=2)
+    assert _posruns_count(tk) == 0
+    assert [k[0] for k in kinds] == ["posruns", "sort"]
+    assert kinds[1][1][1] == "sorted"
+
+
+def _walk(jaxpr, visit):
+    for e in jaxpr.eqns:
+        visit(e)
+        for v in e.params.values():
+            for j in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    _walk(inner, visit)
+
+
+def _wide_gather_operands(build, shapes, agg_kind=None, agg_param=None):
+    """Argument paths of the arrays the body gathers from at fact
+    width ("-" for an intermediate)."""
+    a, k = build
+    a = list(a)
+    if agg_kind is not None:
+        a[7], a[8] = agg_kind, agg_param
+    cj = jax.make_jaxpr(pl._make_pipeline_body(
+        *a, **dict(k, want_fnvalid=True)))(*shapes)
+    paths = [jax.tree_util.keystr(p) for p, _ in
+             jax.tree_util.tree_flatten_with_path(shapes)[0]]
+    name = {id(v): p for v, p in zip(cj.jaxpr.invars, paths)}
+    cap, out = a[1], []
+
+    def visit(e):
+        if e.primitive.name == "gather" and \
+                e.outvars[0].aval.shape[:1] == (cap,):
+            out.append(name.get(id(e.invars[0]), "-"))
+    _walk(cj.jaxpr, visit)
+    return out
+
+
+def test_posruns_q10_gathers_no_group_payload(tk, runs_impl, kinds):
+    """q10 groups by seven dimension columns nothing else reads: under
+    "posruns" none is gathered at fact width (they are decoded from the
+    positions on the host); the control, the same plan under today's
+    kind, gathers every one."""
+    before = _posruns_count(tk)
+    tk.domain.copr._kernel_cache.clear()
+    tk.domain.copr.use_device = True
+    tk.must_query(ALL_QUERIES["q10"])
+    assert _posruns_count(tk) > before
+    kind, param, build, shapes = kinds[0]
+    assert kind == "posruns"
+    plan = build[0][0]
+    gmap, pos_dims = pl._pos_group_items(plan)
+    payload = {f"[2][{di}]['cols'][{g.idx}][0]"
+               for g, (_k, di, _c) in zip(plan.group_items, gmap)}
+    assert len(payload) == 7
+    now = _wide_gather_operands(build, shapes)
+    assert not payload & set(now)
+    old = _wide_gather_operands(build, shapes, "sort",
+                                (param[0], "runs", param[2], param[3]))
+    assert payload <= set(old)
+    assert len(old) - len(now) >= 7

@@ -1491,12 +1491,19 @@ def _runs_agg_core(keys, key_nulls, mask, ctx, aggs, cap, bucket):
     appear as multiple partials; the partial-agg merge combines them)
     but degrade to ~one run per row — callers should prefer this
     lowering when storage order clusters the key, which TPC-H fact
-    tables and join positions do."""
+    tables and join positions do.
+
+    key_nulls=None: the keys cannot be NULL (join positions, the fused
+    pipeline's "posruns" kind) — no null masks are compared or
+    returned."""
     idx = jnp.arange(cap)
     if keys:
         neq = jnp.zeros(cap - 1, dtype=bool)
-        for k, kn in zip(keys, key_nulls):
-            neq = neq | (k[1:] != k[:-1]) | (kn[1:] != kn[:-1])
+        for i, k in enumerate(keys):
+            neq = neq | (k[1:] != k[:-1])
+            if key_nulls is not None:
+                kn = key_nulls[i]
+                neq = neq | (kn[1:] != kn[:-1])
         change = jnp.concatenate([jnp.ones(1, dtype=bool), neq])
     else:
         change = jnp.concatenate([jnp.ones(1, dtype=bool),
@@ -1516,7 +1523,7 @@ def _runs_agg_core(keys, key_nulls, mask, ctx, aggs, cap, bucket):
     re = jnp.minimum(jnp.searchsorted(cs_change, rid + 1), cap) - 1
 
     out_keys = [k[posc] for k in keys]
-    out_key_nulls = [kn[posc] for kn in key_nulls]
+    out_key_nulls = [kn[posc] for kn in key_nulls or ()]
 
     def seg_at_end(vals, combine):
         return _seg_scan(change, vals, combine)[re]

@@ -743,18 +743,21 @@ def _topn_metric_host(spec, aggs, keys, key_nulls, states):
     return np.where(nul, (-_I64_MAX) if desc else (_I64_MAX - 1), m)
 
 
-def _topn_select(res, aggs, topn, bucket):
+def _topn_select(res, aggs, topn, bucket, group_metric=None):
     """In-kernel candidate selection over the partial-group arrays:
     transformed int64 metric (larger = better), empty slots forced last,
     the partition-boundary groups (run 0 and run ngroups-1, whose
     totals may continue in the neighbouring partition) forced FIRST so
     the host merge always sees both halves. Returns the res contract
-    with arrays trimmed to kprime rows plus the selected run ids."""
+    with arrays trimmed to kprime rows plus the selected run ids.
+    group_metric: (values, nulls) of the ordering group item where
+    res["keys"] holds join positions and not the items ("posruns")."""
     kind, ai, desc, kprime = topn
     ng = res["ngroups"]
     if kind == "group":
-        v = res["keys"][ai].astype(jnp.int64)
-        nul = res["key_nulls"][ai]
+        v, nul = group_metric if group_metric is not None else \
+            (res["keys"][ai], res["key_nulls"][ai])
+        v = v.astype(jnp.int64)
     else:
         st = res["states"][ai]
         v = st[0].astype(jnp.int64)
@@ -776,7 +779,7 @@ def _topn_select(res, aggs, topn, bucket):
     return out
 
 
-def _pos_group_map(plan, dim_metas):
+def _pos_group_items(plan):
     """Group-by-FK detection: when every group item is either a column of
     an (inner, unique) dimension or the probe key of one, the join
     POSITION already identifies the group — aggregation becomes a direct
@@ -784,7 +787,9 @@ def _pos_group_map(plan, dim_metas):
     (Q3's group (l_orderkey, o_orderdate, o_shippriority) is position-
     in-orders; the reference reaches the same cardinality through its
     hash table, we get it free from the join.)
-    -> (group_map, pos_dims, nslots) or None."""
+    -> (group_map, pos_dims) or None; group_map[i] = (kind, di, cid):
+    group item i is column `cid` of dimension `di` ("dimcol") or equals
+    its build key on every hit ("probekey")."""
     from ..expression import Column
     group_map = []
     for g in plan.group_items:
@@ -807,13 +812,38 @@ def _pos_group_map(plan, dim_metas):
         group_map.append(m)
     if not group_map:
         return None
-    pos_dims = sorted({di for _, di, _ in group_map})
-    nslots = 1
-    for di in pos_dims:
-        nslots *= dim_metas[di]["n"]
-    if nslots > _POS_DENSE_MAX:
+    return group_map, sorted({di for _, di, _ in group_map})
+
+
+def _pos_group_map(plan, dim_metas):
+    """_pos_group_items plus the size of the position domain, which
+    picks the lowering: slots packed into one array ("posdense") or the
+    positions kept as separate run keys ("posruns").
+    -> (group_map, pos_dims, nslots) or None."""
+    gm = _pos_group_items(plan)
+    if gm is None:
         return None
-    return group_map, pos_dims, nslots
+    nslots = 1
+    for di in gm[1]:
+        nslots *= dim_metas[di]["n"]
+    return gm + (nslots,)
+
+
+def _decode_pos_keys(group_map, poses, dim_metas):
+    """Join positions -> the group items' values, on the host: item i is
+    column `cid` of dimension `di` at that dimension's position. Shared
+    by both position-grouped kinds. poses: {di: int array}.
+    -> (keys, key_nulls, key_dicts)."""
+    keys, key_nulls, key_dicts = [], [], []
+    for kind, di, cid in group_map:
+        pos = poses[di]
+        data, nulls, sdict = dim_metas[di]["arrays"][cid]
+        keys.append(data[pos].astype(np.int64))
+        key_nulls.append(nulls[pos] if (kind == "dimcol" and
+                                        nulls is not None)
+                         else np.zeros(len(pos), dtype=bool))
+        key_dicts.append(sdict)
+    return keys, key_nulls, key_dicts
 
 
 def _compact_pos_dense(plan, res, group_map, pos_dims, dim_metas, sd):
@@ -827,15 +857,8 @@ def _compact_pos_dense(plan, res, group_map, pos_dims, dim_metas, sd):
         dn = dim_metas[di]["n"]
         poses[di] = rem % dn
         rem = rem // dn
-    keys, key_nulls, key_dicts = [], [], []
-    for kind, di, cid in group_map:
-        pos = poses[di]
-        data, nulls, sdict = dim_metas[di]["arrays"][cid]
-        keys.append(data[pos].astype(np.int64))
-        key_nulls.append(nulls[pos] if (kind == "dimcol" and
-                                        nulls is not None)
-                         else np.zeros(len(pos), dtype=bool))
-        key_dicts.append(sdict)
+    keys, key_nulls, key_dicts = _decode_pos_keys(group_map, poses,
+                                                  dim_metas)
     states = [[host_array(s)[slots] for s in st] for st in res["states"]]
     return PartialAggResult(ngroups=len(slots), keys=keys,
                             key_nulls=key_nulls, states=states,
@@ -867,6 +890,22 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
     post = list(plan.post_filters)
     group_items = list(plan.group_items)
     aggs = list(plan.aggs)
+    posruns = agg_kind == "posruns"
+    group_only = frozenset()
+    if posruns:
+        # the positions are the group keys: a dimension column that
+        # nothing but the group items reads is decoded from them on the
+        # host and never gathered at fact width
+        group_map, _pd = _pos_group_items(plan)
+        read = set()
+        for e in post + [arg for a in aggs for arg in a.args]:
+            read |= _expr_idxs(e)
+        for dim in dims:
+            for _, pe in dim.all_keys():
+                read |= _expr_idxs(pe)
+        group_only = frozenset(g.idx for g, (kind, _di, _c) in
+                               zip(group_items, group_map)
+                               if kind == "dimcol") - read
 
     def body(fjc, fvv, dargs):
         cap = fact_cap
@@ -968,6 +1007,8 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                     mask = mask & hit
                     if dim.join_type != "semi":
                         for idx, (jd, jn) in da["cols"].items():
+                            if idx in group_only:
+                                continue
                             g = jd[pos]
                             gn = jn[pos] if jn is not None else None
                             cols[idx] = (g, gn, layout[idx][1])
@@ -1005,10 +1046,15 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
             if ecap is not None or want_fnvalid:
                 res["fnvalid"] = fnvalid
             return res
+        # "sort" and "posruns" share everything but the run keys:
+        # agg_param[1] is the segment impl or the position dims
         gb, agg_impl, topn, ccap = agg_param
+        pos_dims = agg_impl if posruns else ()
+        pkeys = [dim_pos[di] for di in pos_dims]
         with jax.named_scope("compact"):
             csum = jnp.cumsum(mask.astype(jnp.int64))
             nvalid = csum[cap - 1]
+        actx, amask, acap = ctx, mask, cap
         if ccap is not None:
             # compact-then-aggregate (selective pipelines, the
             # Q18/Q21 class): the sort-based agg pays O(cap log cap)
@@ -1028,18 +1074,33 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                 for cidx, (d, nl, sd) in cols.items():
                     ccols[cidx] = (d[src],
                                    None if nl is None else nl[src], sd)
-                cctx = EvalCtx(jnp, ccap, ccols, host=False)
-            with jax.named_scope("group_agg"):
-                res = sort_agg_body(cctx, ok, group_items, aggs, ccap,
-                                    gb, impl=agg_impl)
-        else:
-            with jax.named_scope("group_agg"):
-                res = sort_agg_body(ctx, mask, group_items, aggs, cap,
+                pkeys = [k[src] for k in pkeys]
+                actx, amask, acap = EvalCtx(jnp, ccap, ccols,
+                                            host=False), ok, ccap
+        with jax.named_scope("group_agg"):
+            if posruns:
+                # masked lanes carry whatever position the probe left
+                # them: the core drops wholly masked runs, and a run
+                # they split is two partials the host merge adds up
+                res = _de._runs_agg_core(pkeys, None, amask, actx, aggs,
+                                         acap, gb)
+            else:
+                res = sort_agg_body(actx, amask, group_items, aggs, acap,
                                     gb, impl=agg_impl)
         res["nvalid"] = nvalid
         if topn is not None:
             with jax.named_scope("topn"):
-                res = _topn_select(res, aggs, topn, gb)
+                gm = None
+                if posruns and topn[0] == "group":
+                    # the ordering item's values, at bucket width
+                    kind, di, _c = group_map[topn[1]]
+                    at = res["keys"][pos_dims.index(di)]
+                    jd, jn = dargs[di]["cols"][
+                        group_items[topn[1]].idx if kind == "dimcol"
+                        else dims[di].build_key.col.idx]
+                    gm = (jd[at], jnp.zeros(gb, dtype=bool)
+                          if jn is None or kind != "dimcol" else jn[at])
+                res = _topn_select(res, aggs, topn, gb, gm)
         if ecap is not None or want_fnvalid:
             res["fnvalid"] = fnvalid
         return res
@@ -1535,7 +1596,19 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
                                       delta_rows)
     shim = _AggShim(plan.group_items, plan.aggs)
     kd, sd = capture_agg_dicts(shim, one)
+    runs = _segment_impl() == "runs"
     pos_spec = _pos_group_map(plan, dim_metas)
+    # a position domain too large for the packed-slot lowering (under
+    # the runs policy: for its broadcast-compare-reduce): on one chip
+    # under that policy the positions stay the group keys, as separate
+    # run keys ("posruns", decided a dispatch by _posruns_on); elsewhere
+    # the group items are evaluated at fact width and sorted
+    posruns_spec = None
+    if pos_spec is not None and \
+            pos_spec[2] > (_de._BCR_MAX if runs else _POS_DENSE_MAX):
+        if runs and mesh is None:
+            posruns_spec = pos_spec
+        pos_spec = None
     sizes = None
     if pos_spec is None:
         fcols = None
@@ -1556,20 +1629,15 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
             # delta key OUTSIDE it would silently merge into a boundary
             # group — those executions take the exact sort lowering
             sizes = None
-    if _segment_impl() == "runs":
-        # big dense/position domains have no scatter-free dense
-        # lowering: fall to the "sort" agg kind, which lowers to
-        # runs_agg_body (contiguous-run partials) on TPU. Join
-        # positions inherit the fact table's clustering, so Q3-shaped
-        # group-by-FK stays compact.
-        if pos_spec is not None and pos_spec[2] > _de._BCR_MAX:
-            pos_spec = None
-            sizes = _dense_strides(shim, kd)
-            if sizes is not None and delta_part is not None and \
-                    not _delta_in_span(shim, sizes, delta_part):
-                sizes = None
-        if sizes is not None and _dense_nslots(sizes) > _de._BCR_MAX:
-            sizes = None
+    if runs and sizes is not None and \
+            _dense_nslots(sizes) > _de._BCR_MAX:
+        # big dense domains have no scatter-free dense lowering: they
+        # fall to the contiguous-run partials ("posruns" or "sort")
+        sizes = None
+    if sizes is not None:
+        # a few dict codes (c_mktsegment over 150k customers): the
+        # dense kind's compare-reduce beats runs over scattered positions
+        posruns_spec = None
 
     fact_sdicts = {k: v[2] for k, v in one.items()
                    if k in {sc.col.idx for sc in plan.fact_dag.cols}}
@@ -1600,8 +1668,21 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
     oh_learn = []
     oh_parts = []
 
+    def _posruns_on():
+        """Group on the join positions? Read a dispatch: a degraded
+        partition pins the shape to "sorted" mid-statement. A learned
+        one-hot table (it can only date from a pinned spell) keeps the
+        one-hot kind."""
+        return posruns_spec is not None and \
+            copr._host_cache.get(implk) != "sorted" and \
+            not isinstance(copr._host_cache.get(ohk), dict)
+
     def _oh_eligible():
+        # position-grouped shapes learn no one-hot table: that kind has
+        # to evaluate every group item at fact width, the gathers the
+        # positions save
         if not plan.group_items or pos_spec is not None or \
+                _posruns_on() or \
                 sizes is not None or delta_rows or mesh is not None:
             return False
         if copr._host_cache.get(ohk) is False:
@@ -1669,6 +1750,7 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
             agg_kind, agg_param = "onehot", \
                 (copr._host_cache[ohk]["scap"],)
         else:
+            posruns = _posruns_on()
             agg_impl = copr._host_cache.get(implk) or _segment_impl()
             topn_k = None
             # candidate pruning is sound ONLY under the runs
@@ -1687,8 +1769,13 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
                 topn_k = (ts[0], ts[1], ts[2],
                           min(ts[3] + 66, group_bucket))
             ccap = copr._host_cache.get(compk)
-            agg_kind, agg_param = "sort", (
-                group_bucket, agg_impl, topn_k,
+            # both kinds share the bucket-growth retry, the compaction
+            # policy and the top-n proof, so their agg_param differs
+            # in one slot: the segment impl or the position dims
+            agg_kind = "posruns" if posruns else "sort"
+            agg_param = (
+                group_bucket,
+                tuple(posruns_spec[1]) if posruns else agg_impl, topn_k,
                 ccap if isinstance(ccap, int) else None)
         ec = copr._host_cache.get(ecapk)
         ecap = ec if isinstance(ec, int) and ec < cap else None
@@ -1700,7 +1787,7 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
             # slower with it). Compaction pays when dim probes and
             # multi-pass agg lowerings run at survivor scale.
             ecap = None
-        if ecap is not None and agg_kind == "sort":
+        if ecap is not None and agg_kind in ("sort", "posruns"):
             # survivors are already compacted: the late (post-join)
             # compact stage would re-gather the same buffer
             agg_param = agg_param[:3] + (None,)
@@ -1757,6 +1844,22 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
             if sp is not None:
                 sp.attrs["retries"] = retries
 
+    def _host_keys(res, posruns, pos_dims, ng):
+        """The first ng groups' keys of a "sort" or "posruns" result,
+        as values -> (keys, key_nulls, key_dicts)."""
+        ks = [host_array(k)[:ng] for k in res["keys"]]
+        if posruns:
+            return _decode_pos_keys(posruns_spec[0],
+                                    dict(zip(pos_dims, ks)), dim_metas)
+        return ks, [host_array(kn)[:ng] for kn in res["key_nulls"]], kd
+
+    def _emit(posruns, ng, ks, kns, kds, sts):
+        if posruns and getattr(copr, "domain", None) is not None:
+            copr.domain.inc_metric("fused_posruns_agg")
+        out.append(PartialAggResult(
+            ngroups=ng, keys=ks, key_nulls=kns, states=sts,
+            key_dicts=kds, state_dicts=sd))
+
     def _consume_until_valid(state, cols, v, m, bind_keys):
         nonlocal group_bucket
         retries = -1
@@ -1805,7 +1908,8 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
                                host_int(res["nvalid"]), cap) == "retry":
                 state = _dispatch_part(cols, v, m, bind_keys)
                 continue
-            if agg_param[1] == "runs" and \
+            posruns = agg_kind == "posruns"
+            if (posruns or agg_param[1] == "runs") and \
                     ngroups > max(_de._RUNS_DEGRADE_MIN, m // 4):
                 # unclustered group keys: pin this query shape to the
                 # sorted lowering before learning an inflated bucket
@@ -1828,9 +1932,8 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
                 # provably covers the true top k before trusting it
                 kprime = topn_k[3]
                 ncand = min(ngroups, kprime)
-                ckeys = [host_array(k)[:ncand] for k in res["keys"]]
-                cnulls = [host_array(kn)[:ncand]
-                          for kn in res["key_nulls"]]
+                ckeys, cnulls, ckd = _host_keys(res, posruns,
+                                                agg_param[1], ncand)
                 cstates = [[host_array(s)[:ncand] for s in st]
                            for st in res["states"]]
                 if ngroups > kprime:
@@ -1851,12 +1954,9 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
                         copr._host_cache[offk] = True
                         state = _dispatch_part(cols, v, m, bind_keys)
                         continue
-                out.append(PartialAggResult(
-                    ngroups=ncand, keys=ckeys, key_nulls=cnulls,
-                    states=cstates, key_dicts=kd, state_dicts=sd))
+                _emit(posruns, ncand, ckeys, cnulls, ckd, cstates)
                 return retries
-            ks = [host_array(k)[:ngroups] for k in res["keys"]]
-            kns = [host_array(kn)[:ngroups] for kn in res["key_nulls"]]
+            ks, kns, kds = _host_keys(res, posruns, agg_param[1], ngroups)
             sts = [[host_array(s)[:ngroups] for s in st]
                    for st in res["states"]]
             if oh_elig and copr._host_cache.get(ohk) is None:
@@ -1873,9 +1973,7 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
                     oh_learn.clear()
                 else:
                     oh_learn.append((ks, kns))
-            out.append(PartialAggResult(
-                ngroups=ngroups, keys=ks, key_nulls=kns, states=sts,
-                key_dicts=kd, state_dicts=sd))
+            _emit(posruns, ngroups, ks, kns, kds, sts)
             return retries
 
     # partition pipelining: partition i+1's padding/upload/dispatch is
