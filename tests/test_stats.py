@@ -21,6 +21,33 @@ def test_fmsketch_accuracy_and_merge():
     assert 0.7 * 90_000 <= est <= 1.4 * 90_000, est
 
 
+def _insert_a_mask_bit_at_a_time(fm, hashes):
+    """FMSketch.insert_hashes as it stood before PR 27 (a Python set of
+    every hash under the mask, the mask grown one bit a pass): the
+    reference the array form must equal, mask and set."""
+    h = hashes.astype(np.uint64)
+    while True:
+        keep = h[(h & fm.mask) == 0]
+        fm.hashset.update(keep.tolist())
+        if len(fm.hashset) <= fm.MAX_SIZE:
+            return
+        fm.mask = np.uint64((int(fm.mask) << 1) | 1)
+        fm.hashset = {v for v in fm.hashset if v & int(fm.mask) == 0}
+
+
+@pytest.mark.parametrize("rows, ndv", [(1000, 10), (50_000, 50_000),
+                                       (300_000, 3), (300_000, 200_000)])
+def test_fmsketch_array_form_equals_the_loop(rows, ndv):
+    rng = np.random.default_rng(rows + ndv)
+    ref, fm = FMSketch(), FMSketch()
+    for part in range(3):               # a sketch is fed column by column
+        hv = _hash_values(rng.integers(0, ndv, rows) + part * (ndv // 2))
+        _insert_a_mask_bit_at_a_time(ref, hv)
+        fm.insert_hashes(hv)
+        assert int(fm.mask) == int(ref.mask)
+        assert fm.hashset == ref.hashset and fm.ndv() == ref.ndv()
+
+
 def test_global_partition_stats():
     tk = TestKit()
     tk.must_exec("create table pt (id int, v int) partition by range (id) "

@@ -13,6 +13,7 @@ NULL-aware throughout (masks). Strings ride as dict codes.
 """
 from __future__ import annotations
 
+import functools
 import os
 import time
 
@@ -27,7 +28,6 @@ from ..expression.vec import materialize_nulls
 from ..utils import env_int
 from ..utils.fetch import prefetch, host_array, host_int
 from .residency import DeviceResidentStore
-from ..utils import memory as _memory
 from ..utils import phase
 from ..utils import device_guard
 from ..utils import metrics as _metrics
@@ -73,6 +73,18 @@ class _KernelCache(dict):
             self.hits += 1
             _metrics.KERNEL_CACHE.labels("hit").inc()
         return v
+
+
+def _as_row_block(run):
+    """A partition runner that takes `part=(i, n)` and runs as row block
+    i of n (phase.row_block). Opened inside the runner, not round the
+    guarded call: the watchdog may move the dispatch onto a worker
+    thread, and the block is the thread's."""
+    @functools.wraps(run)
+    def runner(self, *args, part):
+        with phase.row_block(*part):
+            return run(self, *args)
+    return runner
 
 
 class CoprExecutor:
@@ -128,7 +140,13 @@ class CoprExecutor:
         ``cap``, place by spec (local jnp / row-sharded / replicated),
         account upload phases and the Broadcast exchange. -> (dev,
         ndev). Fixes to upload accounting or placement live here
-        once."""
+        once. Every caller places the buffer in the resident store,
+        where it outlives the statement: its bytes are charged to the
+        store's budget (DeviceResidentStore.put), never to the memory
+        tracker of the statement that happened to fault it in — a
+        first touch of a large table is not that statement's to be
+        cancelled for (ER 8175), and HBM pressure is the store's to
+        relieve (LRU, device_guard's pressure protocol)."""
         import jax
         t0 = time.perf_counter()
         arr = arr_np
@@ -155,10 +173,6 @@ class CoprExecutor:
         phase.add("upload_s", time.perf_counter() - t0)
         phase.add("upload_bytes", moved)
         phase.inc("uploads")
-        # device bytes charge the statement's memory tracker (HBM is
-        # governed by the same quota + action chain as host memory;
-        # the statement's detach releases the charge at its end)
-        _memory.consume_current(moved)
         return dev, ndev
 
     def _dev_put(self, key, arr_np, pad_fill=0, uid=None, version=None):
@@ -195,13 +209,6 @@ class CoprExecutor:
         self.last_backend = ""
         dom = getattr(self, "domain", None)
         t0 = time.perf_counter()
-        # install the statement tracker for the upload seams (device
-        # bytes charge the statement that asked for them); only when
-        # this call carries one — a nested tracker-less call must not
-        # clear an enclosing statement's
-        tr = getattr(ectx, "mem_tracker", None) if ectx is not None \
-            else None
-        prev = _memory.push_current(tr) if tr is not None else None
         try:
             if dom is not None:
                 with dom.tracer.span("copr",
@@ -211,8 +218,6 @@ class CoprExecutor:
             return self._execute_inner(dag, overlay, read_ts, use_mpp,
                                        mpp_min_rows, ectx)
         finally:
-            if tr is not None:
-                _memory.set_current(prev)
             # labeled by the backend that actually served the DAG
             # ("none" = early return: empty snapshot / virtual table)
             _metrics.COPR_DISPATCH_SECONDS.labels(
@@ -495,36 +500,38 @@ class CoprExecutor:
         step = self.device_rows
         produced = 0
         dom = getattr(self, "domain", None)
+        parts = -(-n // step)
         for start in range(0, n, step):
             sl = slice(start, min(start + step, n))
             m = sl.stop - sl.start
             cap = shape_bucket(m)
+            part = start // step
             with phase.bind_span():
                 cols = self._bind_cols(dag, tbl, arrays, sl, handles,
                                        cacheable=(n == tbl.n))
             v = valid[sl]
             if dag.aggs or dag.group_items:
                 res = device_guard.guarded_dispatch(
-                    lambda: self._run_agg_partition(dag, tbl, cols, v,
-                                                    m, cap),
+                    lambda: self._run_agg_partition(
+                        dag, tbl, cols, v, m, cap, part=(part, parts)),
                     site="copr/agg", ectx=ectx, domain=dom)
                 out.append(res)
                 continue
             if dag.topn is not None:
                 idx = device_guard.guarded_dispatch(
-                    lambda: self._run_topn_partition(dag, tbl, cols, v,
-                                                     m, cap),
+                    lambda: self._run_topn_partition(
+                        dag, tbl, cols, v, m, cap, part=(part, parts)),
                     site="copr/topn", ectx=ectx, domain=dom,
                     host_fallback=lambda: self._topn_host(dag, cols, v,
                                                           m))
-                with _tracing.span("consume"):
+                with _tracing.span("consume", part=part, parts=parts):
                     out.append(self._gather_chunk(dag, cols, idx))
                 continue
             mask = device_guard.guarded_dispatch(
-                lambda: self._run_filter_partition(dag, tbl, cols, v,
-                                                   m, cap),
+                lambda: self._run_filter_partition(
+                    dag, tbl, cols, v, m, cap, part=(part, parts)),
                 site="copr/filter", ectx=ectx, domain=dom)
-            with _tracing.span("consume"):
+            with _tracing.span("consume", part=part, parts=parts):
                 idx = np.nonzero(np.asarray(mask)[:m])[0]
                 if dag.limit >= 0:
                     remain = dag.limit - produced
@@ -775,6 +782,7 @@ class CoprExecutor:
         return (kind, tbl.uid, cap, fps, gfps, afps, dict_vers, colsig,
                 _segment_impl(), extra)
 
+    @_as_row_block
     def _run_filter_partition(self, dag, tbl, cols, v, m, cap):
         key = self._cache_key(dag, tbl, "filter", cap)
         kern = self._kernel_cache.get(key)
@@ -798,7 +806,7 @@ class CoprExecutor:
             jcols, vv = self._pad_upload(cols, v, m, cap)
         jc = {k: (d, nl) for k, (d, nl, _) in jcols.items()}
         res = prefetch(kern(jc, vv))
-        with _tracing.span("consume"):
+        with _tracing.span("consume", **phase.part_attrs()):
             mask = host_array(res)
             # host-only filters applied on host afterwards
             if dag.host_filters:
@@ -809,6 +817,7 @@ class CoprExecutor:
                 return hm
             return mask
 
+    @_as_row_block
     def _run_topn_partition(self, dag, tbl, cols, v, m, cap):
         """Fused filter + device top-k over the single sort key; returns
         host indices of the top rows (<= k) in key order."""
@@ -859,7 +868,7 @@ class CoprExecutor:
             jc = {kk: (d, nl) for kk, (d, nl, _) in jcols.items()}
             vv = self._and_host_filters(dag, cols, vv, m, cap)
         top_idx, cnt = prefetch(kern(jc, vv))
-        with _tracing.span("consume"):
+        with _tracing.span("consume", **phase.part_attrs()):
             return host_array(top_idx)[:host_int(cnt)]
 
     @staticmethod
@@ -912,6 +921,7 @@ class CoprExecutor:
         order = part[np.argsort(-kv[part], kind="stable")]
         return order[:cnt]
 
+    @_as_row_block
     def _run_agg_partition(self, dag, tbl, cols, v, m, cap,
                            group_bucket=1024):
         """Device partial aggregation; returns PartialAggResult."""
@@ -953,12 +963,12 @@ class CoprExecutor:
                 jc = {k: (d, nl) for k, (d, nl, _) in jcols.items()}
                 vv = self._and_host_filters(dag, cols, vv, m, cap)
             res = prefetch(kern(jc, vv))
-            with _tracing.span("consume", retries=retries):
+            with _tracing.span("consume", retries=retries,
+                               **phase.part_attrs()):
                 if strides is not None:
                     return _compact_dense(dag, res, strides, kd, sd)
                 ngroups = host_int(res["ngroups"])
-                if impl == "runs" and \
-                        ngroups > max(_RUNS_DEGRADE_MIN, m // 4):
+                if impl == "runs" and _runs_degraded(ngroups, m):
                     # keys uncorrelated with storage order: runs
                     # exploded into ~per-row partials. Pin this (table,
                     # group, agg) shape to the sorted lowering (one
@@ -1203,10 +1213,24 @@ _FORCE_SEGMENT_IMPL = None  # tests: "scatter"|"sorted"|"runs"|None (auto)
 # domains (Q1's flag x status = 12, Q5's 25 nations)
 _BCR_MAX = int(os.environ.get("TIDB_TPU_BCR_MAX", "64"))
 
-# if the runs lowering yields more partials than this (and more than a
-# quarter of the partition's rows), the group key is uncorrelated with
+# if the runs lowering yields more partials than this (and more than
+# half the partition's rows), the group key is uncorrelated with
 # storage order — pin the query shape to the sorted lowering instead
 _RUNS_DEGRADE_MIN = int(os.environ.get("TIDB_TPU_RUNS_DEGRADE", "65536"))
+
+
+def _runs_degraded(ngroups, m) -> bool:
+    """Did the runs lowering explode into ~per-row partials over `m`
+    rows? Keys uncorrelated with storage order give about one run a row
+    (m(1 - 1/D) runs for D distinct values); a key the storage clusters
+    gives m / L for runs of L rows, which the sorted lowering could not
+    shrink either. The line is at runs of two and not higher up: at
+    four it is TPC-H's mean lines an order (4.0008), and lineitem GROUP
+    BY l_orderkey (q18's subquery: 1,048,366 +- 500 runs a
+    4,194,304-row block) falls on either side of it block by block —
+    where the wrong side is a sort program that costs the TPU compiler
+    29 GB of host memory and 390 s at that width (PERF.md, PR 27)."""
+    return ngroups > max(_RUNS_DEGRADE_MIN, m // 2)
 
 
 def _segment_impl():

@@ -35,7 +35,6 @@ from .dag_exec import (PartialAggResult, capture_agg_dicts, _dense_strides,
 from ..utils.fetch import prefetch, host_array, host_int
 from ..utils import failpoint
 from ..utils import jaxcfg
-from ..utils import memory as _memory
 from ..utils import phase
 from ..utils import tracing as _tracing
 
@@ -67,7 +66,19 @@ def _set_reason(copr, msg):
     _metrics.FUSED_DECLINE.labels(_metrics.reason_code(msg)).inc()
 
 
-_DIRECT_SPAN_BUDGET = 1 << 24
+def _direct_span(copr, span, nv) -> bool:
+    """May a dimension's build keys be probed through a direct lut
+    (`lut[key - lo]`, one gather a probe) rather than a binary search
+    over the sorted keys (~70 gathers' time on the chip, PERF.md §7)?
+    Two bounds, both from what is observed: the keys are dense enough
+    that the lut is at most four slots a row, and the lut (8 bytes a
+    slot, resident like the dimension's columns) fits an eighth of the
+    resident store's budget — 128 Mi slots at the default 8 GiB, so
+    TPC-H's orders (18,000,000 sparse keys at scale 3, 60,000,000 at
+    10) stay off the binary search, which ran q3, q5 and q10 in 14-16 s
+    instead of 1-4 (PERF.md, PR 27)."""
+    return span <= max(4 * nv, 1 << 12) and \
+        span * 8 <= copr._dev_store.budget // 8
 
 
 def _dim_sort_meta(copr, dim, tbl, read_ts):
@@ -135,7 +146,7 @@ def _dim_sort_meta(copr, dim, tbl, read_ts):
             lo = int(keys_v.min())
             hi = int(keys_v.max())
             span = hi - lo + 1
-            if span <= max(4 * nv, 1 << 12) and span <= _DIRECT_SPAN_BUDGET:
+            if _direct_span(copr, span, nv):
                 lut = np.full(span, n, dtype=np.int64)   # n == miss
                 lut[keys_v - lo] = vidx
                 meta = ("direct", lut, lo, unique, nv, pack)
@@ -443,7 +454,7 @@ def _materialized_dim_meta(copr, ctx, dim, read_ts):
            "dictsig": tuple(sorted(
                (i, len(sd.values)) for i, (_d, _nl, sd) in arrays.items()
                if sd is not None))}
-    if span <= max(4 * n, 1 << 12) and span <= _DIRECT_SPAN_BUDGET:
+    if _direct_span(copr, span, n):
         lut = np.full(span, n, dtype=np.int64)
         lut[keys_v - lo] = vidx
         out.update(mode="direct", lo=lo, lut=lut, n_sorted=n)
@@ -538,8 +549,7 @@ def _semi_prefiltered_meta(copr, dim, tbl, arrays, valid, n, key_cid,
         else:
             lo = int(keys.min())
             span = int(keys.max()) - lo + 1
-            if span <= max(4 * nv, 1 << 12) and \
-                    span <= _DIRECT_SPAN_BUDGET:
+            if _direct_span(copr, span, nv):
                 lut = np.full(span, n, dtype=np.int64)
                 lut[keys - lo] = 0       # any representative: hit test
                 meta = ("direct", lut, lo, True, nv)
@@ -1429,31 +1439,6 @@ def _oh_fold_delta(copr, ohk, plan, fact_arrays, n, version):
         dom.inc_metric("fused_onehot_delta_fold")
 
 
-def fused_partials(copr, plan, read_ts, mesh=None,
-                   bcast_threshold=1 << 20, ctx=None, delta_rows=None,
-                   dead_handles=None):
-    """Execute a PhysFusedPipeline -> [PartialAggResult] (one per fact
-    partition; one per mesh shard for the MPP sort layout), or None when
-    runtime-ineligible (caller falls back to the conventional subtree).
-    With a mesh, the whole pipeline runs as one shard_map program: fact
-    sharded over 'dp', dims broadcast, aggregation allreduced."""
-    # statement memory tracker for the upload seams (same install as
-    # CoprExecutor.execute: the fused path uploads through _dev_put*
-    # without passing through copr.execute)
-    tr = getattr(ctx, "mem_tracker", None) if ctx is not None else None
-    if tr is None:
-        return _fused_partials_inner(copr, plan, read_ts, mesh,
-                                     bcast_threshold, ctx, delta_rows,
-                                     dead_handles)
-    prev = _memory.push_current(tr)
-    try:
-        return _fused_partials_inner(copr, plan, read_ts, mesh,
-                                     bcast_threshold, ctx, delta_rows,
-                                     dead_handles)
-    finally:
-        _memory.set_current(prev)
-
-
 def _bind_tables(copr, plan, read_ts, ctx):
     """The statement's first `bind`: fold committed deltas into the
     resident buffers of the fact table and every dimension, build or
@@ -1518,9 +1503,14 @@ def _bind_tables(copr, plan, read_ts, ctx):
     return fact_tbl, dim_metas, fact_version, fact_arrays, fact_valid
 
 
-def _fused_partials_inner(copr, plan, read_ts, mesh=None,
-                          bcast_threshold=1 << 20, ctx=None,
-                          delta_rows=None, dead_handles=None):
+def fused_partials(copr, plan, read_ts, mesh=None,
+                   bcast_threshold=1 << 20, ctx=None, delta_rows=None,
+                   dead_handles=None):
+    """Execute a PhysFusedPipeline -> [PartialAggResult] (one per fact
+    partition; one per mesh shard for the MPP sort layout), or None when
+    runtime-ineligible (caller falls back to the conventional subtree).
+    With a mesh, the whole pipeline runs as one shard_map program: fact
+    sharded over 'dp', dims broadcast, aggregation allreduced."""
     with phase.bind_span():
         bound = _bind_tables(copr, plan, read_ts, ctx)
     if not isinstance(bound, tuple):
@@ -1714,6 +1704,10 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
             handles, dim_args, dim_metas, dim_caps, dim_ns, dim_sns,
             dim_layouts, fact_sdicts, pos_spec, sizes, shim, kd, sd,
             gbkey, group_bucket, read_ts, dim_pres)
+    # row blocks of this run: the fact's, plus the transaction's own
+    # rows as one more; the `dispatch`/`consume` spans carry the numbers
+    parts = -(-n // step) + (delta_part is not None)
+
     def _partitions():
         for start in range(0, n, step):
             sl = slice(start, min(start + step, n))
@@ -1834,12 +1828,13 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
         res = prefetch(kern(fjc, fvv, kargs))
         return res, cap, agg_kind, agg_param, ecap, oh_table
 
-    def _consume_part(state, cols, v, m, bind_keys):
+    def _consume_part(part, state, cols, v, m, bind_keys):
         """Validate one partition's run against the learned lowering
         parameters and turn it into host partials. A policy that says
         "retry" re-dispatches this partition inside the span: its
         `bind`/`dispatch` children are the cost of the retry."""
-        with _tracing.span("consume") as sp:
+        with phase.row_block(part, parts), \
+                _tracing.span("consume", part=part, parts=parts) as sp:
             retries = _consume_until_valid(state, cols, v, m, bind_keys)
             if sp is not None:
                 sp.attrs["retries"] = retries
@@ -1910,7 +1905,7 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
                 continue
             posruns = agg_kind == "posruns"
             if (posruns or agg_param[1] == "runs") and \
-                    ngroups > max(_de._RUNS_DEGRADE_MIN, m // 4):
+                    _de._runs_degraded(ngroups, m):
                 # unclustered group keys: pin this query shape to the
                 # sorted lowering before learning an inflated bucket
                 copr._host_cache[implk] = "sorted"
@@ -1987,14 +1982,14 @@ def _fused_partials_inner(copr, plan, read_ts, mesh=None,
     # on the rare learning executions, steady state unchanged).
     depth = max(1, int(os.environ.get("TIDB_TPU_PIPELINE_DEPTH", "2")))
     pending = []
-    for cols, v, m, bkeys in _partitions():
-        pending.append((_dispatch_part(cols, v, m, bkeys),
-                        cols, v, m, bkeys))
+    for part, (cols, v, m, bkeys) in enumerate(_partitions()):
+        with phase.row_block(part, parts):
+            state = _dispatch_part(cols, v, m, bkeys)
+        pending.append((part, state, cols, v, m, bkeys))
         if len(pending) >= depth:
-            st, c0, v0, m0, b0 = pending.pop(0)
-            _consume_part(st, c0, v0, m0, b0)
-    for st, c0, v0, m0, b0 in pending:
-        _consume_part(st, c0, v0, m0, b0)
+            _consume_part(*pending.pop(0))
+    for held in pending:
+        _consume_part(*held)
     if oh_parts:
         # drop slots with zero rows across every one-hot partition:
         # stale learned keys (deletes, older read_ts) must not emit
@@ -2254,7 +2249,7 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
                 retries += 1
                 continue
             if agg_param[1] == "runs" and \
-                    ng_max > max(_de._RUNS_DEGRADE_MIN, local // 4):
+                    _de._runs_degraded(ng_max, local):
                 # unclustered group keys on this shard layout: pin to the
                 # sorted lowering before learning an inflated bucket
                 copr._host_cache[("aggimpl", fact_tbl.gc_epoch) + gbkey] = \

@@ -74,6 +74,7 @@ class DeviceResidentStore:
     def __init__(self, budget_bytes: int):
         self.budget = budget_bytes
         self.bytes = 0
+        self.max_bytes = 0             # high-water mark of `bytes`
         self._mu = lockrank.ranked_lock("residency.device")
         self._entries: dict = {}       # key -> device array
         self._sizes: dict = {}         # key -> charged bytes (the spec
@@ -147,6 +148,7 @@ class DeviceResidentStore:
             self._sizes[key] = charged
             self._order[key] = None
             self.bytes += charged
+            self.max_bytes = max(self.max_bytes, self.bytes)
             self._spec_of[key] = spec
             self._bytes_by_spec[spec] += charged
             # delta, not set(): several stores share the process-global
@@ -306,6 +308,7 @@ class DeviceResidentStore:
         per-placement split (information_schema / debugging surface)."""
         with self._mu:
             return {"entries": len(self._entries), "bytes": self.bytes,
+                    "max_bytes": self.max_bytes, "budget": self.budget,
                     "bytes_by_spec": dict(self._bytes_by_spec)}
 
     def _drop_locked(self, key, cause: str):

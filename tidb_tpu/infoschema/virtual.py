@@ -101,7 +101,9 @@ def _gen_memory_usage(domain):
     when unlimited), one 'session' row per live connection, one
     'statement' row per live statement tracker (its quota = the
     effective tidb_mem_quota_query / MEMORY_QUOTA hint, plus the
-    statement's oom action). The instance-level analog of the
+    statement's oom action), and one 'device_pool' row for the
+    device-resident store (HBM bytes charged, high-water mark, byte
+    budget; action 'evict'). The instance-level analog of the
     reference's information_schema.memory_usage."""
     root = getattr(domain, "mem_root", None)
     if root is None:
@@ -110,6 +112,15 @@ def _gen_memory_usage(domain):
     lim = ctl.limit_bytes() if ctl is not None else 0
     yield (0, "global", root.label, root.consumed, root.max_consumed,
            lim if lim else -1, "")
+    # resident device buffers belong to the pool, not to the statement
+    # that faulted them in: charged to the store's own budget, shed by
+    # its LRU and the pressure protocol, never by ER 8175
+    copr = getattr(domain, "copr", None)
+    store = getattr(copr, "_dev_store", None)
+    if store is not None:
+        st = store.stats()
+        yield (0, "device_pool", "device-resident store", st["bytes"],
+               st["max_bytes"], st["budget"], "evict")
     # snapshot both registries: connections register / statements
     # start concurrently with this read, and iterating the live dicts
     # would die on "changed size during iteration" exactly under the

@@ -55,15 +55,23 @@ class FMSketch:
         self.hashset: set = set()
 
     def insert_hashes(self, hashes: np.ndarray):
+        """Add a column's hashes: the mask grows until the distinct
+        survivors fit. All of it on arrays: the set is built once, from
+        at most MAX_SIZE values. (Built a mask bit at a time from Python
+        ints it held the interpreter lock for seconds a column of a
+        multi-million-row table, on whichever thread analysed — the
+        auto-analyze tick stalled every connection: PERF.md, PR 27.)"""
         h = hashes.astype(np.uint64)
-        while True:
-            keep = h[(h & self.mask) == 0]
-            self.hashset.update(keep.tolist())
-            if len(self.hashset) <= self.MAX_SIZE:
-                return
-            self.mask = np.uint64((int(self.mask) << 1) | 1)
-            self.hashset = {v for v in self.hashset
-                            if v & int(self.mask) == 0}
+        mask = self.mask
+        h = np.unique(h[(h & mask) == 0])
+        if self.hashset:
+            h = np.union1d(h, np.fromiter(self.hashset, dtype=np.uint64,
+                                          count=len(self.hashset)))
+        while len(h) > self.MAX_SIZE:
+            mask = np.uint64((int(mask) << 1) | 1)
+            h = h[(h & mask) == 0]
+        self.mask = mask
+        self.hashset = set(h.tolist())
 
     def merge(self, other: "FMSketch"):
         self.mask = max(self.mask, other.mask, key=int)

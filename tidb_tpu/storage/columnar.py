@@ -11,6 +11,7 @@ writes no per-row KV; such tables serve the OLAP path.
 """
 from __future__ import annotations
 
+import gc
 import threading
 
 import numpy as np
@@ -274,6 +275,14 @@ class ColumnarTable:
         # executors fall back to columnar scans)
         self.bulk_rows += n
         self.version += 1
+        # what a bulk load leaves behind lives as long as the table:
+        # column arrays, and the dictionaries' value lists and indexes
+        # with an entry a distinct string. Left in the collector's
+        # generations, every full collection walks them — 0.5-1.4 s at
+        # TPC-H scale 3 (26 M strings), about once a minute, inside
+        # whichever statement is running (PERF.md, PR 27); moved to the
+        # permanent generation, it does not
+        gc.freeze()
 
     def is_clustered(self, cid: int) -> bool:
         """True when the column is non-NULL and monotone non-decreasing

@@ -35,7 +35,6 @@ import time
 import weakref
 
 from . import failpoint
-from . import memory as _memory
 from . import metrics as _metrics
 from . import phase as _phase
 from . import tracing as _tracing
@@ -311,18 +310,14 @@ def _with_watchdog(fn, timeout_ms: int, site: str):
     # worker that later unwedges writes into garbage, never into a
     # subsequent statement's attribution
     worker_stats: dict = {}
-    # the statement's memory tracker is thread-local like phase state:
-    # a dispatch moved onto the watchdog worker must keep charging its
-    # upload bytes to the statement that asked for them
-    mem_tracker = _memory.current_tracker()
-    # and so is the trace context: spans the worker opens (bind,
-    # dispatch) finish into a private list, folded into the statement's
-    # trace below like the stats, and dropped with an abandoned worker
+    # the trace context is thread-local like phase state: spans the
+    # worker opens (bind, dispatch) finish into a private list, folded
+    # into the statement's trace below like the stats, and dropped
+    # with an abandoned worker
     handed = _tracing.handoff()
 
     def run():
         _phase.adopt(worker_stats)
-        _memory.set_current(mem_tracker)
         box["spans"] = _tracing.adopt(handed)
         try:
             box["v"] = fn()

@@ -31,12 +31,13 @@ Consumption from a buffer the CALLER can spill passes
 ``can_spill=True``: such a breach arms triggers but never cancels —
 the operator itself guarantees a spill decision on its next poll.
 
-HBM accounting rides the same tree: the copr upload seams
-(dag_exec._upload_padded and every _dev_put* above it) consume real
-moved bytes against the CURRENT statement tracker (the thread-local
-below, installed by copr.execute / pipeline.fused_partials and
-propagated into watchdog workers by device_guard), so device-memory
-pressure is governed by the same quota + action chain as host memory.
+HBM is NOT on this tree. Buffers the copr upload seams place in the
+device-resident store (dag_exec._upload_padded and every _dev_put*
+above it) outlive the statement that faulted them in, so they are
+charged to the store's own byte budget (copr/residency.py) and shed by
+its LRU and by device_guard's pressure protocol — never to a
+statement's quota: a first touch of a large table is not a reason for
+ER 8175. The tree governs what a statement itself holds on the host.
 
 The ROOT tracker supports a soft limit (``soft_limit_fn`` +
 ``on_soft_breach``): the Domain wires the tidb_tpu_server_memory_limit
@@ -47,8 +48,6 @@ statement tracker is flagged so its very next consume raises even if
 it never reaches a check_killed poll.
 """
 from __future__ import annotations
-
-import threading
 
 from . import metrics as _metrics
 from .logutil import log
@@ -245,38 +244,3 @@ class Tracker:
     def track_array(self, arr):
         self.consume(getattr(arr, "nbytes", 0))
         return arr
-
-
-# ---- the current statement tracker (thread-local) ---------------------
-# Installed around copr/fused execution (dag_exec.execute,
-# pipeline.fused_partials) so the shared upload seams can charge device
-# bytes to the statement that asked for them without threading a
-# tracker through every kernel-builder signature. device_guard's
-# watchdog copies it into the dispatch worker thread (phase-counter
-# idiom).
-
-_TLS = threading.local()
-
-
-def current_tracker() -> Tracker | None:
-    return getattr(_TLS, "tracker", None)
-
-
-def set_current(t: Tracker | None):
-    _TLS.tracker = t
-
-
-def push_current(t: Tracker | None) -> Tracker | None:
-    """Install t as the thread's current tracker, returning the
-    previous one for the caller's finally-restore."""
-    prev = getattr(_TLS, "tracker", None)
-    _TLS.tracker = t
-    return prev
-
-
-def consume_current(n: int):
-    """Charge n bytes to the thread's current statement tracker (the
-    copr upload seams); a no-op when no statement is tracking."""
-    t = getattr(_TLS, "tracker", None)
-    if t is not None and n:
-        t.consume(n)

@@ -710,6 +710,9 @@ def update_runtime_gauges(domain):
     if root is not None:
         MEM_TRACKER_BYTES.labels("consumed").set(root.consumed)
         MEM_TRACKER_BYTES.labels("max_consumed").set(root.max_consumed)
+    store = getattr(getattr(domain, "copr", None), "_dev_store", None)
+    if store is not None:
+        DEV_RESIDENT_BUDGET.set(store.budget)
 
 
 def reset_all():
@@ -873,6 +876,11 @@ DEV_RESIDENT_BYTES = REGISTRY.gauge(
     "spec (local=single chip, sharded=1/ndev per device so charged "
     "once, replicated=full copy per device so charged x ndev)",
     ("spec",))
+DEV_RESIDENT_BUDGET = REGISTRY.gauge(
+    "tidb_tpu_device_resident_budget_bytes",
+    "Byte budget of the serving domain's device-resident store: what "
+    "resident buffers are charged against and LRU-evicted past; "
+    "tidb_tpu_device_resident_bytes over this is how full the pool is")
 FRAGMENT_ROUTING = REGISTRY.counter(
     "tidb_tpu_fragment_routing_total",
     "Copr fragment placement decisions by outcome", ("outcome",))

@@ -207,14 +207,45 @@ class bind_span:
         return self._cm.__exit__(*exc)
 
 
+class row_block:
+    """The row block the thread works on while the context is open:
+    `part` (0-based) of `parts`. The copr partition loops open it round
+    one block's dispatch and round its consume; the `dispatch` and
+    `consume` spans inside carry both numbers (`part_attrs`), so a
+    trace shows which block of a many-block scan a kernel or a merge
+    belonged to. Nests; a kernel dispatched outside any loop (vector
+    search, a mesh program over the whole table) carries neither."""
+
+    __slots__ = ("_part", "_prev")
+
+    def __init__(self, part, parts):
+        self._part = (part, parts)
+
+    def __enter__(self):
+        self._prev = getattr(_TLS, "part", None)
+        _TLS.part = self._part
+        return self
+
+    def __exit__(self, *exc):
+        _TLS.part = self._prev
+        return False
+
+
+def part_attrs() -> dict:
+    """{"part", "parts"} of the open row block, else {}."""
+    p = getattr(_TLS, "part", None)
+    return {} if p is None else {"part": p[0], "parts": p[1]}
+
+
 def timed_kernel(kind, fn):
     """Wrap a compiled kernel callable with dispatch accounting and the
-    `dispatch` span (the enqueue; `kind` is the cache key's). The first
-    call is recorded separately (it pays the XLA trace+compile)."""
+    `dispatch` span (the enqueue; `kind` is the cache key's; `part` /
+    `parts` inside a row-block loop). The first call is recorded
+    separately (it pays the XLA trace+compile)."""
     state = {"first": True}
 
     def wrapped(*args, **kw):
-        with _tracing.span("dispatch", kind=kind) as sp:
+        with _tracing.span("dispatch", kind=kind, **part_attrs()) as sp:
             t0 = time.perf_counter()
             out = fn(*args, **kw)
             dt = time.perf_counter() - t0

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 
 from ..chunk.device import shape_bucket
 from ..utils import device_guard, phase
-from ..utils import memory as _memory
 from ..utils import metrics as _metrics
 from ..utils.fetch import prefetch, host_array
 from . import kernels
@@ -213,7 +212,6 @@ class VectorRuntime:
         phase.add("upload_s", _time.perf_counter() - t0)
         phase.add("upload_bytes", nbytes)
         phase.inc("uploads")
-        _memory.consume_current(nbytes)
         store.put_appendable(key, dev, nbytes, uid, ctab.version,
                              rows=n, start=0, span=None, cap=cap,
                              spec=spec, ndev=ndev,
