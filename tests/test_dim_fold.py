@@ -1,0 +1,582 @@
+"""Folded dimensions of the fused pipeline (copr/dimfold.py): a chain
+root's probe table carries its own mask and the hit of every dimension
+resolved under it, and a fact lane reads a descendant's column or
+position with one gather through the root's position.
+
+Counts here are counts of the traced program (CPU backend), never
+device times."""
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import tidb_tpu.copr.dag_exec as de
+import tidb_tpu.copr.dimfold as df
+import tidb_tpu.copr.pipeline as pl
+from tidb_tpu.bench.tpch import load_tpch, ALL_QUERIES
+from tidb_tpu.testkit import TestKit
+from tidb_tpu.utils import metrics as mu
+from tidb_tpu.utils import phase
+
+
+@pytest.fixture
+def runs_impl():
+    de._FORCE_SEGMENT_IMPL = "runs"
+    try:
+        yield
+    finally:
+        de._FORCE_SEGMENT_IMPL = None
+
+
+@pytest.fixture(scope="module")
+def tk():
+    tk = TestKit()
+    load_tpch(tk, sf=0.003, seed=7)
+    return tk
+
+
+def _unfold(monkeypatch):
+    """The control: every dimension keeps its own probe and mask."""
+    monkeypatch.setattr(df, "fold_plan",
+                        lambda plan: df.FoldPlan(len(plan.dims)))
+
+
+@pytest.fixture
+def kinds(monkeypatch):
+    """[(agg_kind, agg_param, build args, call shapes)] of every fused
+    kernel built while the fixture is live."""
+    seen = []
+    orig = pl._build_fused_kernel
+
+    def spy(*a, **k):
+        kern = orig(*a, **k)
+        rec = [a[7], a[8], (a, k), None]
+        seen.append(rec)
+
+        def call(fjc, fvv, kargs):
+            if rec[3] is None:
+                rec[3] = jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(np.shape(x),
+                                                   np.asarray(x).dtype),
+                    (fjc, fvv, kargs))
+            return kern(fjc, fvv, kargs)
+        return call
+    monkeypatch.setattr(pl, "_build_fused_kernel", spy)
+    return seen
+
+
+def _counts():
+    return {k[0]: c.value for k, c in mu.DIM_FOLD._children.items()}
+
+
+def _grown(before):
+    now = _counts()
+    return {k: v - before.get(k, 0) for k, v in now.items()
+            if v != before.get(k, 0)}
+
+
+def _host(tk, sql):
+    tk.domain.copr.use_device = False
+    try:
+        return tk.must_query(sql).rows
+    finally:
+        tk.domain.copr.use_device = True
+
+
+def _dev_vs_host(tk, sql, runs=1):
+    tk.domain.copr.use_device = True
+    dev = [tk.must_query(sql).rows for _ in range(runs)][-1]
+    assert tk.domain.last_fused_reason is None
+    host = _host(tk, sql)
+    assert len(dev) == len(host)
+    for rd, rh in zip(dev, host):
+        for a, b in zip(rd, rh):
+            if isinstance(a, float) or isinstance(b, float):
+                np.testing.assert_allclose(float(a), float(b), rtol=1e-9)
+            else:
+                assert a == b, (sql, rd, rh)
+    return dev
+
+
+def _walk(jaxpr, visit):
+    for e in jaxpr.eqns:
+        visit(e)
+        for v in e.params.values():
+            for j in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    _walk(inner, visit)
+
+
+def _body_jaxpr(build, shapes):
+    a, k = build
+    return jax.make_jaxpr(pl._make_pipeline_body(
+        *a, **dict(k, want_fnvalid=True)))(*shapes)
+
+
+def _wide_gathers(build, shapes):
+    """Argument paths of what the body gathers from at fact width ("-"
+    for an intermediate)."""
+    cj = _body_jaxpr(build, shapes)
+    paths = [jax.tree_util.keystr(p) for p, _ in
+             jax.tree_util.tree_flatten_with_path(shapes)[0]]
+    name = {id(v): p for v, p in zip(cj.jaxpr.invars, paths)}
+    cap, out = build[0][1], []
+
+    def visit(e):
+        if e.primitive.name == "gather" and \
+                e.outvars[0].aval.shape[:1] == (cap,):
+            out.append(name.get(id(e.invars[0]), "-"))
+    _walk(cj.jaxpr, visit)
+    return out
+
+
+def _q(name):
+    # at SF0.003 no order passes q18's HAVING of 300; 244 pass 200
+    return ALL_QUERIES[name].replace("> 300", "> 200")
+
+
+def _main_kernel(tk, kinds, sql):
+    """(build, shapes) of the statement's program with dimensions."""
+    tk.domain.copr._kernel_cache.clear()
+    del kinds[:]
+    tk.domain.copr.use_device = True
+    tk.must_query(sql)
+    got = [(k[2], k[3]) for k in kinds if k[2][0][0].dims]
+    assert len(got) == 1
+    return got[0]
+
+
+# ---- (a) the census of fact-wide gathers ------------------------------
+
+# query -> (most with the fold, fewest without: ISSUE 28's table)
+_CENSUS = {"q5": (6, 27), "q10": (6, 15), "q3": (5, 10), "q18": (4, 7)}
+
+
+@pytest.mark.parametrize("q", sorted(_CENSUS))
+def test_census_fact_wide_gathers(tk, runs_impl, kinds, monkeypatch, q):
+    most, control = _CENSUS[q]
+    folded = _wide_gathers(*_main_kernel(tk, kinds, _q(q)))
+    assert len(folded) <= most, folded
+    # no mask, no child's table and no group payload at fact width
+    assert not [p for p in folded if "'valid'" in p]
+    plan = kinds[-1][2][0][0]
+    fp = df.fold_plan(plan)
+    kids = [di for di, p in enumerate(fp.parent) if p is not None]
+    assert kids and not [p for p in folded
+                         for di in kids if p.startswith(f"[2][{di}]")]
+    _unfold(monkeypatch)
+    old = _wide_gathers(*_main_kernel(tk, kinds, _q(q)))
+    assert len(old) >= control, old
+    assert [p for p in old if "'valid'" in p] or q == "q3"
+
+
+@pytest.mark.parametrize("q", ["q1", "q6"])
+def test_scans_keep_their_program(tk, runs_impl, kinds, monkeypatch, q):
+    """No dimension, no fold plan: the body is the control's, equation
+    for equation."""
+    tk.domain.copr._kernel_cache.clear()
+    tk.domain.copr.use_device = True
+    tk.must_query(_q(q))
+    assert kinds and all(k[2][1].get("fold") is None for k in kinds)
+    now = [str(_body_jaxpr(k[2], k[3])) for k in kinds]
+    _unfold(monkeypatch)
+    tk.domain.copr._kernel_cache.clear()
+    del kinds[:]
+    tk.must_query(_q(q))
+    assert [str(_body_jaxpr(k[2], k[3])) for k in kinds] == now
+
+
+def test_nothing_folds_keeps_todays_operands(kinds):
+    """A plan whose only dimension is a left join folds nothing: the
+    builder is handed no fold plan and every column, and `valid`."""
+    tk = _chain_tk()
+    before = _counts()
+    _dev_vs_host(tk, "select c.seg, count(*) from d left join c "
+                 "on d.cid = c.id group by c.seg order by c.seg")
+    assert _grown(before) == {"declined_left": 1}
+    (kind, _param, build, shapes), = kinds
+    assert build[1].get("fold") is None
+    assert "valid" in shapes[2][0] and len(shapes[2][0]["cols"]) == 2
+
+
+# ---- (b) all 22 queries, device == host --------------------------------
+
+# the queries whose fused plan has a chain at this scale
+_CHAINED = {"q2", "q3", "q5", "q7", "q8", "q9", "q10", "q11", "q18",
+            "q21"}
+
+
+@pytest.mark.parametrize("q", [f"q{i}" for i in range(1, 23)])
+def test_tpch_device_equals_host(tk, q):
+    before = _counts()
+    tk.domain.copr.use_device = True
+    dev = tk.must_query(_q(q)).rows
+    grown = _grown(before)
+    host = _host(tk, _q(q))
+    assert len(dev) == len(host)
+    for rd, rh in zip(dev, host):
+        for a, b in zip(rd, rh):
+            if isinstance(a, float) or isinstance(b, float):
+                np.testing.assert_allclose(float(a), float(b), rtol=1e-9)
+            else:
+                assert a == b, (q, rd, rh)
+    if q in _CHAINED:
+        assert grown.get("folded", 0) > 0, grown
+    if q == "q9":
+        assert grown.get("declined_composite_key", 0) > 0, grown
+    if q in ("q1", "q6"):
+        assert grown == {}
+
+
+# ---- (c) synthetic chains ---------------------------------------------
+
+def _chain_tk():
+    """f -> d -> c -> n. f: runs of 15 rows a d_id, every 40th row a
+    d_id no d has. d: every 11th cid NULL, cids 20..24 have no c (a
+    child miss in the middle of f's runs). c: every 13th nid NULL, nid 9
+    has no n. cs: c's rows under sparse keys (a sorted, not a direct,
+    probe table); f2 probes it from the fact. cdup: duplicate keys.
+    p: a composite key."""
+    tk = TestKit()
+    tk.must_exec("create table n (id int primary key, name varchar(16), "
+                 "rid int)")
+    tk.must_exec("create table c (id int primary key, nid int, seg int, "
+                 "name varchar(16))")
+    tk.must_exec("create table cs (id bigint primary key, nid int, "
+                 "seg int)")
+    tk.must_exec("create table cdup (id int, nid int)")
+    tk.must_exec("create table d (id int primary key, cid int, grp int, "
+                 "val int, a int, b int, csid bigint)")
+    tk.must_exec("create table p (a int, b int, w int, "
+                 "primary key (a, b))")
+    tk.must_exec("create table f (k int primary key, d_id int, "
+                 "amt decimal(10,2), q int)")
+    tk.must_exec("create table f2 (k int primary key, csid bigint, "
+                 "amt decimal(10,2), q int)")
+    tk.must_exec("insert into n values " + ",".join(
+        f"({i}, 'n{i}', {i % 3})" for i in range(1, 8)))
+    tk.must_exec("insert into c values " + ",".join(
+        "(%d, %s, %d, 'c%d')" % (
+            i, "null" if i % 13 == 0 else (9 if i % 10 == 0
+                                           else i % 7 + 1), i % 4, i % 17)
+        for i in range(1, 61) if not 20 <= i < 25))
+    tk.must_exec("insert into cs values " + ",".join(
+        "(%d, %d, %d)" % (i * 1000003, i % 7 + 1, i % 4)
+        for i in range(1, 61)))
+    tk.must_exec("insert into cdup values " + ",".join(
+        f"({i % 30 + 1}, {i % 7 + 1})" for i in range(60)))
+    tk.must_exec("insert into d values " + ",".join(
+        "(%d, %s, %d, %d, %d, %d, %d)" % (
+            i, "null" if i % 11 == 0 else (i * 7) % 60 + 1, i % 7,
+            (i * 37) % 1000, i % 5, i % 3, ((i * 7) % 70 + 1) * 1000003)
+        for i in range(1, 201)))
+    tk.must_exec("insert into p values " + ",".join(
+        f"({a}, {b}, {a * 10 + b})" for a in range(5) for b in range(3)
+        if (a, b) != (4, 2)))
+    rng = np.random.RandomState(11)
+    frows, f2rows = [], []
+    for k in range(3000):
+        row = (rng.randint(1, 99999) / 100.0, rng.randint(0, 100))
+        frows.append("(%d, %d, %s, %d)" % (
+            (k, 9999 if k % 40 == 7 else k // 15 + 1) + row))
+        f2rows.append("(%d, %d, %s, %d)" % (
+            (k, (k // 15 % 70 + 1) * 1000003) + row))
+    tk.must_exec("insert into f values " + ",".join(frows))
+    tk.must_exec("insert into f2 values " + ",".join(f2rows))
+    return tk
+
+
+@pytest.fixture(scope="module")
+def tkc():
+    return _chain_tk()
+
+
+_AG = "sum(f.amt), count(*), min(f.q), max(f.q)"
+_FDC = "from f, d, c where f.d_id = d.id and d.cid = c.id"
+# case -> (sql, what the fold counter has to grow by in one execution)
+_SYN = {
+    # NULL d.cid, cids without a c row: misses in the middle of a run
+    "child_payload_groups":
+        (f"select c.seg, {_AG} {_FDC} group by c.seg order by c.seg",
+         {"mask_folded": 1, "folded": 1}),
+    "three_deep_filtered_leaf":
+        (f"select n.name, {_AG} {_FDC} and c.nid = n.id and n.rid = 1 "
+         "group by n.name order by n.name".replace(
+             "from f, d, c", "from f, d, c, n"),
+         {"mask_folded": 1, "folded": 2}),
+    # n's position is a function of c's: decoded from it on the host
+    "position_under_position":
+        (f"select c.id, c.name, n.name, {_AG} {_FDC} and c.nid = n.id "
+         "group by c.id, c.name, n.name order by c.id".replace(
+             "from f, d, c", "from f, d, c, n"),
+         {"mask_folded": 1, "folded": 2}),
+    "child_filter_rejects_every_row":
+        (f"select c.seg, {_AG} {_FDC} and c.seg = 77 "
+         "group by c.seg order by c.seg",
+         {"mask_folded": 1, "folded": 1}),
+    "child_column_in_post_filter":
+        (f"select d.grp, {_AG} {_FDC} and c.seg < f.q "
+         "group by d.grp order by d.grp",
+         {"mask_folded": 1, "folded": 1}),
+    "child_column_in_aggregate":
+        (f"select d.grp, sum(c.seg + f.q), count(*) {_FDC} "
+         "group by d.grp order by d.grp",
+         {"mask_folded": 1, "folded": 1}),
+    "sparse_child_keys":
+        (f"select cs.seg, {_AG} from f, d, cs where f.d_id = d.id "
+         "and d.csid = cs.id group by cs.seg order by cs.seg",
+         {"mask_folded": 1, "folded": 1}),
+    "sparse_root_keys":
+        ("select n.name, sum(f2.amt), count(*) from f2, cs, n "
+         "where f2.csid = cs.id and cs.nid = n.id and cs.seg < 3 "
+         "group by n.name order by n.name",
+         {"mask_folded": 1, "folded": 1}),
+    "semi_child":
+        (f"select d.grp, {_AG} from f, d where f.d_id = d.id and exists "
+         "(select 1 from c where c.id = d.cid and c.seg = 1) "
+         "group by d.grp order by d.grp",
+         {"mask_folded": 1, "folded": 1}),
+    "anti_child_declined":
+        (f"select d.grp, {_AG} from f, d where f.d_id = d.id and "
+         "not exists (select 1 from c where c.id = d.cid and c.seg = 1) "
+         "group by d.grp order by d.grp",
+         {"mask_folded": 1, "declined_anti": 1}),
+    "left_child_declined":
+        (f"select c.seg, {_AG} from f join d on f.d_id = d.id "
+         "left join c on d.cid = c.id group by c.seg order by c.seg",
+         {"mask_folded": 1, "declined_left": 1}),
+    "composite_key_declined":
+        (f"select p.w, {_AG} from f, d, p where f.d_id = d.id "
+         "and d.a = p.a and d.b = p.b group by p.w order by p.w",
+         {"mask_folded": 1, "declined_composite_key": 1}),
+    "topn_orders_by_child_column":
+        (f"select c.id, c.seg, sum(f.amt) s {_FDC} "
+         "group by c.id, c.seg order by c.seg desc, c.id limit 5",
+         {"mask_folded": 1, "folded": 1}),
+    "topn_orders_by_root_column":
+        (f"select f.d_id, d.val, c.name, sum(f.amt) s {_FDC} "
+         "group by f.d_id, d.val, c.name order by d.val desc limit 5",
+         {"mask_folded": 1, "folded": 1}),
+}
+
+
+@pytest.mark.parametrize("policy", ["runs", "scatter"])
+@pytest.mark.parametrize("case", sorted(_SYN))
+def test_synthetic_chain_vs_host(tkc, case, policy):
+    sql, want = _SYN[case]
+    de._FORCE_SEGMENT_IMPL = "runs" if policy == "runs" else None
+    try:
+        before = _counts()
+        dev = _dev_vs_host(tkc, sql)
+        grown = _grown(before)
+    finally:
+        de._FORCE_SEGMENT_IMPL = None
+    grown.pop("build", None)
+    grown.pop("cache_hit", None)
+    assert grown == want
+    assert (len(dev) == 0) == (case == "child_filter_rejects_every_row")
+
+
+def test_sparse_keys_take_the_sorted_table(tkc, kinds):
+    """The two sparse cases really probe a sorted table: once at fact
+    width with the fold's sentinel in the row order (the root), once on
+    the host only (the child)."""
+    tkc.domain.copr._kernel_cache.clear()
+    _dev_vs_host(tkc, _SYN["sparse_root_keys"][0])
+    assert "sk" in kinds[-1][3][2][0] and "valid" not in kinds[-1][3][2][0]
+    _dev_vs_host(tkc, _SYN["sparse_child_keys"][0])
+    assert "lut" in kinds[-1][3][2][0] and kinds[-1][3][2][1] == {"cols": {}}
+
+
+def test_duplicate_child_keys_decline(tkc):
+    """No unique position to resolve at the parent's width: the fold is
+    declined with the fused statement, and the host answers."""
+    before = _counts()
+    sql = (f"select d.grp, {_AG} from f, d, cdup where f.d_id = d.id "
+           "and d.cid = cdup.id group by d.grp order by d.grp")
+    tkc.domain.copr.use_device = True
+    dev = tkc.must_query(sql).rows
+    assert "duplicated" in tkc.domain.last_fused_reason
+    assert _grown(before) == {"declined_child_ineligible": 1}
+    assert dev == _host(tkc, sql) and len(dev) == 7
+
+
+def test_group_straddles_two_partitions(tkc, runs_impl, kinds):
+    """1000-row partitions cut f's runs of 15: both halves' partials
+    merge, and the fold is built once for the three."""
+    copr = tkc.domain.copr
+    old = copr.device_rows
+    copr.device_rows = 1000
+    before = _counts()
+    try:
+        _dev_vs_host(tkc, (
+            f"select f.d_id, d.val, c.name, n.name, {_AG} {_FDC} "
+            "and c.nid = n.id group by f.d_id, d.val, c.name, n.name "
+            "order by f.d_id").replace("from f, d, c", "from f, d, c, n"))
+    finally:
+        copr.device_rows = old
+    grown = _grown(before)
+    assert grown.get("build", 0) + grown.get("cache_hit", 0) == 1
+    assert {k[0] for k in kinds} == {"posruns"}
+    # d's position is the only run key: c's and n's are decoded from it
+    assert kinds[0][1][1] == (0,)
+
+
+def test_lowering_change_between_blocks_reuploads(monkeypatch, kinds):
+    """Positions scattered over storage order: the first block's partials
+    exceed the degrade limit, the shape is pinned to "sorted", and the
+    statement uploads the group items' columns it had left out."""
+    monkeypatch.setattr(de, "_RUNS_DEGRADE_MIN", 8)
+    de._FORCE_SEGMENT_IMPL = "runs"
+    try:
+        tk = TestKit()
+        tk.must_exec("create table c (id int primary key, seg int)")
+        tk.must_exec("create table d (id int primary key, cid int, "
+                     "val int)")
+        tk.must_exec("create table f (k int primary key, d_id int, q int)")
+        tk.must_exec("insert into c values " + ",".join(
+            f"({i}, {i % 5})" for i in range(1, 41)))
+        tk.must_exec("insert into d values " + ",".join(
+            f"({i}, {i % 40 + 1}, {i * 3})" for i in range(1, 101)))
+        rng = np.random.RandomState(2)
+        tk.must_exec("insert into f values " + ",".join(
+            f"({k}, {rng.randint(1, 101)}, {k % 9})" for k in range(800)))
+        sql = ("select d.val, c.seg, count(*), sum(f.q) from f, d, c "
+               "where f.d_id = d.id and d.cid = c.id "
+               "group by d.val, c.seg order by d.val")
+        _dev_vs_host(tk, sql, runs=2)
+    finally:
+        de._FORCE_SEGMENT_IMPL = None
+    assert [k[0] for k in kinds] == ["posruns", "sort"]
+    assert not kinds[0][3][2][0]["cols"]
+    assert len(kinds[1][3][2][0]["cols"]) == 2
+
+
+@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs a mesh")
+@pytest.mark.parametrize("case", ["three_deep_filtered_leaf",
+                                  "child_column_in_post_filter"])
+def test_mesh_takes_the_folded_tables(case):
+    """Broadcast dimensions ride the mesh program folded: one program
+    over the whole fact, the same answer as the host's."""
+    tk = _chain_tk()
+    tk.must_exec("set tidb_mpp_min_rows = 0")
+    hits = tk.domain.metrics.get("fused_pipeline_mpp_hit", 0)
+    before = _counts()
+    _dev_vs_host(tk, _SYN[case][0])
+    assert tk.domain.metrics.get("fused_pipeline_mpp_hit", 0) == hits + 1
+    assert _grown(before).get("folded", 0) >= 1
+
+
+# ---- (d) MVCC ----------------------------------------------------------
+
+_MV = (f"select n.name, {_AG} {_FDC} and c.nid = n.id "
+       "group by n.name order by n.name").replace("from f, d, c",
+                                                  "from f, d, c, n")
+
+
+def test_chain_versions_key_the_fold():
+    """A commit anywhere in the chain is seen by the next execution."""
+    tk = _chain_tk()
+    base = _dev_vs_host(tk, _MV)
+    tk.must_exec("update c set nid = 3 where id = 8")       # the middle
+    moved = _dev_vs_host(tk, _MV)
+    assert moved != base
+    tk.must_exec("delete from d where id = 2")              # the root
+    fewer = _dev_vs_host(tk, _MV)
+    assert sum(int(r[2]) for r in fewer) < sum(int(r[2]) for r in moved)
+    tk.must_exec("insert into c values (21, 2, 1, 'new')")  # a missing key
+    more = _dev_vs_host(tk, _MV)
+    assert sum(int(r[2]) for r in more) > sum(int(r[2]) for r in fewer)
+    tk.must_exec("insert into n values (9, 'n9', 0)")       # the leaf
+    assert len(_dev_vs_host(tk, _MV)) == len(more) + 1
+
+
+def test_uncommitted_dimension_write_is_its_writers_alone():
+    tk = _chain_tk()
+    base = _dev_vs_host(tk, _MV)
+    other = TestKit(domain=tk.domain)
+    tk.must_exec("begin")
+    try:
+        tk.must_exec("update c set nid = 3 where id = 8")
+        tk.domain.copr.use_device = True
+        mine = tk.must_query(_MV).rows
+        assert mine == _host(tk, _MV) and mine != base
+        assert other.must_query(_MV).rows == base
+    finally:
+        tk.must_exec("rollback")
+    before = _counts()
+    assert _dev_vs_host(tk, _MV) == base
+    assert _grown(before).get("build", 0) == 0      # nothing was cached
+
+
+def test_dirty_fact_overlay_bypasses_the_fold_cache():
+    """Only the fact is dirty: the statement stays on the device, builds
+    its folds and leaves none behind."""
+    tk = _chain_tk()
+    base = _dev_vs_host(tk, _MV)
+    tk.must_exec("begin")
+    try:
+        tk.must_exec("insert into f values (90001, 1, 5.00, 1)")
+        before = _counts()
+        got = _dev_vs_host(tk, _MV)
+        grown = _grown(before)
+        assert grown.get("build") == 1 and "cache_hit" not in grown
+        assert sum(int(r[2]) for r in got) == \
+            sum(int(r[2]) for r in base) + 1
+    finally:
+        tk.must_exec("rollback")
+    before = _counts()
+    assert _dev_vs_host(tk, _MV) == base
+    assert _grown(before).get("cache_hit") == 1
+
+
+def test_older_snapshot_does_not_see_a_later_fold():
+    from tidb_tpu.types.time_types import micros_to_str
+    tk = _chain_tk()
+    base = _dev_vs_host(tk, _MV)
+    time.sleep(0.05)
+    mid = micros_to_str(int(time.time() * 1e6), 6)
+    time.sleep(0.05)
+    tk.must_exec("update c set nid = 3 where id = 8")
+    tk.must_exec("delete from d where id = 2")
+    now = _dev_vs_host(tk, _MV)
+    assert now != base
+    asof = _MV.replace("from f, d, c, n", " ".join(
+        ["from"] + [f"{t} as of timestamp '{mid}'," for t in "fdc"] +
+        [f"n as of timestamp '{mid}'"]))
+    tk.domain.copr.use_device = True
+    assert tk.must_query(asof).rows == base
+    assert _dev_vs_host(tk, _MV) == now
+
+
+# ---- (e) a second execution builds nothing -----------------------------
+
+@pytest.mark.parametrize("q", ["q3", "q5", "q10", "q18"])
+def test_second_execution_builds_nothing(tk, runs_impl, q):
+    tk.domain.copr.use_device = True
+    for _ in range(2):          # the second run may rebuild with what
+        tk.must_query(_q(q))    # the first learned (bucket, top-n cut)
+    before = _counts()
+    phase.reset()
+    tk.must_query(_q(q))
+    snap = phase.snap()
+    grown = _grown(before)
+    assert "build" not in grown and grown["cache_hit"] >= 1
+    assert snap.get("upload_bytes", 0) == 0
+    assert snap.get("dispatches", 0) <= 2
+    assert snap.get("kernel_builds", 0) == 0
+
+
+def test_bind_span_carries_fold_counts(tk):
+    tk.must_exec("set tidb_tpu_trace_sample_rate = 1")
+    try:
+        tk.domain.copr.use_device = True
+        tk.must_query(_q("q5"))
+        rows = tk.must_query(
+            "select attrs from information_schema.tidb_trace_events "
+            "where span = 'bind' and attrs like '%folds%'").rows
+    finally:
+        tk.must_exec("set tidb_tpu_trace_sample_rate = 0")
+    assert rows and "fold_builds" in rows[-1][0]

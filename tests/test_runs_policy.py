@@ -352,11 +352,20 @@ def _wide_gather_operands(build, shapes, agg_kind=None, agg_param=None):
     return out
 
 
-def test_posruns_q10_gathers_no_group_payload(tk, runs_impl, kinds):
+@pytest.mark.parametrize("folded", [False, True])
+def test_posruns_q10_gathers_no_group_payload(tk, runs_impl, kinds,
+                                              monkeypatch, folded):
     """q10 groups by seven dimension columns nothing else reads: under
     "posruns" none is gathered at fact width (they are decoded from the
     positions on the host); the control, the same plan under today's
-    kind, gathers every one."""
+    kind, gathers every one. With the chain folded (copr/dimfold.py)
+    customer and nation are not probed at fact width at all, and a
+    statement that falls to the "sort" kind reads the seven through
+    orders' position."""
+    import tidb_tpu.copr.dimfold as df
+    if not folded:
+        monkeypatch.setattr(df, "fold_plan",
+                            lambda plan: df.FoldPlan(len(plan.dims)))
     before = _posruns_count(tk)
     tk.domain.copr._kernel_cache.clear()
     tk.domain.copr.use_device = True
@@ -366,12 +375,27 @@ def test_posruns_q10_gathers_no_group_payload(tk, runs_impl, kinds):
     assert kind == "posruns"
     plan = build[0][0]
     gmap, pos_dims = pl._pos_group_items(plan)
-    payload = {f"[2][{di}]['cols'][{g.idx}][0]"
+    assert pos_dims == ([1] if folded else [1, 2])
+    root = {1: 0, 2: 0} if folded else {1: 1, 2: 2}
+    payload = {f"[2][{root[di]}]['cols'][{g.idx}][0]"
                for g, (_k, di, _c) in zip(plan.group_items, gmap)}
     assert len(payload) == 7
     now = _wide_gather_operands(build, shapes)
     assert not payload & set(now)
-    old = _wide_gather_operands(build, shapes, "sort",
-                                (param[0], "runs", param[2], param[3]))
+    # the control: the statement again with the shape pinned to today's
+    # kind, which evaluates the group items at fact width
+    gbkey = ("gb", tk.domain.copr.engine.table(plan.fact_dag.table_info).uid,
+             tuple(g.fingerprint() for g in plan.group_items),
+             tuple(a.fingerprint() for a in plan.aggs))
+    epoch = tk.domain.copr.engine.table(plan.fact_dag.table_info).gc_epoch
+    monkeypatch.setitem(tk.domain.copr._host_cache,
+                        ("aggimpl", epoch) + gbkey, "sorted")
+    tk.domain.copr._kernel_cache.clear()
+    del kinds[:]
+    tk.must_query(ALL_QUERIES["q10"])
+    kind, param, build, shapes = kinds[0]
+    assert kind == "sort"
+    old = _wide_gather_operands(build, shapes)
     assert payload <= set(old)
-    assert len(old) - len(now) >= 7
+    # seven payload gathers more, one of the positions' kind's own less
+    assert len(old) - len(now) >= 6

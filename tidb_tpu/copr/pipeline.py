@@ -28,6 +28,7 @@ from ..expression import EvalCtx, eval_expr, eval_bool_mask
 from ..expression.vec import materialize_nulls
 from ..chunk.device import shape_bucket
 from . import dag_exec as _de
+from . import dimfold
 from .dag_exec import (PartialAggResult, capture_agg_dicts, _dense_strides,
                        dense_agg_body, dense_agg_states, sort_agg_body,
                        _compact_dense, _I64_MAX, _segment_impl,
@@ -49,9 +50,8 @@ class _AggShim:
         self.aggs = aggs
 
 
-def _cid_of(dag, sc):
-    ci = dag.table_info.find_column(sc.name)
-    return -1 if ci is None else ci.id
+_cid_of = dimfold._cid_of
+_expr_idxs = dimfold._idxs
 
 
 def _set_reason(copr, msg):
@@ -380,9 +380,7 @@ def _materialized_dim_meta(copr, ctx, dim, read_ts):
     # version — both caching such a result and serving a committed-data
     # result to the writer would be wrong, so dirty sessions bypass the
     # cache entirely in both directions
-    txn = getattr(getattr(ctx, "sess", None), "_txn", None)
-    dirty = txn is not None and not txn.committed and not txn.aborted \
-        and txn.is_dirty()
+    dirty = dimfold.txn_dirty(ctx)
     ck = base = None
     fp = None if dirty else _plan_fp(dim.subplan)
     if fp is not None and not _VOLATILE_RE.search(fp):
@@ -568,16 +566,27 @@ def _semi_prefiltered_meta(copr, dim, tbl, arrays, valid, n, key_cid,
     return out
 
 
-def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None):
+def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None, want=None,
+                fold_cols=(), fold_pos=()):
     """Pad + upload dim arrays through the HBM buffer pool; -> pytree of
     device arrays for the kernel plus (has_nulls, sdict) layout info.
     With a mesh, every array replicates to all devices (the Broadcast
-    exchange of the dim fragment)."""
+    exchange of the dim fragment).
+
+    A folded dimension (copr/dimfold.py) uploads what its program
+    reads and no more: `want` names the columns of its own (None:
+    all of them, a dimension that folds nothing); a root also takes
+    `fold_cols` [(idx, descendant, cid)] and `fold_pos` [descendant],
+    its descendants' columns and join positions at its own width, and
+    no `valid` (its probe table holds the hit); a dimension resolved
+    under a root takes no probe table at all."""
     tbl = meta["tbl"]
     n = meta["n"]
     ver = tbl.version
-    mk = (() if mesh is None else ("bcast", mesh.devices.size)) + \
-        tuple(meta.get("ukey", ()))
+    ck = () if mesh is None else ("bcast", mesh.devices.size)
+    mk = ck + tuple(meta.get("ukey", ()))
+    fold = meta.get("fold")
+    probed = meta.get("folded_under") is None
     # plain dim column data is append-only table state: it rides the
     # delta-maintained append seam (copr/delta.py) when the meta wraps
     # a REAL columnar table — materialized-dim shims (_MatTbl) and the
@@ -588,11 +597,12 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None):
     def put(tag, arr, length, acap, fill=0, ts_keyed=False):
         # plain column data depends only on the table version; only the
         # MVCC-derived arrays (valid mask, lut/sort built over the valid
-        # set) vary with the snapshot ts — keying data by ts would
-        # re-upload every dim column once per transaction. _dev_put
-        # reads the pad capacity from key[-1]: acap stays LAST.
+        # set, a fold's tables) vary with the snapshot ts and carry the
+        # meta's `ukey` — keying data by either would re-upload every
+        # dim column once per transaction or per folded filter.
+        # _dev_put reads the pad capacity from key[-1]: acap stays LAST.
         key = (tbl.uid, tag, ver, read_ts if ts_keyed else None,
-               length) + mk + (acap,)
+               length) + (mk if ts_keyed else ck) + (acap,)
         if mesh is None:
             return copr._dev_put(key, arr, pad_fill=fill,
                                  uid=tbl.uid, version=ver)
@@ -604,7 +614,7 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None):
         # padded to acap, tail-patched under appends instead of
         # re-uploaded on every dim-table version bump
         from .delta import append_key
-        key = append_key(tbl.uid, ("dim",) + mk, cid, kind,
+        key = append_key(tbl.uid, ("dim",) + ck, cid, kind,
                          tbl.gc_epoch, (), acap)
         return copr._dev_put_append(
             key, arr, n, acap, tbl.uid, ver, tbl.gc_epoch, 0, None,
@@ -613,36 +623,14 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None):
 
     pre = bool(meta.get("pre"))
     args = {"cols": {}}
-    if meta.get("pack") is not None:
-        # small host values ride the kernel call as numpy operands:
-        # jnp.asarray of a scalar or a list is a device program of its
-        # own (`jit_convert_element_type`) on every statement
-        los, spans, strides = meta["pack"]
-        args["plo"] = np.asarray(los, dtype=np.int64)
-        args["pspan"] = np.asarray(spans, dtype=np.int64)
-        args["pstride"] = np.asarray(strides, dtype=np.int64)
-    if not pre:
-        # prefiltered semi dims fold visibility+filters into the lut at
-        # meta time; the kernel never reads valid/cols for them — don't
-        # upload dead copies into the HBM pool
-        args["valid"] = put("valid", meta["valid"], n, cap, False,
-                            ts_keyed=True)
-    if meta["mode"] == "direct":
-        lcap = shape_bucket(len(meta["lut"]))
-        args["lut"] = put("lut", meta["lut"], len(meta["lut"]), lcap,
-                          fill=n, ts_keyed=True)
-        args["lo"] = np.asarray(meta["lo"], dtype=np.int64)
-    else:
-        ns = meta["n_sorted"]
-        scap = shape_bucket(ns)
-        args["sk"] = put("sk", meta["skeys"], ns, scap, fill=_I64_MAX,
-                         ts_keyed=True)
-        args["ord"] = put("ord", meta["order"], ns, scap, ts_keyed=True)
+    if probed:
+        _upload_probe_table(meta, args, put, cap,
+                            with_valid=not pre and fold is None)
     layout = {}
     if not pre:
         for sc in dim.dag.cols:
             cid = _cid_of(dim.dag, sc)
-            if cid == -1:
+            if cid == -1 or (want is not None and sc.col.idx not in want):
                 continue
             data, nulls, sdict = meta["arrays"][cid]
             if appendable:
@@ -657,7 +645,104 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None):
                     jn = put(("fpn", cid), nulls, n, cap, fill=True)
             args["cols"][sc.col.idx] = (jd, jn)
             layout[sc.col.idx] = (nulls is not None, sdict)
+    for idx, d, cid in fold_cols:
+        # tagged by what the fold's signature (in `mk`) pins: the
+        # descendant's place in the chain and its column, not the
+        # plan's numbering
+        data, nulls, sdict = fold.col(d, cid)
+        jd = put(("fc", d, cid), data, n, cap, ts_keyed=True)
+        jn = None
+        if nulls is not None:
+            jn = put(("fcn", d, cid), nulls, n, cap, fill=True,
+                     ts_keyed=True)
+        args["cols"][idx] = (jd, jn)
+        layout[idx] = (nulls is not None, sdict)
+    if fold_pos:
+        args["fpos"] = {d: put(("fpos", d), fold.pos_at[(fold.root, d)], n,
+                               cap, ts_keyed=True) for d in fold_pos}
     return args, layout
+
+
+def _upload_probe_table(meta, args, put, cap, with_valid):
+    """What the kernel's probe of one dimension reads: the composite
+    key's pack layout, `valid` unless the table holds the mask
+    (prefiltered semi dims fold visibility+filters into the lut at meta
+    time, a folded root its whole chain's: don't upload dead copies
+    into the HBM pool), and the direct or the sorted table."""
+    n = meta["n"]
+    if meta.get("pack") is not None:
+        # small host values ride the kernel call as numpy operands:
+        # jnp.asarray of a scalar or a list is a device program of its
+        # own (`jit_convert_element_type`) on every statement
+        los, spans, strides = meta["pack"]
+        args["plo"] = np.asarray(los, dtype=np.int64)
+        args["pspan"] = np.asarray(spans, dtype=np.int64)
+        args["pstride"] = np.asarray(strides, dtype=np.int64)
+    if with_valid:
+        args["valid"] = put("valid", meta["valid"], n, cap, False,
+                            ts_keyed=True)
+    if meta["mode"] == "direct":
+        lcap = shape_bucket(len(meta["lut"]))
+        args["lut"] = put("lut", meta["lut"], len(meta["lut"]), lcap,
+                          fill=n, ts_keyed=True)
+        args["lo"] = np.asarray(meta["lo"], dtype=np.int64)
+    else:
+        ns = meta["n_sorted"]
+        scap = shape_bucket(ns)
+        args["sk"] = put("sk", meta["skeys"], ns, scap, fill=_I64_MAX,
+                         ts_keyed=True)
+        args["ord"] = put("ord", meta["order"], ns, scap, ts_keyed=True)
+
+
+def _topn_group_col(plan):
+    """(dimension, column idx) the device top-n reads at bucket width
+    when it orders by a group item of a position-grouped plan (the `gm`
+    of `_make_pipeline_body`) -> tuple or None."""
+    spec = getattr(plan, "topn_spec", None)
+    if spec is None or spec[0] != "group" or spec[1] >= len(plan.group_items):
+        return None
+    gm = _pos_group_items(plan)
+    if gm is None:
+        return None
+    kind, di, _c = gm[0][spec[1]]
+    return di, (plan.group_items[spec[1]].idx if kind == "dimcol"
+                else plan.dims[di].build_key.col.idx)
+
+
+def _upload_dims(copr, plan, fp, dim_metas, dim_caps, read_ts, mesh,
+                 pos_grouped):
+    """Upload every dimension for one lowering of the statement:
+    `pos_grouped` says whether the join positions stand for the group
+    items ("posdense", "posruns"), which decides what a folded root has
+    to carry at its width. -> (dim_args, dim_layouts)."""
+    need, need_pos, tcol = None, (), None
+    if fp is not None:
+        need = dimfold.needs(plan, fp, pos_grouped)
+        tcol = _topn_group_col(plan)
+        if pos_grouped:
+            need_pos = _pos_group_items(plan)[1]
+    dim_args, dim_layouts = [], []
+    for di, (dim, meta, dcap) in enumerate(zip(plan.dims, dim_metas,
+                                               dim_caps)):
+        want, fcols, fpos = None, [], []
+        if fp is not None and (fp.masked[di] or fp.parent[di] is not None):
+            want = set(need) if fp.masked[di] else set()
+            if tcol is not None and tcol[0] == di:
+                want.add(tcol[1])
+            for d in (fp.descendants(di) if fp.masked[di] else ()):
+                if plan.dims[d].join_type != "inner":
+                    continue
+                fcols += [(sc.col.idx, d, _cid_of(plan.dims[d].dag, sc))
+                          for sc in plan.dims[d].dag.cols
+                          if sc.col.idx in need and
+                          _cid_of(plan.dims[d].dag, sc) != -1]
+                if d in need_pos:
+                    fpos.append(d)
+        da, layout = _upload_dim(copr, dim, meta, dcap, read_ts, mesh,
+                                 want, fcols, fpos)
+        dim_args.append(da)
+        dim_layouts.append(layout)
+    return dim_args, dim_layouts
 
 
 def _fused_topn_state(copr, plan, fact_tbl, offk, kd, sd):
@@ -822,7 +907,14 @@ def _pos_group_items(plan):
         group_map.append(m)
     if not group_map:
         return None
-    return group_map, sorted({di for _, di, _ in group_map})
+    # a dimension folded under another of the set is a function of that
+    # one's position (q10's nation under customer): decoded from it on
+    # the host, not kept as a key of its own
+    spec = getattr(plan, "topn_spec", None)
+    keep = group_map[spec[1]][1] if spec is not None and \
+        spec[0] == "group" and spec[1] < len(group_map) else None
+    return group_map, dimfold.pos_keys(dimfold.fold_plan(plan),
+                                       {di for _, di, _ in group_map}, keep)
 
 
 def _pos_group_map(plan, dim_metas):
@@ -846,7 +938,12 @@ def _decode_pos_keys(group_map, poses, dim_metas):
     -> (keys, key_nulls, key_dicts)."""
     keys, key_nulls, key_dicts = [], [], []
     for kind, di, cid in group_map:
-        pos = poses[di]
+        pos = poses.get(di)
+        if pos is None:
+            # folded under a dimension whose position is a key
+            fold = dim_metas[dim_metas[di]["folded_under"]]["fold"]
+            pos = next(fold.pos_at[(a, di)][poses[a]] for a in poses
+                       if (a, di) in fold.pos_at)
         data, nulls, sdict = dim_metas[di]["arrays"][cid]
         keys.append(data[pos].astype(np.int64))
         key_nulls.append(nulls[pos] if (kind == "dimcol" and
@@ -877,7 +974,8 @@ def _compact_pos_dense(plan, res, group_map, pos_dims, dim_metas, sd):
 
 def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                         dim_sns, dim_layouts, agg_kind, agg_param,
-                        dim_pres=(), ecap=None, want_fnvalid=False):
+                        dim_pres=(), ecap=None, want_fnvalid=False,
+                        fold=None):
     """The traced pipeline: filter fact -> dim probes/gathers -> residual
     filters -> partial agg. fact_cap is the (local, for MPP shards) fact
     partition capacity; dim_ns = full dim row counts, dim_sns = valid
@@ -894,7 +992,14 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
     regrows the bucket and reruns — the group_bucket retry pattern).
     want_fnvalid: single-chip callers get res["fnvalid"] (the
     fact-filter survivor count) for that policy; the MPP wrapper keeps
-    the result pytree unchanged."""
+    the result pytree unchanged.
+
+    fold: the plan's dimfold.FoldPlan when any dimension folds. A
+    dimension resolved under another is not probed here at all; a root
+    whose mask is in its probe table gathers no `valid[pos]`, and of
+    the columns its operands carry (its own and its descendants', at
+    its width) only those something downstream reads. None: today's
+    program for every dimension."""
     fact_filters = list(plan.fact_dag.filters)
     dims = list(plan.dims)
     post = list(plan.post_filters)
@@ -916,6 +1021,8 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
         group_only = frozenset(g.idx for g, (kind, _di, _c) in
                                zip(group_items, group_map)
                                if kind == "dimcol") - read
+    need = None if fold is None else dimfold.needs(
+        plan, fold, agg_kind in ("posdense", "posruns"))
 
     def body(fjc, fvv, dargs):
         cap = fact_cap
@@ -945,11 +1052,15 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
         dim_pos = {}
         for dim_i, (dim, da, dcap, dn, dsn, layout) in enumerate(
                 zip(dims, dargs, dim_caps, dim_ns, dim_sns, dim_layouts)):
+            if fold is not None and fold.parent[dim_i] is not None:
+                continue               # resolved at its root's width
+            masked = fold is not None and fold.masked[dim_i]
             with jax.named_scope("dim_probe"):
                 pre = bool(dim_pres[dim_i]) if dim_i < len(dim_pres) else False
-                if pre:
+                if pre or masked:
                     dmask = None       # filters/visibility folded at meta
-                                       # time (prefiltered semi dims)
+                                       # time (prefiltered semi dims,
+                                       # folded roots)
                 else:
                     dcols = {}
                     for idx, (jd, jn) in da["cols"].items():
@@ -989,9 +1100,13 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                     idx = pv - da["lo"]
                     inb = (idx >= 0) & (idx < lsize)
                     pos = da["lut"][jnp.clip(idx, 0, lsize - 1)]
+                    if masked:
+                        hit = inb & (pos < dn) & ~pnm
                     pos = jnp.minimum(pos, dcap - 1)
-                    hit = inb & (da["lut"][jnp.clip(idx, 0, lsize - 1)] < dn) \
-                        & ~pnm
+                    if not masked:
+                        hit = inb & \
+                            (da["lut"][jnp.clip(idx, 0, lsize - 1)] < dn) \
+                            & ~pnm
                     if dmask is not None:
                         hit = hit & dmask[pos]
                 else:
@@ -1000,6 +1115,10 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                     locc = jnp.minimum(loc, scap - 1)
                     pos = da["ord"][locc]
                     hit = (da["sk"][locc] == pv) & ~pnm & (loc < dsn)
+                    if masked:
+                        # a folded row order holds the miss sentinel
+                        hit = hit & (pos < dn)
+                        pos = jnp.minimum(pos, dcap - 1)
                     if dmask is not None:
                         hit = hit & dmask[pos]
                 if dim.join_type == "left":
@@ -1017,11 +1136,14 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                     mask = mask & hit
                     if dim.join_type != "semi":
                         for idx, (jd, jn) in da["cols"].items():
-                            if idx in group_only:
+                            if idx in group_only or \
+                                    (masked and idx not in need):
                                 continue
                             g = jd[pos]
                             gn = jn[pos] if jn is not None else None
                             cols[idx] = (g, gn, layout[idx][1])
+                        for cdi, cpos in da.get("fpos", {}).items():
+                            dim_pos[cdi] = cpos[pos]
                 dim_pos[dim_i] = jnp.minimum(pos, dn - 1)
                 ctx = EvalCtx(jnp, cap, cols, host=False)
         with jax.named_scope("scan_filter"):
@@ -1119,11 +1241,11 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
 
 def _build_fused_kernel(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                         dim_sns, dim_layouts, agg_kind, agg_param,
-                        dim_pres=(), ecap=None):
+                        dim_pres=(), ecap=None, fold=None):
     body = _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps,
                                dim_ns, dim_sns, dim_layouts, agg_kind,
                                agg_param, dim_pres, ecap=ecap,
-                               want_fnvalid=True)
+                               want_fnvalid=True, fold=fold)
     # donate the fact validity mask: per-dispatch scratch rebuilt by
     # _pad_upload every call; dim args and fact columns ride the
     # resident pool and must never be donated
@@ -1134,7 +1256,7 @@ def _build_fused_kernel(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
 
 def _build_fused_kernel_mpp(plan, local_cap, fact_sdicts, dim_caps,
                             dim_ns, dim_sns, dim_layouts, agg_kind,
-                            agg_param, mesh, dim_pres=()):
+                            agg_param, mesh, dim_pres=(), fold=None):
     """The fused pipeline as ONE shard_map program: fact shards ride the
     'dp' mesh axis (PassThrough exchange from the scan), dims are
     replicated (Broadcast exchange), and the partial aggregation merges
@@ -1146,7 +1268,7 @@ def _build_fused_kernel_mpp(plan, local_cap, fact_sdicts, dim_caps,
 
     body = _make_pipeline_body(plan, local_cap, fact_sdicts, dim_caps,
                                dim_ns, dim_sns, dim_layouts, agg_kind,
-                               agg_param, dim_pres)
+                               agg_param, dim_pres, fold=fold)
     aggs = list(plan.aggs)
     dense = agg_kind in ("dense", "posdense")
 
@@ -1439,10 +1561,12 @@ def _oh_fold_delta(copr, ohk, plan, fact_arrays, n, version):
         dom.inc_metric("fused_onehot_delta_fold")
 
 
-def _bind_tables(copr, plan, read_ts, ctx):
+def _bind_tables(copr, plan, read_ts, ctx, fp=None, sp=None):
     """The statement's first `bind`: fold committed deltas into the
     resident buffers of the fact table and every dimension, build or
-    find each dimension's metadata, snapshot the fact columns.
+    find each dimension's metadata — and, over it, the folded tables of
+    the plan's chains (`fp`, dimfold.py; `sp`, the open span, takes
+    their count) — snapshot the fact columns.
     -> (fact_tbl, dim_metas, fact_version, fact_arrays, fact_valid), or
     the answer itself ([] for no rows, None for runtime-ineligible)."""
     engine = copr.engine
@@ -1455,7 +1579,7 @@ def _bind_tables(copr, plan, read_ts, ctx):
     copr.delta.refresh(fact_tbl, ctx)
     copr._dev_store.invalidate(fact_tbl.uid, fact_tbl.version)
     dim_metas = []
-    for dim in plan.dims:
+    for di, dim in enumerate(plan.dims):
         if dim.subplan is not None:
             meta = _materialized_dim_meta(copr, ctx, dim, read_ts)
             if meta is None:
@@ -1490,8 +1614,19 @@ def _bind_tables(copr, plan, read_ts, ctx):
             continue
         meta = _dim_sort_meta(copr, dim, tbl, read_ts)
         if meta is None:
+            if fp is not None and fp.parent[di] is not None:
+                # duplicated, NULL or non-integer build keys: no join
+                # position to resolve at the parent's width (nor a
+                # fused statement at all)
+                dimfold.count("declined_child_ineligible")
             return None
         dim_metas.append(meta)
+    if fp is not None:
+        dim_metas, folds, builds = dimfold.bind_folds(
+            copr, plan, fp, dim_metas, read_ts, ctx)
+        if sp is not None:
+            sp.attrs["folds"] = folds
+            sp.attrs["fold_builds"] = builds
 
     # version BEFORE the snapshot (delta.refresh rationale): the one-hot
     # coverage watermark must never claim rows it did not see
@@ -1511,10 +1646,13 @@ def fused_partials(copr, plan, read_ts, mesh=None,
     runtime-ineligible (caller falls back to the conventional subtree).
     With a mesh, the whole pipeline runs as one shard_map program: fact
     sharded over 'dp', dims broadcast, aggregation allreduced."""
-    with phase.bind_span():
-        bound = _bind_tables(copr, plan, read_ts, ctx)
+    fp = dimfold.fold_plan(plan) if plan.dims else None
+    with phase.bind_span() as sp:
+        bound = _bind_tables(copr, plan, read_ts, ctx, fp, sp)
     if not isinstance(bound, tuple):
         return bound
+    if fp is not None and not any(fp.masked):
+        fp = None           # nothing folds: today's program and operands
     fact_tbl, dim_metas, fact_version, fact_arrays, fact_valid = bound
     n = len(fact_valid)
     if n == 0 and not delta_rows:
@@ -1539,18 +1677,25 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         if sh is not None:
             return sh
 
-    # upload dims once (shared across fact partitions)
-    dim_args, dim_layouts, dim_caps, dim_ns, dim_sns = [], [], [], [], []
-    with phase.bind_span() if plan.dims else _tracing.NO_SPAN:
-        for dim, meta in zip(plan.dims, dim_metas):
-            dcap = shape_bucket(meta["n"])
-            da, layout = _upload_dim(copr, dim, meta, dcap, read_ts, mesh)
-            dim_args.append(da)
-            dim_layouts.append(layout)
-            dim_caps.append(dcap)
-            dim_ns.append(meta["n"])
-            dim_sns.append(meta["n_sorted"])
+    dim_caps = [shape_bucket(m["n"]) for m in dim_metas]
+    dim_ns = [m["n"] for m in dim_metas]
+    dim_sns = [m["n_sorted"] for m in dim_metas]
     dim_pres = tuple(bool(m.get("pre")) for m in dim_metas)
+    dim_up = {}
+
+    def _dims_for(pos_grouped):
+        """The dimensions on the device, uploaded once a statement
+        (shared across fact partitions) -> (dim_args, dim_layouts).
+        What a folded root carries depends on whether the join
+        positions stand for the group items, so a statement that
+        changes its lowering between row blocks uploads twice."""
+        pos_grouped = pos_grouped and fp is not None
+        if pos_grouped not in dim_up:
+            with phase.bind_span() if plan.dims else _tracing.NO_SPAN:
+                dim_up[pos_grouped] = _upload_dims(
+                    copr, plan, fp, dim_metas, dim_caps, read_ts, mesh,
+                    pos_grouped)
+        return dim_up[pos_grouped]
 
     # 1-row host ctx over ALL pipeline columns: learn output dicts and
     # whether a dense group layout applies (dict-coded keys only here —
@@ -1699,11 +1844,17 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         return True
     oh_elig = _oh_eligible()
     if mesh is not None:
+        dim_args, dim_layouts = _dims_for(pos_spec is not None)
         return _run_fused_mpp(
             copr, plan, mesh, fact_tbl, fact_arrays, fact_valid, n,
             handles, dim_args, dim_metas, dim_caps, dim_ns, dim_sns,
             dim_layouts, fact_sdicts, pos_spec, sizes, shim, kd, sd,
-            gbkey, group_bucket, read_ts, dim_pres)
+            gbkey, group_bucket, read_ts, dim_pres, fp)
+    # the lowering the first row block will take: its operands go up
+    # before the loop, in a `bind` of the statement's own
+    _dims_for(pos_spec is not None or (
+        sizes is None and not isinstance(copr._host_cache.get(ohk), dict)
+        and _posruns_on()))
     # row blocks of this run: the fact's, plus the transaction's own
     # rows as one more; the `dispatch`/`consume` spans carry the numbers
     parts = -(-n // step) + (delta_part is not None)
@@ -1785,16 +1936,18 @@ def fused_partials(copr, plan, read_ts, mesh=None,
             # survivors are already compacted: the late (post-join)
             # compact stage would re-gather the same buffer
             agg_param = agg_param[:3] + (None,)
+        dim_args, dim_layouts = _dims_for(
+            agg_kind in ("posdense", "posruns"))
         key = _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap,
                                tuple(dim_caps), tuple(dim_ns),
                                tuple(dim_sns), agg_kind, agg_param,
-                               ecap)
+                               ecap, fp)
         kern = copr._kernel_cache.get(key)
         if kern is None:
             kern = _build_fused_kernel(
                 plan, cap, fact_sdicts, tuple(dim_caps),
                 tuple(dim_ns), tuple(dim_sns), tuple(dim_layouts),
-                agg_kind, agg_param, dim_pres, ecap=ecap)
+                agg_kind, agg_param, dim_pres, ecap=ecap, fold=fp)
             kern = copr._kernel_cache.put(key, kern)
         with phase.bind_span():
             fjc_full, fvv = copr._pad_upload(cols, v, m, cap,
@@ -2149,17 +2302,12 @@ def _try_fused_shuffle(copr, plan, mesh, dim_metas, fact_tbl, fact_arrays,
         states=states, key_dicts=[psdict], state_dicts=[None] * len(states))]
 
 
-def _expr_idxs(e):
-    s = set()
-    e.collect_columns(s)
-    return s
-
 
 def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
                    n, handles, dim_args, dim_metas, dim_caps, dim_ns,
                    dim_sns, dim_layouts, fact_sdicts, pos_spec, sizes,
                    shim, kd, sd, gbkey, group_bucket, read_ts,
-                   dim_pres=()):
+                   dim_pres=(), fold=None):
     """Mesh execution: ONE shard_map call over the whole fact table."""
     from ..mpp.exec import exchange_observed, tree_nbytes
     from .delta import append_key
@@ -2218,14 +2366,14 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
                 ccap if isinstance(ccap, int) else None)
         key = _fused_cache_key(copr, plan, fact_tbl, dim_metas, local,
                                tuple(dim_caps), tuple(dim_ns),
-                               tuple(dim_sns), agg_kind, agg_param) + \
-            ("mpp", ndev, padded)
+                               tuple(dim_sns), agg_kind, agg_param,
+                               fold=fold) + ("mpp", ndev, padded)
         kern = copr._kernel_cache.get(key)
         if kern is None:
             kern = _build_fused_kernel_mpp(
                 plan, local, fact_sdicts, tuple(dim_caps), tuple(dim_ns),
                 tuple(dim_sns), tuple(dim_layouts), agg_kind, agg_param,
-                mesh, dim_pres)
+                mesh, dim_pres, fold)
             kern = copr._kernel_cache.put(key, kern)
         # tpulint: disable=unguarded-dispatch — supervised by
         # executors.FusedPipeline's guarded_dispatch site="fused/mpp"
@@ -2280,7 +2428,8 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
 
 
 def _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap, dim_caps,
-                     dim_ns, dim_sns, agg_kind, agg_param, ecap=None):
+                     dim_ns, dim_sns, agg_kind, agg_param, ecap=None,
+                     fold=None):
     dict_vers = [tuple(sorted((cid, len(d.values))
                               for cid, d in fact_tbl.dicts.items()))]
     for meta in dim_metas:
@@ -2305,4 +2454,5 @@ def _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap, dim_caps,
     return ("fused", fact_tbl.uid, cap, dim_caps, dim_ns, dim_sns, fps,
             dimsig, postfps, gfps, afps, tuple(dict_vers), colsig,
             agg_kind, agg_param, ecap, _segment_impl(),
-            tuple(bool(m.get("pre")) for m in dim_metas))
+            tuple(bool(m.get("pre")) for m in dim_metas),
+            None if fold is None else fold.sig())

@@ -971,6 +971,12 @@ MEM_TRACKER_BYTES = REGISTRY.gauge(
 FUSED_DECLINE = REGISTRY.counter(
     "tidb_tpu_fused_decline_total",
     "Fused-pipeline declines by reason class", ("reason",))
+DIM_FOLD = REGISTRY.counter(
+    "tidb_tpu_dim_fold_total",
+    "Fused-pipeline dimensions by fold outcome, a statement's bind: "
+    "folded (resolved at its parent's width), mask_folded (a root's "
+    "mask in its probe table), declined_<reason>; build / cache_hit: "
+    "a root's folded tables built or found", ("outcome",))
 FUSED_PIPELINE = REGISTRY.counter(
     "tidb_tpu_fused_pipeline_total",
     "Fused-pipeline executions by outcome", ("outcome",))
