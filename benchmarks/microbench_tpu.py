@@ -10,7 +10,7 @@ Sections: io, reduce, group, sort by default; probe, sort4m, mxu and
 scatter by name (scatter is the slowest to COMPILE on a TPU — run it
 last, with a long timeout).
 
-Design inputs these numbers feed (copr/dag_exec.py lowering choice):
+Design inputs these numbers feed (copr/agg_lowering.py lowering choice):
 - dispatch+fetch round-trip floor
 - masked reductions (no-group aggs)
 - broadcast-compare-reduce (tiny group domains)

@@ -1,0 +1,390 @@
+"""copr/agg_lowering.py: the decision "which aggregation lowering for
+which shape" as a table, and the learn-and-retry verdicts, called
+directly — no kernel is built here. Then the one thing both engines
+share through it: a shape's pin.
+
+Counts and kinds only (CPU backend), never device times."""
+import numpy as np
+import pytest
+
+import tidb_tpu.copr.agg_lowering as al
+import tidb_tpu.copr.pipeline as pl
+from tidb_tpu.chunk.device import shape_bucket
+from tidb_tpu.expression.expr import Column
+from tidb_tpu.testkit import TestKit
+from tidb_tpu.types.field_type import new_bigint_type
+
+
+class _Copr:
+    def __init__(self):
+        self._host_cache = {}
+
+
+class _Tbl:
+    uid, gc_epoch = "t1", 0
+
+
+class _Item:
+    def __init__(self, fp):
+        self.fp = fp
+
+    def fingerprint(self):
+        return self.fp
+
+
+def _state(copr=None, tbl=_Tbl, groups=("g",), aggs=("sum[v]",)):
+    return al.ShapeState(copr or _Copr(), tbl,
+                         [_Item(g) for g in groups],
+                         [_Item(a) for a in aggs])
+
+
+def _pos(nslots, dims=(0,)):
+    """A position grouping over `dims` with `nslots` slots."""
+    return ([("dimcol", d, 1) for d in dims], list(dims), nslots)
+
+
+def _dense(nslots):
+    return [(nslots, 0)]
+
+
+@pytest.fixture
+def runs(monkeypatch):
+    monkeypatch.setattr(al, "_FORCE_SEGMENT_IMPL", "runs")
+
+
+CAP = 1 << 22
+# case -> (pos_spec, sizes, site, dims, kind, agg_param[1] or None)
+SHAPES = {
+    # the benchmark's six statements under the chip's policy
+    "q6_global": (None, [], "fused", False, "dense", None),
+    "q1_12_slots": (None, [(4, 0), (3, 0)], "fused", False, "dense", None),
+    "q5_25_nations": (_pos(25), None, "fused", True, "posdense", None),
+    "q3_orders": (_pos(1_500_000), None, "fused", True, "posruns", (0,)),
+    "q10_customer": (_pos(150_000, (1,)), None, "fused", True, "posruns",
+                     (1,)),
+    "q18_orders": (_pos(1_500_000), None, "fused", True, "posruns", (0,)),
+    "q18_subquery": (None, None, "fused", False, "sort", "runs"),
+    # BCR_MAX, both domains, both sides
+    "dense_64": (None, _dense(64), "fused", False, "dense", None),
+    "dense_65": (None, _dense(65), "fused", False, "sort", "runs"),
+    "pos_64": (_pos(64), None, "fused", True, "posdense", None),
+    "pos_65": (_pos(65), None, "fused", True, "posruns", (0,)),
+    # few dict codes beat runs over scattered positions
+    "pos_65_dense_12": (_pos(65), _dense(12), "fused", True, "dense", None),
+    # the mesh: no posruns
+    "mesh_pos_65": (_pos(65), None, "mesh", True, "sort", "runs"),
+    "mesh_pos_25": (_pos(25), None, "mesh", True, "posdense", None),
+    # the per-DAG executor: dense or sort
+    "dag_dense_12": (None, _dense(12), "dag", False, "dense", None),
+    "dag_dense_65": (None, _dense(65), "dag", False, "sort", "runs"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SHAPES))
+def test_choose_under_the_runs_policy(runs, case):
+    pos, sizes, site, dims, kind, second = SHAPES[case]
+    low = al.Lowering(_state(), pos, sizes, site=site, dims=dims)
+    got, param, ecap = low.choose(CAP)
+    assert got == kind and ecap is None
+    assert sum(x is not None for x in (low.pos, low.posruns, low.sizes)) \
+        <= 1
+    if kind == "posdense":
+        assert param == (tuple(pos[1]), pos[2])
+    elif kind == "dense":
+        assert param == tuple(low.sizes)
+    else:
+        assert param == (al.GROUP_BUCKET_MIN, second, None, None)
+
+
+def test_sizes_are_asked_for_only_without_a_position_domain(runs):
+    asked = []
+
+    def sizes():
+        asked.append(1)
+        return _dense(12)
+    assert al.Lowering(_state(), _pos(25), sizes).choose(CAP)[0] == \
+        "posdense"
+    assert not asked
+    assert al.Lowering(_state(), _pos(65), sizes).choose(CAP)[0] == "dense"
+    assert asked == [1]
+
+
+# case -> (pos_spec, sizes, kind, agg_param[1])
+CPU_DEFAULT = {
+    "dense_65": (None, _dense(65), "dense", None),
+    "pos_65": (_pos(65), None, "posdense", None),
+    "pos_over_pos_dense_max": (_pos(al.POS_DENSE_MAX + 1), None, "sort",
+                               "scatter"),
+    "general": (None, None, "sort", "scatter"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CPU_DEFAULT))
+def test_choose_under_the_cpu_default(case):
+    assert al.policy() == "scatter"
+    pos, sizes, kind, impl = CPU_DEFAULT[case]
+    got, param, _ = al.Lowering(_state(), pos, sizes, dims=True).choose(CAP)
+    assert got == kind
+    if kind == "sort":
+        assert param[1] == impl
+
+
+def test_delta_outside_the_dense_span_takes_the_sort_lowering(runs):
+    """The fused pipeline's dense sizes, as it asks for them: a
+    transaction's row whose key lies outside the span drops the layout
+    (pipeline._delta_in_span), and the choice is the exact sort kind."""
+    g = Column(0, new_bigint_type())
+    shim = pl._AggShim([g], [])
+    sizes = [(12, 0)]               # values 0..10
+
+    def delta(v):
+        return {0: (np.array([v], dtype=np.int64), None, None)}, \
+            np.ones(1, dtype=bool)
+    for v, kind in ((10, "dense"), (11, "sort")):
+        low = al.Lowering(
+            _state(), None,
+            lambda: sizes if pl._delta_in_span(shim, sizes, delta(v))
+            else None)
+        assert low.choose(CAP)[0] == kind
+
+
+def test_a_learned_onehot_table_against_posruns(runs):
+    """A learned table (it can only date from a pinned spell) keeps the
+    one-hot kind on one chip; the mesh and the per-DAG executor do not
+    see it; past ONEHOT_CAP_MAX lanes the sort kind, not posruns."""
+    st = _state()
+    st.onehot = {"scap": 128}
+    assert al.Lowering(st, _pos(65), dims=True).choose(CAP) == \
+        ("onehot", (128,), None)
+    assert al.Lowering(st, _pos(65), dims=True).choose(
+        al.ONEHOT_CAP_MAX * 2)[0] == "sort"
+    assert al.Lowering(st, _pos(65), site="mesh").choose(CAP)[0] == "sort"
+    assert al.Lowering(st, None, site="dag").choose(CAP)[0] == "sort"
+    st.onehot = False               # the tombstone is no table
+    assert al.Lowering(st, _pos(65), dims=True).choose(CAP)[0] == "posruns"
+
+
+def test_a_pinned_shape(runs):
+    st = _state()
+    st.pin = "sorted"
+    low = al.Lowering(st, _pos(65), dims=True, topn=("agg", 0, True, 10))
+    assert low.choose(CAP) == \
+        ("sort", (al.GROUP_BUCKET_MIN, "sorted", None, None), None)
+
+
+@pytest.mark.parametrize("k, bucket, want", [
+    (10, 1024, 76),         # k + 66 candidates
+    (1000, 1024, 1024),     # capped at the bucket
+    (1022, 1024, 1024),     # bucket == k + 2: the proof can still pass
+    (1023, 1024, None),     # it cannot: no candidate kernel
+])
+def test_topn_candidate_width(runs, k, bucket, want):
+    st = _state()
+    st.grow_bucket(bucket)
+    low = al.Lowering(st, _pos(65), dims=True, topn=("agg", 0, True, k))
+    topn = low.choose(CAP)[1][2]
+    assert topn == (want and ("agg", 0, True, want))
+    st.topn_off = True
+    assert low.choose(CAP)[1][2] is None
+    assert al.Lowering(st, _pos(65), site="mesh",
+                       topn=("agg", 0, True, k)).topn is None
+
+
+def test_compaction_capacities(runs):
+    st = _state()
+    st.compact, st.early_compact = 4096, 8192
+    joined = al.Lowering(st, _pos(65), dims=True)
+    # early set: the late stage would re-gather the same buffer
+    assert joined.choose(CAP) == \
+        ("posruns", (al.GROUP_BUCKET_MIN, (0,), None, None), 8192)
+    # an early buffer no smaller than the partition is none
+    assert joined.choose(8192) == \
+        ("posruns", (al.GROUP_BUCKET_MIN, (0,), None, 4096), None)
+    # zero-dim plan: no early compaction
+    assert al.Lowering(st, None).choose(CAP) == \
+        ("sort", (al.GROUP_BUCKET_MIN, "runs", None, 4096), None)
+    # the mesh keeps the late buffer only, the per-DAG executor neither
+    assert al.Lowering(st, None, site="mesh", dims=True).choose(CAP) == \
+        ("sort", (al.GROUP_BUCKET_MIN, "runs", None, 4096), None)
+    assert al.Lowering(st, None, site="dag").choose(CAP) == \
+        ("sort", (al.GROUP_BUCKET_MIN, "runs", None, None), None)
+    st.compact = st.early_compact = "off"
+    assert joined.choose(CAP)[1:] == \
+        ((al.GROUP_BUCKET_MIN, (0,), None, None), None)
+    # dense kinds carry the early capacity too
+    st.early_compact = 8192
+    assert al.Lowering(st, _pos(25), dims=True).choose(CAP)[2] == 8192
+
+
+ROWS = 4_194_304
+SORT = ("sort", (1024, "runs", None, None))
+# case -> (kind, param, ecap, rows, ngroups, nvalid, fnvalid,
+#          verdict, what the state holds afterwards)
+VERDICTS = {
+    "degraded_over_half": (
+        *SORT, None, ROWS, ROWS // 2 + 1, None, None, "retry",
+        {"pin": "sorted"}),
+    "half_is_not_degraded": (
+        "sort", (ROWS, "runs", None, None), None, ROWS, ROWS // 2, None,
+        None, None, {"pin": None}),
+    "q18_subquery_runs_of_four": (
+        "sort", (ROWS // 2, "runs", None, None), None, ROWS, 1_048_366,
+        None, None, None, {"pin": None}),
+    "small_partition_under_the_floor": (
+        "sort", (65536, "runs", None, None), None, 1000, 1000, None, None,
+        None, {"pin": None}),
+    "posruns_degrades_too": (
+        "posruns", (1024, (0,), None, None), None, ROWS, ROWS - 1, None,
+        None, "retry", {"pin": "sorted"}),
+    "sorted_never_degrades": (
+        "sort", (ROWS, "sorted", None, None), None, ROWS, ROWS - 1, None,
+        None, None, {"pin": None}),
+    "bucket_overflow": (
+        *SORT, None, ROWS, 5000, None, None, "retry",
+        {"bucket": shape_bucket(5000), "pin": None}),
+    "bucket_fits": (
+        *SORT, None, ROWS, 1024, None, None, None, {"bucket": 1024}),
+    "late_compaction_learns": (
+        *SORT, None, ROWS, 10, 3000, None, None,
+        {"compact": shape_bucket(3000)}),
+    "late_compaction_first_sight_off": (
+        *SORT, None, ROWS, 10, ROWS // 8 + 1, None, None,
+        {"compact": "off"}),
+    "late_compaction_regrows": (
+        "sort", (1024, "runs", None, 4096), None, ROWS, 10, 5000, None,
+        "retry", {"compact": shape_bucket(5000)}),
+    "late_compaction_drifts_off": (
+        "sort", (1024, "runs", None, 4096), None, ROWS, 10, ROWS // 4 + 1,
+        None, "retry", {"compact": "off"}),
+    "early_compaction_learns": (
+        "dense", ((12, 0),), None, ROWS, None, None, 3000, None,
+        {"early_compact": shape_bucket(3000)}),
+    "early_compaction_regrows": (
+        "dense", ((12, 0),), 4096, ROWS, None, None, 5000, "retry",
+        {"early_compact": shape_bucket(5000)}),
+    # every verdict at once: the early one is taken, nothing else learned
+    "order_early_first": (
+        "sort", (1024, "runs", None, 4096), 4096, ROWS, ROWS - 1, 5000,
+        5000, "retry",
+        {"early_compact": shape_bucket(5000), "compact": None, "pin": None,
+         "bucket": 1024}),
+    "order_late_before_degrade": (
+        "sort", (1024, "runs", None, 4096), None, ROWS, ROWS - 1, 5000,
+        None, "retry",
+        {"compact": shape_bucket(5000), "pin": None, "bucket": 1024}),
+    "order_degrade_before_bucket": (
+        *SORT, None, ROWS, ROWS - 1, None, None, "retry",
+        {"pin": "sorted", "bucket": 1024}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(VERDICTS))
+def test_observe(runs, case):
+    kind, param, ecap, rows, ngroups, nvalid, fnvalid, verdict, after = \
+        VERDICTS[case]
+    st = _state()
+    low = al.Lowering(st, None, dims=True)
+    assert low.observe(kind, param, ecap, ROWS, rows, ngroups, nvalid,
+                       fnvalid) == verdict
+    for name, value in after.items():
+        assert getattr(st, name) == value, name
+
+
+def test_state_is_one_per_shape_and_epoch():
+    copr = _Copr()
+    a, b = _state(copr), _state(copr)
+    a.pin, a.topn_off = "sorted", True
+    a.grow_bucket(5000)
+    assert (b.pin, b.topn_off, b.bucket) == \
+        ("sorted", True, shape_bucket(5000))
+    assert _state(copr, groups=("h",)).pin is None
+    assert _state(copr, aggs=("count[]",)).bucket == al.GROUP_BUCKET_MIN
+
+    class Compacted(_Tbl):
+        gc_epoch = 1
+    c = _state(copr, Compacted)
+    # a compaction lifts the pins; the bucket is the data's, not the order's
+    assert (c.pin, c.topn_off, c.bucket) == (None, None, shape_bucket(5000))
+    del a.pin
+    assert b.pin is None
+    # the keys tests and tools read by prefix
+    assert {k[0] for k in copr._host_cache} == {"gb", "ftopn_off"}
+
+
+def test_thresholds_are_constants_not_switches(monkeypatch):
+    import inspect
+    src = inspect.getsource(al)
+    assert "os.environ" not in src and "getenv" not in src and \
+        "import os" not in src
+    assert (al.BCR_MAX, al.RUNS_DEGRADE_MIN, al.ONEHOT_MAX,
+            al.POS_DENSE_MAX, al.DENSE_MAX) == \
+        (64, 65536, 32768, 1 << 22, 1 << 18)
+    monkeypatch.setattr(al, "_FORCE_SEGMENT_IMPL", "hash")
+    with pytest.raises(ValueError):
+        al.policy()
+
+
+def test_onehot_is_for_accelerators_and_does_not_follow_the_policy(
+        runs, monkeypatch):
+    one = {0: (np.zeros(1, dtype=np.int64), None, None)}
+
+    class Sum:
+        name, args = "sum", [Column(0, new_bigint_type())]
+    low = al.Lowering(_state(), None)
+    assert low.onehot_learnable([Sum], [Sum], one, None) is False
+    monkeypatch.setattr(al, "_FORCE_ONEHOT", True)
+    assert low.onehot_learnable([Sum], [Sum], one, None) is True
+    assert low.onehot_learnable([Sum], [Sum], one, [(1, [])]) is False
+    assert al.Lowering(_state(), None, site="mesh").onehot_learnable(
+        [Sum], [Sum], one, None) is False
+    assert al.Lowering(_state(), _pos(65), dims=True).onehot_learnable(
+        [Sum], [Sum], one, None) is False
+    assert al.onehot_fits(al.ONEHOT_MAX) and not al.onehot_fits(0) and \
+        not al.onehot_fits(al.ONEHOT_MAX + 1)
+
+
+# ---- one key for the impl pin, both engines ---------------------------
+
+def test_a_pin_learned_per_dag_holds_for_the_fused_pipeline(monkeypatch):
+    """Keys uncorrelated with storage order: the per-DAG executor (the
+    table is under the planner's 4,096 rows) pins the shape to "sorted";
+    grown past 4,096 rows the same statement is the zero-dim fused
+    pipeline's, which starts from the pin and never builds the runs
+    kernel; a compaction (gc_epoch) frees the shape to try runs again."""
+    monkeypatch.setattr(al, "_FORCE_SEGMENT_IMPL", "runs")
+    monkeypatch.setattr(al, "RUNS_DEGRADE_MIN", 8)
+    built = []
+    orig = pl._build_fused_kernel
+
+    def spy(*a, **k):
+        built.append((a[7], a[8][1]))
+        return orig(*a, **k)
+    monkeypatch.setattr(pl, "_build_fused_kernel", spy)
+    tk = TestKit()
+    tk.must_exec("create table t (id int primary key, g bigint, v int)")
+    rng = np.random.RandomState(5)
+
+    def insert(lo, hi):
+        tk.must_exec("insert into t values " + ",".join(
+            f"({i}, {int(rng.randint(0, 1 << 40))}, {i % 7})"
+            for i in range(lo, hi)))
+    sql = "select g, count(*), sum(v) from t group by g"
+    insert(0, 2000)
+    assert len(tk.must_query(sql).rows) == 2000
+    copr = tk.domain.copr
+    pins = [v for k, v in copr._host_cache.items() if k[0] == "aggimpl"]
+    assert pins == ["sorted"] and not built      # learned per DAG
+    insert(2000, 6000)
+    tk.must_exec("analyze table t")
+    sql += " order by g"        # a new text: the cached plan is per-DAG
+    assert len(tk.must_query(sql).rows) == 6000
+    # held by the fused pipeline: no runs kernel (the second build is
+    # the bucket grown to 6,000 groups)
+    assert built and set(built) == {("sort", "sorted")}
+    tbl = copr.engine.table(
+        tk.domain.infoschema().table_by_name("test", "t"))
+    tbl.gc_epoch += 1
+    copr._kernel_cache.clear()
+    del built[:]
+    assert len(tk.must_query(sql).rows) == 6000
+    assert built[0] == ("sort", "runs") and built[-1] == ("sort", "sorted")

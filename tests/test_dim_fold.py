@@ -11,7 +11,7 @@ import jax
 import numpy as np
 import pytest
 
-import tidb_tpu.copr.dag_exec as de
+import tidb_tpu.copr.agg_lowering as al
 import tidb_tpu.copr.dimfold as df
 import tidb_tpu.copr.pipeline as pl
 from tidb_tpu.bench.tpch import load_tpch, ALL_QUERIES
@@ -22,11 +22,11 @@ from tidb_tpu.utils import phase
 
 @pytest.fixture
 def runs_impl():
-    de._FORCE_SEGMENT_IMPL = "runs"
+    al._FORCE_SEGMENT_IMPL = "runs"
     try:
         yield
     finally:
-        de._FORCE_SEGMENT_IMPL = None
+        al._FORCE_SEGMENT_IMPL = None
 
 
 @pytest.fixture(scope="module")
@@ -366,13 +366,13 @@ _SYN = {
 @pytest.mark.parametrize("case", sorted(_SYN))
 def test_synthetic_chain_vs_host(tkc, case, policy):
     sql, want = _SYN[case]
-    de._FORCE_SEGMENT_IMPL = "runs" if policy == "runs" else None
+    al._FORCE_SEGMENT_IMPL = "runs" if policy == "runs" else None
     try:
         before = _counts()
         dev = _dev_vs_host(tkc, sql)
         grown = _grown(before)
     finally:
-        de._FORCE_SEGMENT_IMPL = None
+        al._FORCE_SEGMENT_IMPL = None
     grown.pop("build", None)
     grown.pop("cache_hit", None)
     assert grown == want
@@ -428,8 +428,8 @@ def test_lowering_change_between_blocks_reuploads(monkeypatch, kinds):
     """Positions scattered over storage order: the first block's partials
     exceed the degrade limit, the shape is pinned to "sorted", and the
     statement uploads the group items' columns it had left out."""
-    monkeypatch.setattr(de, "_RUNS_DEGRADE_MIN", 8)
-    de._FORCE_SEGMENT_IMPL = "runs"
+    monkeypatch.setattr(al, "RUNS_DEGRADE_MIN", 8)
+    al._FORCE_SEGMENT_IMPL = "runs"
     try:
         tk = TestKit()
         tk.must_exec("create table c (id int primary key, seg int)")
@@ -448,7 +448,7 @@ def test_lowering_change_between_blocks_reuploads(monkeypatch, kinds):
                "group by d.val, c.seg order by d.val")
         _dev_vs_host(tk, sql, runs=2)
     finally:
-        de._FORCE_SEGMENT_IMPL = None
+        al._FORCE_SEGMENT_IMPL = None
     assert [k[0] for k in kinds] == ["posruns", "sort"]
     assert not kinds[0][3][2][0]["cols"]
     assert len(kinds[1][3][2][0]["cols"]) == 2

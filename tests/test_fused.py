@@ -291,7 +291,7 @@ def test_host_partial_agg_shared_dicts():
     """Raw-string group keys aggregated chunk-by-chunk must encode
     through ONE shared dict: per-chunk dicts give colliding int64 codes
     that _merge_partials cannot tell apart (review finding)."""
-    from tidb_tpu.copr.dag_exec import _host_partial_agg
+    from tidb_tpu.copr.agg_lowering import host_partial_agg
     from tidb_tpu.copr.pipeline import _AggShim
     from tidb_tpu.expression import EvalCtx
     from tidb_tpu.expression.expr import Column
@@ -308,7 +308,7 @@ def test_host_partial_agg_shared_dicts():
     for chunk_vals in (["x", "x", "y"], ["y", "z"]):
         data = np.array(chunk_vals, dtype=object)
         ctx = EvalCtx(np, len(data), {0: (data, None, None)}, host=True)
-        outs.append(_host_partial_agg(
+        outs.append(host_partial_agg(
             ctx, shim, np.ones(len(data), dtype=bool),
             shared_dicts=shared))
     # codes from both chunks decode through the SAME dict

@@ -10,18 +10,18 @@ boundary-split groups across partitions, and the tie-boundary fallback.
 import numpy as np
 import pytest
 
-import tidb_tpu.copr.dag_exec as de
+import tidb_tpu.copr.agg_lowering as al
 import tidb_tpu.copr.pipeline as pl
 from tidb_tpu.testkit import TestKit
 
 
 @pytest.fixture
 def runs_impl():
-    de._FORCE_SEGMENT_IMPL = "runs"
+    al._FORCE_SEGMENT_IMPL = "runs"
     try:
         yield
     finally:
-        de._FORCE_SEGMENT_IMPL = None
+        al._FORCE_SEGMENT_IMPL = None
 
 
 def _mk_star(tk, n_orders=300, lines_per=4, val=lambda i: i % 97):
@@ -106,7 +106,7 @@ def test_fused_topn_disabled_after_degrade_pin(runs_impl, monkeypatch):
     """Once the runs-degradation guard pins a shape to the sorted
     lowering, candidate pruning must switch off (its boundary-forcing
     assumes storage order) and results must stay exact."""
-    monkeypatch.setattr(de, "_RUNS_DEGRADE_MIN", 8)
+    monkeypatch.setattr(al, "RUNS_DEGRADE_MIN", 8)
     tk = TestKit()
     # wide unclustered-ish keys: clustered anchor exists (monotone ok)
     # but 1 row per group fires the degrade guard (ngroups > m//4)

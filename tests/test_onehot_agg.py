@@ -1,20 +1,22 @@
-"""One-hot MXU segment-aggregation lowering (copr/dag_exec
+"""One-hot MXU segment-aggregation lowering (copr/agg_lowering
 onehot_agg_body): a host-learned slot table + int8 limb matmuls replace
 the device argsort for small group domains under the TPU segment
 policy. Exactness guards: miss detection on new/out-of-span keys,
 zero-slot drop for deletes, arbitrary-precision limb recombination.
-Forced on here via TIDB_TPU_SEGMENT_IMPL=runs + TIDB_TPU_ONEHOT_FORCE
-(the CPU backend's scatter impl would otherwise skip it)."""
+Forced on here through the module's two test seams: the runs policy
+and the one-hot kind on the CPU backend (which would otherwise take
+its scatter impl and skip it)."""
 import numpy as np
 import pytest
 
+import tidb_tpu.copr.agg_lowering as al
 from tidb_tpu.testkit import TestKit
 
 
 @pytest.fixture()
 def tk(monkeypatch):
-    monkeypatch.setenv("TIDB_TPU_SEGMENT_IMPL", "runs")
-    monkeypatch.setenv("TIDB_TPU_ONEHOT_FORCE", "1")
+    monkeypatch.setattr(al, "_FORCE_SEGMENT_IMPL", "runs")
+    monkeypatch.setattr(al, "_FORCE_ONEHOT", True)
     tk = TestKit()
     tk.must_exec("create table f (id bigint primary key, g bigint, "
                  "h bigint, v bigint, w bigint)")

@@ -10,7 +10,7 @@ def test_dense_agg_sorted_matches_scatter():
     sums/counts exactly, min/max/first_row, NULL args, empty slots."""
     import jax
     import jax.numpy as jnp
-    import tidb_tpu.copr.dag_exec as de
+    import tidb_tpu.copr.agg_lowering as al
     from tidb_tpu.expression import EvalCtx
     from tidb_tpu.expression.expr import Column
     from tidb_tpu.types.field_type import new_bigint_type, new_double_type
@@ -42,11 +42,11 @@ def test_dense_agg_sorted_matches_scatter():
     outs = {}
     for impl in ("scatter", "sorted", "runs"):
         # "runs" at nslots=11 exercises the broadcast-compare lowering
-        de._FORCE_SEGMENT_IMPL = impl
+        al._FORCE_SEGMENT_IMPL = impl
         try:
-            r = de.dense_agg_states(ctx, jm, aggs, js, nslots, cap)
+            r = al.dense_agg_states(ctx, jm, aggs, js, nslots, cap)
         finally:
-            de._FORCE_SEGMENT_IMPL = None
+            al._FORCE_SEGMENT_IMPL = None
         outs[impl] = jax.device_get(r)
     a = outs["scatter"]
     assert a["present"][nslots - 1] == 0 and a["present"][nslots - 2] == 0
@@ -68,7 +68,7 @@ def test_sort_agg_sorted_matches_scatter(shape):
     key branches, null group keys, masked rows, all agg kinds."""
     import jax
     import jax.numpy as jnp
-    import tidb_tpu.copr.dag_exec as de
+    import tidb_tpu.copr.agg_lowering as al
     from tidb_tpu.expression import EvalCtx
     from tidb_tpu.expression.expr import Column
     from tidb_tpu.types.field_type import new_bigint_type, new_double_type
@@ -103,12 +103,12 @@ def test_sort_agg_sorted_matches_scatter(shape):
 
     outs = {}
     for impl in ("scatter", "sorted"):
-        de._FORCE_SEGMENT_IMPL = impl
+        al._FORCE_SEGMENT_IMPL = impl
         try:
-            r = de.sort_agg_body(ctx, jm, group_items, aggs, cap,
+            r = al.sort_agg_body(ctx, jm, group_items, aggs, cap,
                                  group_bucket)
         finally:
-            de._FORCE_SEGMENT_IMPL = None
+            al._FORCE_SEGMENT_IMPL = None
         outs[impl] = jax.device_get(r)
     a, b = outs["scatter"], outs["sorted"]
     ng = int(a["ngroups"])
@@ -172,7 +172,7 @@ def test_runs_agg_matches_scatter(shape):
     runs, all agg kinds."""
     import jax
     import jax.numpy as jnp
-    import tidb_tpu.copr.dag_exec as de
+    import tidb_tpu.copr.agg_lowering as al
     from tidb_tpu.expression import EvalCtx
     from tidb_tpu.expression.expr import Column
     from tidb_tpu.types.field_type import new_bigint_type, new_double_type
@@ -209,11 +209,11 @@ def test_runs_agg_matches_scatter(shape):
     outs = {}
     for impl in ("scatter", "runs"):
         bucket = cap if impl == "runs" else 64
-        de._FORCE_SEGMENT_IMPL = impl
+        al._FORCE_SEGMENT_IMPL = impl
         try:
-            r = de.sort_agg_body(ctx, jm, group_items, aggs, cap, bucket)
+            r = al.sort_agg_body(ctx, jm, group_items, aggs, cap, bucket)
         finally:
-            de._FORCE_SEGMENT_IMPL = None
+            al._FORCE_SEGMENT_IMPL = None
         outs[impl] = jax.device_get(r)
     if shape == "clustered":
         # one run per group: no duplicate partials even pre-merge

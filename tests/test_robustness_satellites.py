@@ -26,16 +26,15 @@ def tk():
 def _learn(kcols, knulls):
     from tidb_tpu.copr.pipeline import _oh_learn_table
 
-    class _Copr:
-        _host_cache = {}
+    class _State:
+        onehot = None
 
     class _Plan:
         group_items = [None] * len(kcols)
 
-    copr = _Copr()
-    _oh_learn_table(copr, "ohk", _Plan(),
-                    [(kcols, knulls)])
-    return copr._host_cache.get("ohk")
+    state = _State()
+    _oh_learn_table(state, _Plan(), [(kcols, knulls)])
+    return state.onehot
 
 
 def test_oh_learn_rejects_uint64_above_int63():

@@ -3,14 +3,14 @@
 The full suite runs with the CPU default (scatter oracle); this module
 re-runs the headline query shapes with the TPU policy forced so the
 scatter-free kernels (reduce / broadcast-compare / contiguous-run
-partials) stay covered in CI. See dag_exec._segment_impl for the
-measured numbers behind the policy.
+partials) stay covered in CI. See copr/agg_lowering.py `policy` for
+the policy and what was measured behind it.
 """
 import jax
 import numpy as np
 import pytest
 
-import tidb_tpu.copr.dag_exec as de
+import tidb_tpu.copr.agg_lowering as al
 import tidb_tpu.copr.pipeline as pl
 from tidb_tpu.testkit import TestKit
 from tidb_tpu.bench.tpch import load_tpch, ALL_QUERIES, QUERIES
@@ -18,11 +18,11 @@ from tidb_tpu.bench.tpch import load_tpch, ALL_QUERIES, QUERIES
 
 @pytest.fixture
 def runs_impl():
-    de._FORCE_SEGMENT_IMPL = "runs"
+    al._FORCE_SEGMENT_IMPL = "runs"
     try:
         yield
     finally:
-        de._FORCE_SEGMENT_IMPL = None
+        al._FORCE_SEGMENT_IMPL = None
 
 
 @pytest.fixture(scope="module")
@@ -70,7 +70,7 @@ def test_first_row_skips_empty_partials(runs_impl):
 def test_runs_degradation_pins_sorted(runs_impl, monkeypatch):
     """Unclustered keys explode into ~per-row runs: the guard must pin
     the query shape to the sorted lowering and still answer exactly."""
-    monkeypatch.setattr(de, "_RUNS_DEGRADE_MIN", 8)
+    monkeypatch.setattr(al, "RUNS_DEGRADE_MIN", 8)
     tk = TestKit()
     tk.must_exec("create table t (k bigint, v int)")
     rng = np.random.RandomState(5)
@@ -303,7 +303,7 @@ def test_posruns_yields_to_pinned_sorted(runs_impl, kinds, monkeypatch):
     """Positions scattered over storage order: the first partition's
     partials exceed the degrade limit, the shape is pinned to "sorted"
     and that run and every later one take today's kind."""
-    monkeypatch.setattr(de, "_RUNS_DEGRADE_MIN", 8)
+    monkeypatch.setattr(al, "RUNS_DEGRADE_MIN", 8)
     tk = TestKit()
     tk.must_exec("create table d (id int primary key, val int)")
     tk.must_exec("create table f (k int primary key, d_id int, q int)")
@@ -384,12 +384,11 @@ def test_posruns_q10_gathers_no_group_payload(tk, runs_impl, kinds,
     assert not payload & set(now)
     # the control: the statement again with the shape pinned to today's
     # kind, which evaluates the group items at fact width
-    gbkey = ("gb", tk.domain.copr.engine.table(plan.fact_dag.table_info).uid,
-             tuple(g.fingerprint() for g in plan.group_items),
-             tuple(a.fingerprint() for a in plan.aggs))
-    epoch = tk.domain.copr.engine.table(plan.fact_dag.table_info).gc_epoch
-    monkeypatch.setitem(tk.domain.copr._host_cache,
-                        ("aggimpl", epoch) + gbkey, "sorted")
+    state = al.ShapeState(
+        tk.domain.copr,
+        tk.domain.copr.engine.table(plan.fact_dag.table_info),
+        plan.group_items, plan.aggs)
+    monkeypatch.setattr(state, "pin", "sorted")
     tk.domain.copr._kernel_cache.clear()
     del kinds[:]
     tk.must_query(ALL_QUERIES["q10"])

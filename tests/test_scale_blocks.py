@@ -23,7 +23,7 @@ import sys
 import numpy as np
 import pytest
 
-import tidb_tpu.copr.dag_exec as de
+import tidb_tpu.copr.agg_lowering as al
 import tidb_tpu.copr.pipeline as pl
 from tidb_tpu.copr.residency import DeviceResidentStore
 from tidb_tpu.errors import MemoryQuotaExceededError
@@ -69,8 +69,8 @@ def served(request, tmp_path_factory):
     tables = ds.generate(SCALE, SEED, shape_seed=shape_seed)
     n_li = len(tables["lineitem"]["l_orderkey"])
     mp = pytest.MonkeyPatch()
-    mp.setattr(de, "_FORCE_SEGMENT_IMPL", "runs")
-    mp.setattr(de, "_BCR_MAX", bcr_max)
+    mp.setattr(al, "_FORCE_SEGMENT_IMPL", "runs")
+    mp.setattr(al, "BCR_MAX", bcr_max)
     kinds = []
     build = pl._build_fused_kernel
 
@@ -289,8 +289,8 @@ def test_runs_of_two_or_more_keep_the_runs_lowering(monkeypatch, run_len,
                                                     pinned):
     """A key the storage clusters in runs of four (TPC-H's lines an
     order) or of two is not degraded: only ~a run a row pins the sort."""
-    monkeypatch.setattr(de, "_FORCE_SEGMENT_IMPL", "runs")
-    monkeypatch.setattr(de, "_RUNS_DEGRADE_MIN", 8)
+    monkeypatch.setattr(al, "_FORCE_SEGMENT_IMPL", "runs")
+    monkeypatch.setattr(al, "RUNS_DEGRADE_MIN", 8)
     tk = TestKit()
     tk.must_exec("set @@tidb_tpu_fragment_min_rows = 0")
     tk.must_exec("create table t (k bigint, v int)")
@@ -307,5 +307,5 @@ def test_runs_of_two_or_more_keep_the_runs_lowering(monkeypatch, run_len,
     pins = [v for key, v in tk.domain.copr._host_cache.items()
             if key and key[0] == "aggimpl"]
     assert ("sorted" in pins) == pinned
-    assert de._runs_degraded(1_048_600, 4_194_304) is False   # q18's
-    assert de._runs_degraded(4_100_000, 4_194_304) is True
+    assert al.runs_degraded(1_048_600, 4_194_304) is False   # q18's
+    assert al.runs_degraded(4_100_000, 4_194_304) is True

@@ -221,7 +221,7 @@ def deserialize_partials(meta, arrays, shared_dicts=None):
     worker's response of one query: the merge machinery assumes all
     partials share ONE dictionary per key/state position — re-encoding
     each worker's values into the same dict keeps codes comparable."""
-    from ..copr.dag_exec import PartialAggResult
+    from ..copr.agg_lowering import PartialAggResult
     from ..chunk.device import StringDict
     shared = shared_dicts if shared_dicts is not None else {}
     out = []

@@ -14,7 +14,6 @@ bottleneck: Q3/Q5 lost all join output to host numpy between operators).
 """
 from __future__ import annotations
 
-import os
 import re
 import threading
 
@@ -27,23 +26,24 @@ import jax.numpy as jnp
 from ..expression import EvalCtx, eval_expr, eval_bool_mask
 from ..expression.vec import materialize_nulls
 from ..chunk.device import shape_bucket
-from . import dag_exec as _de
+from . import agg_lowering as _al
 from . import dimfold
-from .dag_exec import (PartialAggResult, capture_agg_dicts, _dense_strides,
-                       dense_agg_body, dense_agg_states, sort_agg_body,
-                       _compact_dense, _I64_MAX, _segment_impl,
-                       _dense_nslots)
+from .agg_lowering import (PartialAggResult, capture_agg_dicts,
+                           dense_strides, dense_agg_body, dense_agg_states,
+                           sort_agg_body, runs_agg_core, onehot_agg_body,
+                           onehot_decode_states, compact_dense,
+                           psum_dense_result)
 from ..utils.fetch import prefetch, host_array, host_int
 from ..utils import failpoint
 from ..utils import jaxcfg
 from ..utils import phase
 from ..utils import tracing as _tracing
 
-_POS_DENSE_MAX = 1 << 22
+_I64_MAX = np.iinfo(np.int64).max
 
 
 class _AggShim:
-    """Duck-typed dag for capture_agg_dicts/_dense_strides/_host_partial_agg."""
+    """Duck-typed dag for capture_agg_dicts/dense_strides/host_partial_agg."""
 
     def __init__(self, group_items, aggs):
         self.group_items = group_items
@@ -286,31 +286,6 @@ def _plan_base_tables(engine, plan, out=None):
         if _plan_base_tables(engine, c, out) is None:
             return None
     return out
-
-
-def _compact_policy(copr, compk, ccap, nvalid, denom):
-    """Learn/regrow policy for the compact-then-aggregate lowering,
-    shared by the single-chip and MPP loops so the thresholds cannot
-    drift. -> "retry" when the kernel must rebuild with a larger
-    compact buffer; None otherwise (first sight of a shape learns the
-    bucket when survivors are <= 1/8 of the partition, else pins
-    compaction off)."""
-    if ccap is not None and nvalid > ccap:
-        if nvalid > denom // 4:
-            # selectivity drifted: survivors are no longer a small
-            # fraction — compaction would gather ~the whole partition
-            # just to sort the same size again. Pin it off instead of
-            # regrowing toward cap forever.
-            copr._host_cache[compk] = "off"
-        else:
-            copr._host_cache[compk] = shape_bucket(nvalid)
-        return "retry"
-    if ccap is None and copr._host_cache.get(compk) != "off":
-        if nvalid <= denom // 8:
-            copr._host_cache[compk] = shape_bucket(max(nvalid, 1))
-        else:
-            copr._host_cache[compk] = "off"
-    return None
 
 
 _MATDIM_MAX_BYTES = 1 << 29     # 512MB of cached subquery results
@@ -745,7 +720,7 @@ def _upload_dims(copr, plan, fp, dim_metas, dim_caps, read_ts, mesh,
     return dim_args, dim_layouts
 
 
-def _fused_topn_state(copr, plan, fact_tbl, offk, kd, sd):
+def _fused_topn_state(plan, fact_tbl, state, kd, sd):
     """Validate the planner's topn_spec against runtime state ->
     spec tuple or None. Device-side top-k over per-run partials is
     exact only when every group lives in at most one partial per
@@ -759,7 +734,7 @@ def _fused_topn_state(copr, plan, fact_tbl, offk, kd, sd):
       the kernel's top-k and the host safety check — float metrics
       would risk ulp-level disagreement at the cut boundary)."""
     spec = getattr(plan, "topn_spec", None)
-    if spec is None or copr._host_cache.get(offk):
+    if spec is None or state.topn_off:
         return None
     kind, ai, desc, k_total = spec
     from ..expression import Column
@@ -1172,8 +1147,8 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
             (scap_oh,) = agg_param
             sargs = dargs[len(dims)]
             with jax.named_scope("group_agg"):
-                res = _de.onehot_agg_body(ctx, mask, group_items, aggs,
-                                          cap, scap_oh, sargs)
+                res = onehot_agg_body(ctx, mask, group_items, aggs,
+                                      cap, scap_oh, sargs)
                 res["nvalid"] = jnp.sum(mask.astype(jnp.int64))
             if ecap is not None or want_fnvalid:
                 res["fnvalid"] = fnvalid
@@ -1214,8 +1189,8 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                 # masked lanes carry whatever position the probe left
                 # them: the core drops wholly masked runs, and a run
                 # they split is two partials the host merge adds up
-                res = _de._runs_agg_core(pkeys, None, amask, actx, aggs,
-                                         acap, gb)
+                res = runs_agg_core(pkeys, None, amask, actx, aggs,
+                                    acap, gb)
             else:
                 res = sort_agg_body(actx, amask, group_items, aggs, acap,
                                     gb, impl=agg_impl)
@@ -1264,7 +1239,6 @@ def _build_fused_kernel_mpp(plan, local_cap, fact_sdicts, dim_caps,
     per-shard partials (host merge) for the general sort layout."""
     from jax.sharding import PartitionSpec as P
     from jax import shard_map
-    from .dag_exec import psum_dense_result
 
     body = _make_pipeline_body(plan, local_cap, fact_sdicts, dim_caps,
                                dim_ns, dim_sns, dim_layouts, agg_kind,
@@ -1371,7 +1345,7 @@ def _delta_in_span(shim, sizes, delta_part):
     return True
 
 
-def _oh_learn_table(copr, ohk, plan, oh_learn, rows=0, version=None):
+def _oh_learn_table(state, plan, oh_learn, rows=0, version=None):
     """Build the one-hot slot table from a completed sorted/runs
     execution's partials: union the per-partition group keys, pack them
     with host-chosen offsets/spans (the kernel range-checks each code,
@@ -1397,7 +1371,7 @@ def _oh_learn_table(copr, ohk, plan, oh_learn, rows=0, version=None):
     for i in range(K):
         vals = kcols[i]
         if vals.dtype.kind not in "iu":
-            copr._host_cache[ohk] = False
+            state.onehot = False
             return
         nn = vals[~knulls[i]]
         lo = int(nn.min()) if len(nn) else 0
@@ -1407,14 +1381,14 @@ def _oh_learn_table(copr, ohk, plan, oh_learn, rows=0, version=None):
             # would raise an uncaught OverflowError, and the kernel's
             # int64 packing could never represent them anyway — pin the
             # shape off the one-hot path like non-integer dtypes
-            copr._host_cache[ohk] = False
+            state.onehot = False
             return
         span = hi - lo + 2
         total_bits += np.log2(max(span, 1))
         los.append(lo)
         spans.append(span)
     if total_bits >= 61.0:
-        copr._host_cache[ohk] = False
+        state.onehot = False
         return
     packed = np.zeros(len(kcols[0]), dtype=np.int64)
     for i in range(K):
@@ -1423,15 +1397,15 @@ def _oh_learn_table(copr, ohk, plan, oh_learn, rows=0, version=None):
         packed = packed * spans[i] + code
     uniq, idx = np.unique(packed, return_index=True)
     nslots = len(uniq)
-    if nslots == 0 or nslots > _de._ONEHOT_MAX:
-        copr._host_cache[ohk] = False
+    if not _al.onehot_fits(nslots):
+        state.onehot = False
         return
     scap = 128
     while scap < nslots:
         scap <<= 1
     skeys = np.full(scap, _I64_MAX, dtype=np.int64)
     skeys[:nslots] = uniq
-    copr._host_cache[ohk] = {
+    state.onehot = {
         "skeys": skeys, "los": np.asarray(los, dtype=np.int64),
         "spans": np.asarray(spans, dtype=np.int64),
         "nslots": nslots, "scap": scap,
@@ -1473,7 +1447,7 @@ def _oh_tail_keys(copr, plan, fact_arrays, lo, hi):
     return kcols, knulls
 
 
-def _oh_fold_delta(copr, ohk, plan, fact_arrays, n, version):
+def _oh_fold_delta(copr, state, plan, fact_arrays, n, version):
     """Version-advance/delta maintenance of a learned one-hot slot
     table: fold the keys of appended fact rows [rows, n) into the
     table at bind time — new in-span keys become new slots (the
@@ -1482,7 +1456,7 @@ def _oh_fold_delta(copr, ohk, plan, fact_arrays, n, version):
     on the first dispatch-time miss. Out-of-span keys or slot-count
     overflow still pop for a relearn (metered fused_onehot_rebuild);
     an append of existing keys is a pure watermark advance."""
-    OH = copr._host_cache.get(ohk)
+    OH = state.onehot
     if not isinstance(OH, dict):
         return
     rows = OH.get("rows", 0)
@@ -1513,7 +1487,7 @@ def _oh_fold_delta(copr, ohk, plan, fact_arrays, n, version):
                            int(spans[i]) - 2):
             # outside the learned span: the packing cannot represent
             # it — relearn from scratch (the only rebuild left)
-            copr._host_cache.pop(ohk, None)
+            del state.onehot
             if dom is not None:
                 dom.inc_metric("fused_onehot_rebuild")
             return
@@ -1529,8 +1503,8 @@ def _oh_fold_delta(copr, ohk, plan, fact_arrays, n, version):
     merged = np.concatenate([old_keys, uniq[fresh]])
     order = np.argsort(merged, kind="stable")
     nnew = len(merged)
-    if nnew > _de._ONEHOT_MAX:
-        copr._host_cache[ohk] = False       # pin off like the learn path
+    if not _al.onehot_fits(nnew):
+        state.onehot = False        # pin off like the learn path
         if dom is not None:
             dom.inc_metric("fused_onehot_rebuild")
         return
@@ -1551,7 +1525,7 @@ def _oh_fold_delta(copr, ohk, plan, fact_arrays, n, version):
     # replace the dict wholesale: in-flight dispatches carry their own
     # table reference (oh_table in the dispatch state) and stay
     # consistent; the next dispatch binds the extended one
-    copr._host_cache[ohk] = {
+    state.onehot = {
         "skeys": skeys, "los": los, "spans": spans,
         "nslots": nnew, "scap": scap,
         "key_vals": key_vals, "key_nulls": key_nulls,
@@ -1731,21 +1705,13 @@ def fused_partials(copr, plan, read_ts, mesh=None,
                                       delta_rows)
     shim = _AggShim(plan.group_items, plan.aggs)
     kd, sd = capture_agg_dicts(shim, one)
-    runs = _segment_impl() == "runs"
-    pos_spec = _pos_group_map(plan, dim_metas)
-    # a position domain too large for the packed-slot lowering (under
-    # the runs policy: for its broadcast-compare-reduce): on one chip
-    # under that policy the positions stay the group keys, as separate
-    # run keys ("posruns", decided a dispatch by _posruns_on); elsewhere
-    # the group items are evaluated at fact width and sorted
-    posruns_spec = None
-    if pos_spec is not None and \
-            pos_spec[2] > (_de._BCR_MAX if runs else _POS_DENSE_MAX):
-        if runs and mesh is None:
-            posruns_spec = pos_spec
-        pos_spec = None
-    sizes = None
-    if pos_spec is None:
+    # what earlier runs taught about this (table, gc epoch, group items,
+    # aggregates) shape: bucket, impl pin, compaction, top-n, one-hot
+    st = _al.ShapeState(copr, fact_tbl, plan.group_items, plan.aggs)
+
+    def _dense_sizes():
+        """The dense layout of the group items, or None. Asked for only
+        when no position domain stands."""
         fcols = None
         if not plan.dims and n:
             # zero-dim pipeline: int group keys can dense-detect via a
@@ -1757,104 +1723,46 @@ def fused_partials(copr, plan, read_ts, mesh=None,
                 cid = _cid_of(plan.fact_dag, sc)
                 fcols[sc.col.idx] = (handles, None, None) if cid == -1 \
                     else fact_arrays[cid]
-        sizes = _dense_strides(shim, kd, fcols, n)
+        sizes = dense_strides(shim, kd, fcols, n)
         if sizes is not None and delta_part is not None and \
                 not _delta_in_span(shim, sizes, delta_part):
             # dense layouts clip group codes to the derived span: a
             # delta key OUTSIDE it would silently merge into a boundary
             # group — those executions take the exact sort lowering
-            sizes = None
-    if runs and sizes is not None and \
-            _dense_nslots(sizes) > _de._BCR_MAX:
-        # big dense domains have no scatter-free dense lowering: they
-        # fall to the contiguous-run partials ("posruns" or "sort")
-        sizes = None
-    if sizes is not None:
-        # a few dict codes (c_mktsegment over 150k customers): the
-        # dense kind's compare-reduce beats runs over scattered positions
-        posruns_spec = None
+            return None
+        return sizes
+
+    low = _al.Lowering(
+        st, _pos_group_map(plan, dim_metas), _dense_sizes,
+        site="fused" if mesh is None else "mesh", dims=bool(plan.dims),
+        topn=None if mesh is not None else
+        _fused_topn_state(plan, fact_tbl, st, kd, sd))
 
     fact_sdicts = {k: v[2] for k, v in one.items()
                    if k in {sc.col.idx for sc in plan.fact_dag.cols}}
     out = []
     step = copr.device_rows
-    gbkey = ("gb", fact_tbl.uid,
-             tuple(g.fingerprint() for g in plan.group_items),
-             tuple(a.fingerprint() for a in plan.aggs))
-    group_bucket = max(1024, copr._host_cache.get(gbkey, 0))
-    # pins are per gc-epoch: a compaction that restores clustering lets
-    # a shape re-try the runs lowering / device top-N it had pinned off
-    implk = ("aggimpl", fact_tbl.gc_epoch) + gbkey
-    offk = ("ftopn_off", fact_tbl.gc_epoch) + gbkey
-    compk = ("fcompact", fact_tbl.gc_epoch) + gbkey
-    ecapk = ("fecompact", fact_tbl.gc_epoch) + gbkey
-    ts = None
-    if mesh is None:
-        ts = _fused_topn_state(copr, plan, fact_tbl, offk, kd, sd)
-    # one-hot MXU lowering state: a host-learned slot table replaces
-    # the device argsort for small group domains (dag_exec
-    # onehot_agg_body). Learned from the first sorted/runs execution,
-    # invalidated by misses (new/changed keys) at consume time.
-    ohk = ("onehot", fact_tbl.gc_epoch) + gbkey
-    # fold appended rows' keys into a learned slot table BEFORE any
-    # dispatch binds it: an in-bucket append must extend slots, not
-    # force a dispatch-time miss-pop-relearn
-    _oh_fold_delta(copr, ohk, plan, fact_arrays, n, fact_version)
+    # one-hot MXU lowering: a host-learned slot table replaces the
+    # device argsort for small group domains (onehot_agg_body). Learned
+    # from the first sorted/runs execution, invalidated by misses
+    # (new/changed keys) at consume time. Fold appended rows' keys into
+    # a learned table BEFORE any dispatch binds it: an in-bucket append
+    # must extend slots, not force a dispatch-time miss-pop-relearn
+    _oh_fold_delta(copr, st, plan, fact_arrays, n, fact_version)
     oh_learn = []
     oh_parts = []
-
-    def _posruns_on():
-        """Group on the join positions? Read a dispatch: a degraded
-        partition pins the shape to "sorted" mid-statement. A learned
-        one-hot table (it can only date from a pinned spell) keeps the
-        one-hot kind."""
-        return posruns_spec is not None and \
-            copr._host_cache.get(implk) != "sorted" and \
-            not isinstance(copr._host_cache.get(ohk), dict)
-
-    def _oh_eligible():
-        # position-grouped shapes learn no one-hot table: that kind has
-        # to evaluate every group item at fact width, the gathers the
-        # positions save
-        if not plan.group_items or pos_spec is not None or \
-                _posruns_on() or \
-                sizes is not None or delta_rows or mesh is not None:
-            return False
-        if copr._host_cache.get(ohk) is False:
-            return False
-        if jax.default_backend() == "cpu" and \
-                not os.environ.get("TIDB_TPU_ONEHOT_FORCE"):
-            # the one-hot matmul is O(cap*scap*limbs): ~0.5ms on the
-            # MXU at q10's SF1 shape but SECONDS on a host core — this
-            # lowering exists for real accelerators only
-            return False
-        for a in plan.aggs:
-            if a.name == "count":
-                continue
-            if a.name not in ("sum", "avg"):
-                return False
-            try:
-                ectx1 = EvalCtx(np, 1, one, host=True)
-                d1, _nl1, _sd1 = eval_expr(ectx1, a.args[0])
-                dt = getattr(d1, "dtype", None)
-                if dt is None or dt.kind != "i":
-                    return False    # exact limb sums are int64-only
-            except Exception:       # noqa: BLE001
-                return False
-        return True
-    oh_elig = _oh_eligible()
+    oh_elig = low.onehot_learnable(plan.group_items, plan.aggs, one,
+                                   delta_rows)
     if mesh is not None:
-        dim_args, dim_layouts = _dims_for(pos_spec is not None)
+        dim_args, dim_layouts = _dims_for(low.pos is not None)
         return _run_fused_mpp(
             copr, plan, mesh, fact_tbl, fact_arrays, fact_valid, n,
             handles, dim_args, dim_metas, dim_caps, dim_ns, dim_sns,
-            dim_layouts, fact_sdicts, pos_spec, sizes, shim, kd, sd,
-            gbkey, group_bucket, read_ts, dim_pres, fp)
+            dim_layouts, fact_sdicts, low, shim, kd, sd, read_ts,
+            dim_pres, fp)
     # the lowering the first row block will take: its operands go up
     # before the loop, in a `bind` of the statement's own
-    _dims_for(pos_spec is not None or (
-        sizes is None and not isinstance(copr._host_cache.get(ohk), dict)
-        and _posruns_on()))
+    _dims_for(low.choose(0)[0] in ("posdense", "posruns"))
     # row blocks of this run: the fact's, plus the transaction's own
     # rows as one more; the `dispatch`/`consume` spans carry the numbers
     parts = -(-n // step) + (delta_part is not None)
@@ -1883,59 +1791,7 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         currently learned lowering parameters. Returns everything the
         consume step needs to validate the run."""
         cap = shape_bucket(m)
-        if pos_spec is not None:
-            agg_kind = "posdense"
-            agg_param = (tuple(pos_spec[1]), pos_spec[2])
-        elif sizes is not None:
-            agg_kind, agg_param = "dense", tuple(sizes)
-        elif isinstance(copr._host_cache.get(ohk), dict) and \
-                cap <= (1 << 23):
-            # learned slot table: one-hot MXU aggregation (int32 limb
-            # exactness needs cap*127 < 2^31, hence the cap guard).
-            agg_kind, agg_param = "onehot", \
-                (copr._host_cache[ohk]["scap"],)
-        else:
-            posruns = _posruns_on()
-            agg_impl = copr._host_cache.get(implk) or _segment_impl()
-            topn_k = None
-            # candidate pruning is sound ONLY under the runs
-            # lowering: its run order is storage order, so the
-            # partition-edge (possibly split) groups are exactly
-            # runs 0 and ngroups-1, which _topn_select forces into
-            # the candidate set. sorted/scatter order groups by
-            # key rank, where the edge groups can sit anywhere.
-            # the coverage proof needs >= k complete groups strictly
-            # above the candidate min: with group_bucket < k+2 it can
-            # never pass, so don't burn a kernel compile + permanent
-            # off-pin on a shape that cannot verify
-            if ts is not None and agg_impl == "runs" and \
-                    group_bucket >= ts[3] + 2 and \
-                    not copr._host_cache.get(offk):
-                topn_k = (ts[0], ts[1], ts[2],
-                          min(ts[3] + 66, group_bucket))
-            ccap = copr._host_cache.get(compk)
-            # both kinds share the bucket-growth retry, the compaction
-            # policy and the top-n proof, so their agg_param differs
-            # in one slot: the segment impl or the position dims
-            agg_kind = "posruns" if posruns else "sort"
-            agg_param = (
-                group_bucket,
-                tuple(posruns_spec[1]) if posruns else agg_impl, topn_k,
-                ccap if isinstance(ccap, int) else None)
-        ec = copr._host_cache.get(ecapk)
-        ecap = ec if isinstance(ec, int) and ec < cap else None
-        if ecap is not None and not plan.dims:
-            # zero-dim pipeline: downstream of the fact filter is
-            # ONE aggregation pass — gather-compaction (cumsum +
-            # per-column gathers) costs more than it saves (q6's
-            # global reduce, q15's dense group-by both measured
-            # slower with it). Compaction pays when dim probes and
-            # multi-pass agg lowerings run at survivor scale.
-            ecap = None
-        if ecap is not None and agg_kind in ("sort", "posruns"):
-            # survivors are already compacted: the late (post-join)
-            # compact stage would re-gather the same buffer
-            agg_param = agg_param[:3] + (None,)
+        agg_kind, agg_param, ecap = low.choose(cap)
         dim_args, dim_layouts = _dims_for(
             agg_kind in ("posdense", "posruns"))
         key = _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap,
@@ -1957,10 +1813,10 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         oh_table = None
         if agg_kind == "onehot":
             # carry the table in the dispatch state: a sibling
-            # pipelined partition's miss may pop the cache entry
+            # pipelined partition's miss may pop the learned entry
             # before this partition consumes, so consume must never
-            # re-read copr._host_cache
-            oh_table = copr._host_cache[ohk]
+            # re-read it
+            oh_table = st.onehot
             dev = oh_table.get("dev")
             if dev is None:
                 dev = {"skeys": jnp.asarray(oh_table["skeys"]),
@@ -1988,7 +1844,10 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         `bind`/`dispatch` children are the cost of the retry."""
         with phase.row_block(part, parts), \
                 _tracing.span("consume", part=part, parts=parts) as sp:
-            retries = _consume_until_valid(state, cols, v, m, bind_keys)
+            retries = 0
+            while not _consume(state, m):
+                state = _dispatch_part(cols, v, m, bind_keys)
+                retries += 1
             if sp is not None:
                 sp.attrs["retries"] = retries
 
@@ -1997,151 +1856,125 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         as values -> (keys, key_nulls, key_dicts)."""
         ks = [host_array(k)[:ng] for k in res["keys"]]
         if posruns:
-            return _decode_pos_keys(posruns_spec[0],
+            return _decode_pos_keys(low.posruns[0],
                                     dict(zip(pos_dims, ks)), dim_metas)
         return ks, [host_array(kn)[:ng] for kn in res["key_nulls"]], kd
 
+    def _count(metric):
+        if getattr(copr, "domain", None) is not None:
+            copr.domain.inc_metric(metric)
+
     def _emit(posruns, ng, ks, kns, kds, sts):
-        if posruns and getattr(copr, "domain", None) is not None:
-            copr.domain.inc_metric("fused_posruns_agg")
+        if posruns:
+            _count("fused_posruns_agg")
         out.append(PartialAggResult(
             ngroups=ng, keys=ks, key_nulls=kns, states=sts,
             key_dicts=kds, state_dicts=sd))
 
-    def _consume_until_valid(state, cols, v, m, bind_keys):
-        nonlocal group_bucket
-        retries = -1
-        while True:
-            retries += 1
-            res, cap, agg_kind, agg_param, ecap, oh_table = state
-            # early-compaction policy: learn the survivor bucket on
-            # first sight, regrow + rerun on overflow (fnvalid is the
-            # fact-filter survivor count BEFORE any compaction loss, so
-            # an overflowed run is incorrect and must not be consumed)
-            if _compact_policy(copr, ecapk, ecap,
-                               host_int(res["fnvalid"]), cap) == "retry":
-                state = _dispatch_part(cols, v, m, bind_keys)
-                continue
-            if pos_spec is not None:
-                out.append(_compact_pos_dense(plan, res, pos_spec[0],
-                                              pos_spec[1], dim_metas, sd))
-                return retries
-            if sizes is not None:
-                out.append(_compact_dense(shim, res, sizes, kd, sd))
-                return retries
-            if agg_kind == "onehot":
-                if host_int(res["miss"]) > 0:
-                    # new/changed keys since the table was learned:
-                    # fall back to the sorted lowering and relearn
-                    if getattr(copr, "domain", None) is not None:
-                        copr.domain.inc_metric("fused_onehot_miss")
-                    copr._host_cache.pop(ohk, None)
-                    state = _dispatch_part(cols, v, m, bind_keys)
-                    continue
-                OH = oh_table
-                if getattr(copr, "domain", None) is not None:
-                    copr.domain.inc_metric("fused_onehot_agg")
-                acc = host_array(res["oh_acc"])
-                states, rowcnt = _de.onehot_decode_states(
-                    acc, plan.aggs, OH["nslots"])
-                oh_parts.append((len(out), rowcnt))
-                out.append(PartialAggResult(
-                    ngroups=OH["nslots"],
-                    keys=[k.copy() for k in OH["key_vals"]],
-                    key_nulls=[kn.copy() for kn in OH["key_nulls"]],
-                    states=states, key_dicts=kd, state_dicts=sd))
-                return retries
-            ngroups = host_int(res["ngroups"])
-            if _compact_policy(copr, compk, agg_param[3],
-                               host_int(res["nvalid"]), cap) == "retry":
-                state = _dispatch_part(cols, v, m, bind_keys)
-                continue
-            posruns = agg_kind == "posruns"
-            if (posruns or agg_param[1] == "runs") and \
-                    _de._runs_degraded(ngroups, m):
-                # unclustered group keys: pin this query shape to the
-                # sorted lowering before learning an inflated bucket
-                copr._host_cache[implk] = "sorted"
-                state = _dispatch_part(cols, v, m, bind_keys)
-                continue
-            if ngroups > agg_param[0]:
-                # compare against the bucket THIS kernel was built
-                # with (agg_param[0]), not the nonlocal possibly grown
-                # by an earlier partition after this one's speculative
-                # dispatch: an overflowed run truncated its key/state
-                # buffers and must re-run at the larger bucket
-                group_bucket = max(group_bucket, shape_bucket(ngroups))
-                copr._host_cache[gbkey] = group_bucket
-                state = _dispatch_part(cols, v, m, bind_keys)
-                continue
-            topn_k = agg_param[2]
-            if topn_k is not None:
-                # candidate partials only: verify the candidate set
-                # provably covers the true top k before trusting it
-                kprime = topn_k[3]
-                ncand = min(ngroups, kprime)
-                ckeys, cnulls, ckd = _host_keys(res, posruns,
-                                                agg_param[1], ncand)
-                cstates = [[host_array(s)[:ncand] for s in st]
-                           for st in res["states"]]
-                if ngroups > kprime:
-                    sel = host_array(res["sel"])[:ncand]
-                    real_m = _topn_metric_host(ts, plan.aggs, ckeys,
-                                               cnulls, cstates)
-                    nf = ~((sel == 0) | (sel == ngroups - 1))
-                    # the coverage proof may count only COMPLETE groups
-                    # (non-forced candidates): a forced partition-edge
-                    # partial's metric is not its merged total, so it
-                    # cannot vouch for excluding other groups
-                    mnf = real_m[nf]
-                    safe = len(mnf) > 0 and \
-                        int((mnf > mnf.min()).sum()) >= ts[3]
-                    if not safe:
-                        # boundary ties could hide true top-k members:
-                        # permanently disable topn for this query shape
-                        copr._host_cache[offk] = True
-                        state = _dispatch_part(cols, v, m, bind_keys)
-                        continue
-                _emit(posruns, ncand, ckeys, cnulls, ckd, cstates)
-                return retries
-            ks, kns, kds = _host_keys(res, posruns, agg_param[1], ngroups)
-            sts = [[host_array(s)[:ngroups] for s in st]
-                   for st in res["states"]]
-            if oh_elig and copr._host_cache.get(ohk) is None:
-                # runs partials may repeat a key once per run, so the
-                # slot-count limit applies AFTER the union dedupes
-                # (_oh_learn_table). The CUMULATIVE row bound caps the
-                # staged host copies: runs-degrade already limits each
-                # partition to ~65k partials, so only very-many-
-                # partition shapes (which could never learn a small
-                # table anyway) hit it
-                if sum(len(e[0][0]) for e in oh_learn) + ngroups \
-                        > (1 << 21):
-                    copr._host_cache[ohk] = False
-                    oh_learn.clear()
-                else:
-                    oh_learn.append((ks, kns))
-            _emit(posruns, ngroups, ks, kns, kds, sts)
-            return retries
+    def _consume(state, m):
+        """One run's result into `out` -> True, or False when the
+        partition has to run again with what this run taught."""
+        res, cap, agg_kind, agg_param, ecap, oh_table = state
+        runs_like = agg_kind in ("sort", "posruns")
+        ngroups = host_int(res["ngroups"]) if runs_like else None
+        if low.observe(agg_kind, agg_param, ecap, cap, m, ngroups,
+                       host_int(res["nvalid"]) if runs_like else None,
+                       host_int(res["fnvalid"])) == "retry":
+            return False
+        if agg_kind == "posdense":
+            out.append(_compact_pos_dense(plan, res, low.pos[0],
+                                          low.pos[1], dim_metas, sd))
+            return True
+        if agg_kind == "dense":
+            out.append(compact_dense(shim, res, low.sizes, kd, sd))
+            return True
+        if agg_kind == "onehot":
+            if host_int(res["miss"]) > 0:
+                # new/changed keys since the table was learned:
+                # fall back to the sorted lowering and relearn
+                _count("fused_onehot_miss")
+                del st.onehot
+                return False
+            OH = oh_table
+            _count("fused_onehot_agg")
+            acc = host_array(res["oh_acc"])
+            states, rowcnt = onehot_decode_states(
+                acc, plan.aggs, OH["nslots"])
+            oh_parts.append((len(out), rowcnt))
+            out.append(PartialAggResult(
+                ngroups=OH["nslots"],
+                keys=[k.copy() for k in OH["key_vals"]],
+                key_nulls=[kn.copy() for kn in OH["key_nulls"]],
+                states=states, key_dicts=kd, state_dicts=sd))
+            return True
+        posruns = agg_kind == "posruns"
+        topn_k = agg_param[2]
+        if topn_k is not None:
+            # candidate partials only: verify the candidate set
+            # provably covers the true top k before trusting it
+            ts = low.topn
+            kprime = topn_k[3]
+            ncand = min(ngroups, kprime)
+            ckeys, cnulls, ckd = _host_keys(res, posruns,
+                                            agg_param[1], ncand)
+            cstates = [[host_array(s)[:ncand] for s in st_]
+                       for st_ in res["states"]]
+            if ngroups > kprime:
+                sel = host_array(res["sel"])[:ncand]
+                real_m = _topn_metric_host(ts, plan.aggs, ckeys,
+                                           cnulls, cstates)
+                nf = ~((sel == 0) | (sel == ngroups - 1))
+                # the coverage proof may count only COMPLETE groups
+                # (non-forced candidates): a forced partition-edge
+                # partial's metric is not its merged total, so it
+                # cannot vouch for excluding other groups
+                mnf = real_m[nf]
+                safe = len(mnf) > 0 and \
+                    int((mnf > mnf.min()).sum()) >= ts[3]
+                if not safe:
+                    # boundary ties could hide true top-k members:
+                    # permanently disable topn for this query shape
+                    st.topn_off = True
+                    return False
+            _emit(posruns, ncand, ckeys, cnulls, ckd, cstates)
+            return True
+        ks, kns, kds = _host_keys(res, posruns, agg_param[1], ngroups)
+        sts = [[host_array(s)[:ngroups] for s in st_]
+               for st_ in res["states"]]
+        if oh_elig and st.onehot is None:
+            # runs partials may repeat a key once per run, so the
+            # slot-count limit applies AFTER the union dedupes
+            # (_oh_learn_table). The CUMULATIVE row bound caps the
+            # staged host copies: runs-degrade already limits each
+            # partition to ~65k partials, so only very-many-
+            # partition shapes (which could never learn a small
+            # table anyway) hit it
+            if sum(len(e[0][0]) for e in oh_learn) + ngroups \
+                    > (1 << 21):
+                st.onehot = False
+                oh_learn.clear()
+            else:
+                oh_learn.append((ks, kns))
+        _emit(posruns, ngroups, ks, kns, kds, sts)
+        return True
 
     # partition pipelining: partition i+1's padding/upload/dispatch is
     # issued BEFORE partition i's results are consumed, so the fixed
     # per-round-trip dispatch + fetch latency overlaps device compute
-    # instead of adding up across partitions (depth 2 has not been
-    # measured on this chip — ROADMAP D2).
+    # instead of adding up across partitions (one block ahead: deeper
+    # has not been measured on this chip — ROADMAP D2).
     # A consume-time policy retry re-dispatches only its own partition
     # with the freshly learned state; a speculatively dispatched
     # successor then self-corrects the same way (one extra kernel run
     # on the rare learning executions, steady state unchanged).
-    depth = max(1, int(os.environ.get("TIDB_TPU_PIPELINE_DEPTH", "2")))
-    pending = []
+    held = None
     for part, (cols, v, m, bkeys) in enumerate(_partitions()):
         with phase.row_block(part, parts):
             state = _dispatch_part(cols, v, m, bkeys)
-        pending.append((part, state, cols, v, m, bkeys))
-        if len(pending) >= depth:
-            _consume_part(*pending.pop(0))
-    for held in pending:
+        if held is not None:
+            _consume_part(*held)
+        held = (part, state, cols, v, m, bkeys)
+    if held is not None:
         _consume_part(*held)
     if oh_parts:
         # drop slots with zero rows across every one-hot partition:
@@ -2159,12 +1992,11 @@ def fused_partials(copr, plan, read_ts, mesh=None,
                     ngroups=len(keep),
                     keys=[k[keep] for k in p0.keys],
                     key_nulls=[kn[keep] for kn in p0.key_nulls],
-                    states=[[s[keep] for s in st] for st in p0.states],
+                    states=[[s[keep] for s in st_] for st_ in p0.states],
                     key_dicts=p0.key_dicts, state_dicts=p0.state_dicts)
     if oh_elig and oh_learn and len(oh_learn) == len(out) and \
-            copr._host_cache.get(ohk) is None:
-        _oh_learn_table(copr, ohk, plan, oh_learn, rows=n,
-                        version=fact_version)
+            st.onehot is None:
+        _oh_learn_table(st, plan, oh_learn, rows=n, version=fact_version)
     return out
 
 
@@ -2305,9 +2137,8 @@ def _try_fused_shuffle(copr, plan, mesh, dim_metas, fact_tbl, fact_arrays,
 
 def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
                    n, handles, dim_args, dim_metas, dim_caps, dim_ns,
-                   dim_sns, dim_layouts, fact_sdicts, pos_spec, sizes,
-                   shim, kd, sd, gbkey, group_bucket, read_ts,
-                   dim_pres=(), fold=None):
+                   dim_sns, dim_layouts, fact_sdicts, low, shim, kd, sd,
+                   read_ts, dim_pres=(), fold=None):
     """Mesh execution: ONE shard_map call over the whole fact table."""
     from ..mpp.exec import exchange_observed, tree_nbytes
     from .delta import append_key
@@ -2349,21 +2180,9 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
             (fact_tbl.uid, "mppfv", ver, read_ts, ndev, padded),
             fact_valid[:n], mesh, padded, pad_fill=False, uid=fact_tbl.uid,
             version=ver)
-    compk = ("fcompact", fact_tbl.gc_epoch) + gbkey
     retries = 0     # re-dispatches the learned lowering forced
     while True:
-        if pos_spec is not None:
-            agg_kind = "posdense"
-            agg_param = (tuple(pos_spec[1]), pos_spec[2])
-        elif sizes is not None:
-            agg_kind, agg_param = "dense", tuple(sizes)
-        else:
-            agg_impl = copr._host_cache.get(
-                ("aggimpl", fact_tbl.gc_epoch) + gbkey) or _segment_impl()
-            ccap = copr._host_cache.get(compk)
-            agg_kind, agg_param = "sort", (
-                group_bucket, agg_impl, None,
-                ccap if isinstance(ccap, int) else None)
+        agg_kind, agg_param, _ecap = low.choose(local)
         key = _fused_cache_key(copr, plan, fact_tbl, dim_metas, local,
                                tuple(dim_caps), tuple(dim_ns),
                                tuple(dim_sns), agg_kind, agg_param,
@@ -2384,31 +2203,21 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
         # ships per-shard partials to the coordinator in one fetch
         exchange_observed("passthrough", tree_nbytes(res))
         with _tracing.span("consume", retries=retries):
-            if pos_spec is not None:
-                return [_compact_pos_dense(plan, res, pos_spec[0],
-                                           pos_spec[1], dim_metas, sd)]
-            if sizes is not None:
-                return [_compact_dense(shim, res, sizes, kd, sd)]
+            if agg_kind == "posdense":
+                return [_compact_pos_dense(plan, res, low.pos[0],
+                                           low.pos[1], dim_metas, sd)]
+            if agg_kind == "dense":
+                return [compact_dense(shim, res, low.sizes, kd, sd)]
             ngroups_arr = host_array(res["ngroups"])     # [ndev]
-            ng_max = int(ngroups_arr.max())
-            if _compact_policy(copr, compk, agg_param[3],
-                               int(host_array(res["nvalid"]).max()),
-                               local) == "retry":
+            # the verdict is the fullest shard's: every shard runs the
+            # one program
+            if low.observe(agg_kind, agg_param, None, local, local,
+                           int(ngroups_arr.max()),
+                           int(host_array(res["nvalid"]).max())) \
+                    == "retry":
                 retries += 1
                 continue
-            if agg_param[1] == "runs" and \
-                    _de._runs_degraded(ng_max, local):
-                # unclustered group keys on this shard layout: pin to the
-                # sorted lowering before learning an inflated bucket
-                copr._host_cache[("aggimpl", fact_tbl.gc_epoch) + gbkey] = \
-                    "sorted"
-                retries += 1
-                continue
-            if ng_max > group_bucket:
-                group_bucket = shape_bucket(ng_max)
-                copr._host_cache[gbkey] = group_bucket
-                retries += 1
-                continue
+            group_bucket = agg_param[0]
             # unstack the per-shard partials
             out = []
             for si in range(ndev):
@@ -2453,6 +2262,6 @@ def _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap, dim_caps,
                           for sc in plan.fact_dag.cols))
     return ("fused", fact_tbl.uid, cap, dim_caps, dim_ns, dim_sns, fps,
             dimsig, postfps, gfps, afps, tuple(dict_vers), colsig,
-            agg_kind, agg_param, ecap, _segment_impl(),
+            agg_kind, agg_param, ecap, _al.policy(),
             tuple(bool(m.get("pre")) for m in dim_metas),
             None if fold is None else fold.sig())

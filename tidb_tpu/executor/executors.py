@@ -397,7 +397,7 @@ class FusedPipelineExec(Executor):
 
     def _fallback_partials_inner(self):
         from .builder import build_executor
-        from ..copr.dag_exec import _host_partial_agg
+        from ..copr.agg_lowering import host_partial_agg
         from ..copr.pipeline import _AggShim
         fb = build_executor(self.ctx, self.plan.fallback)
         shim = _AggShim(self.plan.group_items, self.plan.aggs)
@@ -408,7 +408,7 @@ class FusedPipelineExec(Executor):
                 continue
             cols = bind_chunk(self.plan.fallback.schema, chunk)
             ectx = EvalCtx(np, len(chunk), cols, host=True)
-            out.append(_host_partial_agg(
+            out.append(host_partial_agg(
                 ectx, shim, np.ones(len(chunk), dtype=bool),
                 shared_dicts=shared_dicts))
         return out
@@ -1329,7 +1329,7 @@ class HashAggExec(Executor):
         if ngk:
             kvecs = [np.where(kn, -(1 << 62), k)
                      for k, kn in zip(keys, key_nulls)]
-            from ..copr.dag_exec import sorted_run_starts
+            from ..copr.agg_lowering import sorted_run_starts
             starts, change = sorted_run_starts(kvecs)
             if starts is not None:
                 # partials over range partitions of a clustered key
@@ -1474,7 +1474,7 @@ class HashAggExec(Executor):
                                "first_row"})
 
     def _complete(self):
-        from ..copr.dag_exec import _host_partial_agg
+        from ..copr.agg_lowering import host_partial_agg
         plan = self.plan
         if any(d.distinct or d.name not in self._DECOMPOSABLE
                for d in plan.aggs):
@@ -1498,7 +1498,7 @@ class HashAggExec(Executor):
                 continue
             cols = bind_chunk(self.child.schema, ch)
             ectx = EvalCtx(np, n, cols, host=True)
-            partials.append(_host_partial_agg(ectx, _FakeDag,
+            partials.append(host_partial_agg(ectx, _FakeDag,
                                               np.ones(n, dtype=bool),
                                               shared_dicts=shared_dicts))
         return self._merge_partials(partials)

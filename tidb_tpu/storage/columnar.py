@@ -288,7 +288,7 @@ class ColumnarTable:
         """True when the column is non-NULL and monotone non-decreasing
         in STORAGE ORDER across every version row — equal values are
         then contiguous, so contiguous-run aggregation partials
-        (copr/dag_exec runs lowering) are exact per-group within a
+        (copr/agg_lowering runs lowering) are exact per-group within a
         partition. TPC-H lineitem.l_orderkey and orders.o_orderkey hold
         this by construction of the load order.
 
