@@ -3,7 +3,7 @@ statements' device programs: a reading, on the chip, of statement times
 and of the compiled HLO's `while` loops.
 
     python benchmarks/fold_probe_tpu.py --scale 1 --seed 28 \
-        --time q5,q3,q10,q18 --hlo q18 --variants control,mask,fold
+        --time q5,q3,q10,q18 --hlo q18 --variants control,mask,fold,packed
 
 The data set, its loader and the statements are the benchmark's
 (benchmark/datasets/tpch.py, loaded by path and not edited); the
@@ -13,7 +13,13 @@ statement's and not the wire's. Variants:
 - `control`: no dimension folds (the program of the commit before);
 - `mask`:    only the `valid[pos]` gathers of the unfiltered inner
              dimensions go (ISSUE 28's kill criterion on q5);
-- `fold`:    the plan's own fold.
+- `fold`:    the plan's own fold;
+- `packed`:  the same, under ISSUE 30's name: since PR 30 a root's
+             payload rides the words its probe table holds (`mask`'s
+             roots too) and PR 28's root-width columns are gone. Its
+             kill criterion (q5 502.8 -> 212.0 ms at SF1) was read in
+             one call before they went; to read it again run PR 29's
+             tree's `fold` and this in one call.
 
 `--hlo q` writes the compiled HLO text of q's program with dimensions to
 chiprun_out/hlo_<q>_<variant>_sf<scale>.txt and prints every `while`
@@ -137,6 +143,18 @@ def main():
         return res
     df._build = timed_build
 
+    orig_compose = df.Fold._compose
+
+    def timed_compose(self, fields):
+        t = time.perf_counter()
+        res = orig_compose(self, fields)
+        log(f"  {len(fields)} field(s) composed over dimension {self.root}: "
+            f"{len(res.tables)} word(s) of {len(res.tables[0])} "
+            f"slots, {res.nbytes} bytes: "
+            f"{(time.perf_counter() - t) * 1e3:.1f} ms")
+        return res
+    df.Fold._compose = timed_compose
+
     real = df.fold_plan
 
     def control(plan):
@@ -148,7 +166,8 @@ def main():
                      d.join_type == "inner" and not d.dag.filters
                      for i, d in enumerate(plan.dims)]
         return fp
-    variants = {"control": control, "mask": mask_only, "fold": real}
+    variants = {"control": control, "mask": mask_only, "fold": real,
+                "packed": real}
 
     result = {"scale": args.scale, "seed": args.seed,
               "device": f"{dev.platform} {dev.device_kind}", "ms": {},

@@ -1,7 +1,8 @@
 """Folded dimensions of the fused pipeline (copr/dimfold.py): a chain
 root's probe table carries its own mask and the hit of every dimension
-resolved under it, and a fact lane reads a descendant's column or
-position with one gather through the root's position.
+resolved under it, and what a fact lane reads through the root's
+position -- a column, a null bit, a descendant's position -- is a field
+of the word the table holds at its key: one gather a root.
 
 Counts here are counts of the traced program (CPU backend), never
 device times."""
@@ -150,17 +151,34 @@ def _main_kernel(tk, kinds, sql):
 
 # ---- (a) the census of fact-wide gathers ------------------------------
 
-# query -> (most with the fold, fewest without: ISSUE 28's table)
-_CENSUS = {"q5": (6, 27), "q10": (6, 15), "q3": (5, 10), "q18": (4, 7)}
+# query -> (most with the fold, of them from a dimension's operands,
+# fewest without: ISSUE 28's table, ISSUE 30's for the composed word)
+_CENSUS = {"q5": (2, 2, 27), "q10": (3, 1, 15), "q3": (2, 1, 10),
+           "q18": (2, 1, 7)}
+# the roots whose payload is composed with their probe table
+_PACKED = {"q5": 2, "q10": 1, "q3": 0, "q18": 0}
 
 
 @pytest.mark.parametrize("q", sorted(_CENSUS))
 def test_census_fact_wide_gathers(tk, runs_impl, kinds, monkeypatch, q):
-    most, control = _CENSUS[q]
-    folded = _wide_gathers(*_main_kernel(tk, kinds, _q(q)))
+    most, of_dims, control = _CENSUS[q]
+    build, shapes = _main_kernel(tk, kinds, _q(q))
+    folded = _wide_gathers(build, shapes)
     assert len(folded) <= most, folded
+    # one gather a root: its probe table, of positions or of words
+    dimops = [p for p in folded if p.startswith("[2]")]
+    assert len(dimops) <= of_dims and (q in ("q5", "q10") or
+                                       len(dimops) == of_dims), folded
+    packed = [da for da in shapes[2] if "pk" in da]
+    assert len(packed) == _PACKED[q]
+    for da in packed:
+        # no root-width column or position rides beside the word
+        assert not da["cols"] and "fpos" not in da and "lut" not in da \
+            and "ord" not in da and "valid" not in da
+        assert all(p.endswith("['pk'][0]") for p in dimops)
     # no mask, no child's table and no group payload at fact width
-    assert not [p for p in folded if "'valid'" in p]
+    assert not [p for p in folded
+                if "'valid'" in p or "'cols'" in p or "'fpos'" in p]
     plan = kinds[-1][2][0][0]
     fp = df.fold_plan(plan)
     kids = [di for di, p in enumerate(fp.parent) if p is not None]
@@ -186,6 +204,26 @@ def test_scans_keep_their_program(tk, runs_impl, kinds, monkeypatch, q):
     del kinds[:]
     tk.must_query(_q(q))
     assert [str(_body_jaxpr(k[2], k[3])) for k in kinds] == now
+
+
+@pytest.mark.parametrize("q", ["q3", "q18"])
+def test_position_only_root_keeps_the_table_of_positions(
+        tk, runs_impl, kinds, monkeypatch, q):
+    """A root that reads its position and nothing else: the composed
+    word would be the `lut`, so it takes `lut` and `lo`, no layout
+    operand, and the body is the one built with no packing at all
+    (the parent's program text: a persistent-cache hit)."""
+    for _ in range(3):          # what a run learns (bucket, top-n cut)
+        tk.must_query(_q(q))    # picks the next one's program
+    before = _counts()
+    build, shapes = _main_kernel(tk, kinds, _q(q))
+    assert not [k for k in _grown(before) if "pack" in k]
+    root = shapes[2][0]
+    assert set(root) == {"cols", "lut", "lo"}
+    assert all(lay.get("pack") is None for lay in build[0][6])
+    now = str(_body_jaxpr(build, shapes))
+    monkeypatch.setattr(df, "pack_fields", lambda *a: ())
+    assert str(_body_jaxpr(*_main_kernel(tk, kinds, _q(q)))) == now
 
 
 def test_nothing_folds_keeps_todays_operands(kinds):
@@ -230,6 +268,57 @@ def test_tpch_device_equals_host(tk, q):
         assert grown == {}
 
 
+# ---- (b2) the composed word: unpack(pack(x)) == x ------------------------
+
+_I62 = (1 << 62) - 1
+# case -> (columns, words the first fit has to give)
+_PACK = {
+    "negative_values": ([np.array([-7, -1, 0, 5]),
+                         np.array([-(1 << 40), 3, 9, -2])], 1),
+    "nulls_are_a_bit": ([np.array([10, 11, 12, 13], dtype=np.int32),
+                         np.array([True, False, False, True])], 1),
+    "a_field_at_62_bits": ([np.array([0, _I62, 17, 1 << 61]),
+                            np.array([False, True, True, False])], 1),
+    "a_field_at_63_bits_all_ones": (
+        [np.array([-(1 << 62), (1 << 62) - 1, 0, -1])], 1),
+    "spill_to_a_second_word": ([np.array([0, 1 << 39, 5, 6]),
+                                np.array([-(1 << 39), 0, 1, 2]),
+                                np.array([0, 1 << 19, 2, 3])], 2),
+    "constant_columns_take_no_bits": ([np.full(4, 42), np.full(4, -3),
+                                       np.arange(4)], 1),
+    "narrow_unsigned": ([np.array([0, 255, 7, 9], dtype=np.uint8),
+                         np.array([65535, 0, 1, 2], dtype=np.uint16)], 1),
+    # no room beside the miss bit: a word of its own, as it is
+    "doubles_are_their_bit_pattern": (
+        [np.array([0.5, -1.5, np.inf, -0.0]), np.arange(4)], 2),
+    "range_past_63_bits": (
+        [np.arange(4), np.array([-(1 << 62) - 5, (1 << 62) + 5, 0, -1]),
+         np.array([1, 1 << 63, 3, (1 << 64) - 1], dtype=np.uint64)], 3),
+    "float32_is_32_bits": ([np.array([0.5, -1.5, 3.25, 1e30],
+                                     dtype=np.float32),
+                            np.array([1, 2, 3, 4], dtype=np.int32)], 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PACK))
+def test_pack_round_trip(case):
+    cols, nwords = _PACK[case]
+    words, word, shift, mask, lo = df.pack_words(cols)
+    assert len(words) == nwords and len(set(word)) == nwords
+    for i, c in enumerate(cols):
+        back = df.unpack_field(words[word[i]], shift[i], mask[i], lo[i],
+                               c.dtype)
+        assert back.dtype == c.dtype
+        np.testing.assert_array_equal(back.view(f"u{c.dtype.itemsize}"),
+                                      c.view(f"u{c.dtype.itemsize}"))
+    # a hit never reads as the miss: word 0's sign bit belongs to no
+    # field, and a miss reads the minimum of every field of word 0
+    assert (words[0] >= 0).all() and df.MISS < 0
+    assert all(df.unpack_field(np.int64(df.MISS), shift[i], mask[i], lo[i],
+                               np.int64) == lo[i]
+               for i in range(len(cols)) if word[i] == 0)
+
+
 # ---- (c) synthetic chains ---------------------------------------------
 
 def _chain_tk():
@@ -238,7 +327,10 @@ def _chain_tk():
     child miss in the middle of f's runs). c: every 13th nid NULL, nid 9
     has no n. cs: c's rows under sparse keys (a sorted, not a direct,
     probe table); f2 probes it from the fact. cdup: duplicate keys.
-    p: a composite key."""
+    p: a composite key. w: d's keys under wide columns (two of 40
+    bits and one of 20: a second word; `huge`: a range past 63 bits,
+    `x`: a double, each a word of its own). f3: f's shape with d_id in storage order (the anchor a
+    device top-n needs)."""
     tk = TestKit()
     tk.must_exec("create table n (id int primary key, name varchar(16), "
                  "rid int)")
@@ -251,10 +343,13 @@ def _chain_tk():
                  "val int, a int, b int, csid bigint)")
     tk.must_exec("create table p (a int, b int, w int, "
                  "primary key (a, b))")
+    tk.must_exec("create table w (id int primary key, a bigint, "
+                 "b bigint, c int, huge bigint, x double)")
     tk.must_exec("create table f (k int primary key, d_id int, "
                  "amt decimal(10,2), q int)")
     tk.must_exec("create table f2 (k int primary key, csid bigint, "
                  "amt decimal(10,2), q int)")
+    tk.must_exec("create table f3 (k int primary key, d_id int, q int)")
     tk.must_exec("insert into n values " + ",".join(
         f"({i}, 'n{i}', {i % 3})" for i in range(1, 8)))
     tk.must_exec("insert into c values " + ",".join(
@@ -275,6 +370,12 @@ def _chain_tk():
     tk.must_exec("insert into p values " + ",".join(
         f"({a}, {b}, {a * 10 + b})" for a in range(5) for b in range(3)
         if (a, b) != (4, 2)))
+    tk.must_exec("insert into w values " + ",".join(
+        "(%d, %d, %d, %d, %d, %s)" % (
+            i, (i * 5497558139) % (1 << 40) - (1 << 39),
+            (i * 7297558133) % (1 << 40), (i * 7919) % (1 << 20),
+            (-1) ** i * ((1 << 62) + i), i / 4.0)
+        for i in range(1, 201)))
     rng = np.random.RandomState(11)
     frows, f2rows = [], []
     for k in range(3000):
@@ -285,6 +386,8 @@ def _chain_tk():
             (k, (k // 15 % 70 + 1) * 1000003) + row))
     tk.must_exec("insert into f values " + ",".join(frows))
     tk.must_exec("insert into f2 values " + ",".join(f2rows))
+    tk.must_exec("insert into f3 values " + ",".join(
+        f"({k}, {k // 15 + 1}, {(k * 31) % 100})" for k in range(3000)))
     return tk
 
 
@@ -295,7 +398,9 @@ def tkc():
 
 _AG = "sum(f.amt), count(*), min(f.q), max(f.q)"
 _FDC = "from f, d, c where f.d_id = d.id and d.cid = c.id"
-# case -> (sql, what the fold counter has to grow by in one execution)
+_FW = "from f, w where f.d_id = w.id"
+# case -> (sql, what the fold counter has to grow by in one execution[,
+# and of the pack outcomes, where not the one root packed in one word])
 _SYN = {
     # NULL d.cid, cids without a c row: misses in the middle of a run
     "child_payload_groups":
@@ -337,7 +442,7 @@ _SYN = {
         (f"select d.grp, {_AG} from f, d where f.d_id = d.id and exists "
          "(select 1 from c where c.id = d.cid and c.seg = 1) "
          "group by d.grp order by d.grp",
-         {"mask_folded": 1, "folded": 1}),
+         {"mask_folded": 1, "folded": 1}, {}),
     "anti_child_declined":
         (f"select d.grp, {_AG} from f, d where f.d_id = d.id and "
          "not exists (select 1 from c where c.id = d.cid and c.seg = 1) "
@@ -355,17 +460,53 @@ _SYN = {
         (f"select c.id, c.seg, sum(f.amt) s {_FDC} "
          "group by c.id, c.seg order by c.seg desc, c.id limit 5",
          {"mask_folded": 1, "folded": 1}),
+    # d's position is all the program reads of d: the table of positions
     "topn_orders_by_root_column":
         (f"select f.d_id, d.val, c.name, sum(f.amt) s {_FDC} "
          "group by f.d_id, d.val, c.name order by d.val desc limit 5",
+         {"mask_folded": 1, "folded": 1}, {}),
+    # ... and with c.seg read at fact width the position is a field of
+    # the word, while the ordering column stays at d's width
+    "topn_root_column_beside_the_word":
+        ("select f3.d_id, d.val, c.name, sum(f3.q) s from f3, d, c "
+         "where f3.d_id = d.id and d.cid = c.id and c.seg < f3.q "
+         "group by f3.d_id, d.val, c.name order by d.val desc limit 5",
          {"mask_folded": 1, "folded": 1}),
+    "packed_direct_root_own_and_child_columns":
+        (f"select d.grp, sum(d.val + c.seg + f.q), count(*) {_FDC} "
+         "group by d.grp order by d.grp",
+         {"mask_folded": 1, "folded": 1}),
+    "packed_sorted_root":
+        ("select n.name, sum(f2.amt + cs.seg), count(*) from f2, cs, n "
+         "where f2.csid = cs.id and cs.nid = n.id "
+         "group by n.name order by n.name",
+         {"mask_folded": 1, "folded": 1}),
+    # c.nid is NULL in every 13th row of c
+    "nullable_folded_column":
+        (f"select d.grp, count(c.nid), sum(c.nid + f.q), count(*) {_FDC} "
+         "group by d.grp order by d.grp",
+         {"mask_folded": 1, "folded": 1}),
+    "spill_to_a_second_word":
+        (f"select f.q, sum(w.a + f.q), sum(w.b), min(w.c), count(*) {_FW} "
+         "group by f.q order by f.q",
+         {"mask_folded": 1}, {"packed": 1, "packed_spill": 1}),
+    # a field with no room beside the miss bit: a word of its own
+    "range_past_63_bits_is_a_word":
+        (f"select f.q, min(w.huge), max(w.c), count(*) {_FW} "
+         "group by f.q order by f.q",
+         {"mask_folded": 1}, {"packed": 1, "packed_spill": 1}),
+    "double_column_is_a_word":
+        (f"select f.q, sum(w.x), max(w.c), count(*) {_FW} "
+         "group by f.q order by f.q",
+         {"mask_folded": 1}, {"packed": 1, "packed_spill": 1}),
 }
 
 
 @pytest.mark.parametrize("policy", ["runs", "scatter"])
 @pytest.mark.parametrize("case", sorted(_SYN))
 def test_synthetic_chain_vs_host(tkc, case, policy):
-    sql, want = _SYN[case]
+    sql, want, pack = (_SYN[case] + ({"packed": 1},))[:3]
+    want = dict(want, **pack)
     al._FORCE_SEGMENT_IMPL = "runs" if policy == "runs" else None
     try:
         before = _counts()
@@ -381,13 +522,52 @@ def test_synthetic_chain_vs_host(tkc, case, policy):
 
 def test_sparse_keys_take_the_sorted_table(tkc, kinds):
     """The two sparse cases really probe a sorted table: once at fact
-    width with the fold's sentinel in the row order (the root), once on
-    the host only (the child)."""
+    width with the composed words in the row order's place (the root),
+    once on the host only (the child)."""
     tkc.domain.copr._kernel_cache.clear()
     _dev_vs_host(tkc, _SYN["sparse_root_keys"][0])
-    assert "sk" in kinds[-1][3][2][0] and "valid" not in kinds[-1][3][2][0]
+    root = kinds[-1][3][2][0]
+    assert "sk" in root and "pk" in root and "valid" not in root and \
+        "ord" not in root and "lo" not in root
+    _dev_vs_host(tkc, _SYN["packed_sorted_root"][0])
+    assert set(kinds[-1][3][2][0]) == set(root)
     _dev_vs_host(tkc, _SYN["sparse_child_keys"][0])
-    assert "lut" in kinds[-1][3][2][0] and kinds[-1][3][2][1] == {"cols": {}}
+    assert "lo" in kinds[-1][3][2][0] and kinds[-1][3][2][1] == {"cols": {}}
+
+
+def test_topn_keeps_its_root_width_column(tkc, runs_impl, kinds):
+    """The top-n's ordering column is gathered at bucket width through
+    the groups' positions: it stays a column of d's beside the word,
+    and nothing else does."""
+    tkc.domain.copr._kernel_cache.clear()
+    _dev_vs_host(tkc, _SYN["topn_root_column_beside_the_word"][0], runs=3)
+    (kind, param, build, shapes) = kinds[-1]
+    assert kind == "posruns" and param[2] is not None     # a device top-n
+    root = shapes[2][0]
+    assert "pk" in root and len(root["cols"]) == 1
+    assert {t[0] for t in build[0][6][0]["pack"]} == {"pos", "col"}
+    wide = [p for p in _wide_gathers(build, shapes) if p.startswith("[2]")]
+    assert wide == ["[2][0]['pk'][0]"]
+
+
+@pytest.mark.parametrize("case,words,fields", [
+    ("spill_to_a_second_word", 2, 3),
+    ("nullable_folded_column", 1, 3),
+    ("range_past_63_bits_is_a_word", 2, 2),
+    ("double_column_is_a_word", 2, 2)])
+def test_words_and_their_gathers(tkc, kinds, case, words, fields):
+    """A spill is a second key-addressed table and a second gather,
+    never more than the gathers of the table of positions and of the
+    columns read through it; a null mask is a field."""
+    tkc.domain.copr._kernel_cache.clear()
+    _dev_vs_host(tkc, _SYN[case][0])
+    (_kind, _param, build, shapes) = kinds[-1]
+    root = shapes[2][0]
+    wide = [p for p in _wide_gathers(build, shapes) if p.startswith("[2]")]
+    assert len(root["pk"]) == words == len(wide) <= fields
+    assert not root["cols"] and "lut" not in root
+    assert root["fshift"].shape == (fields,) == root["fmask"].shape
+    assert {t[2] for t in build[0][6][0]["pack"]} == set(range(words))
 
 
 def test_duplicate_child_keys_decline(tkc):
@@ -446,17 +626,31 @@ def test_lowering_change_between_blocks_reuploads(monkeypatch, kinds):
         sql = ("select d.val, c.seg, count(*), sum(f.q) from f, d, c "
                "where f.d_id = d.id and d.cid = c.id "
                "group by d.val, c.seg order by d.val")
+        before = _counts()
         _dev_vs_host(tk, sql, runs=2)
+        grown = _grown(before)
     finally:
         al._FORCE_SEGMENT_IMPL = None
+    # the first execution's second upload and the second execution's
+    # only one (the host twin's run counts nothing)
+    assert grown["packed"] == 2 and grown["build"] == 1
     assert [k[0] for k in kinds] == ["posruns", "sort"]
-    assert not kinds[0][3][2][0]["cols"]
-    assert len(kinds[1][3][2][0]["cols"]) == 2
+    # the positions are the keys: d's table of positions and no column
+    first, second = kinds[0][3][2][0], kinds[1][3][2][0]
+    assert not first["cols"] and "lut" in first and "pk" not in first
+    # the group items' values at fact width: a second upload, of the
+    # word that holds both (another field set, another table)
+    assert not second["cols"] and "lut" not in second
+    assert len(second["pk"]) == 1 and second["fshift"].shape == (2,)
+    assert {t[:2] for t in kinds[1][2][0][6][0]["pack"]} == \
+        {("col", c.idx) for c in kinds[1][2][0][0].group_items}
 
 
 @pytest.mark.skipif(len(jax.devices()) < 2, reason="needs a mesh")
 @pytest.mark.parametrize("case", ["three_deep_filtered_leaf",
-                                  "child_column_in_post_filter"])
+                                  "child_column_in_post_filter",
+                                  "nullable_folded_column",
+                                  "packed_sorted_root"])
 def test_mesh_takes_the_folded_tables(case):
     """Broadcast dimensions ride the mesh program folded: one program
     over the whole fact, the same answer as the host's."""
@@ -466,7 +660,14 @@ def test_mesh_takes_the_folded_tables(case):
     before = _counts()
     _dev_vs_host(tk, _SYN[case][0])
     assert tk.domain.metrics.get("fused_pipeline_mpp_hit", 0) == hits + 1
-    assert _grown(before).get("folded", 0) >= 1
+    grown = _grown(before)
+    assert grown.get("folded", 0) >= 1 and grown.get("packed") == 1
+    # the composed table went up replicated, in the lut's place
+    store = tk.domain.copr._dev_store
+    pk = [k for k in store._entries
+          if isinstance(k[1], tuple) and k[1][0] == "pk"]
+    assert pk and all(store._spec_of[k] == "replicated" for k in pk)
+    assert not [k for k in store._entries if k[1] in ("lut", "ord")]
 
 
 # ---- (d) MVCC ----------------------------------------------------------
@@ -564,6 +765,11 @@ def test_second_execution_builds_nothing(tk, runs_impl, q):
     snap = phase.snap()
     grown = _grown(before)
     assert "build" not in grown and grown["cache_hit"] >= 1
+    # one `packed` a packed root an execution; the words themselves are
+    # the fold's, found with it
+    assert grown.get("packed", 0) == _PACKED[q]
+    assert not [k for k in grown if k.startswith(("declined_pack",
+                                                  "packed_spill"))]
     assert snap.get("upload_bytes", 0) == 0
     assert snap.get("dispatches", 0) <= 2
     assert snap.get("kernel_builds", 0) == 0
@@ -577,6 +783,10 @@ def test_bind_span_carries_fold_counts(tk):
         rows = tk.must_query(
             "select attrs from information_schema.tidb_trace_events "
             "where span = 'bind' and attrs like '%folds%'").rows
+        packed = tk.must_query(
+            "select attrs from information_schema.tidb_trace_events "
+            "where span = 'bind' and attrs like '%packed_roots%'").rows
     finally:
         tk.must_exec("set tidb_tpu_trace_sample_rate = 0")
     assert rows and "fold_builds" in rows[-1][0]
+    assert [r for r in packed if "packed_roots=2" in r[0]], packed

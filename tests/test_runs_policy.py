@@ -360,8 +360,8 @@ def test_posruns_q10_gathers_no_group_payload(tk, runs_impl, kinds,
     positions on the host); the control, the same plan under today's
     kind, gathers every one. With the chain folded (copr/dimfold.py)
     customer and nation are not probed at fact width at all, and a
-    statement that falls to the "sort" kind reads the seven through
-    orders' position."""
+    statement that falls to the "sort" kind reads the seven as fields
+    of the words orders' key addresses."""
     import tidb_tpu.copr.dimfold as df
     if not folded:
         monkeypatch.setattr(df, "fold_plan",
@@ -395,6 +395,15 @@ def test_posruns_q10_gathers_no_group_payload(tk, runs_impl, kinds,
     kind, param, build, shapes = kinds[0]
     assert kind == "sort"
     old = _wide_gather_operands(build, shapes)
+    if folded:
+        text = build[0][6][0]["pack"]
+        assert {("col", g.idx) for g in plan.group_items} <= \
+            {t[:2] for t in text}
+        words = [p for p in old if p.startswith("[2]")]
+        assert words == [f"[2][0]['pk'][{i}]"
+                         for i in range(len(shapes[2][0]["pk"]))]
+        assert 1 <= len(words) <= 2 and not shapes[2][0]["cols"]
+        return
     assert payload <= set(old)
     # seven payload gathers more, one of the positions' kind's own less
     assert len(old) - len(now) >= 6

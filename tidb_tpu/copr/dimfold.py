@@ -10,9 +10,13 @@ is a function of its own row:
   under supplier, region under nation). A slot whose row fails any of
   them holds the miss sentinel `n`, so the kernel's hit test is the
   probe alone;
-- the payload: a descendant's column (or join position) that something
-  downstream reads at fact width becomes a column of the root, at the
-  root's width, read with one gather through the root's position.
+- the payload: what something downstream reads at fact width through
+  the root's position -- the root's own columns, a descendant's column
+  or join position, their null bits, the position itself when it is a
+  group key -- is packed into the fields of one int64 word a root row
+  and composed with the probe table (`Fold.packed`): the kernel gathers
+  the word once by key and shifts each field out. A root that reads
+  nothing but its position keeps the plain table of positions.
 
 Which dimension folds under which is read from the plan's shape alone
 (`fold_plan`); the tables are built once a snapshot on the host
@@ -158,6 +162,26 @@ def needs(plan, fp, pos_grouped):
     return read
 
 
+def pack_fields(plan, fp, root, need, pos_keyed):
+    """What the program reads at fact width through `root`'s position,
+    from the plan's shape: the position itself when it is a group key
+    (`pos_keyed`: the lowering's position dimensions), an inner
+    descendant's, and every column of `need` that is the root's or an
+    inner descendant's -> tuple of ("pos",) | ("fpos", d) |
+    ("col", idx, d, cid)."""
+    fields = [("pos",)] if root in pos_keyed else []
+    inner = [d for d in fp.descendants(root)
+             if plan.dims[d].join_type == "inner"]
+    fields += [("fpos", d) for d in inner if d in pos_keyed]
+    if plan.dims[root].join_type == "inner":
+        for d in [root] + inner:
+            dag = plan.dims[d].dag
+            fields += [("col", sc.col.idx, d, _cid_of(dag, sc))
+                       for sc in dag.cols
+                       if sc.col.idx in need and _cid_of(dag, sc) != -1]
+    return tuple(fields)
+
+
 def txn_dirty(ctx) -> bool:
     """Does the statement run inside a transaction with uncommitted
     writes? Such a statement's derived tables are neither cached nor
@@ -200,36 +224,130 @@ def _host_probe(meta, pv, pnm):
     return np.minimum(meta["order"][locc], n - 1), hit
 
 
+# a slot no row passes: the sign bit of word 0 alone, so `word >= 0` is
+# the hit and every field of a miss reads its minimum
+MISS = np.iinfo(np.int64).min
+_WORD_BITS = 63
+
+
+def pack_words(cols):
+    """Pack arrays of one length into int64 words, first fit in the
+    order given: array i holds `value - lo[i]` in the bits its range
+    needs, at `shift[i]` of word `word[i]`. Word 0 keeps its sign bit
+    for the miss; a field that needs more than 63 bits (a double's
+    pattern, a 64-bit range) is a later word of its own, as it is.
+    -> (words, word, shift, mask, lo)."""
+    ints, lo, bits = [], [], []
+    for c in cols:
+        if c.dtype.kind in "fu":
+            c = c.view(f"i{c.dtype.itemsize}")      # its bit pattern
+        mn, mx = (int(c.min()), int(c.max())) if len(c) else (0, 0)
+        wide = (mx - mn) >> _WORD_BITS              # all 64 bits, as is
+        ints.append(c.astype(np.int64))
+        lo.append(0 if wide else mn)
+        bits.append(64 if wide else (mx - mn).bit_length())
+    used, word, shift = [0], [], []
+    for b in bits:
+        wi = next((i for i, u in enumerate(used) if u + b <= _WORD_BITS),
+                  len(used))
+        if wi == len(used):
+            used.append(0)
+        word.append(wi)
+        shift.append(used[wi])
+        used[wi] += b
+    words = [np.zeros(len(cols[0]), dtype=np.int64) for _ in used]
+    for c, mn, wi, sh in zip(ints, lo, word, shift):
+        words[wi] |= (c - mn) << sh
+    as64 = lambda xs: np.asarray(xs, dtype=np.int64)    # noqa: E731
+    return words, tuple(word), as64(shift), \
+        as64([-1 if b == 64 else (1 << b) - 1 for b in bits]), as64(lo)
+
+
+def unpack_field(word, shift, mask, lo, dtype):
+    """Field of a packed word (numpy on the host, jax.numpy in the
+    kernel): the value `pack_words` was given, in its own dtype."""
+    dtype = np.dtype(dtype)
+    v = ((word >> shift) & mask) + lo
+    if dtype.kind == "f":
+        return v.astype(f"i{dtype.itemsize}").view(dtype)
+    return v.astype(dtype)
+
+
+class Packed:
+    """One root's composed probe words for one field set (`fields`,
+    `pack_fields`' tuple): `tables[w]` is word w addressed as the probe
+    table is (by key slot, or by sorted rank), `text[i]` = (kind, ident,
+    word, dtype) names field i for the program, `shift` / `mask` / `lo`
+    ride the call as operands, `sdicts[idx]` is a column's dictionary."""
+
+    __slots__ = ("fields", "tables", "text", "shift", "mask", "lo",
+                 "sdicts", "nbytes")
+
+    def __init__(self, fields, tables, text, shift, mask, lo, sdicts):
+        self.fields, self.tables, self.text = fields, tables, text
+        self.shift, self.mask, self.lo = shift, mask, lo
+        self.sdicts = sdicts
+        self.nbytes = sum(t.nbytes for t in tables)
+
+
 class Fold:
     """One root's folded tables over one snapshot: the probe table with
     the chain's hit folded in, each inner descendant's position at each
-    of its ancestors' widths (`pos_at[(a, d)]`), and the descendants'
-    columns at the root's width, built when first asked for."""
+    of its ancestors' widths (`pos_at[(a, d)]`), and the composed words
+    of each field set something reads through it (`packed`), built when
+    first asked for."""
 
     def __init__(self, root, sig, table, pos_at, metas):
         self.root = root
         self.sig = sig
         self.table = table
         self.pos_at = pos_at
-        # the snapshot arrays of the descendants that have a position at
-        # the root's width, and nothing else of the statement's metas
+        self.n = metas[root]["n"]
+        # the snapshot arrays of the root and of the descendants that
+        # have a position at its width, and nothing else of the metas
         self._arrays = {d: metas[d]["arrays"] for a, d in pos_at
                         if a == root}
-        self._cols = {}
+        self._arrays[root] = metas[root]["arrays"]
+        self._packed = {}
         self.nbytes = table.nbytes + sum(a.nbytes for a in pos_at.values())
 
-    def col(self, d, cid):
-        """Column `cid` of descendant `d` at the root's width
-        -> (data, nulls, sdict)."""
-        got = self._cols.get((d, cid))
+    def packed(self, fields):
+        """The composed words of `fields` (`pack_fields`' tuple)
+        -> Packed."""
+        got = self._packed.get(fields)
         if got is None:
-            data, nulls, sd = self._arrays[d][cid]
-            pos = self.pos_at[(self.root, d)]
-            got = (data[pos], None if nulls is None else nulls[pos], sd)
-            self._cols[(d, cid)] = got
-            self.nbytes += got[0].nbytes + \
-                (0 if got[1] is None else got[1].nbytes)
+            got = self._packed[fields] = self._compose(fields)
+            self.nbytes += got.nbytes
         return got
+
+    def _compose(self, fields):
+        n, cols, text, sdicts = self.n, [], [], {}
+        for f in fields:
+            if f[0] == "pos":
+                cols.append(np.arange(n, dtype=np.int64))
+                text.append(("pos", None, "int64"))
+            elif f[0] == "fpos":
+                cols.append(self.pos_at[(self.root, f[1])])
+                text.append(("fpos", f[1], "int64"))
+            else:
+                _, idx, d, cid = f
+                data, nulls, sdicts[idx] = self._arrays[d][cid]
+                at = slice(n) if d == self.root else \
+                    self.pos_at[(self.root, d)]
+                cols.append(data[at])
+                text.append(("col", idx, data.dtype.name))
+                if nulls is not None:
+                    cols.append(nulls[at])
+                    text.append(("null", idx, "bool"))
+        words, word, shift, mask, lo = pack_words(cols)
+        # composed with the probe table: one fancy index a word at the
+        # table's width, the sentinel slot n reading the miss
+        at = np.minimum(self.table, n)
+        tables = [np.append(w, 0 if wi else MISS)[at]
+                  for wi, w in enumerate(words)]
+        text = tuple((k, ident, wi, dt)
+                     for (k, ident, dt), wi in zip(text, word))
+        return Packed(fields, tables, text, shift, mask, lo, sdicts)
 
 
 def _build(fp, plan, metas, root):

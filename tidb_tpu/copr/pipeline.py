@@ -542,25 +542,27 @@ def _semi_prefiltered_meta(copr, dim, tbl, arrays, valid, n, key_cid,
 
 
 def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None, want=None,
-                fold_cols=(), fold_pos=()):
+                pack=None):
     """Pad + upload dim arrays through the HBM buffer pool; -> pytree of
     device arrays for the kernel plus (has_nulls, sdict) layout info.
     With a mesh, every array replicates to all devices (the Broadcast
     exchange of the dim fragment).
 
     A folded dimension (copr/dimfold.py) uploads what its program
-    reads and no more: `want` names the columns of its own (None:
-    all of them, a dimension that folds nothing); a root also takes
-    `fold_cols` [(idx, descendant, cid)] and `fold_pos` [descendant],
-    its descendants' columns and join positions at its own width, and
-    no `valid` (its probe table holds the hit); a dimension resolved
-    under a root takes no probe table at all."""
+    reads and no more: `want` names the columns of its own that go up
+    at its width (None: all of them, a dimension that folds nothing;
+    of a folded one only the device top-n's ordering column), and no
+    `valid` (its probe table holds the hit); a dimension resolved under
+    a root takes no probe table at all. A root through whose position
+    something but the position is read takes `pack` (dimfold.Packed):
+    the composed words go up where the table of positions would, keyed
+    by the field set too."""
     tbl = meta["tbl"]
     n = meta["n"]
     ver = tbl.version
     ck = () if mesh is None else ("bcast", mesh.devices.size)
     mk = ck + tuple(meta.get("ukey", ()))
-    fold = meta.get("fold")
+    folded = meta.get("fold") is not None
     probed = meta.get("folded_under") is None
     # plain dim column data is append-only table state: it rides the
     # delta-maintained append seam (copr/delta.py) when the meta wraps
@@ -600,8 +602,13 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None, want=None,
     args = {"cols": {}}
     if probed:
         _upload_probe_table(meta, args, put, cap,
-                            with_valid=not pre and fold is None)
+                            with_valid=not pre and not folded, pack=pack)
     layout = {}
+    if pack is not None:
+        layout["pack"] = pack.text
+        nullable = {idx for kind, idx, _w, _dt in pack.text if kind == "null"}
+        for idx, sdict in pack.sdicts.items():
+            layout[idx] = (idx in nullable, sdict)
     if not pre:
         for sc in dim.dag.cols:
             cid = _cid_of(dim.dag, sc)
@@ -620,30 +627,17 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None, want=None,
                     jn = put(("fpn", cid), nulls, n, cap, fill=True)
             args["cols"][sc.col.idx] = (jd, jn)
             layout[sc.col.idx] = (nulls is not None, sdict)
-    for idx, d, cid in fold_cols:
-        # tagged by what the fold's signature (in `mk`) pins: the
-        # descendant's place in the chain and its column, not the
-        # plan's numbering
-        data, nulls, sdict = fold.col(d, cid)
-        jd = put(("fc", d, cid), data, n, cap, ts_keyed=True)
-        jn = None
-        if nulls is not None:
-            jn = put(("fcn", d, cid), nulls, n, cap, fill=True,
-                     ts_keyed=True)
-        args["cols"][idx] = (jd, jn)
-        layout[idx] = (nulls is not None, sdict)
-    if fold_pos:
-        args["fpos"] = {d: put(("fpos", d), fold.pos_at[(fold.root, d)], n,
-                               cap, ts_keyed=True) for d in fold_pos}
     return args, layout
 
 
-def _upload_probe_table(meta, args, put, cap, with_valid):
+def _upload_probe_table(meta, args, put, cap, with_valid, pack=None):
     """What the kernel's probe of one dimension reads: the composite
     key's pack layout, `valid` unless the table holds the mask
     (prefiltered semi dims fold visibility+filters into the lut at meta
     time, a folded root its whole chain's: don't upload dead copies
-    into the HBM pool), and the direct or the sorted table."""
+    into the HBM pool), and the direct or the sorted table -- of
+    positions, or of a folded root's composed words (`pack`) with the
+    fields' layout as operands beside `lo`."""
     n = meta["n"]
     if meta.get("pack") is not None:
         # small host values ride the kernel call as numpy operands:
@@ -656,17 +650,26 @@ def _upload_probe_table(meta, args, put, cap, with_valid):
     if with_valid:
         args["valid"] = put("valid", meta["valid"], n, cap, False,
                             ts_keyed=True)
-    if meta["mode"] == "direct":
-        lcap = shape_bucket(len(meta["lut"]))
-        args["lut"] = put("lut", meta["lut"], len(meta["lut"]), lcap,
-                          fill=n, ts_keyed=True)
+    direct = meta["mode"] == "direct"
+    length = len(meta["lut"]) if direct else meta["n_sorted"]
+    tcap = shape_bucket(length)
+    if pack is not None:
+        args["pk"] = [put(("pk", pack.fields, wi), t, length, tcap,
+                          fill=0 if wi else dimfold.MISS, ts_keyed=True)
+                      for wi, t in enumerate(pack.tables)]
+        args["fshift"], args["fmask"], args["flo"] = \
+            pack.shift, pack.mask, pack.lo
+    elif direct:
+        args["lut"] = put("lut", meta["lut"], length, tcap, fill=n,
+                          ts_keyed=True)
+    else:
+        args["ord"] = put("ord", meta["order"], length, tcap,
+                          ts_keyed=True)
+    if direct:
         args["lo"] = np.asarray(meta["lo"], dtype=np.int64)
     else:
-        ns = meta["n_sorted"]
-        scap = shape_bucket(ns)
-        args["sk"] = put("sk", meta["skeys"], ns, scap, fill=_I64_MAX,
+        args["sk"] = put("sk", meta["skeys"], length, tcap, fill=_I64_MAX,
                          ts_keyed=True)
-        args["ord"] = put("ord", meta["order"], ns, scap, ts_keyed=True)
 
 
 def _topn_group_col(plan):
@@ -689,35 +692,35 @@ def _upload_dims(copr, plan, fp, dim_metas, dim_caps, read_ts, mesh,
     """Upload every dimension for one lowering of the statement:
     `pos_grouped` says whether the join positions stand for the group
     items ("posdense", "posruns"), which decides what a folded root has
-    to carry at its width. -> (dim_args, dim_layouts)."""
+    to carry. -> (dim_args, dim_layouts, the roots' pack outcomes for
+    `tidb_tpu_dim_fold_total`)."""
     need, need_pos, tcol = None, (), None
     if fp is not None:
         need = dimfold.needs(plan, fp, pos_grouped)
         tcol = _topn_group_col(plan)
         if pos_grouped:
             need_pos = _pos_group_items(plan)[1]
-    dim_args, dim_layouts = [], []
+    dim_args, dim_layouts, outcomes = [], [], []
     for di, (dim, meta, dcap) in enumerate(zip(plan.dims, dim_metas,
                                                dim_caps)):
-        want, fcols, fpos = None, [], []
+        want, pack = None, None
         if fp is not None and (fp.masked[di] or fp.parent[di] is not None):
-            want = set(need) if fp.masked[di] else set()
-            if tcol is not None and tcol[0] == di:
-                want.add(tcol[1])
-            for d in (fp.descendants(di) if fp.masked[di] else ()):
-                if plan.dims[d].join_type != "inner":
-                    continue
-                fcols += [(sc.col.idx, d, _cid_of(plan.dims[d].dag, sc))
-                          for sc in plan.dims[d].dag.cols
-                          if sc.col.idx in need and
-                          _cid_of(plan.dims[d].dag, sc) != -1]
-                if d in need_pos:
-                    fpos.append(d)
+            want = {tcol[1]} if tcol is not None and tcol[0] == di else ()
+        if fp is not None and fp.masked[di]:
+            # what the program reads through this root's position: all
+            # of it composed with the probe table, unless that is the
+            # position alone (the table of positions is that word)
+            fields = dimfold.pack_fields(plan, fp, di, need, need_pos)
+            if set(fields) - {("pos",)}:
+                pack = meta["fold"].packed(fields)
+                outcomes.append("packed")
+                if len(pack.tables) > 1:
+                    outcomes.append("packed_spill")
         da, layout = _upload_dim(copr, dim, meta, dcap, read_ts, mesh,
-                                 want, fcols, fpos)
+                                 want, pack)
         dim_args.append(da)
         dim_layouts.append(layout)
-    return dim_args, dim_layouts
+    return dim_args, dim_layouts, outcomes
 
 
 def _fused_topn_state(plan, fact_tbl, state, kd, sd):
@@ -971,9 +974,9 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
 
     fold: the plan's dimfold.FoldPlan when any dimension folds. A
     dimension resolved under another is not probed here at all; a root
-    whose mask is in its probe table gathers no `valid[pos]`, and of
-    the columns its operands carry (its own and its descendants', at
-    its width) only those something downstream reads. None: today's
+    whose mask is in its probe table gathers no `valid[pos]`, and what
+    is read through its position (`dim_layouts[i]["pack"]`) comes out
+    of the words its key addresses: one gather a word. None: today's
     program for every dimension."""
     fact_filters = list(plan.fact_dag.filters)
     dims = list(plan.dims)
@@ -996,8 +999,6 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
         group_only = frozenset(g.idx for g, (kind, _di, _c) in
                                zip(group_items, group_map)
                                if kind == "dimcol") - read
-    need = None if fold is None else dimfold.needs(
-        plan, fold, agg_kind in ("posdense", "posruns"))
 
     def body(fjc, fvv, dargs):
         cap = fact_cap
@@ -1069,6 +1070,35 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                         pv = jnp.full(cap, pv)
                     pv = pv.astype(jnp.int64)
                     pnm = materialize_nulls(ctx, pnl)
+                pk = layout.get("pack")
+                if pk is not None:
+                    # a folded root's composed words: ONE gather a word
+                    # by key slot (or sorted rank), every field a shift
+                    # and a mask of it; the sign bit is the miss
+                    if "lo" in da:
+                        lsize = da["pk"][0].shape[0]
+                        idx = pv - da["lo"]
+                        at = jnp.clip(idx, 0, lsize - 1)
+                        hit = (idx >= 0) & (idx < lsize)
+                    else:
+                        scap = da["sk"].shape[0]
+                        loc = jnp.searchsorted(da["sk"], pv)
+                        at = jnp.minimum(loc, scap - 1)
+                        hit = (da["sk"][at] == pv) & (loc < dsn)
+                    words = [t[at] for t in da["pk"]]
+                    mask = mask & hit & (words[0] >= 0) & ~pnm
+                    got = {(kind, ident): dimfold.unpack_field(
+                        words[wi], da["fshift"][fi], da["fmask"][fi],
+                        da["flo"][fi], dt)
+                        for fi, (kind, ident, wi, dt) in enumerate(pk)}
+                    for (kind, ident), v in got.items():
+                        if kind == "col":
+                            cols[ident] = (v, got.get(("null", ident)),
+                                           layout[ident][1])
+                        elif kind != "null":
+                            dim_pos[dim_i if kind == "pos" else ident] = v
+                    ctx = EvalCtx(jnp, cap, cols, host=False)
+                    continue
                 if "lut" in da:
                     # dense key domain: the join is ONE gather
                     lsize = da["lut"].shape[0]
@@ -1111,14 +1141,13 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                     mask = mask & hit
                     if dim.join_type != "semi":
                         for idx, (jd, jn) in da["cols"].items():
-                            if idx in group_only or \
-                                    (masked and idx not in need):
+                            # (a folded root's are the top-n's, read
+                            # at bucket width)
+                            if idx in group_only or masked:
                                 continue
                             g = jd[pos]
                             gn = jn[pos] if jn is not None else None
                             cols[idx] = (g, gn, layout[idx][1])
-                        for cdi, cpos in da.get("fpos", {}).items():
-                            dim_pos[cdi] = cpos[pos]
                 dim_pos[dim_i] = jnp.minimum(pos, dn - 1)
                 ctx = EvalCtx(jnp, cap, cols, host=False)
         with jax.named_scope("scan_filter"):
@@ -1665,10 +1694,16 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         changes its lowering between row blocks uploads twice."""
         pos_grouped = pos_grouped and fp is not None
         if pos_grouped not in dim_up:
-            with phase.bind_span() if plan.dims else _tracing.NO_SPAN:
-                dim_up[pos_grouped] = _upload_dims(
+            with phase.bind_span() if plan.dims else _tracing.NO_SPAN \
+                    as sp:
+                *up, outcomes = _upload_dims(
                     copr, plan, fp, dim_metas, dim_caps, read_ts, mesh,
                     pos_grouped)
+                for o in outcomes:
+                    dimfold.count(o)
+                if sp is not None and fp is not None:
+                    sp.attrs["packed_roots"] = outcomes.count("packed")
+                dim_up[pos_grouped] = up
         return dim_up[pos_grouped]
 
     # 1-row host ctx over ALL pipeline columns: learn output dicts and
@@ -1797,7 +1832,7 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         key = _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap,
                                tuple(dim_caps), tuple(dim_ns),
                                tuple(dim_sns), agg_kind, agg_param,
-                               ecap, fp)
+                               ecap, fp, dim_layouts)
         kern = copr._kernel_cache.get(key)
         if kern is None:
             kern = _build_fused_kernel(
@@ -2186,7 +2221,8 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
         key = _fused_cache_key(copr, plan, fact_tbl, dim_metas, local,
                                tuple(dim_caps), tuple(dim_ns),
                                tuple(dim_sns), agg_kind, agg_param,
-                               fold=fold) + ("mpp", ndev, padded)
+                               fold=fold, dim_layouts=dim_layouts) + \
+            ("mpp", ndev, padded)
         kern = copr._kernel_cache.get(key)
         if kern is None:
             kern = _build_fused_kernel_mpp(
@@ -2238,7 +2274,7 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
 
 def _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap, dim_caps,
                      dim_ns, dim_sns, agg_kind, agg_param, ecap=None,
-                     fold=None):
+                     fold=None, dim_layouts=()):
     dict_vers = [tuple(sorted((cid, len(d.values))
                               for cid, d in fact_tbl.dicts.items()))]
     for meta in dim_metas:
@@ -2264,4 +2300,7 @@ def _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap, dim_caps,
             dimsig, postfps, gfps, afps, tuple(dict_vers), colsig,
             agg_kind, agg_param, ecap, _al.policy(),
             tuple(bool(m.get("pre")) for m in dim_metas),
-            None if fold is None else fold.sig())
+            None if fold is None else fold.sig(),
+            # which field of a composed word is read where is program
+            # text; its shifts, masks and minima are operands
+            tuple(lay.get("pack") for lay in dim_layouts))

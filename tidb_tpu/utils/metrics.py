@@ -976,7 +976,10 @@ DIM_FOLD = REGISTRY.counter(
     "Fused-pipeline dimensions by fold outcome, a statement's bind: "
     "folded (resolved at its parent's width), mask_folded (a root's "
     "mask in its probe table), declined_<reason>; build / cache_hit: "
-    "a root's folded tables built or found", ("outcome",))
+    "a root's folded tables built or found; packed (a root's payload "
+    "composed into the word its key addresses), packed_spill (a second "
+    "word): a root, a lowering's upload",
+    ("outcome",))
 FUSED_PIPELINE = REGISTRY.counter(
     "tidb_tpu_fused_pipeline_total",
     "Fused-pipeline executions by outcome", ("outcome",))
