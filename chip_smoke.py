@@ -362,22 +362,49 @@ def check_reopened(data_dir):
 
 
 def check_mesh(jax, domain):
-    """More than one device: fragments must have gone to the mesh and
-    sharded resident columns must sit on every device."""
+    """More than one device, after the warm pass of the six statements:
+    every fused dispatch went to the mesh (the route counter beside
+    fused_pipeline_mpp_hit), sharded resident columns sit on every
+    device, and no table is held `local` on device 0 beside its sharded
+    or replicated copies."""
+    from tidb_tpu.utils import metrics as mu
     devs = jax.devices()
     m = domain.metrics
     check(m.get("fused_pipeline_mpp_hit", 0) > 0,
           f"{len(devs)} devices but fused_pipeline_mpp_hit == 0")
+    routes = mu.mesh_routes()
+    print(f"# mesh routes: {routes}")
+    check(routes.get(("mesh", "ok"), 0) >= m.get("fused_pipeline_mpp_hit", 0),
+          f"tidb_tpu_mesh_route_total{{mesh,ok}} under "
+          f"fused_pipeline_mpp_hit: {routes}")
+    off = {k: v for k, v in routes.items()
+           if k[0] != "mesh" and k[1] != "min_rows"}
+    check(not off, f"dispatches routed off the mesh: {off}")
     store = domain.copr._dev_store
     stats = store.stats()
     check(stats["bytes_by_spec"].get("sharded", 0) > 0,
           f"no mesh-sharded resident entries: {stats}")
     print(f"# residency: {stats}  budget {store.budget} B")
+    twice = {uid: by for uid, by in store.placements().items()
+             if "local" in by and len(by) > 1}
+    check(not twice, f"tables resident local beside their mesh copies: "
+                     f"{twice}")
     for d in devs:
         ms = d.memory_stats() or {}
         print(f"# {d}: bytes_in_use={ms.get('bytes_in_use')} "
+              f"peak_bytes_in_use={ms.get('peak_bytes_in_use')} "
               f"bytes_limit={ms.get('bytes_limit')}")
         check(ms.get("bytes_in_use", 0) > 0, f"{d} holds no bytes")
+
+
+def report_mesh_after_writes(domain):
+    """The write path's filter-only and top-n fragments over lineitem
+    run on one chip (`ineligible_no_aggregation`) and bind `local`
+    copies there (ROADMAP R-A5): reported, not failed."""
+    from tidb_tpu.utils import metrics as mu
+    print(f"# mesh routes after writes: {mu.mesh_routes()}")
+    print(f"# placements after writes: "
+          f"{domain.copr._dev_store.placements()}")
 
 
 def run(jax, sf):
@@ -412,10 +439,12 @@ def run(jax, sf):
         cold, cold_s = run_pass(wire, domain, "cold", want)
         warm, warm_s = run_pass(wire, domain, "warm", want)
         check_oracles(domain, warm, "before writes")
+        if device["count"] > 1:
+            check_mesh(jax, domain)
         applied = write_path(wire, domain, warm)
         durability(wire)
         if device["count"] > 1:
-            check_mesh(jax, domain)
+            report_mesh_after_writes(domain)
         m = dict(domain.metrics)
         wire.close()
         srv.shutdown()

@@ -1,0 +1,213 @@
+"""The deployment `tpch-sf1-mesh4` at a small size on the CPU backend:
+the benchmark's own data set and numpy references
+(benchmark/datasets/tpch.py, loaded by path), the store behind the wire
+server as `--serve` starts it, and one `dp` mesh of 4 made from the
+first four of the 8 forced host devices. Counts and answers here are
+correctness results, never device times."""
+import importlib.util
+import os
+
+import jax
+import pytest
+
+from tidb_tpu.parallel import make_mesh
+from tidb_tpu.server import Server
+from tidb_tpu.session import new_store
+from tidb_tpu.testkit import MiniClient
+from tidb_tpu.utils import failpoint
+from tidb_tpu.utils import metrics as mu
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SCALE, SEED = 0.01, 3_100_000_007       # a 60,000-row lineitem
+DEGRADE = ("device_fallback", "device_dispatch_error", "device_retry",
+           "device_breaker_open", "fused_pipeline_error")
+
+pytestmark = pytest.mark.skipif(len(jax.devices()) < 4,
+                                reason="needs four devices for the mesh")
+
+
+def _dataset():
+    spec = importlib.util.spec_from_file_location(
+        "mesh_deployment_tpch",
+        os.path.join(ROOT, "benchmark", "datasets", "tpch.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+class Deployment:
+    def __init__(self, data_dir, ndev):
+        self.ds = _dataset()
+        self.domain = new_store(str(data_dir))
+        self.domain.start_background()
+        # the mesh `_get_mesh` would make of a four-device process
+        self.domain.copr._mesh = make_mesh(ndev) if ndev > 1 else False
+        self.server = Server(self.domain, port=0).start()
+        admin = self.client()
+        self.tables = self.ds.generate(SCALE, SEED)
+        dom = self.domain
+        self.ds.load(self.tables, admin.query, lambda name:
+                     dom.columnar.table(
+                         dom.infoschema().table_by_name("test", name)))
+        # a small lineitem is under the default 65,536
+        admin.query("set global tidb_mpp_min_rows = 0")
+        admin.close()
+
+    def client(self):
+        return MiniClient(self.server.port, db="test", timeout=120)
+
+    def close(self):
+        self.server.shutdown()
+        self.domain.timer.stop_all()
+        self.domain.close()
+
+
+@pytest.fixture(scope="module")
+def mesh4(tmp_path_factory):
+    d = Deployment(tmp_path_factory.mktemp("mesh4"), 4)
+    yield d
+    d.close()
+
+
+@pytest.fixture(scope="module")
+def one_device(tmp_path_factory):
+    d = Deployment(tmp_path_factory.mktemp("one"), 1)
+    yield d
+    d.close()
+
+
+routes = mu.mesh_routes
+
+
+@pytest.mark.parametrize("stmt", ["q1", "q3", "q5", "q6", "q10", "q18"])
+def test_statement_on_the_mesh_equals_the_reference(mesh4, stmt):
+    c = mesh4.client()
+    dom = mesh4.domain
+    before = dict(dom.metrics)
+    try:
+        got = c.query(mesh4.ds.STATEMENTS[stmt])["rows"]
+        warned = c.query("show warnings")["rows"]
+    finally:
+        c.close()
+    want = mesh4.ds.reference(mesh4.tables, stmt)
+    assert not mesh4.ds.answer_wrong(got, want), (got[:3], want[:3])
+    assert warned == []
+    grown = {k: dom.metrics.get(k, 0) - before.get(k, 0)
+             for k in DEGRADE + ("fused_pipeline_mpp_hit",)}
+    assert grown.pop("fused_pipeline_mpp_hit") >= 1
+    assert not any(grown.values()), grown
+    r = routes()
+    assert r.get(("mesh", "ok"), 0) >= 1
+    assert not [k for k in r if k[0] == "single_chip"], r
+    # one placement a table: nothing held `local` on device 0 beside
+    # its sharded or replicated copies
+    specs = dom.copr._dev_store.placements()
+    assert specs and not [u for u, by in specs.items()
+                          if "local" in by and len(by) > 1], specs
+    assert any("sharded" in by for by in specs.values())
+
+
+def _q6(d, prepare=(), cleanup=()):
+    """q6 on a connection of its own -> (rows, the routes it grew)."""
+    c = d.client()
+    try:
+        for sql in prepare:
+            c.query(sql)
+        before = routes()
+        rows = c.query(d.ds.STATEMENTS["q6"])["rows"]
+        after = routes()
+        for sql in cleanup:
+            c.query(sql)
+    finally:
+        c.close()
+    return rows, {k: v - before.get(k, 0) for k, v in after.items()
+                  if v - before.get(k, 0)}
+
+
+LINE = ("insert into lineitem values (7, 1, 1, {n}, 10.00, 1000.00, 0.06, "
+        "0.02, 'N', 'O', '1994-03-01', '1994-03-01', '1994-03-02', "
+        "'NONE', 'MAIL', 'mesh deployment')")
+
+
+@pytest.mark.parametrize("reason,prepare,cleanup", [
+    ("mpp_off", ["set tidb_enable_mpp = 0"], []),
+    ("min_rows", ["set tidb_mpp_min_rows = 1000000000"], []),
+    ("delta_overlay", ["begin", LINE.format(n=7)], ["rollback"]),
+])
+def test_each_reason_off_the_mesh_is_counted(mesh4, reason, prepare,
+                                             cleanup):
+    want = mesh4.ds.reference(mesh4.tables, "q6")
+    rows, grown = _q6(mesh4, prepare, cleanup)
+    assert grown == {("single_chip", reason): 1}, grown
+    if reason != "delta_overlay":       # the overlay's row counts
+        assert not mesh4.ds.answer_wrong(rows, want)
+
+
+def test_a_degraded_mesh_run_is_counted_and_answers_single_chip(mesh4):
+    want = mesh4.ds.reference(mesh4.tables, "q6")
+    failpoint.enable("device_guard/fused/mpp", "error:compile")
+    try:
+        rows, grown = _q6(mesh4)
+    finally:
+        failpoint.disable("device_guard/fused/mpp")
+    assert grown == {("single_chip", "degraded"): 1}, grown
+    assert not mesh4.ds.answer_wrong(rows, want)
+    assert mesh4.domain.metrics.get("device_fallback", 0) >= 1
+
+
+@pytest.mark.parametrize("sql,route", [
+    # a per-DAG aggregation over a small dense domain: psum on the mesh
+    ("select g, sum(v) from mesh_t group by g order by g",
+     ("mesh", "ok")),
+    # one the mesh has no lowering for: sparse 64-bit group keys
+    ("select k, sum(v) from mesh_t group by k order by 2 desc limit 2",
+     ("single_chip", "ineligible_no_dense_layout")),
+    # filter-only and top-n fragments run on one chip
+    ("select id from mesh_t where v > 1990 order by id",
+     ("single_chip", "ineligible_no_aggregation")),
+    ("select id from mesh_t where v > 10 order by v desc limit 3",
+     ("single_chip", "ineligible_no_aggregation")),
+])
+def test_per_dag_fragments_name_their_route(mesh4, sql, route):
+    c = mesh4.client()
+    try:
+        c.query("create table if not exists mesh_t (id int primary key, "
+                "k bigint, g int, v int)")
+        if c.query("select count(*) from mesh_t")["rows"][0][0] == "0":
+            c.query("insert into mesh_t values " + ",".join(
+                f"({i},{i * 1000003},{i % 5},{i})" for i in range(1, 2001)))
+        c.query("set tidb_tpu_fragment_min_rows = 0")
+        before = routes()
+        c.query(sql)
+        grown = {k: v - before.get(k, 0) for k, v in routes().items()
+                 if v - before.get(k, 0)}
+    finally:
+        c.close()
+    assert grown == {route: 1}, grown
+
+
+def test_one_device_counts_nothing(one_device):
+    want = one_device.ds.reference(one_device.tables, "q6")
+    rows, grown = _q6(one_device)
+    assert grown == {} and routes() == {}
+    assert not one_device.ds.answer_wrong(rows, want)
+    rows, grown = _q6(one_device, ["set tidb_enable_mpp = 0"])
+    assert grown == {}
+
+
+def test_an_acknowledged_insert_is_seen_by_the_next_q6_on_the_mesh(mesh4):
+    """The configuration's isolation guarantee: a read sees every write
+    acknowledged before it was sent, on the mesh too (the sharded
+    buffers are tail-patched or re-keyed, never stale)."""
+    base, _ = _q6(mesh4)
+    w = mesh4.client()
+    try:
+        assert w.query(LINE.format(n=9))["affected"] == 1
+    finally:
+        w.close()
+    rows, grown = _q6(mesh4)
+    assert grown == {("mesh", "ok"): 1}, grown
+    # 1000.00 x 0.06 inside q6's date, discount and quantity windows
+    assert float(rows[0][0]) == pytest.approx(float(base[0][0]) + 60.0,
+                                              abs=1e-6)
+    assert rows != base

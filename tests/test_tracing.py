@@ -499,6 +499,59 @@ def test_wire_statement_tree_reaches_dispatch_and_wire_write(served):
             _path(q3, e)[:2] == ["command", "statement"]
 
 
+def test_mesh_statement_tree_has_the_route_span(served):
+    """On a mesh the fused route opens `mpp_dispatch` inside the
+    supervised attempt: command > statement > execute > device_attempt
+    > mpp_dispatch > bind/dispatch/consume, the route's attributes on
+    it and what the host merged on `consume`."""
+    import jax
+    from tidb_tpu.bench.tpch import Q6
+    from tidb_tpu.parallel import make_mesh
+    by_order = ("select l_orderkey, l_partkey, count(*) from lineitem "
+                "group by l_orderkey, l_partkey order by 3 desc, 1, 2 limit 3")
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four devices for the mesh")
+    tk, c = served
+    was = tk.domain.copr._get_mesh()
+    tk.domain.copr._mesh = make_mesh(4)
+    try:
+        c.query("set tidb_mpp_min_rows = 0")
+        c.query("set tidb_tpu_trace_sample_rate = 1")
+        found = {}
+        for name, sql in (("sorted", by_order), ("q6", Q6)):
+            tk.domain.tracer.recorder.clear()
+            c.query(sql)
+            evs = tk.domain.tracer.recorder.events()
+            roots = [e for e in evs if e.name == "command" and
+                     not e.parent_id]
+            found[name] = next(
+                t for t in (_tree(evs, r) for r in roots)
+                if any(e.name == "dispatch" for e in t.values()))
+    finally:
+        c.query("set tidb_tpu_trace_sample_rate = 0")
+        c.query("set tidb_mpp_min_rows = 65536")
+        tk.domain.copr._mesh = was if was is not None else False
+    route = ("command", "statement", "execute", "device_attempt",
+             "mpp_dispatch")
+    for name, tree in found.items():
+        paths = {tuple(_path(tree, e)) for e in tree.values()}
+        for leaf in ("bind", "dispatch", "consume"):
+            assert route + (leaf,) in paths, (name, sorted(paths))
+    so = {e.name: e.attrs for e in found["sorted"].values()}
+    q6 = {e.name: e.attrs for e in found["q6"].values()}
+    for attrs in (so["mpp_dispatch"], q6["mpp_dispatch"]):
+        assert "ndev=4" in attrs and "exchange=passthrough" in attrs
+    assert "kind=sort" in so["mpp_dispatch"]
+    assert "kind=dense" in q6["mpp_dispatch"]
+    # the sort layout's per-shard partials are merged on the host,
+    # q6's sums on the mesh
+    merged = [e.attrs for e in found["sorted"].values()
+              if e.name == "consume" and "shards=4" in e.attrs]
+    assert len(merged) == 1 and "merged_groups=0" not in merged[0], \
+        [e.attrs for e in found["sorted"].values() if e.name == "consume"]
+    assert "shards=1" in q6["consume"] and "merged_groups=0" in q6["consume"]
+
+
 def test_profiler_segments_are_flat_self_time(served, tmp_path):
     """Under a profiler session the host plane holds tidb:<span>
     segments that match the benchmark's name filter, never overlap

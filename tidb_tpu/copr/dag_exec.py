@@ -44,6 +44,11 @@ from ..chunk.chunk import Chunk
 _I64_MAX = np.iinfo(np.int64).max
 
 
+# what _try_execute_mpp answers where the mesh has no lowering for the
+# aggregation: the caller runs it single-chip (None is a degraded run's)
+_NO_DENSE_LAYOUT = object()
+
+
 class _KernelCache(dict):
     """Compiled-kernel cache with hit/miss counters (reference
     coprocessor_cache.go metrics; surfaced per-operator by
@@ -299,15 +304,25 @@ class CoprExecutor:
             self._bump("copr_host_exec")
             return self._execute_host(dag, tbl, arrays, valid, n, handles)
         _metrics.FRAGMENT_ROUTING.labels("device").inc()
-        if use_mpp and (dag.aggs or dag.group_items) and not overlay \
-                and not dag.host_filters \
-                and n >= mpp_min_rows:
+        # why a dispatch that could see a mesh stays on one chip
+        # (tidb_tpu_mesh_route_total; one device counts nothing)
+        mesh = self._get_mesh()
+        off_mesh = None if mesh is None else \
+            "ineligible_no_aggregation" if not (
+                dag.aggs or dag.group_items) else \
+            "mpp_off" if not use_mpp else \
+            "delta_overlay" if overlay else \
+            "ineligible_host_filter" if dag.host_filters else \
+            "min_rows" if n < mpp_min_rows else "ok"
+        if off_mesh == "ok":
             try:
                 # supervised mesh dispatch: retryable classes retry with
                 # backoff, anything else degrades to None so the
                 # single-chip path (which always works) takes over
                 with _tracing.span("mpp_dispatch",
-                                   table=dag.table_info.name, rows=n):
+                                   table=dag.table_info.name, rows=n,
+                                   ndev=int(mesh.devices.size),
+                                   exchange="passthrough", kind="dense"):
                     res = device_guard.guarded_dispatch(
                         lambda: self._try_execute_mpp(dag, tbl, arrays,
                                                       valid, n, handles,
@@ -322,9 +337,14 @@ class CoprExecutor:
                 raise                       # kill/quota: statement error
             except Exception:               # noqa: BLE001
                 res = None                  # single-chip path always works
-            if res is not None:
+            if res is not None and res is not _NO_DENSE_LAYOUT:
+                _metrics.MESH_ROUTE.labels("mesh", "ok").inc()
                 self._bump("copr_mpp_exec")
                 return res
+            off_mesh = "degraded" if res is None else \
+                "ineligible_no_dense_layout"
+        if off_mesh is not None:
+            _metrics.MESH_ROUTE.labels("single_chip", off_mesh).inc()
         self._bump("copr_device_exec")
         return self._execute_device(dag, tbl, arrays, valid, n, handles,
                                     ectx)
@@ -697,21 +717,20 @@ class CoprExecutor:
         """MPP fragment path: shard rows across the mesh, run the dense
         partial-agg kernel per shard inside shard_map, merge with psum
         (the hash exchange collapsed into an allreduce over the dense key
-        domain — tidb_tpu/mpp design). Returns None when ineligible.
+        domain — tidb_tpu/mpp design). Returns _NO_DENSE_LAYOUT when
+        the aggregation has no dense layout to allreduce.
 
         Every input — column data AND the MVCC validity mask — rides the
         sharded residency store, so a repeated statement over an
         unchanged table uploads zero bytes to the mesh."""
         mesh = self._get_mesh()
-        if mesh is None:
-            return None
         cols_full = self._bind_cols(dag, tbl, arrays, slice(0, n), handles)
         kd, sd = capture_agg_dicts(dag, cols_full)
         strides = dense_strides(dag, kd, cols_full, n)
         if strides is None or not _al.dense_fits(strides):
             # no dense layout, or none the policy lowers at this size:
             # the caller falls through to the single-chip path
-            return None
+            return _NO_DENSE_LAYOUT
         ndev = int(mesh.devices.size)
         lane = 128 * ndev
         # BUCKETED lane-multiple padding (was an exact lane multiple):

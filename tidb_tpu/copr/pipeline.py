@@ -2163,6 +2163,7 @@ def _try_fused_shuffle(copr, plan, mesh, dim_metas, fact_tbl, fact_arrays,
             states.append([s, cnts[slots]])
     if getattr(copr, "domain", None) is not None:
         copr.domain.inc_metric("fused_shuffle_join")
+    _tracing.tag(exchange="hash", kind="dense")
     return [PartialAggResult(
         ngroups=len(slots), keys=keys,
         key_nulls=[np.zeros(len(slots), dtype=bool)],
@@ -2238,7 +2239,12 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
         # mesh (the result tree is already global); the sort layout
         # ships per-shard partials to the coordinator in one fetch
         exchange_observed("passthrough", tree_nbytes(res))
-        with _tracing.span("consume", retries=retries):
+        # on the route's own span (executors.FusedPipelineExec opens it)
+        _tracing.tag(exchange="passthrough", kind=agg_kind)
+        # shards: whose partials the host merges (1: the mesh merged
+        # them, psum); merged_groups: how many partial groups that is
+        with _tracing.span("consume", retries=retries, shards=1,
+                           merged_groups=0) as csp:
             if agg_kind == "posdense":
                 return [_compact_pos_dense(plan, res, low.pos[0],
                                            low.pos[1], dim_metas, sd)]
@@ -2269,6 +2275,9 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
                     states=[[host_array(s)[sl][:ng] for s in st]
                             for st in res["states"]],
                     key_dicts=kd, state_dicts=sd))
+            if csp is not None:
+                csp.attrs.update(shards=len(out), merged_groups=sum(
+                    p.ngroups for p in out))
             return out
 
 

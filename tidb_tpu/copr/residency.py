@@ -311,6 +311,18 @@ class DeviceResidentStore:
                     "max_bytes": self.max_bytes, "budget": self.budget,
                     "bytes_by_spec": dict(self._bytes_by_spec)}
 
+    def placements(self) -> dict:
+        """-> {table uid: {placement spec: charged bytes}}: a table held
+        `local` beside its sharded or replicated copies is resident
+        twice on device 0."""
+        with self._mu:
+            out = {}
+            for key, uid in self._uid_of.items():
+                by = out.setdefault(uid, {})
+                spec = self._spec_of.get(key, "local")
+                by[spec] = by.get(spec, 0) + self._sizes.get(key, 0)
+            return out
+
     def _drop_locked(self, key, cause: str):
         self._entries.pop(key, None)
         self._append.pop(key, None)

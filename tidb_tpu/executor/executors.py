@@ -307,29 +307,45 @@ class FusedPipelineExec(Executor):
             sess.domain.last_fused_reason = drows   # the reason string
         else:
             from ..copr.pipeline import fused_partials
-            mesh = None
-            if getattr(self.plan, "mpp", False) and drows is None:
-                # the delta overlay runs single-chip: the extra
-                # partition is tiny and not worth a mesh program
+            from ..utils import tracing as _tracing
+            # where the dispatch runs and why: the mesh, or one chip
+            # beside it (tidb_tpu_mesh_route_total; a process with one
+            # device has no mesh and counts nothing)
+            seen, reason = self.ctx.copr._get_mesh(), "ok"
+            if seen is not None:
                 fm = getattr(self.ctx, "force_mpp", None)
-                want = bool(self.ctx.sv.get("tidb_enable_mpp")) \
-                    if fm is None else fm
-                min_rows = 0 if fm else int(
-                    self.ctx.sv.get("tidb_mpp_min_rows"))
                 fact = sess.domain.columnar.tables.get(
                     self.plan.fact_dag.table_info.id)
-                if want and fact is not None and fact.n >= min_rows:
-                    mesh = self.ctx.copr._get_mesh()
+                if not getattr(self.plan, "mpp", False) or not (
+                        bool(self.ctx.sv.get("tidb_enable_mpp"))
+                        if fm is None else fm):
+                    reason = "mpp_off"
+                elif drows is not None:
+                    # the delta overlay runs single-chip: the extra
+                    # partition is tiny and not worth a mesh program
+                    reason = "delta_overlay"
+                elif fact is None:
+                    reason = "ineligible_no_columnar_image"
+                elif fact.n < (0 if fm else int(
+                        self.ctx.sv.get("tidb_mpp_min_rows"))):
+                    reason = "min_rows"
+            mesh = seen if reason == "ok" else None
             from ..utils import device_guard
             bt = int(self.ctx.sv.get(
                 "tidb_broadcast_join_threshold_count"))
 
             def _run_fused(m):
-                return fused_partials(
-                    self.ctx.copr, self.plan, self.ctx.read_ts(), m,
-                    bcast_threshold=bt, ctx=self.ctx,
-                    delta_rows=drows[0] if drows else None,
-                    dead_handles=drows[1] if drows else None)
+                # the route's own span: its self time is the mesh
+                # route's host code (pipeline tags exchange and kind)
+                with (_tracing.span(
+                        "mpp_dispatch", ndev=int(m.devices.size),
+                        table=self.plan.fact_dag.table_info.name)
+                        if m is not None else _tracing.NO_SPAN):
+                    return fused_partials(
+                        self.ctx.copr, self.plan, self.ctx.read_ts(), m,
+                        bcast_threshold=bt, ctx=self.ctx,
+                        delta_rows=drows[0] if drows else None,
+                        dead_handles=drows[1] if drows else None)
 
             try:
                 # supervised dispatch (classified retry/backoff +
@@ -342,7 +358,7 @@ class FusedPipelineExec(Executor):
                             lambda: _run_fused(mesh), site="fused/mpp",
                             ectx=self.ctx, fallback_is_host=False)
                     except device_guard.DeviceDegradedError:
-                        used_mesh = False
+                        used_mesh, reason = False, "degraded"
                         res = device_guard.guarded_dispatch(
                             lambda: _run_fused(None), site="fused",
                             ectx=self.ctx)
@@ -352,6 +368,10 @@ class FusedPipelineExec(Executor):
                         ectx=self.ctx)
                 if res is not None:
                     from ..utils import metrics as _mtr
+                    if seen is not None:
+                        _mtr.MESH_ROUTE.labels(
+                            "mesh" if used_mesh else "single_chip",
+                            reason).inc()
                     _mtr.FUSED_PIPELINE.labels(
                         "mpp_hit" if used_mesh else "hit").inc()
                     sess.domain.inc_metric(

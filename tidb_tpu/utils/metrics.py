@@ -812,6 +812,22 @@ MPP_EXCHANGE_BYTES = REGISTRY.counter(
     "tidb_tpu_mpp_exchange_bytes_total",
     "Bytes moved across the mesh by exchange collectives by exchange "
     "type (aggregate over devices, not per-chip)", ("type",))
+MESH_ROUTE = REGISTRY.counter(
+    "tidb_tpu_mesh_route_total",
+    "Device dispatches of statements that could see a mesh (a process "
+    "with two or more devices), by where they ran (route: mesh | "
+    "single_chip) and why (reason: ok, mpp_off, min_rows, "
+    "delta_overlay, ineligible_<why>, degraded); does not move on one "
+    "device", ("route", "reason"))
+
+
+def mesh_routes() -> dict:
+    """-> {(route, reason): dispatches} of tidb_tpu_mesh_route_total,
+    the samples that have moved."""
+    return {(lb["route"], lb["reason"]): int(v)
+            for _name, lb, v in MESH_ROUTE.sample_rows() if v}
+
+
 KERNEL_CACHE = REGISTRY.counter(
     "tidb_tpu_kernel_cache_total",
     "Compiled-kernel cache lookups by result", ("result",))
