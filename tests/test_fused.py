@@ -287,6 +287,62 @@ def test_fused_semi_filter_rejects_all_key_zero(tk):
     assert _conventional(tk, sql) == [(0,)]
 
 
+def test_row_blocks_and_the_overlay_merge_on_the_probe_key(monkeypatch):
+    """Four row blocks' partials and the transaction's own rows as a
+    fifth, under the chip's policy: each names `fact.d_id` as the item
+    that identifies the group, the merge groups on it alone
+    (tidb_tpu_agg_merge_total{path="ident"}), and the rows are the
+    conventional subtree's."""
+    import tidb_tpu.copr.agg_lowering as al
+    from tidb_tpu.utils import metrics as mu
+    monkeypatch.setattr(al, "_FORCE_SEGMENT_IMPL", "runs")
+    tk = TestKit()
+    tk.must_exec("create table d (id int primary key, name varchar(16), "
+                 "val int)")
+    tk.must_exec("create table f (k int primary key, d_id int, q int)")
+    tk.must_exec("insert into d values " + ",".join(
+        f"({i}, 'n{i % 11}', {i % 3})" for i in range(1, 201)))
+    rng = np.random.RandomState(11)
+    tk.must_exec("insert into f values " + ",".join(
+        f"({i}, {rng.randint(1, 220)}, {rng.randint(0, 50)})"
+        for i in range(1, 2001)))
+    monkeypatch.setattr(tk.domain.copr, "device_rows", 500)
+    sql = ("select f.d_id, d.name, d.val, sum(f.q), count(*) from f, d "
+           "where f.d_id = d.id group by f.d_id, d.name, d.val "
+           "order by f.d_id")
+
+    def merged():
+        before = mu.agg_merges()
+        return tk.must_query(sql).rs.rows, mu.agg_merges(before)
+    hit = tk.domain.metrics.get("fused_pipeline_hit", 0)
+    got, grown = merged()
+    assert tk.domain.metrics.get("fused_pipeline_hit", 0) == hit + 1
+    assert grown == {"ident": 1}, grown
+    assert got == _conventional(tk, sql) and len(got) == 200
+    # the same three facts on the statement's `execute` span, not on a
+    # span of the merge's own
+    tk.must_exec("set tidb_tpu_trace_sample_rate = 1")
+    tk.must_query(sql)
+    spans = dict(tk.must_query(
+        "select span, attrs from information_schema.tidb_trace_events "
+        "where attrs like '%merge=%'").rs.rows)
+    tk.must_exec("set tidb_tpu_trace_sample_rate = 0")
+    assert list(spans) == ["execute"] and \
+        "merge=ident" in spans["execute"] and \
+        "merge_groups=200" in spans["execute"], spans
+    tk.must_exec("begin")
+    tk.must_exec("insert into f values (3001, 7, 5), (3002, 199, 1)")
+    overlay = tk.domain.metrics.get("fused_pipeline_dirty_overlay", 0)
+    dirty, grown = merged()
+    assert tk.domain.metrics.get(
+        "fused_pipeline_dirty_overlay", 0) == overlay + 1
+    assert grown == {"ident": 1}, grown
+    assert dirty == _conventional(tk, sql)
+    tk.must_exec("rollback")
+    assert [int(r[3]) for r in dirty if r[0] == 7] == \
+        [int(r[3]) + 5 for r in got if r[0] == 7]
+
+
 def test_host_partial_agg_shared_dicts():
     """Raw-string group keys aggregated chunk-by-chunk must encode
     through ONE shared dict: per-chunk dicts give colliding int64 codes

@@ -10,6 +10,7 @@ import os
 import jax
 import pytest
 
+import tidb_tpu.copr.agg_lowering as al
 from tidb_tpu.parallel import make_mesh
 from tidb_tpu.server import Server
 from tidb_tpu.session import new_store
@@ -77,6 +78,7 @@ def one_device(tmp_path_factory):
 
 
 routes = mu.mesh_routes
+merges = mu.agg_merges
 
 
 @pytest.mark.parametrize("stmt", ["q1", "q3", "q5", "q6", "q10", "q18"])
@@ -105,6 +107,87 @@ def test_statement_on_the_mesh_equals_the_reference(mesh4, stmt):
     assert specs and not [u for u, by in specs.items()
                           if "local" in by and len(by) > 1], specs
     assert any("sharded" in by for by in specs.values())
+
+
+@pytest.fixture
+def runs_policy():
+    """The chip's lowering policy on the CPU backend: under it the join
+    statements return a partial a shard or a row block ("sort",
+    "posruns") and not psum-able slots."""
+    al._FORCE_SEGMENT_IMPL = "runs"
+    try:
+        yield
+    finally:
+        al._FORCE_SEGMENT_IMPL = None
+
+
+@pytest.fixture
+def three_blocks(one_device):
+    copr = one_device.domain.copr
+    old, copr.device_rows = copr.device_rows, 24_000
+    try:
+        yield one_device
+    finally:
+        copr.device_rows = old
+
+
+def _merged(d, stmt, prepare=()):
+    """The statement, checked against the reference -> the final merges
+    it counted, by path."""
+    c = d.client()
+    try:
+        for sql in prepare:
+            c.query(sql)
+        before = merges()
+        got = c.query(d.ds.STATEMENTS[stmt])["rows"]
+        grown = merges(before)
+    finally:
+        c.close()
+    assert not d.ds.answer_wrong(got, d.ds.reference(d.tables, stmt))
+    return grown
+
+
+@pytest.mark.parametrize("stmt", ["q10", "q3", "q18"])
+@pytest.mark.parametrize("where", ["mesh4", "three_blocks"])
+def test_join_statements_merge_on_the_identifying_item(
+        request, runs_policy, where, stmt):
+    """c_custkey, l_orderkey, o_orderkey: four shards' or three row
+    blocks' partials merged on one item of seven, three and five."""
+    grown = _merged(request.getfixturevalue(where), stmt)
+    # at this size no statement's groups reach the length at which
+    # keys in order merge as runs. q18's IN subquery, unless its cached
+    # result serves, is a final aggregation of its own, on its one item
+    assert grown.pop("ident", 0) == 1, grown
+    assert sum(grown.values()) <= (1 if stmt == "q18" else 0), grown
+
+
+@pytest.mark.parametrize("where", ["mesh4", "three_blocks"])
+def test_one_partial_counts_no_merge(request, runs_policy, where):
+    """q5's 25 slots are summed on the mesh (psum) or arrive as dense
+    slots a block: no identifying item is named, and on the mesh there
+    is one partial."""
+    grown = _merged(request.getfixturevalue(where), "q5")
+    assert grown == ({} if where == "mesh4" else {"all_items": 1}), grown
+
+
+def test_a_statement_on_the_fallback_merges_on_all_items(
+        one_device, monkeypatch):
+    """The host's partials (`_fallback_partials`) name no item. Its join
+    emits one chunk, so one partial and nothing to count; two of them
+    merge on every item."""
+    from tidb_tpu.executor.executors import HashAggExec
+    dom, seen = one_device.domain, []
+    merge = HashAggExec._merge_partials
+    monkeypatch.setattr(
+        HashAggExec, "_merge_partials",
+        lambda self, ps: seen.append((self, ps)) or merge(self, ps))
+    monkeypatch.setattr(dom.copr, "use_device", False)
+    assert _merged(one_device, "q10") == {}
+    agg, partials = seen[-1]
+    assert [p.ident for p in partials] == [None]
+    before = merges()
+    assert len(merge(agg, partials * 2)) == partials[0].ngroups
+    assert merges(before) == {"all_items": 1}
 
 
 def _q6(d, prepare=(), cleanup=()):

@@ -220,7 +220,10 @@ def deserialize_partials(meta, arrays, shared_dicts=None):
     """-> [PartialAggResult]. `shared_dicts` must be reused across every
     worker's response of one query: the merge machinery assumes all
     partials share ONE dictionary per key/state position — re-encoding
-    each worker's values into the same dict keeps codes comparable."""
+    each worker's values into the same dict keeps codes comparable.
+    A partial's `ident` does not cross hosts: what makes it true was
+    verified on the worker's own copy of the dimensions, so here it
+    reads None and the partials merge on every group item."""
     from ..copr.agg_lowering import PartialAggResult
     from ..chunk.device import StringDict
     shared = shared_dicts if shared_dicts is not None else {}

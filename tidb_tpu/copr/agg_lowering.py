@@ -372,19 +372,26 @@ class PartialAggResult:
     int64) + per-agg state arrays (sum/count/min/max). key_dicts/state_dicts
     carry StringDicts for string-typed keys/args (codes are comparable
     across partitions because dict transforms are deterministic over the
-    shared table dictionary)."""
+    shared table dictionary).
+
+    ident: the indices into `keys` of the group items that identify the
+    group, the others being functions of them (q10's six customer and
+    nation columns beside `c_custkey`), or None: all of them. Set only
+    by a producer that has verified what makes it true; the final merge
+    (executors.HashAggExec) then groups on those items alone."""
 
     __slots__ = ("ngroups", "keys", "key_nulls", "states", "key_dicts",
-                 "state_dicts")
+                 "state_dicts", "ident")
 
     def __init__(self, ngroups, keys, key_nulls, states, key_dicts=None,
-                 state_dicts=None):
+                 state_dicts=None, ident=None):
         self.ngroups = ngroups
         self.keys = keys
         self.key_nulls = key_nulls
         self.states = states
         self.key_dicts = key_dicts or [None] * len(keys)
         self.state_dicts = state_dicts or [None] * len(states)
+        self.ident = ident
 
 
 def capture_agg_dicts(dag, cols):

@@ -895,6 +895,32 @@ def _pos_group_items(plan):
                                        {di for _, di, _ in group_map}, keep)
 
 
+def _ident_items(plan):
+    """The group items that identify the group of a fused statement's
+    partials (`PartialAggResult.ident`): for every dimension whose join
+    position is a key of `_pos_group_items`, the item that is unique a
+    position of it — its probe key, or its build key as a column. The
+    other items are columns at those positions. Holds where the fused
+    statement ran: build keys verified unique and non-NULL, inner joins.
+    -> tuple of indices into the group items, or None when a dimension
+    has no such item (`group by c_name` alone) or joins on several
+    columns (its build key alone is not unique)."""
+    gm = _pos_group_items(plan)
+    if gm is None:
+        return None
+    group_map, pos_dims = gm
+    ident = []
+    for di in pos_dims:
+        dim = plan.dims[di]
+        key_cid = _cid_of(dim.dag, dim.build_key)
+        at = next((i for i, (_kind, d, cid) in enumerate(group_map)
+                   if d == di and cid == key_cid), None)
+        if at is None or key_cid == -1 or dim.extra_keys:
+            return None
+        ident.append(at)
+    return tuple(ident)
+
+
 def _pos_group_map(plan, dim_metas):
     """_pos_group_items plus the size of the position domain, which
     picks the lowering: slots packed into one array ("posdense") or the
@@ -1795,6 +1821,8 @@ def fused_partials(copr, plan, read_ts, mesh=None,
             handles, dim_args, dim_metas, dim_caps, dim_ns, dim_sns,
             dim_layouts, fact_sdicts, low, shim, kd, sd, read_ts,
             dim_pres, fp)
+    # which group items identify a "sort" / "posruns" partial's groups
+    ident = _ident_items(plan)
     # the lowering the first row block will take: its operands go up
     # before the loop, in a `bind` of the statement's own
     _dims_for(low.choose(0)[0] in ("posdense", "posruns"))
@@ -1904,7 +1932,7 @@ def fused_partials(copr, plan, read_ts, mesh=None,
             _count("fused_posruns_agg")
         out.append(PartialAggResult(
             ngroups=ng, keys=ks, key_nulls=kns, states=sts,
-            key_dicts=kds, state_dicts=sd))
+            key_dicts=kds, state_dicts=sd, ident=ident))
 
     def _consume(state, m):
         """One run's result into `out` -> True, or False when the
@@ -2260,6 +2288,7 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
                 retries += 1
                 continue
             group_bucket = agg_param[0]
+            ident = _ident_items(plan)
             # unstack the per-shard partials
             out = []
             for si in range(ndev):
@@ -2274,7 +2303,7 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
                                for kn in res["key_nulls"]],
                     states=[[host_array(s)[sl][:ng] for s in st]
                             for st in res["states"]],
-                    key_dicts=kd, state_dicts=sd))
+                    key_dicts=kd, state_dicts=sd, ident=ident))
             if csp is not None:
                 csp.attrs.update(shards=len(out), merged_groups=sum(
                     p.ngroups for p in out))

@@ -17,6 +17,7 @@ from ..types.decimal import _POW10
 from ..errors import UnsupportedError, TiDBError
 from .exec_base import Executor, bind_chunk, eval_to_column, spill_quota
 from ..utils import metrics as _metrics
+from ..utils import tracing as _tracing
 
 _I64_MAX = np.iinfo(np.int64).max
 
@@ -307,7 +308,6 @@ class FusedPipelineExec(Executor):
             sess.domain.last_fused_reason = drows   # the reason string
         else:
             from ..copr.pipeline import fused_partials
-            from ..utils import tracing as _tracing
             # where the dispatch runs and why: the mesh, or one chip
             # beside it (tidb_tpu_mesh_route_total; a process with one
             # device has no mesh and counts nothing)
@@ -1347,8 +1347,14 @@ class HashAggExec(Executor):
                      for i in range(ngk)]
         starts = None      # run starts when partial keys arrive sorted
         if ngk:
-            kvecs = [np.where(kn, -(1 << 62), k)
-                     for k, kn in zip(keys, key_nulls)]
+            # the items that identify the group, when every partial
+            # names the same ones: the others are functions of them,
+            # so grouping on them alone gives the same groups
+            ident = live[0].ident
+            if ident is None or any(p.ident != ident for p in live):
+                ident = range(ngk)
+            kvecs = [np.where(key_nulls[i], -(1 << 62), keys[i])
+                     for i in ident]
             from ..copr.agg_lowering import sorted_run_starts
             starts, change = sorted_run_starts(kvecs)
             if starts is not None:
@@ -1357,11 +1363,22 @@ class HashAggExec(Executor):
                 g = len(starts)
                 inverse = np.cumsum(change) - 1
                 firsts = starts
+            elif len(kvecs) == 1:
+                uniq, inverse = np.unique(kvecs[0], return_inverse=True)
+                g = len(uniq)
             else:
                 kmat = np.stack(kvecs, axis=1)
                 uniq, inverse = np.unique(kmat, axis=0,
                                           return_inverse=True)
                 g = len(uniq)
+            if len(live) > 1:
+                path = "sorted_runs" if starts is not None else \
+                    "ident" if len(kvecs) < ngk else "all_items"
+                _metrics.AGG_MERGE.labels(path).inc()
+                # on the statement's open `execute` span: no span of
+                # its own, the merge stays in that span's self time
+                _tracing.tag(merge=path, merge_rows=len(keys[0]),
+                             merge_groups=g)
         else:
             g = 1
             inverse = np.zeros(sum(p.ngroups for p in live), dtype=np.int64)

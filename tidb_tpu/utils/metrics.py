@@ -996,6 +996,24 @@ DIM_FOLD = REGISTRY.counter(
     "composed into the word its key addresses), packed_spill (a second "
     "word): a root, a lowering's upload",
     ("outcome",))
+AGG_MERGE = REGISTRY.counter(
+    "tidb_tpu_agg_merge_total",
+    "Final merges of two or more live aggregation partials by what "
+    "they grouped on: ident (the items the partials name as identifying "
+    "the group, a proper subset), sorted_runs (one key that arrived in "
+    "order: run boundaries, no sort), all_items (every group item)",
+    ("path",))
+
+
+def agg_merges(since=None) -> dict:
+    """-> {path: count} of `tidb_tpu_agg_merge_total`'s samples that
+    have moved, since an earlier reading of this when one is given."""
+    since = since or {}
+    return {path: n for path, n in (
+        (lb["path"], int(v) - since.get(lb["path"], 0))
+        for _name, lb, v in AGG_MERGE.sample_rows()) if n}
+
+
 FUSED_PIPELINE = REGISTRY.counter(
     "tidb_tpu_fused_pipeline_total",
     "Fused-pipeline executions by outcome", ("outcome",))
