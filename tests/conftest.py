@@ -32,3 +32,19 @@ def _metrics_isolation():
     device_guard.reset()
     phase.reset()
     yield
+
+
+@pytest.fixture
+def judged_runs():
+    """-> read(since=None): {(site, kind, verdict): runs} of
+    `tidb_tpu_agg_lowering_total`'s samples that have moved, since an
+    earlier reading when one is given."""
+    from tidb_tpu.utils.metrics import AGG_LOWERING
+
+    def read(since=None):
+        since = since or {}
+        now = {(lb["site"], lb["kind"], lb["verdict"]): int(v)
+               for _name, lb, v in AGG_LOWERING.sample_rows()}
+        return {k: n - since.get(k, 0) for k, n in now.items()
+                if n - since.get(k, 0)}
+    return read

@@ -72,8 +72,8 @@ SHAPES = {
     # few dict codes beat runs over scattered positions
     "pos_65_dense_12": (_pos(65), _dense(12), "fused", True, "dense", None),
     # the mesh: no posruns
-    "mesh_pos_65": (_pos(65), None, "mesh", True, "sort", "runs"),
-    "mesh_pos_25": (_pos(25), None, "mesh", True, "posdense", None),
+    "mesh_pos_65": (_pos(65), None, "fused_mpp", True, "sort", "runs"),
+    "mesh_pos_25": (_pos(25), None, "fused_mpp", True, "posdense", None),
     # the per-DAG executor: dense or sort
     "dag_dense_12": (None, _dense(12), "dag", False, "dense", None),
     "dag_dense_65": (None, _dense(65), "dag", False, "sort", "runs"),
@@ -158,7 +158,7 @@ def test_a_learned_onehot_table_against_posruns(runs):
         ("onehot", (128,), None)
     assert al.Lowering(st, _pos(65), dims=True).choose(
         al.ONEHOT_CAP_MAX * 2)[0] == "sort"
-    assert al.Lowering(st, _pos(65), site="mesh").choose(CAP)[0] == "sort"
+    assert al.Lowering(st, _pos(65), site="fused_mpp").choose(CAP)[0] == "sort"
     assert al.Lowering(st, None, site="dag").choose(CAP)[0] == "sort"
     st.onehot = False               # the tombstone is no table
     assert al.Lowering(st, _pos(65), dims=True).choose(CAP)[0] == "posruns"
@@ -186,7 +186,7 @@ def test_topn_candidate_width(runs, k, bucket, want):
     assert topn == (want and ("agg", 0, True, want))
     st.topn_off = True
     assert low.choose(CAP)[1][2] is None
-    assert al.Lowering(st, _pos(65), site="mesh",
+    assert al.Lowering(st, _pos(65), site="fused_mpp",
                        topn=("agg", 0, True, k)).topn is None
 
 
@@ -204,7 +204,7 @@ def test_compaction_capacities(runs):
     assert al.Lowering(st, None).choose(CAP) == \
         ("sort", (al.GROUP_BUCKET_MIN, "runs", None, 4096), None)
     # the mesh keeps the late buffer only, the per-DAG executor neither
-    assert al.Lowering(st, None, site="mesh", dims=True).choose(CAP) == \
+    assert al.Lowering(st, None, site="fused_mpp", dims=True).choose(CAP) == \
         ("sort", (al.GROUP_BUCKET_MIN, "runs", None, 4096), None)
     assert al.Lowering(st, None, site="dag").choose(CAP) == \
         ("sort", (al.GROUP_BUCKET_MIN, "runs", None, None), None)
@@ -290,6 +290,23 @@ def test_observe(runs, case):
         assert getattr(st, name) == value, name
 
 
+@pytest.mark.parametrize("why", [None, "onehot_miss", "topn_unproven"])
+def test_a_held_run_is_counted_once_by_its_consumer(runs, judged_runs, why):
+    """A run that stands by its sizes but whose consumer can still throw
+    it away (`hold`) is counted by `settle`, once, under its final
+    verdict; a retry `observe` sees itself is counted there."""
+    low = al.Lowering(_state(), None, dims=True)
+    kind, param = "posruns", (1024, (0,), ("agg", 0, True, 7), None)
+    assert low.observe(kind, param, None, ROWS, ROWS, 10, hold=True) is None
+    assert judged_runs() == {}
+    low.settle(kind, param, why)
+    verdict = "retry_" + why if why else "stands"
+    assert judged_runs() == {("fused", "posruns", verdict): 1}
+    assert low.observe(kind, param, None, ROWS, ROWS, 5000,
+                       hold=True) == "retry"
+    assert judged_runs().get(("fused", "posruns", "retry_grow_bucket")) == 1
+
+
 def test_state_is_one_per_shape_and_epoch():
     copr = _Copr()
     a, b = _state(copr), _state(copr)
@@ -335,7 +352,7 @@ def test_onehot_is_for_accelerators_and_does_not_follow_the_policy(
     monkeypatch.setattr(al, "_FORCE_ONEHOT", True)
     assert low.onehot_learnable([Sum], [Sum], one, None) is True
     assert low.onehot_learnable([Sum], [Sum], one, [(1, [])]) is False
-    assert al.Lowering(_state(), None, site="mesh").onehot_learnable(
+    assert al.Lowering(_state(), None, site="fused_mpp").onehot_learnable(
         [Sum], [Sum], one, None) is False
     assert al.Lowering(_state(), _pos(65), dims=True).onehot_learnable(
         [Sum], [Sum], one, None) is False

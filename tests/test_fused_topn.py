@@ -140,7 +140,7 @@ def test_fused_topn_disabled_after_degrade_pin(runs_impl, monkeypatch):
                         if k and k[0] == "aggimpl"]
 
 
-def test_fused_topn_tie_fallback(runs_impl):
+def test_fused_topn_tie_fallback(runs_impl, judged_runs):
     """All groups tie on the metric: the candidate set cannot prove
     coverage, so the shape must fall back (off flag) and still answer
     from full partials."""
@@ -154,6 +154,13 @@ def test_fused_topn_tie_fallback(runs_impl):
         [tuple(map(str, r)) for r in host]
     hc = tk.domain.copr._host_cache
     assert any(k and k[0] == "ftopn_off" for k in hc)
+    # three device runs, one count each: the bucket grows, the
+    # candidates' run is thrown away unproven (and not counted as one
+    # that stood), the run without top-n answers
+    assert judged_runs() == {
+        ("fused", "posruns", "retry_grow_bucket"): 1,
+        ("fused", "posruns", "retry_topn_unproven"): 1,
+        ("fused", "posruns", "stands"): 1}
 
 
 def test_clustered_tracker():

@@ -2,8 +2,10 @@
 the benchmark's own data set and numpy references
 (benchmark/datasets/tpch.py, loaded by path), the store behind the wire
 server as `--serve` starts it, and one `dp` mesh of 4 made from the
-first four of the 8 forced host devices. Counts and answers here are
-correctness results, never device times."""
+first four of the 8 forced host devices; and `tpch-sf3-mesh4`'s
+relation at the same size: a shard larger than a one-chip row block
+(`shard_over_block`). Counts and answers here are correctness results,
+never device times."""
 import importlib.util
 import os
 
@@ -277,6 +279,162 @@ def test_one_device_counts_nothing(one_device):
     rows, grown = _q6(one_device, ["set tidb_enable_mpp = 0"])
     assert grown == {}
 
+
+# ---- tpch-sf3-mesh4: a shard larger than a row block (PR 34) -----------
+
+@pytest.fixture
+def shard_over_block(mesh4):
+    """The relation scale 3 has on the chips (a shard of 5,242,880 lanes
+    against a row block of 4,194,304): the 60,000-row lineitem buckets
+    to 65,536 lanes, 16,384 a shard, and the executor's row block is
+    set to half a shard."""
+    copr = mesh4.domain.copr
+    old, copr.device_rows = copr.device_rows, 8192
+    try:
+        yield mesh4
+    finally:
+        copr.device_rows = old
+
+
+def _traced(d, lowerings, sql, prepare=()):
+    """The statement with every span recorded -> (rows, the judged runs
+    it grew by (site, kind, verdict), its spans' attributes by name)."""
+    c = d.client()
+    try:
+        for q in prepare:
+            c.query(q)
+        c.query("set tidb_tpu_trace_sample_rate = 1")
+        d.domain.tracer.recorder.clear()
+        before = lowerings()
+        rows = c.query(sql)["rows"]
+        grown = lowerings(before)
+        spans = {}
+        for e in d.domain.tracer.recorder.events():
+            spans.setdefault(e.name, []).append(
+                dict(kv.split("=", 1) for kv in e.attrs.split(";") if kv))
+    finally:
+        c.close()
+    return rows, grown, spans
+
+
+MESH_KIND = {"q1": "dense", "q6": "dense", "q5": "posdense",
+             "q3": "sort_runs", "q10": "sort_runs", "q18": "sort_runs"}
+
+
+@pytest.mark.parametrize("stmt", ["q1", "q3", "q5", "q6", "q10", "q18"])
+def test_a_shard_larger_than_a_row_block_runs_whole(
+        shard_over_block, runs_policy, judged_runs, stmt):
+    """The mesh program takes the shard whole, under the chip's
+    lowering policy: the reference's answer, no degrade, one dispatch
+    of a shard's lanes, and one `stands` a judged run once the sizes
+    are learned."""
+    d = shard_over_block
+    dom = d.domain
+    _traced(d, judged_runs, d.ds.STATEMENTS[stmt])    # learns the sizes
+    before = dict(dom.metrics)
+    rows, grown, spans = _traced(d, judged_runs, d.ds.STATEMENTS[stmt])
+    assert not d.ds.answer_wrong(rows, d.ds.reference(d.tables, stmt))
+    assert not any(dom.metrics.get(k, 0) - before.get(k, 0)
+                   for k in DEGRADE)
+    assert set(grown) == {("fused_mpp", MESH_KIND[stmt], "stands")}, grown
+    route = spans["mpp_dispatch"]
+    assert len(route) == sum(grown.values())
+    for attrs in route:
+        assert attrs["lanes"] == "16384"
+        assert int(attrs["lanes"]) > dom.copr.device_rows
+    judged = [a for a in spans["consume"] if "verdict" in a]
+    assert [(a["lowering"], a["verdict"], a["retries"]) for a in judged] \
+        == [(MESH_KIND[stmt], "stands", "0")] * len(route)
+
+
+def _fresh_table(d):
+    """2,000 rows whose `k` is sparse (no dense layout) and rises with
+    the primary key (a run a row): the per-DAG engine's shape."""
+    c = d.client()
+    try:
+        c.query("create table if not exists low_t (id int primary key, "
+                "k bigint, g int, v int)")
+        if c.query("select count(*) from low_t")["rows"][0][0] == "0":
+            c.query("insert into low_t values " + ",".join(
+                f"({i},{i * 1000003},{i % 5},{i})" for i in range(1, 2001)))
+    finally:
+        c.close()
+
+
+# a statement a site; `{n}` makes the shape one no other test has taught
+SITES = {
+    "fused": ("one_device", "select l_suppkey, sum(l_linenumber + {n}) "
+              "from lineitem group by l_suppkey"),
+    "fused_mpp": ("mesh4", "select l_suppkey, sum(l_linenumber + {n}) "
+                  "from lineitem group by l_suppkey"),
+    "dag": ("one_device", "select k, sum(v + {n}) from low_t group by k"),
+}
+
+
+@pytest.mark.parametrize("site", sorted(SITES))
+@pytest.mark.parametrize("reason", ["grow_bucket", "pin_sorted"])
+def test_a_forced_retry_is_named_by_its_reason(
+        request, runs_policy, monkeypatch, judged_runs, site, reason):
+    """Keys that do not cluster give a run a row: more partials than
+    the first bucket of GROUP_BUCKET_MIN (the bucket grows), and, with
+    the floor of `runs_degraded` out of the way, more than half the
+    rows (the shape is pinned to the sorted lowering). Each thrown-away
+    run is counted under its reason, the run that stands under
+    `stands`, on the site whose kernel ran."""
+    where, sql = SITES[site]
+    d = request.getfixturevalue(where)
+    _fresh_table(d)
+    if reason == "pin_sorted":
+        monkeypatch.setattr(al, "RUNS_DEGRADE_MIN", 0)
+    n = 3400 + sorted(SITES).index(site) * 2 + (reason == "pin_sorted")
+    prepare = ["set tidb_tpu_fragment_min_rows = 0"]
+    rows, grown, spans = _traced(d, judged_runs, sql.format(n=n), prepare)
+    assert len(rows) == (2000 if site == "dag" else 100)
+    want = {(site, "sort_runs", "retry_grow_bucket"): 1,
+            (site, "sort_runs", "stands"): 1}
+    if reason == "pin_sorted":
+        want = {(site, "sort_runs", "retry_pin_sorted"): 1,
+                (site, "sort_sorted", "stands"): 1}
+        if site == "dag":       # 2,000 groups against a bucket of 1,024
+            want[(site, "sort_sorted", "retry_grow_bucket")] = 1
+    assert grown == want, grown
+    # the open `consume` span carries the last verdict and the count
+    last = [a for a in spans["consume"] if "verdict" in a][-1]
+    assert last["verdict"] == "stands" and \
+        last["retries"] == str(sum(grown.values()) - 1)
+    # learned: the next run stands at once
+    _rows, grown, _spans = _traced(d, judged_runs, sql.format(n=n), prepare)
+    assert list(grown.values()) == [1] and \
+        list(grown)[0][2] == "stands", grown
+
+
+def test_the_per_dag_mesh_program_has_no_verdict(mesh4, judged_runs):
+    """`_try_execute_mpp` is dense or not at all: no lowering judges
+    it, so nothing is counted; its shard's lanes are on the route's
+    span."""
+    _fresh_table(mesh4)
+    _rows, grown, spans = _traced(
+        mesh4, judged_runs, "select g, sum(v + 3410) from low_t group by g",
+        ["set tidb_tpu_fragment_min_rows = 0"])
+    assert grown == {}, grown
+    (route,) = spans["mpp_dispatch"]
+    assert route["lanes"] == "512"                          # 2,048 / 4
+
+
+def test_shard_lanes_is_the_bucket_split_evenly():
+    from tidb_tpu.chunk.device import shape_bucket, shard_lanes
+    # scale 3: 18,003,645 rows -> 20,971,520 lanes, 5,242,880 a shard
+    assert shape_bucket(18_003_645) == 20_971_520
+    assert shard_lanes(18_003_645, 4) == (20_971_520, 5_242_880)
+    assert shard_lanes(6_001_215, 4) == (6_291_456, 1_572_864)
+    assert shard_lanes(60_000, 4) == (65_536, 16_384)
+    # a bucket that is no lane multiple is rounded up to one
+    padded, local = shard_lanes(1000, 3)
+    assert padded % (128 * 3) == 0 and local * 3 == padded >= 1000
+
+
+# last: it changes the mesh deployment's lineitem, and with it what the
+# references above were computed from
 
 def test_an_acknowledged_insert_is_seen_by_the_next_q6_on_the_mesh(mesh4):
     """The configuration's isolation guarantee: a read sees every write

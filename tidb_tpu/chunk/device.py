@@ -150,6 +150,23 @@ def shape_bucket(n: int) -> int:
     return 2 * p
 
 
+def shard_lanes(n: int, ndev: int):
+    """-> (padded, local): the lanes a mesh program over an n-row table
+    holds in all and a shard (a device). The one rule both mesh sites
+    take (copr/pipeline._run_fused_mpp, copr/dag_exec._try_execute_mpp):
+    the table's whole bucket in ONE program, `shape_bucket(n)` rounded
+    up to a lane multiple (128 a device) and split evenly. Bucketed, not
+    an exact lane multiple, so that the sharded buffers and the kernel's
+    shape survive appends within a bucket and the delta maintainer can
+    tail-patch them on the mesh (copr/delta.py). Nothing caps `local`:
+    the one-chip row block (`CoprExecutor.device_rows`) is not consulted
+    (docs/PERFORMANCE.md "Processes and chips" has the largest shard
+    that has run on chips)."""
+    lane = 128 * ndev
+    padded = ((shape_bucket(n) + lane - 1) // lane) * lane
+    return padded, padded // ndev
+
+
 class StringDict:
     """Per-column string dictionary: code <-> str, append-only."""
 

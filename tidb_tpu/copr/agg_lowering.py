@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from ..chunk.device import shape_bucket
 from ..expression import EvalCtx, eval_expr
 from ..expression.vec import materialize_nulls
+from ..utils import metrics as _metrics, tracing as _tracing
 from ..utils.fetch import prefetch, host_array
 
 _I64_MAX = np.iinfo(np.int64).max
@@ -194,6 +195,19 @@ def _compact_verdict(state, which, ccap, nvalid, denom):
     return None
 
 
+def judged(site, kind, param, verdict):
+    """One device run of an aggregation program, judged: a count in
+    `tidb_tpu_agg_lowering_total{site, kind, verdict}` and the same on
+    the open `consume` span. site: the `Lowering`'s; kind: the lowering
+    that ran, the sort kind with its segment impl ("sort_runs",
+    "sort_sorted"; the CPU's "sort_scatter"); verdict: "stands" or why
+    the run is thrown away and run again ("retry_<reason>")."""
+    if kind == "sort":
+        kind = "sort_" + param[1]
+    _metrics.AGG_LOWERING.labels(site, kind, verdict).inc()
+    _tracing.tag(lowering=kind, verdict=verdict)
+
+
 # ---- the decision -----------------------------------------------------
 
 class Lowering:
@@ -204,9 +218,10 @@ class Lowering:
     pos_spec: the fused pipeline's (group_map, pos_dims, nslots) or
     None. sizes: the dense layout, None, or a callable asked only when
     no position domain stands (it may cost a host pass). site: whose
-    kernels — "fused" (one chip: every kind), "mesh" (no posruns,
-    one-hot, top-n, early compaction), "dag" (per-DAG: dense or sort,
-    no compaction). topn: the validated (kind, index, desc, k)."""
+    kernels, and the `site` of the runs `judged` — "fused" (one chip:
+    every kind), "fused_mpp" (the mesh: no posruns, one-hot, top-n,
+    early compaction), "dag" (per-DAG: dense or sort, no compaction).
+    topn: the validated (kind, index, desc, k)."""
 
     __slots__ = ("state", "pos", "posruns", "sizes", "site", "dims",
                  "topn")
@@ -306,34 +321,56 @@ class Lowering:
         return kind, param, ecap
 
     def observe(self, kind, param, ecap, cap, rows, ngroups=None,
-                nvalid=None, fnvalid=None):
+                nvalid=None, fnvalid=None, hold=False):
         """Does a run stand? -> "retry": run the partition again with
         what this run taught (already in the state); None: consume it.
         rows / cap: the partition's; fnvalid: the fact filter's
         survivors BEFORE any compaction loss; nvalid: survivors at the
-        aggregation; ngroups: partials — None where not reported."""
+        aggregation; ngroups: partials — None where not reported.
+        Every device run of an aggregation program comes here once and
+        is counted once (`judged`) under its verdict. hold: a run that
+        stands by its sizes is not counted yet, because its consumer
+        can still throw it away: the consumer owes one `settle`."""
+        why = self._run_again(kind, param, ecap, cap, rows, ngroups,
+                              nvalid, fnvalid)
+        if why or not hold:
+            judged(self.site, kind, param, why or "stands")
+        return "retry" if why else None
+
+    def settle(self, kind, param, why=None):
+        """The count of a run `observe` held: it stands, or is thrown
+        away after all by what only its consumer sees (`why`:
+        "onehot_miss", the learned slot table lacks a key;
+        "topn_unproven", the candidates cannot be shown to cover the
+        top k)."""
+        judged(self.site, kind, param, "retry_" + why if why else "stands")
+
+    def _run_again(self, kind, param, ecap, cap, rows, ngroups, nvalid,
+                   fnvalid):
+        """-> why the run must be repeated (the verdict's name), with
+        the lesson already in the state; None: it stands."""
         st = self.state
         if fnvalid is not None and _compact_verdict(
                 st, "early_compact", ecap, fnvalid, cap):
-            return "retry"
+            return "retry_early_compact"
         if kind not in ("sort", "posruns"):
             return None
         if nvalid is not None and _compact_verdict(
                 st, "compact", param[3], nvalid, cap):
-            return "retry"
+            return "retry_compact"
         if (kind == "posruns" or param[1] == "runs") and \
                 runs_degraded(ngroups, rows):
             # unclustered group keys: pin this shape to the sorted
             # lowering (one partial per group) before the bucket
             # learns the inflated count
             st.pin = "sorted"
-            return "retry"
+            return "retry_pin_sorted"
         if ngroups > param[0]:
             # against the bucket THIS kernel was built with, not one
             # grown since by another partition: an overflowed run
             # truncated its key/state buffers
             st.grow_bucket(ngroups)
-            return "retry"
+            return "retry_grow_bucket"
         return None
 
     def onehot_learnable(self, group_items, aggs, one, delta) -> bool:

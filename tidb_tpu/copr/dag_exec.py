@@ -37,7 +37,7 @@ from ..utils import device_guard
 from ..utils import metrics as _metrics
 from ..utils import tracing as _tracing
 from ..errors import TiDBError
-from ..chunk.device import shape_bucket
+from ..chunk.device import shape_bucket, shard_lanes
 from ..chunk.column import Column
 from ..chunk.chunk import Chunk
 
@@ -105,8 +105,14 @@ class CoprExecutor:
         if device_rows is None:
             # partition size (rows per jit call): every partition
             # costs a fixed dispatch + fetch, so fewer/bigger
-            # partitions win until HBM pressure. 4M rows has not been
-            # measured on this chip (ROADMAP D2, S4)
+            # partitions win until HBM pressure. 4,194,304 rows is what
+            # every chip run since PR 24 measured (SF1 as two blocks,
+            # scale 3 as five: PERF.md sections 5 and 6); no other size
+            # has been (ROADMAP D2, S4). It bounds a one-chip program
+            # only: the mesh route takes a shard whole
+            # (chunk.device.shard_lanes), 5,242,880 lanes at scale 3 on
+            # four chips (PR 34; docs/PERFORMANCE.md "Processes and
+            # chips")
             device_rows = int(os.environ.get("TIDB_TPU_DEVICE_ROWS",
                                              str(1 << 22)))
         self.device_rows = device_rows
@@ -319,10 +325,12 @@ class CoprExecutor:
                 # supervised mesh dispatch: retryable classes retry with
                 # backoff, anything else degrades to None so the
                 # single-chip path (which always works) takes over
+                ndev = int(mesh.devices.size)
                 with _tracing.span("mpp_dispatch",
                                    table=dag.table_info.name, rows=n,
-                                   ndev=int(mesh.devices.size),
-                                   exchange="passthrough", kind="dense"):
+                                   ndev=ndev, exchange="passthrough",
+                                   kind="dense",
+                                   lanes=shard_lanes(n, ndev)[1]):
                     res = device_guard.guarded_dispatch(
                         lambda: self._try_execute_mpp(dag, tbl, arrays,
                                                       valid, n, handles,
@@ -732,15 +740,7 @@ class CoprExecutor:
             # the caller falls through to the single-chip path
             return _NO_DENSE_LAYOUT
         ndev = int(mesh.devices.size)
-        lane = 128 * ndev
-        # BUCKETED lane-multiple padding (was an exact lane multiple):
-        # residency + delta maintenance need the padded capacity — and
-        # with it the compiled kernel shape and the buffer keys — to
-        # survive appends within a bucket, so a steady write stream
-        # tail-patches the sharded buffers instead of re-keying them
-        # every `lane` rows
-        padded = ((shape_bucket(n) + lane - 1) // lane) * lane
-        local = padded // ndev
+        padded, local = shard_lanes(n, ndev)
         cols = cols_full
         names = sorted(cols.keys())
         # cache by STORAGE column id, never plan column idx: idxs are
@@ -973,13 +973,14 @@ class CoprExecutor:
             res = prefetch(kern(jc, vv))
             with _tracing.span("consume", retries=retries,
                                **phase.part_attrs()):
-                if kind == "dense":
-                    return compact_dense(dag, res, low.sizes, kd, sd)
-                ngroups = host_int(res["ngroups"])
+                ngroups = None if kind == "dense" else \
+                    host_int(res["ngroups"])
                 if low.observe(kind, param, None, cap, m,
                                ngroups=ngroups) == "retry":
                     retries += 1
                     continue
+                if kind == "dense":
+                    return compact_dense(dag, res, low.sizes, kd, sd)
                 return PartialAggResult(
                     ngroups=ngroups,
                     keys=[host_array(k)[:ngroups] for k in res["keys"]],

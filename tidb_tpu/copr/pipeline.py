@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 from ..expression import EvalCtx, eval_expr, eval_bool_mask
 from ..expression.vec import materialize_nulls
-from ..chunk.device import shape_bucket
+from ..chunk.device import shape_bucket, shard_lanes
 from . import agg_lowering as _al
 from . import dimfold
 from .agg_lowering import (PartialAggResult, capture_agg_dicts,
@@ -1795,7 +1795,8 @@ def fused_partials(copr, plan, read_ts, mesh=None,
 
     low = _al.Lowering(
         st, _pos_group_map(plan, dim_metas), _dense_sizes,
-        site="fused" if mesh is None else "mesh", dims=bool(plan.dims),
+        site="fused" if mesh is None else "fused_mpp",
+        dims=bool(plan.dims),
         topn=None if mesh is not None else
         _fused_topn_state(plan, fact_tbl, st, kd, sd))
 
@@ -1940,9 +1941,13 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         res, cap, agg_kind, agg_param, ecap, oh_table = state
         runs_like = agg_kind in ("sort", "posruns")
         ngroups = host_int(res["ngroups"]) if runs_like else None
+        # a one-hot run can still miss a key, a top-n run its proof:
+        # those are counted (`settle`) where that is known
+        held = agg_kind == "onehot" or \
+            (runs_like and agg_param[2] is not None)
         if low.observe(agg_kind, agg_param, ecap, cap, m, ngroups,
                        host_int(res["nvalid"]) if runs_like else None,
-                       host_int(res["fnvalid"])) == "retry":
+                       host_int(res["fnvalid"]), hold=held) == "retry":
             return False
         if agg_kind == "posdense":
             out.append(_compact_pos_dense(plan, res, low.pos[0],
@@ -1956,8 +1961,10 @@ def fused_partials(copr, plan, read_ts, mesh=None,
                 # new/changed keys since the table was learned:
                 # fall back to the sorted lowering and relearn
                 _count("fused_onehot_miss")
+                low.settle(agg_kind, agg_param, "onehot_miss")
                 del st.onehot
                 return False
+            low.settle(agg_kind, agg_param)
             OH = oh_table
             _count("fused_onehot_agg")
             acc = host_array(res["oh_acc"])
@@ -1998,7 +2005,9 @@ def fused_partials(copr, plan, read_ts, mesh=None,
                     # boundary ties could hide true top-k members:
                     # permanently disable topn for this query shape
                     st.topn_off = True
+                    low.settle(agg_kind, agg_param, "topn_unproven")
                     return False
+            low.settle(agg_kind, agg_param)
             _emit(posruns, ncand, ckeys, cnulls, ckd, cstates)
             return True
         ks, kns, kds = _host_keys(res, posruns, agg_param[1], ngroups)
@@ -2207,13 +2216,7 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
     from ..mpp.exec import exchange_observed, tree_nbytes
     from .delta import append_key
     ndev = int(mesh.devices.size)
-    lane = 128 * ndev
-    # BUCKETED lane-multiple padding (was an exact lane multiple): the
-    # sharded fact buffers and their kernel shape must survive appends
-    # within a bucket so the delta maintainer can tail-patch them
-    # on-mesh instead of re-keying every `lane` rows (copr/delta.py)
-    padded = ((shape_bucket(n) + lane - 1) // lane) * lane
-    local = padded // ndev
+    padded, local = shard_lanes(n, ndev)
     with phase.bind_span():
         cols = copr._bind_cols(plan.fact_dag, fact_tbl, fact_arrays,
                                slice(0, n), handles)
@@ -2268,25 +2271,27 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
         # ships per-shard partials to the coordinator in one fetch
         exchange_observed("passthrough", tree_nbytes(res))
         # on the route's own span (executors.FusedPipelineExec opens it)
-        _tracing.tag(exchange="passthrough", kind=agg_kind)
+        _tracing.tag(exchange="passthrough", kind=agg_kind, lanes=local)
         # shards: whose partials the host merges (1: the mesh merged
         # them, psum); merged_groups: how many partial groups that is
         with _tracing.span("consume", retries=retries, shards=1,
                            merged_groups=0) as csp:
+            # the verdict is the fullest shard's: every shard runs the
+            # one program (the psum-merged kinds report no sizes)
+            ngroups = nvalid = None
+            if agg_kind == "sort":
+                ngroups_arr = host_array(res["ngroups"])     # [ndev]
+                ngroups = int(ngroups_arr.max())
+                nvalid = int(host_array(res["nvalid"]).max())
+            if low.observe(agg_kind, agg_param, None, local, local,
+                           ngroups, nvalid) == "retry":
+                retries += 1
+                continue
             if agg_kind == "posdense":
                 return [_compact_pos_dense(plan, res, low.pos[0],
                                            low.pos[1], dim_metas, sd)]
             if agg_kind == "dense":
                 return [compact_dense(shim, res, low.sizes, kd, sd)]
-            ngroups_arr = host_array(res["ngroups"])     # [ndev]
-            # the verdict is the fullest shard's: every shard runs the
-            # one program
-            if low.observe(agg_kind, agg_param, None, local, local,
-                           int(ngroups_arr.max()),
-                           int(host_array(res["nvalid"]).max())) \
-                    == "retry":
-                retries += 1
-                continue
             group_bucket = agg_param[0]
             ident = _ident_items(plan)
             # unstack the per-shard partials

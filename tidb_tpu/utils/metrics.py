@@ -1014,6 +1014,18 @@ def agg_merges(since=None) -> dict:
         for _name, lb, v in AGG_MERGE.sample_rows()) if n}
 
 
+AGG_LOWERING = REGISTRY.counter(
+    "tidb_tpu_agg_lowering_total",
+    "Device runs of an aggregation program judged by the lowering "
+    "(copr/agg_lowering.py), by whose kernel ran (site: fused | fused_mpp "
+    "| dag), the lowering that ran (kind: dense, posdense, "
+    "posruns, sort_<segment impl>, onehot) and the verdict: stands, or "
+    "why the run was thrown away and run again (retry_early_compact, "
+    "retry_compact, retry_pin_sorted, retry_grow_bucket, "
+    "retry_onehot_miss, retry_topn_unproven)",
+    ("site", "kind", "verdict"))
+
+
 FUSED_PIPELINE = REGISTRY.counter(
     "tidb_tpu_fused_pipeline_total",
     "Fused-pipeline executions by outcome", ("outcome",))
