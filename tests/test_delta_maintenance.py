@@ -9,6 +9,7 @@ import pytest
 
 import jax
 
+from tidb_tpu.chunk.device import shape_bucket
 from tidb_tpu.testkit import TestKit
 from tidb_tpu.utils import metrics as mu
 from tidb_tpu.utils import phase
@@ -68,8 +69,11 @@ class TestAppendFold:
             # delta bytes are the REAL appended rows, tiny vs table
             assert ph.get("delta_bytes", 0) <= 8 * 8 * 4
         assert _outcome("applied") > applied0
-        # zero full re-uploads after warmup: every bind was a pool hit
-        assert mu.DEV_BUFFER_POOL.labels("miss").value == miss0
+        # zero full re-uploads after warmup: every column's bind was a
+        # pool hit; a new version's visibility mask is the one entry
+        # each of the four writes makes anew (one byte a lane, a pool
+        # miss since the mask is a store entry)
+        assert mu.DEV_BUFFER_POOL.labels("miss").value == miss0 + 4
         assert mu.DELTA_APPLY_BYTES.labels().value > 0
         assert mu.DELTA_REUPLOAD_AVOIDED_BYTES.labels().value > 0
 
@@ -102,8 +106,11 @@ class TestAppendFold:
         assert got == _expected(rows_kv[14:])
         assert _outcome("advanced") > adv0
         ph = phase.snap()
-        assert ph.get("uploads", 0) == 0
-        assert mu.DEV_BUFFER_POOL.labels("miss").value == miss0
+        # the one upload is the new version's visibility mask (the
+        # delete's marks): a bucket's lanes of one byte, no column
+        assert ph.get("uploads", 0) == 1
+        assert ph.get("upload_bytes", 0) == shape_bucket(2100)
+        assert mu.DEV_BUFFER_POOL.labels("miss").value == miss0 + 1
         # an UPDATE appends a new version row: patch, not re-upload
         tk.must_exec("update t set v = v + 1000000 where id = 20")
         phase.reset()
@@ -111,7 +118,7 @@ class TestAppendFold:
         exp = _expected(rows_kv[14:20] + [(20 % 7, 20 * 3 + 1000000)] +
                         rows_kv[21:])
         assert got == exp
-        assert mu.DEV_BUFFER_POOL.labels("miss").value == miss0
+        assert mu.DEV_BUFFER_POOL.labels("miss").value == miss0 + 2
 
     def test_bucket_crossing_falls_back_to_full_upload(self):
         """Growth past the padding bucket cannot patch: the entry is

@@ -48,6 +48,18 @@ _I64_MAX = np.iinfo(np.int64).max
 # aggregation: the caller runs it single-chip (None is a degraded run's)
 _NO_DENSE_LAYOUT = object()
 
+# _bind_keys' record of a row block's visibility mask, beside the
+# columns' (theirs are under the plan column's idx)
+_MASK = "valid"
+
+
+@jax.jit
+def tidb_mask_copy(vv):
+    """A fresh device buffer holding `vv`: what a program that donates
+    its mask operand gets of a resident mask (_mask_operand). The
+    `def` carries the program's name (jaxcfg.name_program)."""
+    return jnp.copy(vv)
+
 
 class _KernelCache(dict):
     """Compiled-kernel cache with hit/miss counters (reference
@@ -440,12 +452,21 @@ class CoprExecutor:
 
     # ---- shared prep --------------------------------------------------
     def _bind_cols(self, dag, tbl, arrays, part_slice, handles,
-                   cacheable=False):
+                   cacheable=False, valid=None):
         """-> cols mapping plan-col-idx -> (np data, np nulls, dict).
         When cacheable, also records device-cache keys per column in
-        self._bind_keys (cache valid only for pristine table arrays)."""
+        self._bind_keys (cache valid only for pristine table arrays),
+        and under _MASK the block's of `valid` where that is the
+        table version's kept visibility mask (version_mask: not a mask
+        made for one snapshot, nor one a statement laid its rows
+        over)."""
         cols = {}
         self._bind_keys = {}
+        if cacheable and valid is not None:
+            mver = tbl.version_mask(valid)
+            if mver is not None:
+                self._bind_keys[_MASK] = (tbl.uid, tbl.gc_epoch,
+                                          part_slice.start, mver)
         for sc in dag.cols:
             cid = self._cid(dag, sc)
             if cid == -1:
@@ -540,7 +561,7 @@ class CoprExecutor:
             part = start // step
             with phase.bind_span():
                 cols = self._bind_cols(dag, tbl, arrays, sl, handles,
-                                       cacheable=(n == tbl.n))
+                                       cacheable=(n == tbl.n), valid=valid)
             v = valid[sl]
             if dag.aggs or dag.group_items:
                 res = device_guard.guarded_dispatch(
@@ -617,16 +638,56 @@ class CoprExecutor:
                 if len(d) != cap:
                     d = np.concatenate([d, np.zeros(cap - m, dtype=d.dtype)])
                 jd = jnp.asarray(d)
+                phase.add("upload_bytes", d.nbytes)
                 jn = None
                 if nulls is not None:
                     nl = np.concatenate(
                         [nulls, np.ones(cap - m, dtype=bool)]) \
                         if len(nulls) != cap else nulls
                     jn = jnp.asarray(nl)
+                    phase.add("upload_bytes", nl.nbytes)
             jcols[k] = (jd, jn, sdict)
+        mk = bind_keys.get(_MASK)
+        if mk is not None:
+            return jcols, self._mask_operand(mk, v, cap)
+        # the statement's own mask (a snapshot older than the table's
+        # newest timestamp, a transaction's overlay, its delta
+        # partition): scratch of this dispatch, and bytes the
+        # statement uploads
         vv = np.concatenate([v, np.zeros(cap - m, dtype=bool)]) \
             if len(v) != cap else v
+        phase.add("upload_bytes", vv.nbytes)
         return jcols, jnp.asarray(vv)
+
+    def _mask_operand(self, mk, v, cap):
+        """A row block's slice `v` of the table version's visibility
+        mask as a program's operand: resident with the block's columns
+        under the block's own key (uid, epoch, block start, version,
+        cap; dropped by invalidate(uid, version) like every versioned
+        entry), so a statement over an unchanged version pads and
+        uploads nothing. The one-chip programs donate their mask
+        operand (per-dispatch scratch they may overwrite), so where
+        donation is on they get a copy made on the device, never the
+        store's buffer."""
+        uid, epoch, start, ver = mk
+        dev = self._dev_put((uid, "fragv", epoch, start, ver, cap), v,
+                            pad_fill=False, uid=uid, version=ver)
+        if not jaxcfg.donation_enabled():
+            return dev
+        # tpulint: disable=unguarded-dispatch — inside the partition's
+        # supervised dispatch (_run_*_partition under guarded_dispatch;
+        # fused_partials under executors.FusedPipeline's)
+        return tidb_mask_copy(dev)
+
+    @staticmethod
+    def _whole_mask_key(tbl, valid, read_ts):
+        """(version, read_ts) to key a resident copy of the whole
+        visibility mask `valid` by: the kept mask of a table version
+        is every snapshot's at or past its newest timestamp (one entry
+        a version, under the version it was stamped with); any other
+        is its own snapshot's."""
+        mver = tbl.version_mask(valid)
+        return (tbl.version, read_ts) if mver is None else (mver, None)
 
     def _get_mesh(self):
         import jax
@@ -773,10 +834,11 @@ class CoprExecutor:
             # read_ts) it is immutable, so it stays resident too — the old
             # raw device_put here was an uncounted warm re-upload per
             # statement
+            mver, mts = self._whole_mask_key(tbl, valid, read_ts)
             args.append(self._dev_put_sharded(
-                (tbl.uid, "mppvalid", tbl.version, read_ts, ndev, padded),
+                (tbl.uid, "mppvalid", mver, mts, ndev, padded),
                 valid[:n], mesh, padded, pad_fill=False, uid=tbl.uid,
-                version=tbl.version))
+                version=mver))
         key = self._cache_key(dag, tbl, "mpp", padded,
                               (tuple(strides), ndev,
                                tuple(sorted(has_nulls.items()))))

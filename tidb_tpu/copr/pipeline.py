@@ -1838,7 +1838,8 @@ def fused_partials(copr, plan, read_ts, mesh=None,
             with phase.bind_span():
                 pcols = copr._bind_cols(plan.fact_dag, fact_tbl,
                                         fact_arrays, sl, handles,
-                                        cacheable=(n == fact_tbl.n))
+                                        cacheable=(n == fact_tbl.n),
+                                        valid=fact_valid)
             # capture this partition's device-cache keys: the pipelined
             # loop dispatches the NEXT partition (overwriting
             # copr._bind_keys) before this one's consume-time retries
@@ -2243,10 +2244,11 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
         # the fact validity mask is (version, read_ts)-immutable: residency
         # (same contract as the sharded columns above) instead of a raw
         # device_put, which re-uploaded it warm on every statement
+        mver, mts = copr._whole_mask_key(fact_tbl, fact_valid, read_ts)
         fvv = copr._dev_put_sharded(
-            (fact_tbl.uid, "mppfv", ver, read_ts, ndev, padded),
+            (fact_tbl.uid, "mppfv", mver, mts, ndev, padded),
             fact_valid[:n], mesh, padded, pad_fill=False, uid=fact_tbl.uid,
-            version=ver)
+            version=mver)
     retries = 0     # re-dispatches the learned lowering forced
     while True:
         agg_kind, agg_param, _ecap = low.choose(local)

@@ -21,6 +21,8 @@ from ..chunk.device import StringDict
 from ..codec.tablecodec import decode_record_key, TABLE_PREFIX, RECORD_PREFIX_SEP
 from ..codec.codec import decode_row_value
 from ..types.field_type import TypeClass
+from ..utils import metrics as _metrics
+from ..utils import phase
 
 
 _CTAB_UID = [0]
@@ -31,6 +33,32 @@ def _is_big_decimal(ft) -> bool:
     # scale > 18 cannot ride the scaled-int64 fast path; precision <= 38
     # with small scale keeps int64 (the documented money-scale trade)
     return ft.tclass == TypeClass.DECIMAL and max(ft.decimal, 0) > 18
+
+
+class _VersionFacts:
+    """What every reader of one table version sees alike, kept with the
+    table so that a statement over an unchanged version computes
+    nothing proportional to the table's rows (docs/PERFORMANCE.md
+    "Incremental HTAP"): the read-latest visibility mask of rows
+    [0, n), read-only; the newest insert/delete timestamp those rows
+    hold (a snapshot at or past it sees exactly that mask); whether a
+    column holds any NULL in them (filled in by the first reader of the
+    column). Stamped with the `version` read BEFORE any of it was
+    computed — a commit that lands meanwhile leaves the facts claiming
+    an older version than they cover, which costs the next reader one
+    build and never serves it rows the facts did not see — and with
+    the `n` and `gc_epoch` they cover."""
+
+    __slots__ = ("version", "n", "gc_epoch", "valid", "newest_ts",
+                 "any_null")
+
+    def __init__(self, version, n, gc_epoch, valid, newest_ts):
+        self.version = version
+        self.n = n
+        self.gc_epoch = gc_epoch
+        self.valid = valid
+        self.newest_ts = newest_ts
+        self.any_null: dict[int, bool] = {}
 
 
 class ColumnarTable:
@@ -73,6 +101,9 @@ class ColumnarTable:
         # vector_matrix(); gc() compaction resets it — positions move)
         self._vecmat: dict = {}
         self._vecmat_mu = threading.Lock()
+        # the newest version's snapshot facts, built by its first
+        # reader (never by a writer) and replaced whole: _facts_at
+        self._facts: _VersionFacts | None = None
         self._init_columns()
 
     def _init_columns(self):
@@ -179,7 +210,11 @@ class ColumnarTable:
         """Insert/overwrite one row; an existing version is closed at
         commit_ts and a new version row appended. Row data is fully
         written BEFORE self.n is bumped so concurrent snapshot readers
-        never see a half-written row."""
+        never see a half-written row. max_commit_ts rises BEFORE the
+        first array write: a reader that saw any of this commit in the
+        arrays reads a max_commit_ts that covers it (_facts_at)."""
+        if commit_ts > self.max_commit_ts:
+            self.max_commit_ts = commit_ts
         old = self.handle_pos.get(handle)
         if old is not None and self.delete_ts[old] == 0:
             self.delete_ts[old] = commit_ts
@@ -225,16 +260,14 @@ class ColumnarTable:
         self.n = pos + 1
         self.handle_pos[handle] = pos
         self.version += 1
-        if commit_ts > self.max_commit_ts:
-            self.max_commit_ts = commit_ts
 
     def delete_row(self, handle: int, commit_ts: int = 1):
         pos = self.handle_pos.get(handle)
         if pos is not None and self.delete_ts[pos] == 0:
+            if commit_ts > self.max_commit_ts:      # before the mark,
+                self.max_commit_ts = commit_ts      # as in put_row
             self.delete_ts[pos] = commit_ts
             self.version += 1
-            if commit_ts > self.max_commit_ts:
-                self.max_commit_ts = commit_ts
 
     def bulk_append(self, columns: dict, n: int, handles=None,
                     commit_ts: int = 1, nulls=None):
@@ -244,13 +277,13 @@ class ColumnarTable:
         otherwise dense."""
         self._ensure(n)
         start = self.n
+        if commit_ts > self.max_commit_ts:
+            self.max_commit_ts = commit_ts
         if handles is None:
             handles = np.arange(start + 1, start + n + 1, dtype=np.int64)
         self.handles[start:start + n] = handles
         self.insert_ts[start:start + n] = commit_ts
         self.delete_ts[start:start + n] = 0
-        if commit_ts > self.max_commit_ts:
-            self.max_commit_ts = commit_ts
         self._hpos = None     # rebuilt lazily on first point access: a
         # bulk load of N rows must not pay N Python dict inserts when
         # the workload never point-reads the table
@@ -342,30 +375,90 @@ class ColumnarTable:
     def live_count(self) -> int:
         return int((self.delete_ts[:self.n] == 0).sum())
 
-    def valid_at(self, read_ts: int | None = None, n: int | None = None
-                 ) -> np.ndarray:
-        """MVCC visibility mask: inserted at-or-before read_ts and not yet
-        deleted at read_ts (read_ts None = read latest)."""
+    def _facts_at(self, read_ts, n):
+        """-> (the version's kept facts when they answer a snapshot of
+        rows [0, n) at read_ts, else None; the snapshot's visibility
+        mask). One count a call of tidb_tpu_snapshot_facts_total (and
+        of the statement's phase counters, which the open `bind` span
+        reads): `hit`; `build` (no facts of this version yet: this
+        reader makes them, at the price every read paid before);
+        `bypass_read_ts` (a snapshot older than the table's newest
+        timestamp sees other rows); `bypass_overlay` (rows other than
+        the table's own n: a reader that captured n before an append).
+        What decides is what can be observed here — version, n, gc
+        epoch, read_ts — and nothing else; a bypassed reader computes
+        what it always did."""
+        version = self.version      # BEFORE n and before the arrays
         if n is None:
             n = self.n
+        f = self._facts
+        if f is not None and (f.version != version or
+                              f.gc_epoch != self.gc_epoch):
+            f = None
+        if read_ts is not None and read_ts < (
+                self.max_commit_ts if f is None else f.newest_ts):
+            outcome, f = "bypass_read_ts", None
+        elif n != (self.n if f is None else f.n):
+            outcome, f = "bypass_overlay", None
+        elif f is not None:
+            outcome = "hit"
+        else:
+            outcome = "build"
+            epoch = self.gc_epoch
+            valid = self.delete_ts[:n] == 0
+            valid.flags.writeable = False
+            # max_commit_ts AFTER the mask: writers raise it before
+            # they touch the arrays, so it covers whatever the mask saw
+            f = self._facts = _VersionFacts(version, n, epoch, valid,
+                                            self.max_commit_ts)
+        _metrics.SNAPSHOT_FACTS.labels(outcome).inc()
+        phase.note_facts(outcome)
+        if f is not None:
+            return f, f.valid
         ins = self.insert_ts[:n]
         dele = self.delete_ts[:n]
         if read_ts is None:
-            return dele == 0
-        return (ins <= read_ts) & ((dele == 0) | (dele > read_ts))
+            return None, dele == 0
+        return None, (ins <= read_ts) & ((dele == 0) | (dele > read_ts))
+
+    def valid_at(self, read_ts: int | None = None, n: int | None = None
+                 ) -> np.ndarray:
+        """MVCC visibility mask: inserted at-or-before read_ts and not yet
+        deleted at read_ts (read_ts None = read latest). The kept mask
+        of the version (read-only: copy before writing) where it
+        answers, _facts_at."""
+        return self._facts_at(read_ts, n)[1]
+
+    def version_mask(self, valid) -> int | None:
+        """The version whose kept visibility mask `valid` is (the
+        object itself, as snapshot / valid_at handed it out), else
+        None: what a derived copy of the whole mask — a device buffer —
+        may be keyed by. A mask computed for one snapshot, or one a
+        statement has laid its own rows over, is nobody's."""
+        f = self._facts
+        return f.version if f is not None and f.valid is valid else None
 
     def snapshot(self, col_ids: list, read_ts: int | None = None):
         """-> (arrays dict col_id -> (data, nulls|None, dict|None), valid).
         Captures self.n ONCE so concurrent appends can't produce
         inconsistent column lengths (copy-on-read consistency: rows below
-        the captured n are immutable apart from delete marks)."""
-        n = self.n
-        valid = self.valid_at(read_ts, n)
+        the captured n are immutable apart from delete marks). Over an
+        unchanged version, at or past its newest timestamp, nothing here
+        is proportional to the rows: the mask and each column's
+        has-a-NULL are the version's kept facts."""
+        f, valid = self._facts_at(read_ts, None)
+        n = len(valid)
         out = {}
         for cid in col_ids:
             arr = self.data[cid][:n]
             nl = self.nulls[cid][:n]
-            out[cid] = (arr, nl if nl.any() else None, self.dicts.get(cid))
+            if f is None:
+                has_null = nl.any()
+            else:
+                has_null = f.any_null.get(cid)
+                if has_null is None:
+                    has_null = f.any_null[cid] = bool(nl.any())
+            out[cid] = (arr, nl if has_null else None, self.dicts.get(cid))
         return out, valid
 
     def handle_array(self):

@@ -1005,13 +1005,19 @@ AGG_MERGE = REGISTRY.counter(
     ("path",))
 
 
+def _moved(counter, label, since=None) -> dict:
+    """-> {label value: count} of a one-label counter's samples that
+    have moved, since an earlier reading of this when one is given."""
+    since = since or {}
+    return {k: n for k, n in (
+        (lb[label], int(v) - since.get(lb[label], 0))
+        for _name, lb, v in counter.sample_rows()) if n}
+
+
 def agg_merges(since=None) -> dict:
     """-> {path: count} of `tidb_tpu_agg_merge_total`'s samples that
     have moved, since an earlier reading of this when one is given."""
-    since = since or {}
-    return {path: n for path, n in (
-        (lb["path"], int(v) - since.get(lb["path"], 0))
-        for _name, lb, v in AGG_MERGE.sample_rows()) if n}
+    return _moved(AGG_MERGE, "path", since)
 
 
 AGG_LOWERING = REGISTRY.counter(
@@ -1024,6 +1030,24 @@ AGG_LOWERING = REGISTRY.counter(
     "retry_compact, retry_pin_sorted, retry_grow_bucket, "
     "retry_onehot_miss, retry_topn_unproven)",
     ("site", "kind", "verdict"))
+
+
+SNAPSHOT_FACTS = REGISTRY.counter(
+    "tidb_tpu_snapshot_facts_total",
+    "Snapshots of a columnar table (storage/columnar.py snapshot / "
+    "valid_at) by what answered them: hit (the version's kept "
+    "visibility mask and null facts), build (the version's first "
+    "reader made them), bypass_read_ts (a snapshot older than the "
+    "table's newest timestamp: computed for it alone), bypass_overlay "
+    "(rows other than the table's own n: computed for it alone)",
+    ("outcome",))
+
+
+def snapshot_facts(since=None) -> dict:
+    """-> {outcome: count} of `tidb_tpu_snapshot_facts_total`'s samples
+    that have moved, since an earlier reading of this when one is
+    given."""
+    return _moved(SNAPSHOT_FACTS, "outcome", since)
 
 
 FUSED_PIPELINE = REGISTRY.counter(

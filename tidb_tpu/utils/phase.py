@@ -181,12 +181,25 @@ except Exception as _e:                             # noqa: BLE001
           "fetch_s/sync_s will be absent", file=_sys.stderr)
 
 
+# what answered a snapshot of a columnar table: the outcomes of
+# tidb_tpu_snapshot_facts_total, counted here a statement
+_FACTS = ("hit", "build", "bypass_read_ts", "bypass_overlay")
+
+
+def note_facts(outcome):
+    """storage/columnar.py _facts_at: one snapshot of a table answered
+    as `outcome`; the open `bind` span reads what grew."""
+    inc("facts_" + outcome)
+
+
 class bind_span:
     """The `bind` span: the host readying a kernel's operands (delta
     fold, snapshot, column binding, padding and upload). At close it
-    carries what the statement's upload counters grew by inside it."""
+    carries what the statement's upload counters grew by inside it
+    and, where a table was snapshot inside it, what answered
+    (`facts`: `hit`, or every outcome that occurred, `+`-joined)."""
 
-    __slots__ = ("_cm", "_sp", "_stats", "_bytes", "_hits")
+    __slots__ = ("_cm", "_sp", "_stats", "_bytes", "_hits", "_facts")
 
     def __enter__(self):
         self._cm = _tracing.span("bind")
@@ -195,6 +208,7 @@ class bind_span:
             d = self._stats = _cur()
             self._bytes = d.get("upload_bytes", 0)
             self._hits = d.get("upload_hits", 0)
+            self._facts = [d.get("facts_" + o, 0) for o in _FACTS]
         return sp
 
     def __exit__(self, *exc):
@@ -204,6 +218,10 @@ class bind_span:
             sp.attrs["upload_bytes"] = d.get("upload_bytes", 0) - \
                 self._bytes
             sp.attrs["pool_hits"] = d.get("upload_hits", 0) - self._hits
+            facts = [o for o, was in zip(_FACTS, self._facts)
+                     if d.get("facts_" + o, 0) != was]
+            if facts:
+                sp.attrs["facts"] = "+".join(facts)
         return self._cm.__exit__(*exc)
 
 
