@@ -1,0 +1,11 @@
+"""Device milliseconds of a query in the `scan_filter` stage of the fused
+pipeline: evaluating the fact table's filters and the filters after the
+probes. From the trace's operations, each joined to the stage its
+program's catalogue (`tidb_tpu_kernel_stage_ops`) gives its instruction;
+the six `stage_*` metrics sum to the device's busy time a statement. See
+`kernel_stages.py`."""
+import kernel_stages
+
+
+def read(run):
+    return kernel_stages.ms_per_query(run, "scan_filter")

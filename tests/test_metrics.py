@@ -341,36 +341,47 @@ def test_plan_feedback_and_drift_histogram(mtk):
 
 # ---- recording overhead ----------------------------------------------
 
-def test_recording_overhead_under_5_percent():
-    """Acceptance: < 5% wall-time delta on a 1k-statement loop with the
-    registry enabled vs disabled (recording must stay lock-cheap)."""
+# what a warm `select 1` may record: 8 today (AST and plan cache
+# look-ups, the statement's duration and its spans); twice that is a
+# regression to look at, not noise
+RECORDING_OPS_CEILING = 16
+
+
+def test_recording_is_a_bounded_count_of_operations(monkeypatch):
+    """What recording costs a statement, as a count and not as a ratio
+    of host clocks (which a loaded machine moves: ROADMAP D14): one warm
+    `select 1` performs at most RECORDING_OPS_CEILING registry
+    operations (`inc` / `observe` / `set` / `dec` on a labelled child,
+    each one short-held lock and an add), and with the registry
+    disabled it performs none: every sample stays as it was."""
+    recorded = []
+
+    def counting(cls, name):
+        orig = getattr(cls, name)
+
+        def method(self, *a, **kw):
+            if self._reg.enabled:
+                recorded.append((cls.__name__, name))
+            return orig(self, *a, **kw)
+        monkeypatch.setattr(cls, name, method)
+
+    for cls, names in ((metrics._CounterChild, ("inc",)),
+                       (metrics._GaugeChild, ("set", "inc", "dec")),
+                       (metrics._HistogramChild, ("observe",))):
+        for name in names:
+            counting(cls, name)
     tk = TestKit()
-    n = 1000
-
-    def loop():
-        t0 = time.perf_counter()
-        for _ in range(n):
-            tk.must_exec("select 1")
-        return time.perf_counter() - t0
-
-    for _ in range(300):                 # warm plan/AST caches
+    for _ in range(50):                  # warm plan/AST caches
         tk.must_exec("select 1")
-    on, off = [], []
-    try:
-        # interleave BOTH orders so background noise (GC, another CI
-        # job) cannot systematically land on one configuration
-        for first_on in (False, True, False, True):
-            for enabled in (first_on, not first_on):
-                metrics.REGISTRY.enabled = enabled
-                (on if enabled else off).append(loop())
-    finally:
-        metrics.REGISTRY.enabled = True
-    best_on, best_off = min(on), min(off)
-    # min-of-4 strips scheduler noise; 50ms absolute floor keeps a
-    # ~150ms loop from flaking on a busy CI box (the real recording
-    # cost is a few µs/statement, far under both bounds)
-    assert best_on <= best_off * 1.05 + 0.05, \
-        f"registry overhead {best_on:.3f}s vs {best_off:.3f}s disabled"
+    del recorded[:]
+    tk.must_exec("select 1")
+    assert 0 < len(recorded) <= RECORDING_OPS_CEILING, recorded
+    del recorded[:]
+    before = metrics.REGISTRY.snapshot()
+    monkeypatch.setattr(metrics.REGISTRY, "enabled", False)
+    tk.must_exec("select 1")
+    assert recorded == []
+    assert metrics.REGISTRY.snapshot() == before
 
 
 def test_replica_instruments_exposed_and_parse():

@@ -1929,9 +1929,7 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         if getattr(copr, "domain", None) is not None:
             copr.domain.inc_metric(metric)
 
-    def _emit(posruns, ng, ks, kns, kds, sts):
-        if posruns:
-            _count("fused_posruns_agg")
+    def _emit(ng, ks, kns, kds, sts):
         out.append(PartialAggResult(
             ngroups=ng, keys=ks, key_nulls=kns, states=sts,
             key_dicts=kds, state_dicts=sd, ident=ident))
@@ -2009,7 +2007,7 @@ def fused_partials(copr, plan, read_ts, mesh=None,
                     low.settle(agg_kind, agg_param, "topn_unproven")
                     return False
             low.settle(agg_kind, agg_param)
-            _emit(posruns, ncand, ckeys, cnulls, ckd, cstates)
+            _emit(ncand, ckeys, cnulls, ckd, cstates)
             return True
         ks, kns, kds = _host_keys(res, posruns, agg_param[1], ngroups)
         sts = [[host_array(s)[:ngroups] for s in st_]
@@ -2028,7 +2026,7 @@ def fused_partials(copr, plan, read_ts, mesh=None,
                 oh_learn.clear()
             else:
                 oh_learn.append((ks, kns))
-        _emit(posruns, ngroups, ks, kns, kds, sts)
+        _emit(ngroups, ks, kns, kds, sts)
         return True
 
     # partition pipelining: partition i+1's padding/upload/dispatch is

@@ -713,6 +713,10 @@ def update_runtime_gauges(domain):
     store = getattr(getattr(domain, "copr", None), "_dev_store", None)
     if store is not None:
         DEV_RESIDENT_BUDGET.set(store.budget)
+    # the stage catalogue of programs that ran under a profiler session
+    # is filled here, off every statement's path
+    from . import kernel_stages
+    kernel_stages.materialise()
 
 
 def reset_all():

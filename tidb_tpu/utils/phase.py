@@ -32,6 +32,7 @@ lands in the statement's trace, and in the profiler's as
 import threading
 import time
 
+from . import kernel_stages as _stages
 from . import tracing as _tracing
 
 _TLS = threading.local()
@@ -259,10 +260,14 @@ def timed_kernel(kind, fn):
     """Wrap a compiled kernel callable with dispatch accounting and the
     `dispatch` span (the enqueue; `kind` is the cache key's; `part` /
     `parts` inside a row-block loop). The first call is recorded
-    separately (it pays the XLA trace+compile)."""
+    separately (it pays the XLA trace+compile). Under a profiler
+    session the program is noted for the stage catalogue
+    (utils/kernel_stages); without one that costs the flag's read."""
     state = {"first": True}
 
     def wrapped(*args, **kw):
+        if _stages.session_active():
+            _stages.note(kind, fn, args, kw)
         with _tracing.span("dispatch", kind=kind, **part_attrs()) as sp:
             t0 = time.perf_counter()
             out = fn(*args, **kw)
