@@ -21,11 +21,23 @@ statement's and not the wire's. Variants:
              one call before they went; to read it again run PR 29's
              tree's `fold` and this in one call.
 
-`--hlo q` writes the compiled HLO text of q's program with dimensions to
-chiprun_out/hlo_<q>_<variant>_sf<scale>.txt and prints every `while`
-in it: name, `op_name` metadata (the `jax.named_scope` stage), known
-trip count, carried shapes. Off the chip (JAX_PLATFORMS=cpu) the
-script runs for rehearsal and says so; its times then mean nothing.
+`--hlo q` writes the compiled HLO text of q's programs (those with
+dimensions; all of them for a statement that has none, q1 and q6) to
+chiprun_out/hlo_<q>_<variant>_sf<scale>[_x<devices>]_<i>.txt and
+prints of each its sha256 (and that of the text less its source
+locations, which is what two trees' programs are compared by), its ` gather(` instructions counted by element type and width
+(an s64 table gathered at fact width shows as two u32 gathers: XLA:TPU
+splits it), and every `while` in it: name, `op_name` metadata (the
+`jax.named_scope` stage), known trip count, carried shapes. Each
+composed word is printed with the bits it uses and the type that holds
+it when it is built. With more than one device the mesh programs
+(`jit_tidb_mpp_fused_*`) are what the statements build, and what is
+written. Off the chip (JAX_PLATFORMS=cpu) the script runs for rehearsal
+and says so; its times then mean nothing. `--aot v5e:2x2` is for there:
+it forces the chip's lowering policy ("runs") and compiles each program
+for that described topology with the TPU's compiler instead of for the
+backend that ran it (one device of it, or a mesh of as many as ran): the
+texts and their counts are the chip's compiler's, nothing is timed.
 """
 import argparse
 import importlib.util
@@ -73,6 +85,42 @@ def _whiles(text):
     return out
 
 
+def _gathers(text):
+    """{"u32[4194304]": count} of an HLO module's ` gather(`
+    instructions by result element type and shape."""
+    out = {}
+    for m in re.finditer(r"= (\w+\[[\d,]*\])\S* gather\(", text):
+        out[m.group(1)] = out.get(m.group(1), 0) + 1
+    return dict(sorted(out.items()))
+
+
+def _program_only(text):
+    """An HLO module's text less what names the source: the FileNames /
+    FunctionNames / FileLocations / StackFrames tables under its first
+    line and every instruction's `stack_frame_id`. Two trees whose
+    Python moved by a line compile the same program to texts that
+    differ there and nowhere else."""
+    head, _, rest = text.partition("\n\nFileNames\n")
+    if rest:
+        tables, _, body = rest.partition("\n\n\n")
+        if not body:            # no blank pair: cut at the first block
+            m = re.search(r"\n\n(?=[%\w].* \{\n|ENTRY )", rest)
+            body = rest[m.end():] if m else rest
+        text = head + "\n\n" + body
+    return re.sub(r",? ?stack_frame_id=\d+", "", text)
+
+
+def _used_bits(packed):
+    """[(bits used, type)] a word of a dimfold.Packed, from the fields'
+    shifts and masks (a mask of -1: a 64-bit field, as it is)."""
+    used = [0] * len(packed.tables)
+    for (_k, _ident, wi, _dt), sh, mk in zip(packed.text, packed.shift,
+                                             packed.mask):
+        used[wi] = max(used[wi], int(sh) + (64 if int(mk) == -1
+                                            else int(mk).bit_length()))
+    return [(u, t.dtype.name) for u, t in zip(used, packed.tables)]
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--scale", type=float, required=True)
@@ -81,12 +129,15 @@ def main():
     ap.add_argument("--hlo", default="")
     ap.add_argument("--variants", default="control,fold")
     ap.add_argument("--runs", type=int, default=5)
+    ap.add_argument("--aot", default="")
     args = ap.parse_args()
     timed = [q for q in args.time.split(",") if q]
     hlo = [q for q in args.hlo.split(",") if q]
 
+    import hashlib
     import jax
     import numpy as np
+    import tidb_tpu.copr.agg_lowering as al
     import tidb_tpu.copr.dimfold as df
     import tidb_tpu.copr.pipeline as pl
     from tidb_tpu.session import new_store
@@ -97,6 +148,24 @@ def main():
            " -- NOT a chip: a rehearsal, its times mean nothing"))
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
+    # the files of a mesh run beside a one-chip run's
+    tag = f"sf{args.scale:g}" + (f"_x{len(jax.devices())}"
+                                 if len(jax.devices()) > 1 else "")
+    topo = None
+    if args.aot:
+        if dev.platform == "tpu":
+            sys.exit("--aot is for a run off the chip")
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name=args.aot)
+        al._FORCE_SEGMENT_IMPL = "runs"
+        # a TPU executable cannot be read back here: keep it out of
+        # the CPU run's cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        log(f"--aot: the chip's lowering policy forced, programs compiled "
+            f"for {args.aot} ({topo.devices[0].device_kind}), nothing run "
+            "there")
 
     ds = _dataset()
     tk = TestKit(new_store(tempfile.mkdtemp(prefix="fold_probe_")))
@@ -110,26 +179,63 @@ def main():
     ds.load(tables, tk.must_exec, bulk_table)
     log("data loaded")
 
-    # every fused kernel built, with the shapes of its first call
+    # every fused kernel built, one chip's or the mesh's, with the
+    # shapes (and shardings) of its first call
     built = []
-    orig_build = pl._build_fused_kernel
 
-    def spy(*a, **k):
-        kern = orig_build(*a, **k)
-        # guard_donation wraps the jitted program where it donates
-        rec = {"plan": a[0], "kind": a[7], "shapes": None,
-               "jit": kern if hasattr(kern, "lower") else kern.__wrapped__}
-        built.append(rec)
+    def spy_on(name, family):
+        orig_build = getattr(pl, name)
 
-        def call(fjc, fvv, kargs):
-            if rec["shapes"] is None:
-                rec["shapes"] = jax.tree_util.tree_map(
-                    lambda x: jax.ShapeDtypeStruct(np.shape(x),
-                                                   np.asarray(x).dtype),
-                    (fjc, fvv, kargs))
-            return kern(fjc, fvv, kargs)
-        return call
-    pl._build_fused_kernel = spy
+        def spy(*a, **k):
+            kern = orig_build(*a, **k)
+            # guard_donation wraps the jitted program where it donates
+            rec = {"plan": a[0], "kind": a[7], "shapes": None,
+                   "family": family, "build": (orig_build, a, k),
+                   "jit": kern if hasattr(kern, "lower")
+                   else kern.__wrapped__}
+            built.append(rec)
+
+            def call(fjc, fvv, kargs):
+                if rec["shapes"] is None:
+                    rec["shapes"] = jax.tree_util.tree_map(
+                        lambda x: jax.ShapeDtypeStruct(
+                            np.shape(x), np.asarray(x).dtype,
+                            sharding=getattr(x, "sharding", None)),
+                        (fjc, fvv, kargs))
+                return kern(fjc, fvv, kargs)
+            return call
+        setattr(pl, name, spy)
+    spy_on("_build_fused_kernel", "fused_")
+    spy_on("_build_fused_kernel_mpp", "mpp_fused_")
+
+    def compiled_text(rec):
+        """The program's compiled text: for the backend it ran on, or
+        (--aot) for the described topology."""
+        if topo is None:
+            return rec["jit"].lower(*rec["shapes"]).compile().as_text()
+        from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                                  SingleDeviceSharding)
+        orig_build, a, k = rec["build"]
+        if rec["family"] == "fused_":
+            jit = orig_build(*a, **k)
+            jit = jit if hasattr(jit, "lower") else jit.__wrapped__
+
+            def place(x):
+                return SingleDeviceSharding(topo.devices[0])
+        else:
+            ran = a[9]
+            mesh = Mesh(np.array(topo.devices[:ran.devices.size]),
+                        ran.axis_names)
+            jit = orig_build(*a[:9], mesh, *a[10:], **k)
+
+            def place(x):
+                spec = getattr(x.sharding, "spec", PartitionSpec())
+                return NamedSharding(mesh, spec)
+        shapes = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=place(x)),
+            rec["shapes"])
+        return jit.lower(*shapes).compile().as_text()
 
     orig_fold_build = df._build
 
@@ -151,7 +257,8 @@ def main():
         log(f"  {len(fields)} field(s) composed over dimension {self.root}: "
             f"{len(res.tables)} word(s) of {len(res.tables[0])} "
             f"slots, {res.nbytes} bytes: "
-            f"{(time.perf_counter() - t) * 1e3:.1f} ms")
+            f"{(time.perf_counter() - t) * 1e3:.1f} ms; bits used a word: "
+            + ", ".join(f"{u} ({dt})" for u, dt in _used_bits(res)))
         return res
     df.Fold._compose = timed_compose
 
@@ -171,7 +278,8 @@ def main():
 
     result = {"scale": args.scale, "seed": args.seed,
               "device": f"{dev.platform} {dev.device_kind}", "ms": {},
-              "whiles": {}}
+              "whiles": {}, "gathers": {}, "sha256": {},
+              "compiled_for": args.aot or f"{dev.platform} {dev.device_kind}"}
     for variant in args.variants.split(","):
         df.fold_plan = variants[variant]
         dom.copr._kernel_cache.clear()
@@ -197,27 +305,38 @@ def main():
                     f"{statistics.median(ms):.1f} ms of {args.runs}: "
                     + " ".join(f"{x:.1f}" for x in ms))
             if q in hlo:
-                recs = [r for r in built
-                        if r["plan"].dims and r["shapes"] is not None]
+                recs = [r for r in built if r["shapes"] is not None]
+                if any(r["plan"].dims for r in recs):
+                    recs = [r for r in recs if r["plan"].dims]
                 for i, rec in enumerate(recs):
-                    text = rec["jit"].lower(*rec["shapes"]).compile() \
-                        .as_text()
-                    name = f"hlo_{q}_{variant}_sf{args.scale:g}_{i}.txt"
+                    text = compiled_text(rec)
+                    name = f"hlo_{q}_{variant}_{tag}_{i}.txt"
                     with open(os.path.join(out_dir, name), "w") as f:
                         f.write(text)
-                    ws = _whiles(text)
+                    ws, gs = _whiles(text), _gathers(text)
+                    sha = hashlib.sha256(text.encode()).hexdigest()
+                    prog = hashlib.sha256(
+                        _program_only(text).encode()).hexdigest()
                     result["whiles"][f"{q}.{variant}.{i}"] = ws
+                    result["gathers"][f"{q}.{variant}.{i}"] = gs
+                    result["sha256"][f"{q}.{variant}.{i}"] = (sha, prog)
                     log(f"  {q} {variant} program {i} "
-                        f"(jit_tidb_fused_{rec['kind']}): "
+                        f"(jit_tidb_{rec['family']}{rec['kind']}, "
+                        f"{rec['shapes'][1].shape[0]} lanes): "
+                        f"{len(text)} bytes, sha256 {sha[:16]}, less "
+                        f"source locations {prog[:16]}, "
                         f"{len(ws)} while loop(s), text in "
                         f"chiprun_out/{name}")
+                    log(f"    {sum(gs.values())} gather(s): "
+                        + (", ".join(f"{n} x {t}" for t, n in gs.items())
+                           or "none"))
                     for w in ws:
                         log(f"    {w[0]}: op_name={w[1]} trip={w[2]} "
                             f"carries {w[3][:300]}")
     stats = dev.memory_stats() or {}
     result["memory_peak_bytes"] = stats.get("peak_bytes_in_use")
     with open(os.path.join(
-            out_dir, f"fold_probe_sf{args.scale:g}.json"), "w") as f:
+            out_dir, f"fold_probe_{tag}.json"), "w") as f:
         json.dump(result, f, indent=1)
     log(f"done; HBM peak {result['memory_peak_bytes']}")
     dom.timer.stop_all()

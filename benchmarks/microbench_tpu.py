@@ -118,27 +118,73 @@ def main(sections):
         idx4 = jnp.asarray(rng.integers(0, 1 << 21, n4), dtype=jnp.int64)
         bench("gather 4M from 2M lut", jax.jit(lambda lu, i: lu[i]),
               lut, idx4)
-        # the same gather by physical type (ROADMAP S1(c): is a 64-bit
+        # the same gather by physical type (ROADMAP S1(d): is a 64-bit
         # gather two 32-bit ones?). The "sum" rows reduce on the device,
         # so the sample is the gather and not the 4-32 MB download; the
         # "sorted idx" rows read the table in storage order, as a probe
-        # by a clustered key (l_orderkey into orders) does
+        # by a clustered key (l_orderkey into orders) does.
+        #
+        # A row is named for what its program keeps alive. The PR 26
+        # rows summed `lu[i].astype(int32)`: the narrowing leaves the
+        # high u32 half of an s64 gather dead, the compiler drops it,
+        # and the row prices ONE u32 gather whatever the table's dtype
+        # ("low half" below). The "all 64 bits" rows sum in int64, or
+        # the two halves apart, so both u32 gathers of an s64 table
+        # run: what a composed word or a table of positions held as
+        # int64 pays in the fused programs (PR 36's traces).
         lut32, idx32 = lut.astype(jnp.int32), idx4.astype(jnp.int32)
         lutb = (lut & 1).astype(bool)
         sidx4, sidx32 = jnp.sort(idx4), jnp.sort(idx32)
-        gsum = jax.jit(lambda lu, i: jnp.sum(lu[i].astype(jnp.int32)))
+        glow = jax.jit(lambda lu, i: jnp.sum(lu[i].astype(jnp.int32)))
+        g64 = jax.jit(lambda lu, i: jnp.sum(lu[i].astype(jnp.int64)))
+
+        def ghalves(lu, i):
+            w = lu[i]
+            return (jnp.sum((w >> 32).astype(jnp.int32)),
+                    jnp.sum(w.astype(jnp.int32)))
+        ghalves = jax.jit(ghalves)
         bench("gather 4M from 2M lut int32 (table and idx)",
               jax.jit(lambda lu, i: lu[i]), lut32, idx32)
         bench("gather 4M from 2M lut bool (int32 idx)",
               jax.jit(lambda lu, i: lu[i]), lutb, idx32)
         bench("read+sum 4M int32 (the floor under the sum rows)",
               jax.jit(lambda i: jnp.sum(i)), idx32)
-        bench("gather+sum 4M int64", gsum, lut, idx4)
-        bench("gather+sum 4M int64 table, int32 idx", gsum, lut, idx32)
-        bench("gather+sum 4M int32", gsum, lut32, idx32)
-        bench("gather+sum 4M bool", gsum, lutb, idx32)
-        bench("gather+sum 4M int64, sorted idx", gsum, lut, sidx4)
-        bench("gather+sum 4M int32, sorted idx", gsum, lut32, sidx32)
+        bench("read+sum 4M int64 (the floor under the all-64-bits rows)",
+              jax.jit(lambda i: jnp.sum(i)), idx4)
+        bench("gather+sum 4M int64 table, low half alone", glow, lut, idx4)
+        bench("gather+sum 4M int64 table, low half alone, int32 idx",
+              glow, lut, idx32)
+        bench("gather+sum 4M int64 table, all 64 bits (int64 sum)",
+              g64, lut, idx4)
+        bench("gather+sum 4M int64 table, all 64 bits (halves apart)",
+              ghalves, lut, idx4)
+        bench("gather+sum 4M int64 table, all 64 bits, int32 idx",
+              g64, lut, idx32)
+        bench("gather+sum 4M int32 table", glow, lut32, idx32)
+        bench("gather+sum 4M int32 table, widened after (int64 sum)",
+              g64, lut32, idx4)
+        bench("gather+sum 4M bool table", glow, lutb, idx32)
+        bench("gather+sum 4M int64 table, low half alone, sorted idx",
+              glow, lut, sidx4)
+        bench("gather+sum 4M int64 table, all 64 bits, sorted idx",
+              g64, lut, sidx4)
+        bench("gather+sum 4M int32 table, sorted idx", glow, lut32, sidx32)
+        # the same pair from a 6M-slot table (orders' key span at SF1:
+        # two thirds of its slots a miss, three quarters of orders')
+        big = np.full(6 << 20, 1 << 21, dtype=np.int64)
+        big[rng.choice(6 << 20, 1 << 21, replace=False)] = \
+            np.arange(1 << 21)
+        big64 = jnp.asarray(big)
+        big32 = big64.astype(jnp.int32)
+        bidx = jnp.asarray(rng.integers(0, 6 << 20, n4), dtype=jnp.int64)
+        bench("gather+sum 4M from 6M-slot int64 table, all 64 bits",
+              g64, big64, bidx)
+        bench("gather+sum 4M from 6M-slot int32 table, widened after",
+              g64, big32, bidx)
+        bench("gather+sum 4M from 6M-slot int64 table, all 64 bits, "
+              "sorted idx", g64, big64, jnp.sort(bidx))
+        bench("gather+sum 4M from 6M-slot int32 table, widened after, "
+              "sorted idx", g64, big32, jnp.sort(bidx))
         skeys = jnp.asarray(np.sort(rng.choice(1 << 24, 1 << 21,
                                                replace=False)),
                             dtype=jnp.int64)

@@ -116,9 +116,9 @@ def _body_jaxpr(build, shapes):
         *a, **dict(k, want_fnvalid=True)))(*shapes)
 
 
-def _wide_gathers(build, shapes):
-    """Argument paths of what the body gathers from at fact width ("-"
-    for an intermediate)."""
+def _wide_census(build, shapes):
+    """[(argument path of what the body gathers from at fact width, "-"
+    for an intermediate; bytes an element of it)]."""
     cj = _body_jaxpr(build, shapes)
     paths = [jax.tree_util.keystr(p) for p, _ in
              jax.tree_util.tree_flatten_with_path(shapes)[0]]
@@ -128,9 +128,14 @@ def _wide_gathers(build, shapes):
     def visit(e):
         if e.primitive.name == "gather" and \
                 e.outvars[0].aval.shape[:1] == (cap,):
-            out.append(name.get(id(e.invars[0]), "-"))
+            out.append((name.get(id(e.invars[0]), "-"),
+                        e.invars[0].aval.dtype.itemsize))
     _walk(cj.jaxpr, visit)
     return out
+
+
+def _wide_gathers(build, shapes):
+    return [p for p, _ in _wide_census(build, shapes)]
 
 
 def _q(name):
@@ -152,19 +157,25 @@ def _main_kernel(tk, kinds, sql):
 # ---- (a) the census of fact-wide gathers ------------------------------
 
 # query -> (most with the fold, of them from a dimension's operands,
-# fewest without: ISSUE 28's table, ISSUE 30's for the composed word)
-_CENSUS = {"q5": (2, 2, 27), "q10": (3, 1, 15), "q3": (2, 1, 10),
-           "q18": (2, 1, 7)}
+# fewest without: ISSUE 28's table, ISSUE 30's for the composed word;
+# bytes a fact lane gathers from the dimensions' operands: ISSUE 37's,
+# every one of these tables fits 31 bits and was 8 bytes a slot before)
+_CENSUS = {"q5": (2, 2, 27, 8), "q10": (3, 1, 15, 4), "q3": (2, 1, 10, 4),
+           "q18": (2, 1, 7, 4)}
 # the roots whose payload is composed with their probe table
 _PACKED = {"q5": 2, "q10": 1, "q3": 0, "q18": 0}
+# the tables a statement binds for a 32-bit gather: words and `lut`s
+_WORD32 = {"q5": 2, "q10": 1, "q3": 1, "q18": 1}
 
 
 @pytest.mark.parametrize("q", sorted(_CENSUS))
 def test_census_fact_wide_gathers(tk, runs_impl, kinds, monkeypatch, q):
-    most, of_dims, control = _CENSUS[q]
+    most, of_dims, control, lane_bytes = _CENSUS[q]
     build, shapes = _main_kernel(tk, kinds, _q(q))
-    folded = _wide_gathers(build, shapes)
+    census = _wide_census(build, shapes)
+    folded = [p for p, _ in census]
     assert len(folded) <= most, folded
+    assert sum(b for p, b in census if p.startswith("[2]")) == lane_bytes
     # one gather a root: its probe table, of positions or of words
     dimops = [p for p in folded if p.startswith("[2]")]
     assert len(dimops) <= of_dims and (q in ("q5", "q10") or
@@ -220,6 +231,7 @@ def test_position_only_root_keeps_the_table_of_positions(
     assert not [k for k in _grown(before) if "pack" in k]
     root = shapes[2][0]
     assert set(root) == {"cols", "lut", "lo"}
+    assert root["lut"].dtype == np.int32        # positions under n
     assert all(lay.get("pack") is None for lay in build[0][6])
     now = str(_body_jaxpr(build, shapes))
     monkeypatch.setattr(df, "pack_fields", lambda *a: ())
@@ -233,7 +245,7 @@ def test_nothing_folds_keeps_todays_operands(kinds):
     before = _counts()
     _dev_vs_host(tk, "select c.seg, count(*) from d left join c "
                  "on d.cid = c.id group by c.seg order by c.seg")
-    assert _grown(before) == {"declined_left": 1}
+    assert _grown(before) == {"declined_left": 1, "word32": 1}
     (kind, _param, build, shapes), = kinds
     assert build[1].get("fold") is None
     assert "valid" in shapes[2][0] and len(shapes[2][0]["cols"]) == 2
@@ -271,12 +283,29 @@ def test_tpch_device_equals_host(tk, q):
 # ---- (b2) the composed word: unpack(pack(x)) == x ------------------------
 
 _I62 = (1 << 62) - 1
-# case -> (columns, words the first fit has to give)
+# case -> (columns, words the first fit has to give[, and their types
+# where not int64: a word is int32 where its fields end within 31 bits])
 _PACK = {
     "negative_values": ([np.array([-7, -1, 0, 5]),
                          np.array([-(1 << 40), 3, 9, -2])], 1),
     "nulls_are_a_bit": ([np.array([10, 11, 12, 13], dtype=np.int32),
-                         np.array([True, False, False, True])], 1),
+                         np.array([True, False, False, True])], 1,
+                        ("int32",)),
+    "a_word_of_one_bit": ([np.array([False, True, True, False])], 1,
+                          ("int32",)),
+    "a_negative_lo_in_a_narrow_word": (
+        [np.array([-(1 << 40) - 3, -(1 << 40), -(1 << 40) + 900, -1 << 40]),
+         np.array([-5, 5, 0, 1], dtype=np.int8)], 1, ("int32",)),
+    "a_word_at_31_bits": ([np.array([0, (1 << 30) - 1, 5, 1 << 29]),
+                           np.array([True, False, False, True])], 1,
+                          ("int32",)),
+    "a_word_at_32_bits": ([np.array([0, (1 << 31) - 1, 5, 1 << 30]),
+                           np.array([True, False, False, True])], 1),
+    "a_field_of_31_bits_beside_a_wide_word": (
+        [np.array([0, 1 << 39, 5, 6]), np.array([7, (1 << 31) + 6, 8, 9]),
+         np.array([0, 3, 2, 1])], 2, ("int64", "int32")),
+    "no_rows": ([np.array([], dtype=np.int64),
+                 np.array([], dtype=bool)], 1, ("int32",)),
     "a_field_at_62_bits": ([np.array([0, _I62, 17, 1 << 61]),
                             np.array([False, True, True, False])], 1),
     "a_field_at_63_bits_all_ones": (
@@ -285,38 +314,84 @@ _PACK = {
                                 np.array([-(1 << 39), 0, 1, 2]),
                                 np.array([0, 1 << 19, 2, 3])], 2),
     "constant_columns_take_no_bits": ([np.full(4, 42), np.full(4, -3),
-                                       np.arange(4)], 1),
+                                       np.arange(4)], 1, ("int32",)),
     "narrow_unsigned": ([np.array([0, 255, 7, 9], dtype=np.uint8),
-                         np.array([65535, 0, 1, 2], dtype=np.uint16)], 1),
+                         np.array([65535, 0, 1, 2], dtype=np.uint16)], 1,
+                        ("int32",)),
     # no room beside the miss bit: a word of its own, as it is
     "doubles_are_their_bit_pattern": (
-        [np.array([0.5, -1.5, np.inf, -0.0]), np.arange(4)], 2),
+        [np.array([0.5, -1.5, np.inf, -0.0]), np.arange(4)], 2,
+        ("int32", "int64")),
     "range_past_63_bits": (
         [np.arange(4), np.array([-(1 << 62) - 5, (1 << 62) + 5, 0, -1]),
-         np.array([1, 1 << 63, 3, (1 << 64) - 1], dtype=np.uint64)], 3),
+         np.array([1, 1 << 63, 3, (1 << 64) - 1], dtype=np.uint64)], 3,
+        ("int32", "int64", "int64")),
     "float32_is_32_bits": ([np.array([0.5, -1.5, 3.25, 1e30],
                                      dtype=np.float32),
                             np.array([1, 2, 3, 4], dtype=np.int32)], 1),
 }
 
 
-@pytest.mark.parametrize("case", sorted(_PACK))
-def test_pack_round_trip(case):
-    cols, nwords = _PACK[case]
+def _round_trip(cols):
+    """unpack(pack(x)) == x bit for bit, from words as wide as the rule
+    says; -> the words' types."""
     words, word, shift, mask, lo = df.pack_words(cols)
-    assert len(words) == nwords and len(set(word)) == nwords
     for i, c in enumerate(cols):
         back = df.unpack_field(words[word[i]], shift[i], mask[i], lo[i],
                                c.dtype)
         assert back.dtype == c.dtype
         np.testing.assert_array_equal(back.view(f"u{c.dtype.itemsize}"),
                                       c.view(f"u{c.dtype.itemsize}"))
+    # a word is int32 exactly where its fields end within 31 bits
+    for wi, w in enumerate(words):
+        used = max((int(shift[i]) + (64 if mask[i] == -1 else
+                                     int(mask[i]).bit_length())
+                    for i in range(len(cols)) if word[i] == wi), default=0)
+        assert w.dtype == (np.int32 if used <= 31 else np.int64), used
     # a hit never reads as the miss: word 0's sign bit belongs to no
-    # field, and a miss reads the minimum of every field of word 0
-    assert (words[0] >= 0).all() and df.MISS < 0
-    assert all(df.unpack_field(np.int64(df.MISS), shift[i], mask[i], lo[i],
-                               np.int64) == lo[i]
+    # field, and a miss, widened as the program widens it after the
+    # gather, is negative and reads the minimum of every field of word 0
+    miss = np.array([df.miss(words[0].dtype)], dtype=words[0].dtype)
+    assert (words[0] >= 0).all() and miss.astype(np.int64)[0] < 0
+    assert all(df.unpack_field(miss.astype(np.int64), shift[i], mask[i],
+                               lo[i], np.int64)[0] == lo[i]
                for i in range(len(cols)) if word[i] == 0)
+    return words, word
+
+
+@pytest.mark.parametrize("case", sorted(_PACK))
+def test_pack_round_trip(case):
+    cols, nwords, wtypes = (_PACK[case] + (("int64",) * _PACK[case][1],))[:3]
+    words, word = _round_trip(cols)
+    assert len(words) == nwords and len(set(word)) == nwords
+    assert tuple(w.dtype.name for w in words) == wtypes
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_pack_round_trip_property(seed):
+    """Random field sets about the two edges (31 | 32 and 63 | 64 bits),
+    with negative minima and a double among them."""
+    rng = np.random.RandomState(seed)
+    cols = []
+    for _ in range(rng.randint(1, 7)):
+        bits = int(rng.choice([0, 1, 5, 20, 30, 31, 32, 40, 62, 63, 64]))
+        if bits == 64 and rng.randint(2):
+            cols.append(rng.standard_normal(16) * 1e9)      # its pattern
+            continue
+        lo = -int(rng.randint(0, 1 << 30)) << int(rng.randint(0, 30))
+        span = (1 << bits) - 1
+        v = [lo + (int(rng.randint(0, 1 << 31)) * int(rng.randint(1, 1 << 31))
+                   % (span + 1)) for _ in range(14)] + [lo, lo + span]
+        if bits == 64:
+            v = [x - lo + np.iinfo(np.int64).min for x in v]
+        cols.append(np.array(v, dtype=np.int64))
+    _round_trip(cols)
+
+
+def test_positions_take_the_type_their_miss_fits():
+    assert df.pos_dtype(1) == np.int32 == df.pos_dtype((1 << 31) - 1)
+    assert df.pos_dtype(1 << 31) == np.int64
+    assert df.miss(np.int32) == -(1 << 31) and df.miss(np.int64) == -(1 << 63)
 
 
 # ---- (c) synthetic chains ---------------------------------------------
@@ -502,11 +577,20 @@ _SYN = {
 }
 
 
+# the tables a case binds for a 32-bit gather, where not the root's one:
+# a declined dimension's own `lut` beside it; two words of 40 bits and
+# more stay int64, a double or a 64-bit range beside a narrow word 0 too
+_SYN_WORD32 = {"anti_child_declined": 2, "left_child_declined": 2,
+               "composite_key_declined": 2, "spill_to_a_second_word": 0}
+
+
 @pytest.mark.parametrize("policy", ["runs", "scatter"])
 @pytest.mark.parametrize("case", sorted(_SYN))
 def test_synthetic_chain_vs_host(tkc, case, policy):
     sql, want, pack = (_SYN[case] + ({"packed": 1},))[:3]
     want = dict(want, **pack)
+    if _SYN_WORD32.get(case, 1):
+        want["word32"] = _SYN_WORD32.get(case, 1)
     al._FORCE_SEGMENT_IMPL = "runs" if policy == "runs" else None
     try:
         before = _counts()
@@ -550,24 +634,126 @@ def test_topn_keeps_its_root_width_column(tkc, runs_impl, kinds):
     assert wide == ["[2][0]['pk'][0]"]
 
 
-@pytest.mark.parametrize("case,words,fields", [
-    ("spill_to_a_second_word", 2, 3),
-    ("nullable_folded_column", 1, 3),
-    ("range_past_63_bits_is_a_word", 2, 2),
-    ("double_column_is_a_word", 2, 2)])
-def test_words_and_their_gathers(tkc, kinds, case, words, fields):
+@pytest.mark.parametrize("case,words,fields,wtypes", [
+    # two fields of 40 bits: neither word fits 31, both stay int64
+    ("spill_to_a_second_word", 2, 3, ("int64", "int64")),
+    ("nullable_folded_column", 1, 3, ("int32",)),
+    ("range_past_63_bits_is_a_word", 2, 2, ("int32", "int64")),
+    ("double_column_is_a_word", 2, 2, ("int32", "int64"))])
+def test_words_and_their_gathers(tkc, kinds, case, words, fields, wtypes):
     """A spill is a second key-addressed table and a second gather,
     never more than the gathers of the table of positions and of the
-    columns read through it; a null mask is a field."""
+    columns read through it; a null mask is a field; a word is gathered
+    in the type that holds it."""
     tkc.domain.copr._kernel_cache.clear()
     _dev_vs_host(tkc, _SYN[case][0])
     (_kind, _param, build, shapes) = kinds[-1]
     root = shapes[2][0]
-    wide = [p for p in _wide_gathers(build, shapes) if p.startswith("[2]")]
+    census = [(p, b) for p, b in _wide_census(build, shapes)
+              if p.startswith("[2]")]
+    wide = [p for p, _ in census]
+    assert tuple(t.dtype.name for t in root["pk"]) == wtypes == \
+        build[0][6][0]["words"]
+    assert [b for _, b in census] == [np.dtype(t).itemsize for t in wtypes]
     assert len(root["pk"]) == words == len(wide) <= fields
     assert not root["cols"] and "lut" not in root
     assert root["fshift"].shape == (fields,) == root["fmask"].shape
     assert {t[2] for t in build[0][6][0]["pack"]} == set(range(words))
+
+
+def test_a_word_that_outgrows_31_bits_takes_a_second_program(kinds):
+    """One commit widens a field past 31 bits between two statements:
+    the root's word goes from int32 to int64, the statement builds the
+    program that gathers it and answers as the host does."""
+    tk = TestKit()
+    tk.must_exec("create table dd (id int primary key, v bigint, g int)")
+    tk.must_exec("create table f (k int primary key, d_id int, q int)")
+    tk.must_exec("insert into dd values " + ",".join(
+        f"({i}, {i * 1000}, {i % 5})" for i in range(1, 101)))
+    tk.must_exec("insert into f values " + ",".join(
+        f"({k}, {k % 110 + 1}, {k % 9})" for k in range(1500)))
+    sql = ("select dd.g, sum(dd.v + f.q), count(*) from f, dd "
+           "where f.d_id = dd.id group by dd.g order by dd.g")
+    before = _counts()
+    _dev_vs_host(tk, sql)
+    assert _grown(before)["word32"] == 1
+    assert kinds[-1][3][2][0]["pk"][0].dtype == np.int32
+    phase.reset()
+    tk.must_query(sql)
+    assert phase.snap().get("kernel_builds", 0) == 0
+    tk.must_exec(f"update dd set v = {1 << 40} where id = 7")
+    before, built = _counts(), len(kinds)
+    wide = _dev_vs_host(tk, sql)
+    assert "word32" not in _grown(before) and len(kinds) == built + 1
+    assert [t.dtype for t in kinds[-1][3][2][0]["pk"]] == [np.int64]
+    assert kinds[-1][2][0][6][0]["words"] == ("int64",)
+    assert sum(int(r[1]) for r in wide) > 1 << 40
+    phase.reset()
+    tk.must_query(sql)
+    assert phase.snap().get("kernel_builds", 0) == 0
+
+
+def test_the_words_type_keys_the_kernel_cache(tkc, kinds):
+    """The type a word is gathered in is program text: the same
+    statement over the same shapes with another type is another key."""
+    tkc.domain.copr._kernel_cache.clear()
+    _dev_vs_host(tkc, _SYN["child_payload_groups"][0])
+    (a, k), = [k[2] for k in kinds]
+    lay = dict(a[6][0])
+    assert lay["words"] == ("int32",)
+    keys = set()
+    copr = tkc.domain.copr
+    for words in (("int32",), ("int64",)):
+        lay["words"] = words
+        plan = a[0]
+        fact = copr.engine.table(plan.fact_dag.table_info)
+        metas = [{"tbl": copr.engine.table(d.dag.table_info),
+                  "mode": "direct", "lut": np.zeros(1, np.int32)}
+                 for d in plan.dims]
+        keys.add(pl._fused_cache_key(
+            copr, plan, fact, metas, a[1], tuple(a[3]), tuple(a[4]),
+            tuple(a[5]), a[7], a[8], fold=k.get("fold"),
+            dim_layouts=(lay,) + tuple(a[6][1:])))
+    assert len(keys) == 2
+
+
+def test_positions_past_31_bits_keep_the_int64_table(monkeypatch, kinds):
+    """No table of this suite has 2**31 rows: with the edge moved under
+    d's 200 the `lut`, the folded table and the word stay int64 (the
+    program of before), nothing is counted as bound for a 32-bit gather
+    and the answers are the host's."""
+    monkeypatch.setattr(df, "_NARROW_BITS", 4)
+    tk = _chain_tk()
+    before = _counts()
+    for case in ("topn_orders_by_root_column", "child_payload_groups",
+                 "left_child_declined"):
+        _dev_vs_host(tk, _SYN[case][0])
+        root = kinds[-1][3][2][0]
+        assert [t.dtype for t in root.get("pk", [root.get("lut")])] == \
+            [np.int64]
+    assert "word32" not in _grown(before)
+
+
+@pytest.mark.parametrize("join,rows", [
+    ("left join e on f.d_id = e.id", 1),
+    ("where not exists (select 1 from e where e.id = f.d_id)", 1),
+    ("join e on f.d_id = e.id", 0)])
+def test_a_root_of_no_rows(kinds, join, rows):
+    """An empty dimension under a left or an anti join is the one-slot
+    always-miss table, as narrow as any other table of positions; under
+    an inner join the statement has no row and no program."""
+    tk = TestKit()
+    tk.must_exec("create table e (id int primary key, v int)")
+    tk.must_exec("create table f (k int primary key, d_id int, q int)")
+    tk.must_exec("insert into f values " + ",".join(
+        f"({k}, {k % 7}, {k % 9})" for k in range(300)))
+    before = _counts()
+    dev = _dev_vs_host(tk, f"select count(*), sum(f.q) from f {join}")
+    assert (int(dev[0][0]) == 300) == bool(rows)
+    assert _grown(before).get("word32", 0) == rows == len(kinds)
+    if rows:
+        lut = kinds[-1][3][2][0]["lut"]
+        assert lut.dtype == np.int32        # one slot, padded to a bucket
 
 
 def test_duplicate_child_keys_decline(tkc):
@@ -662,11 +848,14 @@ def test_mesh_takes_the_folded_tables(case):
     assert tk.domain.metrics.get("fused_pipeline_mpp_hit", 0) == hits + 1
     grown = _grown(before)
     assert grown.get("folded", 0) >= 1 and grown.get("packed") == 1
-    # the composed table went up replicated, in the lut's place
+    assert grown.get("word32") == 1
+    # the composed table went up replicated, in the lut's place, as
+    # narrow as the host holds it
     store = tk.domain.copr._dev_store
     pk = [k for k in store._entries
           if isinstance(k[1], tuple) and k[1][0] == "pk"]
     assert pk and all(store._spec_of[k] == "replicated" for k in pk)
+    assert all(store.get(k).dtype == np.int32 for k in pk)
     assert not [k for k in store._entries if k[1] in ("lut", "ord")]
 
 
@@ -768,6 +957,7 @@ def test_second_execution_builds_nothing(tk, runs_impl, q):
     # one `packed` a packed root an execution; the words themselves are
     # the fold's, found with it
     assert grown.get("packed", 0) == _PACKED[q]
+    assert grown.get("word32", 0) == _WORD32[q]
     assert not [k for k in grown if k.startswith(("declined_pack",
                                                   "packed_spill"))]
     assert snap.get("upload_bytes", 0) == 0
@@ -789,4 +979,5 @@ def test_bind_span_carries_fold_counts(tk):
     finally:
         tk.must_exec("set tidb_tpu_trace_sample_rate = 0")
     assert rows and "fold_builds" in rows[-1][0]
-    assert [r for r in packed if "packed_roots=2" in r[0]], packed
+    assert [r for r in packed if "packed_roots=2" in r[0]
+            and "word32=2" in r[0]], packed

@@ -13,10 +13,16 @@ is a function of its own row:
 - the payload: what something downstream reads at fact width through
   the root's position -- the root's own columns, a descendant's column
   or join position, their null bits, the position itself when it is a
-  group key -- is packed into the fields of one int64 word a root row
-  and composed with the probe table (`Fold.packed`): the kernel gathers
+  group key -- is packed into the fields of one word a root row and
+  composed with the probe table (`Fold.packed`): the kernel gathers
   the word once by key and shifts each field out. A root that reads
   nothing but its position keeps the plain table of positions.
+
+A table a fact lane gathers from is as wide as what it holds
+(`table_dtype`): int32 where a word's fields, or a table's positions
+and its miss, fit 31 bits and the sign bit, int64 otherwise. The chip
+gathers 32 bits a lane at a time, so an int64 table costs two gathers
+a look-up and an int32 one; the program widens after the gather.
 
 Which dimension folds under which is read from the plan's shape alone
 (`fold_plan`); the tables are built once a snapshot on the host
@@ -224,18 +230,39 @@ def _host_probe(meta, pv, pnm):
     return np.minimum(meta["order"][locc], n - 1), hit
 
 
-# a slot no row passes: the sign bit of word 0 alone, so `word >= 0` is
-# the hit and every field of a miss reads its minimum
-MISS = np.iinfo(np.int64).min
 _WORD_BITS = 63
+_NARROW_BITS = 31
+
+
+def table_dtype(bits):
+    """The physical type of a table a fact lane gathers from, by the
+    bits of the largest value it holds: int32 where they leave the sign
+    bit free (one 32-bit gather a look-up on the chip), else int64
+    (two)."""
+    return np.int32 if bits <= _NARROW_BITS else np.int64
+
+
+def pos_dtype(n):
+    """`table_dtype` of a table of positions under `n`, the miss."""
+    return table_dtype(int(n).bit_length())
+
+
+def miss(dtype):
+    """A slot no row passes: the sign bit of word 0 alone, so
+    `word >= 0` is the hit and every field of a miss reads its minimum
+    (sign-extended, an int32 miss is an int64 one with no field bit)."""
+    return np.iinfo(dtype).min
 
 
 def pack_words(cols):
-    """Pack arrays of one length into int64 words, first fit in the
-    order given: array i holds `value - lo[i]` in the bits its range
-    needs, at `shift[i]` of word `word[i]`. Word 0 keeps its sign bit
-    for the miss; a field that needs more than 63 bits (a double's
-    pattern, a 64-bit range) is a later word of its own, as it is.
+    """Pack arrays of one length into words, first fit in the order
+    given into 63 bits: array i holds `value - lo[i]` in the bits its
+    range needs, at `shift[i]` of word `word[i]`. Word 0 keeps its sign
+    bit for the miss; a field that needs more than 63 bits (a double's
+    pattern, a 64-bit range) is a later word of its own, as it is. A
+    word whose fields end within 31 bits is held as int32, every other
+    as int64 (`table_dtype`): the fit is never re-packed into narrower
+    bins, which could only add words.
     -> (words, word, shift, mask, lo)."""
     ints, lo, bits = [], [], []
     for c in cols:
@@ -258,6 +285,8 @@ def pack_words(cols):
     words = [np.zeros(len(cols[0]), dtype=np.int64) for _ in used]
     for c, mn, wi, sh in zip(ints, lo, word, shift):
         words[wi] |= (c - mn) << sh
+    words = [w.astype(table_dtype(u), copy=False)
+             for w, u in zip(words, used)]
     as64 = lambda xs: np.asarray(xs, dtype=np.int64)    # noqa: E731
     return words, tuple(word), as64(shift), \
         as64([-1 if b == 64 else (1 << b) - 1 for b in bits]), as64(lo)
@@ -265,7 +294,9 @@ def pack_words(cols):
 
 def unpack_field(word, shift, mask, lo, dtype):
     """Field of a packed word (numpy on the host, jax.numpy in the
-    kernel): the value `pack_words` was given, in its own dtype."""
+    kernel): the value `pack_words` was given, in its own dtype. The
+    layout is int64's whatever holds the word: `shift`, `mask` and
+    `lo` widen an int32 one."""
     dtype = np.dtype(dtype)
     v = ((word >> shift) & mask) + lo
     if dtype.kind == "f":
@@ -343,7 +374,7 @@ class Fold:
         # composed with the probe table: one fancy index a word at the
         # table's width, the sentinel slot n reading the miss
         at = np.minimum(self.table, n)
-        tables = [np.append(w, 0 if wi else MISS)[at]
+        tables = [np.append(w, w.dtype.type(0 if wi else miss(w.dtype)))[at]
                   for wi, w in enumerate(words)]
         text = tuple((k, ident, wi, dt)
                      for (k, ident, dt), wi in zip(text, word))
@@ -388,7 +419,9 @@ def _build(fp, plan, metas, root):
     n = meta["n"]
     ok = np.append(passing, False)         # the sentinel n stays a miss
     src = meta["lut"] if meta["mode"] == "direct" else meta["order"]
-    return np.where(ok[np.minimum(src, n)], src, n), pos_at
+    # (in the physical type of the table it stands in for)
+    return np.where(ok[np.minimum(src, n)], src, n) \
+        .astype(src.dtype, copy=False), pos_at
 
 
 _MU = threading.Lock()

@@ -71,9 +71,9 @@ def _direct_span(copr, span, nv) -> bool:
     (`lut[key - lo]`, one gather a probe) rather than a binary search
     over the sorted keys (~70 gathers' time on the chip, PERF.md §7)?
     Two bounds, both from what is observed: the keys are dense enough
-    that the lut is at most four slots a row, and the lut (8 bytes a
-    slot, resident like the dimension's columns) fits an eighth of the
-    resident store's budget — 128 Mi slots at the default 8 GiB, so
+    that the lut is at most four slots a row, and the lut (at most 8
+    bytes a slot, resident like the dimension's columns) fits an eighth
+    of the resident store's budget — 128 Mi slots at the default 8 GiB, so
     TPC-H's orders (18,000,000 sparse keys at scale 3, 60,000,000 at
     10) stay off the binary search, which ran q3, q5 and q10 in 14-16 s
     instead of 1-4 (PERF.md, PR 27)."""
@@ -147,7 +147,8 @@ def _dim_sort_meta(copr, dim, tbl, read_ts):
             hi = int(keys_v.max())
             span = hi - lo + 1
             if _direct_span(copr, span, nv):
-                lut = np.full(span, n, dtype=np.int64)   # n == miss
+                # n == miss
+                lut = np.full(span, n, dtype=dimfold.pos_dtype(n))
                 lut[keys_v - lo] = vidx
                 meta = ("direct", lut, lo, unique, nv, pack)
             else:
@@ -428,7 +429,7 @@ def _materialized_dim_meta(copr, ctx, dim, read_ts):
                (i, len(sd.values)) for i, (_d, _nl, sd) in arrays.items()
                if sd is not None))}
     if _direct_span(copr, span, n):
-        lut = np.full(span, n, dtype=np.int64)
+        lut = np.full(span, n, dtype=dimfold.pos_dtype(n))
         lut[keys_v - lo] = vidx
         out.update(mode="direct", lo=lo, lut=lut, n_sorted=n)
     else:
@@ -518,12 +519,13 @@ def _semi_prefiltered_meta(copr, dim, tbl, arrays, valid, n, key_cid,
             # nothing passes: a 1-slot always-miss lut (the kernel's hit
             # test is lut[idx] < n, so the sentinel must be n itself —
             # any smaller value is a false hit for probe key == lo)
-            meta = ("direct", np.array([n], dtype=np.int64), 0, True, 0)
+            meta = ("direct", np.array([n], dtype=dimfold.pos_dtype(n)),
+                    0, True, 0)
         else:
             lo = int(keys.min())
             span = int(keys.max()) - lo + 1
             if _direct_span(copr, span, nv):
-                lut = np.full(span, n, dtype=np.int64)
+                lut = np.full(span, n, dtype=dimfold.pos_dtype(n))
                 lut[keys - lo] = 0       # any representative: hit test
                 meta = ("direct", lut, lo, True, nv)
             else:
@@ -606,6 +608,7 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None, want=None,
     layout = {}
     if pack is not None:
         layout["pack"] = pack.text
+        layout["words"] = tuple(t.dtype.name for t in pack.tables)
         nullable = {idx for kind, idx, _w, _dt in pack.text if kind == "null"}
         for idx, sdict in pack.sdicts.items():
             layout[idx] = (idx in nullable, sdict)
@@ -655,7 +658,8 @@ def _upload_probe_table(meta, args, put, cap, with_valid, pack=None):
     tcap = shape_bucket(length)
     if pack is not None:
         args["pk"] = [put(("pk", pack.fields, wi), t, length, tcap,
-                          fill=0 if wi else dimfold.MISS, ts_keyed=True)
+                          fill=0 if wi else dimfold.miss(t.dtype),
+                          ts_keyed=True)
                       for wi, t in enumerate(pack.tables)]
         args["fshift"], args["fmask"], args["flo"] = \
             pack.shift, pack.mask, pack.lo
@@ -692,8 +696,9 @@ def _upload_dims(copr, plan, fp, dim_metas, dim_caps, read_ts, mesh,
     """Upload every dimension for one lowering of the statement:
     `pos_grouped` says whether the join positions stand for the group
     items ("posdense", "posruns"), which decides what a folded root has
-    to carry. -> (dim_args, dim_layouts, the roots' pack outcomes for
-    `tidb_tpu_dim_fold_total`)."""
+    to carry. -> (dim_args, dim_layouts, the roots' pack outcomes and
+    a "word32" a table bound for a 32-bit gather, a word or a table of
+    positions, for `tidb_tpu_dim_fold_total`)."""
     need, need_pos, tcol = None, (), None
     if fp is not None:
         need = dimfold.needs(plan, fp, pos_grouped)
@@ -718,6 +723,10 @@ def _upload_dims(copr, plan, fp, dim_metas, dim_caps, read_ts, mesh,
                     outcomes.append("packed_spill")
         da, layout = _upload_dim(copr, dim, meta, dcap, read_ts, mesh,
                                  want, pack)
+        # what a fact lane gathers from by key: the words, or the `lut`
+        probed = da["pk"] if "pk" in da else [da["lut"]] if "lut" in da \
+            else []
+        outcomes += ["word32"] * sum(t.dtype == np.int32 for t in probed)
         dim_args.append(da)
         dim_layouts.append(layout)
     return dim_args, dim_layouts, outcomes
@@ -1100,7 +1109,9 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                 if pk is not None:
                     # a folded root's composed words: ONE gather a word
                     # by key slot (or sorted rank), every field a shift
-                    # and a mask of it; the sign bit is the miss
+                    # and a mask of it; the sign bit is the miss. A word
+                    # is gathered as narrow as its table holds it and
+                    # widened after: never the table before the gather
                     if "lo" in da:
                         lsize = da["pk"][0].shape[0]
                         idx = pv - da["lo"]
@@ -1111,7 +1122,7 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                         loc = jnp.searchsorted(da["sk"], pv)
                         at = jnp.minimum(loc, scap - 1)
                         hit = (da["sk"][at] == pv) & (loc < dsn)
-                    words = [t[at] for t in da["pk"]]
+                    words = [t[at].astype(jnp.int64) for t in da["pk"]]
                     mask = mask & hit & (words[0] >= 0) & ~pnm
                     got = {(kind, ident): dimfold.unpack_field(
                         words[wi], da["fshift"][fi], da["fmask"][fi],
@@ -1126,18 +1137,20 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                     ctx = EvalCtx(jnp, cap, cols, host=False)
                     continue
                 if "lut" in da:
-                    # dense key domain: the join is ONE gather
+                    # dense key domain: the join is ONE gather, of a
+                    # table as narrow as its positions (widened after)
                     lsize = da["lut"].shape[0]
                     idx = pv - da["lo"]
                     inb = (idx >= 0) & (idx < lsize)
-                    pos = da["lut"][jnp.clip(idx, 0, lsize - 1)]
+                    pos = da["lut"][jnp.clip(idx, 0, lsize - 1)] \
+                        .astype(jnp.int64)
                     if masked:
                         hit = inb & (pos < dn) & ~pnm
                     pos = jnp.minimum(pos, dcap - 1)
                     if not masked:
                         hit = inb & \
-                            (da["lut"][jnp.clip(idx, 0, lsize - 1)] < dn) \
-                            & ~pnm
+                            (da["lut"][jnp.clip(idx, 0, lsize - 1)]
+                             .astype(jnp.int64) < dn) & ~pnm
                     if dmask is not None:
                         hit = hit & dmask[pos]
                 else:
@@ -1634,7 +1647,7 @@ def _bind_tables(copr, plan, read_ts, ctx, fp=None, sp=None):
             dim_metas.append({
                 "arrays": arrays, "valid": np.zeros(1, dtype=bool),
                 "n": 1, "tbl": tbl, "mode": "direct",
-                "lut": np.array([1], dtype=np.int64), "lo": 0,
+                "lut": np.array([1], dtype=dimfold.pos_dtype(1)), "lo": 0,
                 "n_sorted": 0, "pack": None,
                 # arrays are fabricated 1-row placeholders, NOT the
                 # table's append-only columns: they must never enter
@@ -1729,6 +1742,7 @@ def fused_partials(copr, plan, read_ts, mesh=None,
                     dimfold.count(o)
                 if sp is not None and fp is not None:
                     sp.attrs["packed_roots"] = outcomes.count("packed")
+                    sp.attrs["word32"] = outcomes.count("word32")
                 dim_up[pos_grouped] = up
         return dim_up[pos_grouped]
 
@@ -2328,7 +2342,8 @@ def _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap, dim_caps,
     dimsig = tuple(
         (d.dag.table_info.id, d.build_key.col.idx, d.join_type,
          d.probe_expr.fingerprint(), m["mode"],
-         len(m["lut"]) if m["mode"] == "direct" else 0,
+         (len(m["lut"]), m["lut"].dtype.name) if m["mode"] == "direct"
+         else 0,
          tuple(f.fingerprint() for f in d.dag.filters),
          tuple(sorted((sc.col.idx, sc.name) for sc in d.dag.cols)),
          tuple((sc.col.idx, pe.fingerprint()) for sc, pe in d.extra_keys),
@@ -2344,6 +2359,8 @@ def _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap, dim_caps,
             agg_kind, agg_param, ecap, _al.policy(),
             tuple(bool(m.get("pre")) for m in dim_metas),
             None if fold is None else fold.sig(),
-            # which field of a composed word is read where is program
-            # text; its shifts, masks and minima are operands
-            tuple(lay.get("pack") for lay in dim_layouts))
+            # which field of a composed word is read where, and the
+            # physical type a word (like a `lut`) is gathered in, is
+            # program text; its shifts, masks and minima are operands
+            tuple((lay.get("pack"), lay.get("words"))
+                  for lay in dim_layouts))

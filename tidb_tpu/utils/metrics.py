@@ -998,7 +998,8 @@ DIM_FOLD = REGISTRY.counter(
     "mask in its probe table), declined_<reason>; build / cache_hit: "
     "a root's folded tables built or found; packed (a root's payload "
     "composed into the word its key addresses), packed_spill (a second "
-    "word): a root, a lowering's upload",
+    "word): a root, a lowering's upload; word32: a word or a table of "
+    "positions of it bound as int32, one 32-bit gather a fact lane",
     ("outcome",))
 AGG_MERGE = REGISTRY.counter(
     "tidb_tpu_agg_merge_total",
