@@ -405,3 +405,104 @@ def test_a_pin_learned_per_dag_holds_for_the_fused_pipeline(monkeypatch):
     del built[:]
     assert len(tk.must_query(sql).rows) == 6000
     assert built[0] == ("sort", "runs") and built[-1] == ("sort", "sorted")
+
+
+# ---- a shape with a dense layout and keys that do not cluster ----------
+
+def test_a_dense_domain_past_bcr_is_kept_for_the_day_runs_degrade(runs):
+    """200,001 part keys: no shape's first choice under the runs policy
+    (a scatter on the chip), so the runs lowering, with the layout kept;
+    a degraded run pins the shape to it and not to the argsort
+    program."""
+    st = _state()
+    low = al.Lowering(st, None, _dense(200_001))
+    assert low.sizes is None and low.dense_alt == _dense(200_001)
+    kind, param, _ = low.choose(CAP)
+    assert (kind, param[1]) == ("sort", "runs")
+    assert low.observe(kind, param, None, CAP, CAP, CAP - 1, CAP,
+                       None) == "retry"
+    assert st.pin == "dense"
+    assert low.choose(CAP) == ("dense", tuple(_dense(200_001)), None)
+    assert low.sizes == _dense(200_001)     # what the consumer decodes by
+    # the same shape seen by a statement that knows the layout
+    assert al.Lowering(st, None, _dense(200_001),
+                       site="dag").choose(CAP)[0] == "dense"
+    # and by one that does not: the policy's lowering, then "sorted"
+    other = al.Lowering(st, None, None)
+    kind, param, _ = other.choose(CAP)
+    assert (kind, param[1]) == ("sort", "runs")
+    assert other.observe(kind, param, None, CAP, CAP, CAP - 1, CAP,
+                         None) == "retry"
+    assert st.pin == "sorted"
+
+
+def test_the_degraded_verdict_names_the_pin(runs, judged_runs):
+    low = al.Lowering(_state(), None, _dense(70_000), site="dag")
+    kind, param, _ = low.choose(CAP)
+    low.observe(kind, param, None, CAP, CAP, CAP - 1)
+    assert judged_runs() == {("dag", "sort_runs", "retry_pin_dense"): 1}
+
+
+@pytest.mark.parametrize("counted, pin, kind", [
+    (True, "dense", "dense"), (False, None, "sort")])
+def test_keys_the_host_counted_unclustered_build_no_runs_program(
+        runs, counted, pin, kind):
+    asked = []
+    st = _state()
+    low = al.Lowering(st, None, _dense(200_001),
+                      unclustered=lambda: asked.append(1) or counted)
+    assert asked == [1] and st.pin == pin
+    assert low.choose(CAP)[0] == kind
+    # asked once a shape: a pin answers from then on
+    al.Lowering(st, None, _dense(200_001),
+                unclustered=lambda: asked.append(1) or counted)
+    assert len(asked) == (1 if counted else 2)
+
+
+def test_the_host_is_not_asked_where_dense_is_the_first_choice(runs):
+    asked = []
+    for sizes in (_dense(64), None):
+        al.Lowering(_state(), None, sizes,
+                    unclustered=lambda: asked.append(1) or True)
+    assert asked == []
+
+
+def test_the_cpu_default_never_keeps_a_layout_aside():
+    asked = []
+    low = al.Lowering(_state(), None, _dense(200_001),
+                      unclustered=lambda: asked.append(1) or True)
+    assert low.sizes == _dense(200_001) and low.dense_alt is None
+    assert asked == [] and low.choose(CAP)[0] == "dense"
+
+
+def test_dense_form_under_the_runs_policy(runs):
+    assert [al.dense_form(n) for n in (1, 64, 65, al.DENSE_MAX,
+                                       al.DENSE_MAX + 1)] == \
+        ["reduce", "bcr", "scatter", "scatter", None]
+    assert [al.dense_first(n) for n in (1, 64, 65, al.DENSE_MAX + 1)] == \
+        [True, True, False, False]
+
+
+class _Dag:
+    def __init__(self, *items):
+        self.group_items = list(items)
+
+
+@pytest.mark.parametrize("keys, want", [
+    ("shuffled", True), ("ordered_runs_of_four", False), ("unique", True)])
+def test_host_unclustered_counts_key_changes(monkeypatch, keys, want):
+    monkeypatch.setattr(al, "RUNS_DEGRADE_MIN", 1024)
+    n = 1 << 14
+    data = {"shuffled": np.random.default_rng(7).integers(0, 1000, n),
+            "ordered_runs_of_four": np.arange(n) // 4,
+            "unique": np.arange(n)}[keys].astype(np.int64)
+    g = Column(0, new_bigint_type(), "k")
+    assert al.host_unclustered(_Dag(g), {0: (data, None, None)}, n) is want
+
+
+def test_host_unclustered_leaves_what_it_cannot_count_to_the_device():
+    n = 1 << 14
+    g = Column(0, new_bigint_type(), "k")
+    floats = np.random.default_rng(7).random(n)
+    assert not al.host_unclustered(_Dag(g), {0: (floats, None, None)}, n)
+    assert not al.host_unclustered(_Dag(), {}, n)

@@ -102,10 +102,15 @@ def test_fused_topn_negative_sums_split_groups(runs_impl):
         [tuple(map(str, r)) for r in host]
 
 
-def test_fused_topn_disabled_after_degrade_pin(runs_impl, monkeypatch):
+@pytest.mark.parametrize("stride, pin", [(1000003, "sorted"), (1, "dense")])
+def test_fused_topn_disabled_after_degrade_pin(runs_impl, monkeypatch,
+                                               stride, pin):
     """Once the runs-degradation guard pins a shape to the sorted
     lowering, candidate pruning must switch off (its boundary-forcing
-    assumes storage order) and results must stay exact."""
+    assumes storage order) and results must stay exact. Keys that span
+    a dense integer domain (stride 1: 1..400) are pinned to the dense
+    table instead, by the host's count of key changes before any
+    program is built (PR 38): no pruning there either."""
     monkeypatch.setattr(al, "RUNS_DEGRADE_MIN", 8)
     tk = TestKit()
     # wide unclustered-ish keys: clustered anchor exists (monotone ok)
@@ -114,9 +119,9 @@ def test_fused_topn_disabled_after_degrade_pin(runs_impl, monkeypatch):
     tk.must_exec("create table f (ok bigint, v int)")
     n = 400
     tk.must_exec("insert into d values " + ",".join(
-        f"({k},{k % 13})" for k in range(1, n + 1)))
+        f"({k * stride},{k % 13})" for k in range(1, n + 1)))
     tk.must_exec("insert into f values " + ",".join(
-        f"({k},{(k * 31) % 50})" for k in range(1, n + 1)))
+        f"({k * stride},{(k * 31) % 50})" for k in range(1, n + 1)))
     sql = ("select f.ok, sum(f.v) s from f join d on f.ok = d.ok "
            "group by f.ok order by s desc, f.ok limit 4")
     calls = {"n": 0}
@@ -136,8 +141,8 @@ def test_fused_topn_disabled_after_degrade_pin(runs_impl, monkeypatch):
         assert [tuple(map(str, r)) for r in got] == \
             [tuple(map(str, r)) for r in host]
     hc = tk.domain.copr._host_cache
-    assert "sorted" in [v for k, v in hc.items()
-                        if k and k[0] == "aggimpl"]
+    assert [v for k, v in hc.items()
+            if k and k[0] == "aggimpl"] == [pin]
 
 
 def test_fused_topn_tie_fallback(runs_impl, judged_runs):

@@ -371,8 +371,15 @@ SITES = {
 }
 
 
+# the same shapes over a key with a dense layout (100 suppliers, ids
+# 1..2000): past BCR_MAX slots, so not the runs policy's first choice
+DENSE_KEY = {"l_suppkey * 1000003": "l_suppkey", " k,": " id,",
+             "by k": "by id"}
+
+
 @pytest.mark.parametrize("site", sorted(SITES))
-@pytest.mark.parametrize("reason", ["grow_bucket", "pin_sorted"])
+@pytest.mark.parametrize("reason", ["grow_bucket", "pin_sorted",
+                                    "pin_dense"])
 def test_a_forced_retry_is_named_by_its_reason(
         request, runs_policy, monkeypatch, judged_runs, site, reason):
     """Keys that do not cluster give a run a row: more partials than
@@ -380,13 +387,22 @@ def test_a_forced_retry_is_named_by_its_reason(
     the floor of `runs_degraded` out of the way, more than half the
     rows (the shape is pinned to the sorted lowering). Each thrown-away
     run is counted under its reason, the run that stands under
-    `stands`, on the site whose kernel ran."""
+    `stands`, on the site whose kernel ran. Where the keys span a dense
+    layout (`pin_dense`) the host has counted the key changes before
+    any program is built: the shape is pinned to the dense table at
+    once, nothing is thrown away, and no argsort program exists."""
     where, sql = SITES[site]
     d = request.getfixturevalue(where)
     _fresh_table(d)
-    if reason == "pin_sorted":
+    # a sparse key: no dense layout to fall back on
+    sql = sql.replace("l_suppkey", "l_suppkey * 1000003")
+    if reason == "pin_dense":
+        for sparse, dense in DENSE_KEY.items():
+            sql = sql.replace(sparse, dense)
+    if reason != "grow_bucket":
         monkeypatch.setattr(al, "RUNS_DEGRADE_MIN", 0)
-    n = 3400 + sorted(SITES).index(site) * 2 + (reason == "pin_sorted")
+    n = 3400 + sorted(SITES).index(site) * 3 + \
+        ["grow_bucket", "pin_sorted", "pin_dense"].index(reason)
     prepare = ["set tidb_tpu_fragment_min_rows = 0"]
     rows, grown, spans = _traced(d, judged_runs, sql.format(n=n), prepare)
     assert len(rows) == (2000 if site == "dag" else 100)
@@ -397,6 +413,8 @@ def test_a_forced_retry_is_named_by_its_reason(
                 (site, "sort_sorted", "stands"): 1}
         if site == "dag":       # 2,000 groups against a bucket of 1,024
             want[(site, "sort_sorted", "retry_grow_bucket")] = 1
+    if reason == "pin_dense":
+        want = {(site, "dense", "stands"): 1}
     assert grown == want, grown
     # the open `consume` span carries the last verdict and the count
     last = [a for a in spans["consume"] if "verdict" in a][-1]

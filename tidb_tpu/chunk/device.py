@@ -173,7 +173,7 @@ class StringDict:
     __slots__ = ("values", "index", "sort_keys", "_vec_cache",
                  "_vecmat_cache",
                  "_ci_norm", "_ci_fold", "_ci_ranks", "_ci_fold_ranks",
-                 "_rank_codes")
+                 "_rank_codes", "_fn_tables")
 
     def __init__(self):
         self.values: list[str] = []
@@ -186,6 +186,9 @@ class StringDict:
         self._ci_ranks = {}  # coll -> (n, code -> ci sort rank)
         self._ci_fold_ranks = {}  # coll -> (n, code -> folded ci rank)
         self._rank_codes = None  # ((coll, n), (code_map, sorted dict))
+        # expression/vec.py _dict_table: (predicate fingerprint, dtype)
+        # -> (n, table of fn over values[:n]), oldest first
+        self._fn_tables = {}
 
     def encode(self, arr: np.ndarray) -> np.ndarray:
         """Encode an object array of strings to int32 codes, extending dict.

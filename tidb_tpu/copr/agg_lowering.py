@@ -92,9 +92,13 @@ def dense_nslots(sizes):
 
 def dense_form(nslots):
     """How a dense table of `nslots` slots is reduced: "reduce" | "bcr"
-    | "sorted" | "scatter"; None: no dense form at this size (more than
-    BCR_MAX slots under the runs policy). The one place that rule
-    lives: `Lowering` and `dense_agg_states` ask here."""
+    | "sorted" | "scatter"; None: no dense form at this size. Under the
+    runs policy a table of more than BCR_MAX slots is a scatter
+    (`jax.ops.segment_*`, which XLA:TPU serializes: seconds over a 6M-row
+    table, and two seconds to compile where the argsort program takes
+    minutes and 29 GB) up to DENSE_MAX slots, and no shape's first
+    choice (`dense_fits`): only a shape pinned to it takes it. The one
+    place that rule lives: `Lowering` and `dense_agg_states` ask here."""
     if nslots == 1:
         # global aggregation: a scatter into one slot is never better
         # than a plain masked reduce, on ANY backend (on the CPU proxy
@@ -103,12 +107,22 @@ def dense_form(nslots):
     impl = policy()
     if impl != "runs":
         return impl
-    return "bcr" if nslots <= BCR_MAX else None
+    if nslots <= BCR_MAX:
+        return "bcr"
+    return "scatter" if nslots <= DENSE_MAX else None
+
+
+def dense_first(nslots) -> bool:
+    """Is a dense table of `nslots` slots a shape's first choice under
+    the policy? Under "runs" only the scatter-free forms are."""
+    form = dense_form(nslots)
+    return form is not None and not (form == "scatter" and
+                                     policy() == "runs")
 
 
 def dense_fits(sizes) -> bool:
-    """Has the dense layout `sizes` a dense form under the policy?"""
-    return dense_form(dense_nslots(sizes)) is not None
+    """Is the dense layout `sizes` a shape's first choice?"""
+    return dense_first(dense_nslots(sizes))
 
 
 def onehot_fits(nslots) -> bool:
@@ -128,6 +142,32 @@ def runs_degraded(ngroups, m) -> bool:
     where the wrong side is a sort program that costs the TPU compiler
     29 GB of host memory and 390 s at that width (PERF.md, PR 27)."""
     return ngroups > max(RUNS_DEGRADE_MIN, m // 2)
+
+
+def host_unclustered(dag, cols, n) -> bool:
+    """Would the runs lowering explode over these `n` rows? One host
+    pass over the group items (plain integer items alone: anything else
+    answers False and the device run decides) counting the rows whose
+    key differs from the row before: the least partials the runs
+    lowering can return, since a filter only splits runs further.
+    Asked once a shape, while nothing is pinned (`Lowering`)."""
+    if not dag.group_items or n < 2:
+        return False
+    ctx = EvalCtx(np, n, cols, host=True)
+    change = np.zeros(n - 1, dtype=bool)
+    for g in dag.group_items:
+        try:
+            data, nulls, sd = eval_expr(ctx, g)
+        except Exception:               # noqa: BLE001
+            return False
+        if sd is not None or np.isscalar(data) or nulls is not None and \
+                not np.isscalar(nulls) and np.asarray(nulls).any():
+            return False
+        data = np.asarray(data)
+        if data.dtype.kind not in "iu" or len(data) != n:
+            return False
+        change |= data[1:] != data[:-1]
+    return runs_degraded(int(np.count_nonzero(change)) + 1, n)
 
 
 # ---- what a shape has taught ------------------------------------------
@@ -158,7 +198,7 @@ class ShapeState:
                     tuple(g.fingerprint() for g in group_items),
                     tuple(a.fingerprint() for a in aggs))
 
-    pin = _slot("aggimpl")              # "sorted" | None
+    pin = _slot("aggimpl")              # "sorted" | "dense" | None
     compact = _slot("fcompact")         # late buffer: int | "off" | None
     early_compact = _slot("fecompact")  # early buffer, likewise
     topn_off = _slot("ftopn_off")       # True | None
@@ -223,11 +263,11 @@ class Lowering:
     early compaction), "dag" (per-DAG: dense or sort, no compaction).
     topn: the validated (kind, index, desc, k)."""
 
-    __slots__ = ("state", "pos", "posruns", "sizes", "site", "dims",
-                 "topn")
+    __slots__ = ("state", "pos", "posruns", "sizes", "dense_alt", "site",
+                 "dims", "topn")
 
     def __init__(self, state, pos_spec=None, sizes=None, *, site="fused",
-                 dims=False, topn=None):
+                 dims=False, topn=None, unclustered=None):
         self.state, self.site, self.dims = state, site, dims
         self.topn = topn if site == "fused" else None
         # a position domain too large for the packed-slot lowering: on
@@ -236,7 +276,7 @@ class Lowering:
         # items are evaluated at fact width and sorted
         self.posruns = None
         if pos_spec is not None and (pos_spec[2] > POS_DENSE_MAX or
-                                     dense_form(pos_spec[2]) is None):
+                                     not dense_first(pos_spec[2])):
             if site == "fused" and policy() == "runs":
                 self.posruns = pos_spec
             pos_spec = None
@@ -245,10 +285,18 @@ class Lowering:
             sizes = None
         elif callable(sizes):
             sizes = sizes()
+        self.dense_alt = None
         if sizes is not None and not dense_fits(sizes):
             # big dense domains have no scatter-free dense lowering:
-            # they fall to the contiguous-run partials
-            sizes = None
+            # they fall to the contiguous-run partials, and keep the
+            # layout for the day the runs degrade (pin "dense": the
+            # scatter, where the other pin is the argsort program)
+            self.dense_alt, sizes = sizes, None
+            if state.pin is None and unclustered is not None and \
+                    unclustered():
+                # the host has counted the key changes: no runs program
+                # is built to be thrown away
+                state.pin = "dense"
         if sizes is not None:
             # a few dict codes (c_mktsegment over 150k customers): the
             # dense kind's compare-reduce beats runs over scattered
@@ -276,12 +324,17 @@ class Lowering:
             kind, param = "posdense", (tuple(self.pos[1]), self.pos[2])
         elif self.sizes is not None:
             kind, param = "dense", tuple(self.sizes)
+        elif st.pin == "dense" and self.dense_alt is not None:
+            self.sizes = self.dense_alt     # what the consumers decode by
+            kind, param = "dense", tuple(self.sizes)
         elif fused and isinstance(st.onehot, dict) and \
                 cap <= ONEHOT_CAP_MAX:
             kind, param = "onehot", (st.onehot["scap"],)
         else:
             posruns = self._posruns_on()
-            impl = st.pin or policy()
+            # (a "dense" pin whose layout this statement lacks: the
+            # policy's; a degraded run then pins "sorted")
+            impl = "sorted" if st.pin == "sorted" else policy()
             bucket = st.bucket
             topn = None
             # candidate pruning is sound ONLY under the runs lowering:
@@ -360,11 +413,12 @@ class Lowering:
             return "retry_compact"
         if (kind == "posruns" or param[1] == "runs") and \
                 runs_degraded(ngroups, rows):
-            # unclustered group keys: pin this shape to the sorted
-            # lowering (one partial per group) before the bucket
+            # unclustered group keys: pin this shape to the dense
+            # scatter where it has a dense layout, else to the sorted
+            # lowering (one partial per group), before the bucket
             # learns the inflated count
-            st.pin = "sorted"
-            return "retry_pin_sorted"
+            st.pin = "dense" if self.dense_alt is not None else "sorted"
+            return "retry_pin_" + st.pin
         if ngroups > param[0]:
             # against the bucket THIS kernel was built with, not one
             # grown since by another partition: an overflowed run

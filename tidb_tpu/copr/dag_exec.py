@@ -1011,7 +1011,8 @@ class CoprExecutor:
         # direct scatter-add, no sort (Q1 / year()-grouping shapes)
         low = _al.Lowering(
             _al.ShapeState(self, tbl, dag.group_items, dag.aggs),
-            sizes=dense_strides(dag, kd, cols, m), site="dag")
+            sizes=dense_strides(dag, kd, cols, m), site="dag",
+            unclustered=lambda: _al.host_unclustered(dag, cols, m))
         retries = 0     # re-dispatches the learned lowering forced
         while True:
             kind, param, _ecap = low.choose(cap)

@@ -36,6 +36,7 @@ from .agg_lowering import (PartialAggResult, capture_agg_dicts,
 from ..utils.fetch import prefetch, host_array, host_int
 from ..utils import failpoint
 from ..utils import jaxcfg
+from ..utils import metrics as _metrics
 from ..utils import phase
 from ..utils import tracing as _tracing
 
@@ -62,7 +63,6 @@ def _set_reason(copr, msg):
     dom = getattr(copr, "domain", None)
     if dom is not None:
         dom.last_fused_reason = msg
-    from ..utils import metrics as _metrics
     _metrics.FUSED_DECLINE.labels(_metrics.reason_code(msg)).inc()
 
 
@@ -337,10 +337,29 @@ class _MatTbl:
 
 
 def _materialized_dim_meta(copr, ctx, dim, read_ts):
+    """Span `matdim` and tidb_tpu_matdim_total{outcome} round
+    `_matdim_meta`: `hit` where the cache keyed on the subplan's
+    fingerprint and its base tables' versions answered, `build` where
+    the subplan ran (`groups`: the dimension's rows; `rows`: its base
+    tables')."""
+    with _tracing.span("matdim") as sp:
+        seen = {}
+        meta = _matdim_meta(copr, ctx, dim, read_ts, seen)
+        outcome = seen.get("outcome", "build")
+        _metrics.MATDIM.labels(outcome).inc()
+        if sp is not None:
+            sp.attrs["outcome"] = outcome
+            sp.attrs["groups"] = 0 if meta is None else meta["n"]
+            sp.attrs["rows"] = seen.get("rows", 0)
+    return meta
+
+
+def _matdim_meta(copr, ctx, dim, read_ts, seen):
     """Execute dim.subplan (Q17's decorrelated per-key aggregate, Q18's
     grouped IN-subquery) and shape its output like a dim table: arrays
     keyed by output POSITION, every row valid, group keys unique by
-    construction (still verified). -> meta dict or None."""
+    construction (still verified). -> meta dict or None; `seen` takes
+    `outcome` "hit" on a cache hit and the base tables' `rows`."""
     if ctx is None:
         _set_reason(copr, "materialized dim: no execution context")
         return None
@@ -369,6 +388,7 @@ def _materialized_dim_meta(copr, ctx, dim, read_ts):
         ck = ("matdim", fp, tz)
         vers = tuple((t.uid, t.version) for t in base)
         maxts = max(t.max_commit_ts for t in base)
+        seen["rows"] = sum(t.n for t in base)
         lru = _matdim_cache(copr)
         ent = lru.get(ck)
         if ent is not None:
@@ -377,6 +397,7 @@ def _materialized_dim_meta(copr, ctx, dim, read_ts):
             if evers == vers and (ets is None or maxts <= ets) and \
                     (read_ts is None or maxts <= read_ts):
                 lru.move_to_end(ck)
+                seen["outcome"] = "hit"
                 return cached
     from ..executor.builder import build_executor
     ex = build_executor(ctx, dim.subplan)
@@ -730,6 +751,30 @@ def _upload_dims(copr, plan, fp, dim_metas, dim_caps, read_ts, mesh,
         dim_args.append(da)
         dim_layouts.append(layout)
     return dim_args, dim_layouts, outcomes
+
+
+def _probe_modes(plan, fp, dim_metas):
+    """-> [(join type, mode)] a dimension, for
+    tidb_tpu_fused_dim_probe_total: what resolves it at fact width.
+    `folded`: at its parent's width, no probe of its own; `search`: a
+    binary search over sorted keys, whatever the dimension is; else one
+    gather, of a prefiltered semi table (`exists`), of a materialised
+    aggregate dimension's table (`matdim`) or of its own table of
+    positions or word (`direct`)."""
+    out = []
+    for di, (dim, meta) in enumerate(zip(plan.dims, dim_metas)):
+        if fp is not None and fp.parent[di] is not None:
+            mode = "folded"
+        elif meta["mode"] != "direct":
+            mode = "search"
+        elif meta.get("pre"):
+            mode = "exists"
+        elif dim.subplan is not None:
+            mode = "matdim"
+        else:
+            mode = "direct"
+        out.append((dim.join_type, mode))
+    return out
 
 
 def _fused_topn_state(plan, fact_tbl, state, kd, sd):
@@ -1743,6 +1788,13 @@ def fused_partials(copr, plan, read_ts, mesh=None,
                 if sp is not None and fp is not None:
                     sp.attrs["packed_roots"] = outcomes.count("packed")
                     sp.attrs["word32"] = outcomes.count("word32")
+                if not dim_up:      # once a statement, not a lowering
+                    probes = _probe_modes(plan, fp, dim_metas)
+                    for join, mode in probes:
+                        _metrics.FUSED_DIM_PROBE.labels(join, mode).inc()
+                    if sp is not None:
+                        sp.attrs["probes"] = "+".join(
+                            m for _j, m in probes)
                 dim_up[pos_grouped] = up
         return dim_up[pos_grouped]
 
@@ -1784,15 +1836,24 @@ def fused_partials(copr, plan, read_ts, mesh=None,
     # aggregates) shape: bucket, impl pin, compaction, top-n, one-hot
     st = _al.ShapeState(copr, fact_tbl, plan.group_items, plan.aggs)
 
+    fcols = None
+
     def _dense_sizes():
         """The dense layout of the group items, or None. Asked for only
         when no position domain stands."""
-        fcols = None
-        if not plan.dims and n:
-            # zero-dim pipeline: int group keys can dense-detect via a
-            # host min/max pass over the fact arrays (q15's GROUP BY
-            # l_suppkey), exactly like the copr reader path — without
-            # this they fall to the sort lowering
+        nonlocal fcols
+        fact_idxs = {sc.col.idx for sc in plan.fact_dag.cols}
+        if n and (not plan.dims or all(
+                dimfold._idxs(g) <= fact_idxs
+                for g in plan.group_items)):
+            # group items that read the fact's own columns alone (a
+            # zero-dim pipeline: q15's GROUP BY l_suppkey; Q13's
+            # customers under their counted orders): int group keys can
+            # dense-detect via a host min/max pass over the fact arrays,
+            # exactly like the copr reader path — without this they
+            # fall to the sort lowering. Items that read a dimension
+            # would need a host pass over gathered values, which the
+            # fused path deliberately avoids
             fcols = {}
             for sc in plan.fact_dag.cols:
                 cid = _cid_of(plan.fact_dag, sc)
@@ -1812,7 +1873,9 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         site="fused" if mesh is None else "fused_mpp",
         dims=bool(plan.dims),
         topn=None if mesh is not None else
-        _fused_topn_state(plan, fact_tbl, st, kd, sd))
+        _fused_topn_state(plan, fact_tbl, st, kd, sd),
+        unclustered=lambda: fcols is not None and
+        _al.host_unclustered(shim, fcols, n))
 
     fact_sdicts = {k: v[2] for k, v in one.items()
                    if k in {sc.col.idx for sc in plan.fact_dag.cols}}

@@ -32,6 +32,7 @@ from ..types.time_types import MICROS_PER_DAY, MICROS_PER_SEC
 from ..errors import UnknownFunctionError
 from .expr import Expression, Column, Constant, ScalarFunc
 from ..chunk.device import StringDict
+from ..utils import metrics as _metrics, tracing as _tracing
 
 _POW10 = [10 ** i for i in range(19)]
 
@@ -283,12 +284,44 @@ def _is_string_val(val, expr):
         (hasattr(data, "dtype") and data.dtype == object)
 
 
-def _dict_table(ctx, sdict: StringDict, fn, dtype):
-    """Host-compute fn over dictionary values -> lookup table (device const)."""
+_FN_TABLES_MAX_BYTES = 64 << 20      # kept with one dictionary
+
+
+def _dict_table(ctx, sdict: StringDict, fn, dtype, key=None):
+    """Host-compute fn over dictionary values -> lookup table (device
+    const): a Python call a value, so 1.5 M distinct comments are a
+    second of host time. `key` names fn (a predicate's fingerprint):
+    the table is then kept with the dictionary, an unchanged dictionary
+    answers from it (`hit`) and a grown one evaluates its new values
+    alone; without one every call evaluates every value. Span
+    `dict_filter`, counter tidb_tpu_dict_filter_total."""
     vals = sdict.values
-    tbl = np.empty(max(len(vals), 1), dtype=dtype)
-    for i, s in enumerate(vals):
-        tbl[i] = fn(s)
+    n = len(vals)
+    with _tracing.span("dict_filter", values=n) as sp:
+        kept = sdict._fn_tables if key is not None else None
+        ck = (key, np.dtype(dtype).str)
+        have, old = kept.get(ck, (0, None)) if kept is not None \
+            else (0, None)
+        if old is not None and have == n:
+            tbl, outcome = old, "hit"
+        else:
+            tbl = np.empty(max(n, 1), dtype=dtype)
+            if have:                  # dictionaries only grow
+                tbl[:have] = old[:have]
+            for i in range(have, n):
+                tbl[i] = fn(vals[i])
+            outcome = "build"
+            if kept is not None:
+                kept.pop(ck, None)
+                kept[ck] = (n, tbl)
+                while len(kept) > 1 and sum(
+                        t.nbytes for _n, t in kept.values()) > \
+                        _FN_TABLES_MAX_BYTES:
+                    kept.pop(next(iter(kept)))
+        _metrics.DICT_FILTER.labels(outcome).inc()
+        if sp is not None:
+            sp.attrs["outcome"] = outcome
+            sp.attrs["kept"] = int(np.count_nonzero(tbl[:n]))
     return ctx.xp.asarray(tbl) if not ctx.host else tbl
 
 
@@ -310,10 +343,12 @@ def _string_elementwise(ctx, data, fn, dtype=object):
     return out
 
 
-def _apply_str_fn(ctx, val, fn, out_is_string=True, out_dtype=None):
+def _apply_str_fn(ctx, val, fn, out_is_string=True, out_dtype=None,
+                  key=None):
     """Apply python str->x over a string value (dict column, object array,
     or scalar). out_dtype picks the non-string result dtype (int64
-    default; float fns MUST pass float64 or values truncate)."""
+    default; float fns MUST pass float64 or values truncate). `key`: see
+    _dict_table."""
     data, nulls, sdict = val
     if out_dtype is None:
         out_dtype = np.int64
@@ -323,7 +358,7 @@ def _apply_str_fn(ctx, val, fn, out_is_string=True, out_dtype=None):
     if sdict is not None:
         if out_is_string:
             return _dict_transform(ctx, data, nulls, sdict, fn)
-        tbl = _dict_table(ctx, sdict, fn, out_dtype)
+        tbl = _dict_table(ctx, sdict, fn, out_dtype, key)
         return tbl[data], nulls, None
     # host object array
     if out_is_string:
@@ -572,6 +607,7 @@ def _cmp_strings(ctx, expr, op_name, aval, bval):
         fold = collation_fold(cn)
     else:
         fold = None if nopad else _pad_fold
+        cn = "pad"
     if fold is not None:
         if isinstance(a, str) and isinstance(b, str):
             return (_cmp_core(xp, op_name, fold(a), fold(b)),
@@ -579,14 +615,17 @@ def _cmp_strings(ctx, expr, op_name, aval, bval):
         if isinstance(b, str) and ad is not None:
             tbl = _dict_table(ctx, ad,
                               lambda s: _cmp_core(np, op_name, fold(s),
-                                                  fold(b)), np.bool_)
+                                                  fold(b)), np.bool_,
+                              key=("cmp", op_name, cn, b))
             return tbl[a], or_nulls(xp, an, bn), None
         if isinstance(a, str) and bd is not None:
             flip = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}
             tbl = _dict_table(ctx, bd,
                               lambda s: _cmp_core(
                                   np, flip.get(op_name, op_name),
-                                  fold(s), fold(a)), np.bool_)
+                                  fold(s), fold(a)), np.bool_,
+                              key=("cmp", flip.get(op_name, op_name), cn,
+                                   a))
             return tbl[b], or_nulls(xp, an, bn), None
         if ad is not None and bd is not None:
             merged = StringDict()
@@ -612,7 +651,7 @@ def _cmp_strings(ctx, expr, op_name, aval, bval):
                 r = _cmp_core(xp, op_name, a, code)
                 return r, or_nulls(xp, an, bn), None
             tbl = _dict_table(ctx, ad, lambda s: _cmp_core(np, op_name, s, b),
-                              np.bool_)
+                              np.bool_, key=("cmp", op_name, "binary", b))
             return tbl[a], or_nulls(xp, an, bn), None
         fb = fold(b) if fold else b
         r = _string_elementwise(
@@ -699,7 +738,8 @@ def _truthy(ctx, val, ft):
         except ValueError:
             data = 0.0
     if sdict is not None:
-        tbl = _dict_table(ctx, sdict, _str_truthy, np.bool_)
+        tbl = _dict_table(ctx, sdict, _str_truthy, np.bool_,
+                          key=("truthy",))
         return tbl[data], nulls
     if hasattr(data, "dtype") and data.dtype == object:
         return _string_elementwise(ctx, data, _str_truthy, np.bool_), nulls
@@ -1055,7 +1095,7 @@ def op_like(ctx, expr):
     flags = re.DOTALL | (re.IGNORECASE if _is_ci(expr.args[0].ft) else 0)
     rx = re.compile(like_to_regex(pat, esc), flags)
     return _apply_str_fn(ctx, av, lambda s: rx.match(s) is not None,
-                         out_is_string=False)
+                         out_is_string=False, key=("like", pat, esc, flags))
 
 
 @op("regexp")
@@ -1066,7 +1106,7 @@ def op_regexp(ctx, expr):
         raise UnknownFunctionError("non-constant REGEXP pattern unsupported")
     rx = re.compile(pat)
     return _apply_str_fn(ctx, av, lambda s: rx.search(s) is not None,
-                         out_is_string=False)
+                         out_is_string=False, key=("regexp", pat))
 
 
 # ---------------- string functions ----------------

@@ -1001,6 +1001,30 @@ DIM_FOLD = REGISTRY.counter(
     "word): a root, a lowering's upload; word32: a word or a table of "
     "positions of it bound as int32, one 32-bit gather a fact lane",
     ("outcome",))
+FUSED_DIM_PROBE = REGISTRY.counter(
+    "tidb_tpu_fused_dim_probe_total",
+    "Dimensions of fused statements by join type (inner, semi, anti, "
+    "left) and by what resolves the dimension at fact width, one count "
+    "a dimension a statement, at the bind that uploads the dimensions: "
+    "folded (resolved into a parent's table or word: no probe of its "
+    "own), search (a binary search over sorted keys, whatever the "
+    "dimension), exists (a prefiltered semi table, one gather), matdim "
+    "(a materialised aggregate dimension, one gather), direct (its own "
+    "table of positions or word, one gather)", ("join", "mode"))
+MATDIM = REGISTRY.counter(
+    "tidb_tpu_matdim_total",
+    "Materialised aggregate dimensions of fused statements (Q17's "
+    "decorrelated avg, Q18's grouped IN-subquery, Q13's counted outer "
+    "side) by outcome: hit (answered from the cache keyed on the "
+    "subplan's fingerprint and its base tables' versions), build (the "
+    "subplan ran)", ("outcome",))
+DICT_FILTER = REGISTRY.counter(
+    "tidb_tpu_dict_filter_total",
+    "Python functions mapped over a string dictionary's values on the "
+    "host (expression/vec.py _dict_table: LIKE, REGEXP, a string in "
+    "boolean context) by outcome: hit (an unchanged dictionary answered "
+    "from the table kept with it), build (values were evaluated: all of "
+    "them, or a grown dictionary's new ones)", ("outcome",))
 AGG_MERGE = REGISTRY.counter(
     "tidb_tpu_agg_merge_total",
     "Final merges of two or more live aggregation partials by what "
@@ -1032,7 +1056,8 @@ AGG_LOWERING = REGISTRY.counter(
     "| dag), the lowering that ran (kind: dense, posdense, "
     "posruns, sort_<segment impl>, onehot) and the verdict: stands, or "
     "why the run was thrown away and run again (retry_early_compact, "
-    "retry_compact, retry_pin_sorted, retry_grow_bucket, "
+    "retry_compact, retry_pin_sorted, retry_pin_dense, "
+    "retry_grow_bucket, "
     "retry_onehot_miss, retry_topn_unproven)",
     ("site", "kind", "verdict"))
 
