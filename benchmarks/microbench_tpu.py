@@ -6,7 +6,8 @@ directly, on the chip:
 
     python benchmarks/microbench_tpu.py [section ...]
 
-Sections: io, reduce, group, sort by default; probe, sort4m, mxu and
+Sections: io, reduce, group, sort by default; probe (its last rows
+alone: bucket), sort4m, mxu and
 scatter by name (scatter is the slowest to COMPILE on a TPU — run it
 last, with a long timeout).
 
@@ -46,6 +47,197 @@ def bench(label, fn, *args, reps=5):
         fetch(fn(*args))
     print(f"{label}: {(time.time() - t0) / reps * 1000:.2f} ms/op",
           flush=True)
+
+
+def _bucket_rows(rng):
+    """The forms a probe on a two-column key can take, at q9's sizes
+    (PR 40, step 0): partsupp at SF1 is 800,000 rows keyed by
+    (ps_partkey, ps_suppkey), a part has four suppliers, and q9 probes
+    it at lineitem's width, a 4,194,304-lane block and a
+    1,835,008-lane tail. Every row returns the sum of the hit lanes'
+    positions and the hits' count, so the probe's whole result is kept
+    alive and nothing but two scalars is fetched; the first call's
+    answer is checked against numpy's.
+    (a) what the parent runs: a binary search over the packed s64 keys
+        and the two gathers after it;
+    (b) buckets on ps_partkey, a row of four int32 slots a gather;
+    (c) four flat int32 gathers at b*4 + j;
+    (d) one int64 a slot holding (other key, position)."""
+    P, S, M = 200_000, 10_000, 4
+    # chunk.device.shape_bucket of 800,000 rows and of 200,000 buckets
+    scap, pcap = 917_504, 229_376
+
+    def supp_of(part, j):
+        return (part + j * (S // M + (part - 1) // S)) % S + 1
+
+    part = np.repeat(np.arange(1, P + 1, dtype=np.int64), M)
+    slot_j = np.tile(np.arange(M, dtype=np.int64), P)
+    supp = supp_of(part, slot_j)
+    nv = P * M
+    row = rng.permutation(nv)          # the row a key lives at
+    packed = (part - 1) * S + (supp - 1)
+    o = np.argsort(packed, kind="stable")
+    sk = np.full(scap, np.iinfo(np.int64).max, dtype=np.int64)
+    sk[:nv] = packed[o]
+    ordr = np.zeros(scap, dtype=np.int64)
+    ordr[:nv] = row[o]
+    kt2 = np.full((pcap, M), -1, dtype=np.int32)      # other key - lo
+    pt2 = np.full((pcap, M), nv, dtype=np.int32)      # position, nv = miss
+    kt2[part - 1, slot_j] = supp - 1
+    pt2[part - 1, slot_j] = row
+    w2 = (kt2.astype(np.int64) << 32) | pt2.astype(np.int64)
+    w2[kt2 < 0] = -1
+    kp2 = np.concatenate([kt2, pt2], axis=1)          # [pcap, 8]
+    dev = {k: jnp.asarray(v) for k, v in dict(
+        sk=sk, ord=ordr, kt2=kt2, pt2=pt2, kt=kt2.reshape(-1),
+        pt=pt2.reshape(-1), ktT=np.ascontiguousarray(kt2.T),
+        ptT=np.ascontiguousarray(pt2.T), w2=w2, w=w2.reshape(-1),
+        kp2=kp2, kp=kp2.reshape(-1)).items()}
+    kcols = [jnp.asarray(np.ascontiguousarray(kt2[:, j])) for j in range(M)]
+    pcols = [jnp.asarray(np.ascontiguousarray(pt2[:, j])) for j in range(M)]
+
+    def out(pos, hit):
+        return jnp.sum(jnp.where(hit, pos.astype(jnp.int64), 0)), \
+            jnp.sum(hit.astype(jnp.int64))
+
+    def split(lp, ls):
+        b = jnp.clip(lp - 1, 0, P - 1)
+        return b, (ls - 1).astype(jnp.int32)
+
+    def searched(lp, ls):
+        pv = (lp - 1) * S + (ls - 1)
+        loc = jnp.searchsorted(dev["sk"], pv)
+        locc = jnp.minimum(loc, scap - 1)
+        pos = dev["ord"][locc]
+        return out(pos, (dev["sk"][locc] == pv) & (loc < nv))
+
+    def rows_rows(lp, ls):
+        b, rem = split(lp, ls)
+        eq = dev["kt2"][b] == rem[:, None]
+        return out(jnp.sum(jnp.where(eq, dev["pt2"][b], 0), axis=1),
+                   eq.any(axis=1))
+
+    def rows_flat(lp, ls):
+        b, rem = split(lp, ls)
+        eq = dev["kt2"][b] == rem[:, None]
+        at = b * M + jnp.argmax(eq, axis=1)
+        return out(dev["pt"][at], eq.any(axis=1))
+
+    def rows8(lp, ls):
+        b, rem = split(lp, ls)
+        r = dev["kp2"][b]
+        eq = r[:, :M] == rem[:, None]
+        return out(jnp.sum(jnp.where(eq, r[:, M:], 0), axis=1),
+                   eq.any(axis=1))
+
+    def rows8_flat(lp, ls):
+        b, rem = split(lp, ls)
+        r = dev["kp"].reshape(-1, 2 * M)[b]
+        eq = r[:, :M] == rem[:, None]
+        return out(jnp.sum(jnp.where(eq, r[:, M:], 0), axis=1),
+                   eq.any(axis=1))
+
+    def rows_rows_flat(lp, ls):
+        b, rem = split(lp, ls)
+        eq = dev["kt"].reshape(-1, M)[b] == rem[:, None]
+        return out(jnp.sum(jnp.where(eq, dev["pt"].reshape(-1, M)[b], 0),
+                           axis=1), eq.any(axis=1))
+
+    def colsT(lp, ls):
+        b, rem = split(lp, ls)
+        eq = dev["ktT"][:, b] == rem[None, :]
+        at = b * M + jnp.argmax(eq, axis=0)
+        return out(dev["pt"][at], eq.any(axis=0))
+
+    def _first(eqs):
+        j = jnp.zeros(eqs[0].shape, dtype=jnp.int64)
+        for k in range(M - 1, 0, -1):
+            j = jnp.where(eqs[k], k, j)
+        hit = eqs[0]
+        for e in eqs[1:]:
+            hit = hit | e
+        return j, hit
+
+    def flat_flat(lp, ls):
+        b, rem = split(lp, ls)
+        j, hit = _first([dev["kt"][b * M + k] == rem for k in range(M)])
+        return out(dev["pt"][b * M + j], hit)
+
+    def flat_four(lp, ls):
+        b, rem = split(lp, ls)
+        pos = jnp.zeros(b.shape, dtype=jnp.int32)
+        hit = jnp.zeros(b.shape, dtype=bool)
+        for k in range(M):
+            e = dev["kt"][b * M + k] == rem
+            pos = jnp.where(e, dev["pt"][b * M + k], pos)
+            hit = hit | e
+        return out(pos, hit)
+
+    def tables_flat(lp, ls):
+        b, rem = split(lp, ls)
+        j, hit = _first([kc[b] == rem for kc in kcols])
+        return out(dev["pt"][b * M + j], hit)
+
+    def tables_tables(lp, ls):
+        b, rem = split(lp, ls)
+        pos = jnp.zeros(b.shape, dtype=jnp.int32)
+        hit = jnp.zeros(b.shape, dtype=bool)
+        for kc, pc in zip(kcols, pcols):
+            e = kc[b] == rem
+            pos = jnp.where(e, pc[b], pos)
+            hit = hit | e
+        return out(pos, hit)
+
+    def words_flat(lp, ls):
+        b, rem = split(lp, ls)
+        pos = jnp.zeros(b.shape, dtype=jnp.int64)
+        hit = jnp.zeros(b.shape, dtype=bool)
+        for k in range(M):
+            w = dev["w"][b * M + k]
+            e = (w >> 32) == rem
+            pos = jnp.where(e, w & 0xFFFFFFFF, pos)
+            hit = hit | e
+        return out(pos, hit)
+
+    def words_rows(lp, ls):
+        b, rem = split(lp, ls)
+        w = dev["w2"][b]
+        eq = (w >> 32) == rem[:, None]
+        return out(jnp.sum(jnp.where(eq, w & 0xFFFFFFFF, 0), axis=1),
+                   eq.any(axis=1))
+
+    forms = [
+        ("(a) searchsorted in 800k s64 keys + ord, sk gathers", searched),
+        ("(b) row of 4 int32 [P,4], keys and positions", rows_rows),
+        ("(b) row of 4 int32 keys + 1 flat position gather", rows_flat),
+        ("(b) row of 8 int32 [P,8], keys beside positions", rows8),
+        ("(b) row of 8 int32, the table handed over flat and reshaped "
+         "in the program", rows8_flat),
+        ("(b) rows of 4 int32 keys and of 4 positions, both tables flat "
+         "and reshaped in the program", rows_rows_flat),
+        ("(b) column of 4 int32 [4,P] + 1 flat position gather", colsT),
+        ("(c) 4 flat int32 at b*4+j + 1 flat position gather", flat_flat),
+        ("(c) 4 flat int32 keys + 4 flat positions", flat_four),
+        ("(c) 4 tables of P int32 at b + 1 flat position gather",
+         tables_flat),
+        ("(c) 4 tables of P int32 keys + 4 of positions", tables_tables),
+        ("(d) 4 flat int64 (key<<32 | position) at b*4+j", words_flat),
+        ("(d) row of 4 int64 [P,4]", words_rows),
+    ]
+    for n in (4_194_304, 1_835_008):
+        lp = rng.integers(1, P + 1, n)
+        ls = supp_of(lp, rng.integers(0, M, n))
+        ls[::97] = (ls[::97] % S) + 1          # a neighbour: mostly misses
+        hp = (lp - 1) * S + (ls - 1)
+        loc = np.minimum(np.searchsorted(sk[:nv], hp), nv - 1)
+        hit = sk[loc] == hp
+        want = (int(ordr[loc][hit].sum()), int(hit.sum()))
+        jlp, jls = jnp.asarray(lp), jnp.asarray(ls)
+        for label, fn in forms:
+            f = jax.jit(fn)
+            got = tuple(int(x) for x in f(jlp, jls))
+            assert got == want, (label, got, want)
+            bench(f"bucket probe {n} lanes {label}", f, jlp, jls)
 
 
 def main(sections):
@@ -193,6 +385,9 @@ def main(sections):
         bench("5x gather 4M (multi-dim probe)",
               jax.jit(lambda lu, i: sum(lu[(i + k) & ((1 << 21) - 1)]
                                         for k in range(5))), lut, idx4)
+
+    if "probe" in sections or "bucket" in sections:
+        _bucket_rows(rng)
 
     if "sort4m" in sections:
         n4 = 1 << 22

@@ -126,6 +126,9 @@ def _wide_census(build, shapes):
     cap, out = build[0][1], []
 
     def visit(e):
+        if e.primitive.name == "reshape" and id(e.invars[0]) in name:
+            # (a flat table gathered by rows keeps its operand's name)
+            name[id(e.outvars[0])] = name[id(e.invars[0])]
         if e.primitive.name == "gather" and \
                 e.outvars[0].aval.shape[:1] == (cap,):
             out.append((name.get(id(e.invars[0]), "-"),
@@ -981,3 +984,255 @@ def test_bind_span_carries_fold_counts(tk):
     assert rows and "fold_builds" in rows[-1][0]
     assert [r for r in packed if "packed_roots=2" in r[0]
             and "word32=2" in r[0]], packed
+
+
+# ---- (f) a composite key's table of buckets (PR 40) --------------------
+#
+# A dimension joined on several columns whose packed span is no direct
+# table is probed through buckets on one of them (`pl._bucket_table`):
+# the small tables against a plain join in Python, the census of q9's
+# program, and the first-set statements' programs left as they were.
+
+_BQ = ("select count(*), sum(fb.q), sum(ps.w), min(ps.w) from fb, ps "
+       "where fb.a = ps.a and fb.b = ps.b")
+_BQ3 = _BQ + " and fb.c = ps.c"
+
+
+def _ps_rows(case):
+    """-> [(a, b, c, w)] of the dimension: unique on (a, b) and on
+    (a, b, c), spans of 300 x 1000 (x 41) so that no packed key is a
+    direct table at these sizes (`_direct_span`'s floor is 4,096
+    slots)."""
+    rng = np.random.RandomState(40)
+    rows = []
+    for a in range(1, 301):
+        if a % 7 == 0:
+            continue                    # a bucket of empty slots alone
+        k = 1 + a % 4                   # 1 to 4 rows a bucket
+        if case == "deep_bucket" and a == 5:
+            k = 12                      # more slots than the search's steps
+        for b in sorted(rng.choice(1000, k, replace=False)):
+            rows.append((a, int(b), (a + int(b)) % 41, len(rows) + 1))
+    if case == "sparse_buckets":
+        # a's span 30 times as wide: past four slots a row, and b's
+        # values shared by more rows than a bucket may hold
+        rows = [(a * 30, b % 50, c, w) for a, b, c, w in rows]
+        rows = list({(a, b): (a, b, c, w) for a, b, c, w in rows}.values())
+    return rows
+
+
+def _fb_rows(ps):
+    """The fact: every dimension row twice, its bucket under a `b` that
+    row lacks (the span's lowest and highest among them: what an empty
+    slot must not answer to), components out of range on either side
+    and NULL."""
+    amax = max(r[0] for r in ps)
+    out = []
+    for a, b, c, _w in ps:
+        out += [(a, b, c), (a, b, c), (a, (b + 1) % 1000, c), (a, 0, c),
+                (a, 999, c), (a, b, (c + 1) % 41)]
+    out += [(0, 5, 1), (-5, 5, 1), (amax + 1, 5, 1), (10 ** 9, 5, 1),
+            (7, 5, 1), (14, 0, 0), (1, -1, 1), (1, 1000, 1),
+            (1, 10 ** 9, 1), (1, 5, -1), (1, 5, 41), (None, 5, 1),
+            (1, None, 1), (1, 5, None), (None, None, None)]
+    return out
+
+
+def _bucket_tk(case="two_columns", key="a, b"):
+    tk = TestKit()
+    tk.must_exec("create table ps (a int, b int, c int, w int" +
+                 (f", primary key ({key}))" if key else ")"))
+    tk.must_exec("create table fb (k int primary key, a int, b int, "
+                 "c int, q int)")
+    ps = _ps_rows(case)
+    tk.must_exec("insert into ps values " + ",".join(
+        "(%d, %d, %d, %d)" % r for r in ps))
+    fb = _fb_rows(ps)
+    sql = lambda v: "null" if v is None else str(v)     # noqa: E731
+    tk.must_exec("insert into fb values " + ",".join(
+        "(%d, %s, %s, %s, %d)" % (k, sql(a), sql(b), sql(c), k % 97)
+        for k, (a, b, c) in enumerate(fb)))
+    return tk, ps, fb
+
+
+def _plain_join(ps, fb, ncols):
+    """count(*), sum(q), sum(w), min(w) of the inner join, in Python."""
+    table = {r[:ncols]: r[3] for r in ps}
+    hits = [(k % 97, table[r[:ncols]]) for k, r in enumerate(fb)
+            if None not in r[:ncols] and r[:ncols] in table]
+    return (len(hits), sum(q for q, _ in hits), sum(w for _, w in hits),
+            min(w for _, w in hits))
+
+
+def _probe_modes_of(tk, sql):
+    before = {k: c.value for k, c in mu.FUSED_DIM_PROBE._children.items()}
+    tk.domain.copr.use_device = True
+    rows = tk.must_query(sql).rows
+    grown = {k[1]: c.value - before.get(k, 0)
+             for k, c in mu.FUSED_DIM_PROBE._children.items()
+             if c.value != before.get(k, 0)}
+    return rows, grown
+
+
+# case -> (key columns, statement, mode, (bucket column, slots a bucket))
+_BUCKET = {
+    "two_columns": ("a, b", _BQ, "bucket", (0, 4)),
+    # the order the key's columns are named in does not pick the column
+    "two_columns_b_first": ("b, a", _BQ.replace(
+        "fb.a = ps.a and fb.b = ps.b", "fb.b = ps.b and fb.a = ps.a"),
+        "bucket", (1, 4)),
+    "three_columns": ("a, b, c", _BQ3, "bucket", (0, 4)),
+    # one `a` with 12 rows of 857 (the search has 10 steps), and no
+    # other column whose table keeps the bounds
+    "deep_bucket": ("a, b", _BQ, "search", None),
+    # a's 8,970 values x 4 slots and b's 50 x 19: neither keeps them
+    "sparse_buckets": ("a, b", _BQ, "search", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BUCKET))
+def test_bucket_probe_equals_a_plain_join(kinds, case):
+    key, sql, mode, bucket = _BUCKET[case]
+    tk, ps, fb = _bucket_tk(case.replace("_b_first", ""), key)
+    rows, grown = _probe_modes_of(tk, sql)
+    assert tk.domain.last_fused_reason is None
+    assert grown == {mode: 1}
+    want = _plain_join(ps, fb, 3 if sql is _BQ3 else 2)
+    assert tuple(int(v) for v in rows[0]) == want
+    assert want[0] >= 2 * len(ps) and _host(tk, sql) == rows
+    (_kind, _param, build, shapes), = kinds
+    da, layout = shapes[2][0], build[0][6][0]
+    assert layout.get("bucket") == bucket
+    assert ("bt" in da, "sk" in da) == (mode == "bucket", mode == "search")
+    if bucket is not None:
+        # a row a bucket: its slots' other keys, then their positions;
+        # -1 and the miss where a slot is empty
+        bcol, slots = bucket
+        meta = pl._dim_sort_meta(tk.domain.copr, build[0][0].dims[0],
+                                 tk.domain.columnar.table(
+                                     tk.domain.infoschema().table_by_name(
+                                         "test", "ps")), None)
+        rows_ = meta["btab"].reshape(-1, 2 * slots)
+        bk, bp = rows_[:, :slots], rows_[:, slots:]
+        assert len(rows_) == max(r[0] for r in ps)
+        assert int((bk >= 0).sum()) == int((bp < meta["n"]).sum()) \
+            == len(ps)
+        assert (bk[bp == meta["n"]] == -1).all()
+        assert rows_.dtype == np.int32 and da["bt"].dtype == np.int32
+        assert da["bt"].shape[0] % (2 * slots) == 0     # whole rows
+        assert meta["pack"][2][bcol] == 0       # what the lane packs
+
+
+def test_duplicate_composite_keys_are_still_refused():
+    """No primary key and one (a, b) twice: not a unique build side,
+    whatever table the keys would take; the statement is answered off
+    the fused path."""
+    tk, ps, fb = _bucket_tk(key=None)
+    tk.must_exec("insert into ps values (%d, %d, 0, 9999)" % ps[3][:2])
+    rows, grown = _probe_modes_of(tk, _BQ)
+    assert grown == {}
+    assert "duplicated" in tk.domain.last_fused_reason
+    assert rows == _host(tk, _BQ)
+    again = sum(r[:2] == ps[3][:2] for r in fb)
+    assert int(rows[0][0]) == _plain_join(ps, fb, 2)[0] + again
+
+
+def test_a_fifth_row_in_a_bucket_rebuilds_the_table_for_the_next_snapshot(
+        kinds):
+    """A commit gives one `a` a fifth `b`: the next statement's table
+    has five slots a bucket (another program), a statement that reads
+    as of before the commit keeps four and its answer."""
+    from tidb_tpu.types.time_types import micros_to_str
+    tk, ps, fb = _bucket_tk()
+    base, _ = _probe_modes_of(tk, _BQ)
+    assert kinds[-1][2][0][6][0]["bucket"] == (0, 4)
+    time.sleep(0.05)
+    mid = micros_to_str(int(time.time() * 1e6), 6)
+    time.sleep(0.05)
+    a = next(r[0] for r in ps if r[0] % 4 == 3)       # a full bucket
+    b = next(b for b in (0, 999) if (a, b) not in {r[:2] for r in ps})
+    tk.must_exec(f"insert into ps values ({a}, {b}, 0, 5000)")
+    now, grown = _probe_modes_of(tk, _BQ)
+    assert grown == {"bucket": 1}
+    assert kinds[-1][2][0][6][0]["bucket"] == (0, 5)
+    want = _plain_join(ps + [(a, b, 0, 5000)], fb, 2)
+    assert tuple(int(v) for v in now[0]) == want and now != base
+    asof = _BQ.replace("from fb, ps", f"from fb as of timestamp '{mid}', "
+                       f"ps as of timestamp '{mid}'")
+    old, grown = _probe_modes_of(tk, asof)
+    assert old == base and grown == {"bucket": 1}
+    assert kinds[-1][2][0][6][0]["bucket"] == (0, 4)
+    assert _probe_modes_of(tk, _BQ)[0] == now
+
+
+def _forget_key_tables(tk):
+    """Drop the cached probe tables (`_dim_sort_meta`'s entries) and
+    nothing a run has learned."""
+    cache = tk.domain.copr._host_cache
+    for k in [k for k in cache if isinstance(k, tuple) and
+              ("dim" in k[2:3] or k[-1:] == ("dimcur",))]:
+        del cache[k]
+
+
+def _loops(build, shapes):
+    """Loop and sort primitives of the body, and what it gathers from
+    at fact width."""
+    n = [0]
+
+    def visit(e):
+        n[0] += e.primitive.name in ("while", "scan", "sort")
+    _walk(_body_jaxpr(build, shapes).jaxpr, visit)
+    return n[0], _wide_census(build, shapes)
+
+
+def test_census_q9_probes_partsupp_without_a_search(tk, runs_impl, kinds,
+                                                    monkeypatch):
+    """q9's program: partsupp's probe is ONE gather of its bucket's
+    row of 32-bit words (four other keys, four positions), its payload
+    gathered at the matching slot's position; no `valid[pos]` (the
+    table holds visible rows alone and partsupp has no filter), no
+    sorted keys, and fewer loops than the program that searches them."""
+    build, shapes = _main_kernel(tk, kinds, _q("q9"))
+    di, = [i for i, lay in enumerate(build[0][6]) if "bucket" in lay]
+    assert build[0][6][di]["bucket"][1] == 4
+    loops, census = _loops(build, shapes)
+    mine = [(p, b) for p, b in census if p.startswith(f"[2][{di}]")]
+    assert [(p.rsplit("[", 1)[1], b) for p, b in mine
+            if "'cols'" not in p] == [("'bt']", 4)]
+    assert [p for p, _ in mine if "'cols'" in p]        # ps_supplycost
+    assert not [p for p, _ in census if "'sk'" in p or "'ord'" in p]
+    monkeypatch.setattr(pl, "_bucket_table", lambda *a: None)
+    _forget_key_tables(tk)
+    searched, old = _loops(*_main_kernel(tk, kinds, _q("q9")))
+    _forget_key_tables(tk)
+    assert [p for p, _ in old if "'sk'" in p] and searched > loops
+
+
+def _lowered_text(build, shapes):
+    """The program's lowered text less its source locations
+    (benchmarks/fold_probe_tpu.py --hlo's normalisation)."""
+    import re
+    a, k = build
+    text = jax.jit(pl._make_pipeline_body(*a, **k)).lower(*shapes) \
+        .as_text()
+    return re.sub(r"loc\(.*?\)|#loc\d*( = .*)?", "", text)
+
+
+@pytest.mark.parametrize("q", ["q3", "q5", "q10", "q18"])
+def test_single_column_keys_keep_their_program(tk, runs_impl, kinds,
+                                               monkeypatch, q):
+    """The first-set join statements have no dimension on two columns:
+    with the bucket form reachable or not, the host builds the same
+    tables and the program's lowered text is the same, and nothing is
+    counted `bucket`."""
+    for _ in range(3):          # what a run learns (bucket, top-n cut)
+        _rows, grown = _probe_modes_of(tk, _q(q))   # picks the next's
+    assert "bucket" not in grown and "search" not in grown
+    now = _lowered_text(*_main_kernel(tk, kinds, _q(q)))
+
+    def unreachable(*a):
+        raise AssertionError("a single-column key asked for buckets")
+    monkeypatch.setattr(pl, "_bucket_table", unreachable)
+    _forget_key_tables(tk)
+    assert _lowered_text(*_main_kernel(tk, kinds, _q(q))) == now
+    _forget_key_tables(tk)

@@ -164,21 +164,30 @@ def test_float32_control_reads_right_on_small_counts(stmt):
 
 # ---- the counter and the two spans -------------------------------------
 
+@pytest.mark.parametrize("route", ["one_device", "mesh8"])
 @pytest.mark.parametrize("stmt", STATEMENTS)
-def test_every_dimension_is_counted_once_under_one_mode(one_device, stmt):
+def test_every_dimension_is_counted_once_under_one_mode(request, route,
+                                                        stmt):
+    d = request.getfixturevalue(route)
     before = _moved(mu.FUSED_DIM_PROBE)
-    _answer(one_device, stmt)
+    _answer(d, stmt)
     grown = _moved(mu.FUSED_DIM_PROBE, before)
     # the aggregate dimensions of Q13 and Q17 are cached by now or run
     # statements without a dimension of their own
     assert sum(grown.values()) == DIMENSIONS[stmt], grown
     modes = {dict(k)["mode"] for k in grown}
-    assert modes <= {"folded", "direct", "search", "exists", "matdim"}
+    assert modes <= {"folded", "direct", "search", "bucket", "exists",
+                     "matdim"}
     joins = {dict(k)["join"] for k in grown}
     assert joins == {{"q4": "semi", "q13": "left"}.get(stmt, "inner")}
-    if stmt == "q9":        # partsupp, on two columns: no direct table
-        assert grown[(("join", "inner"), ("mode", "search"))] == 1
+    if stmt == "q9":
+        # partsupp, on two columns whose packed span is no direct
+        # table: buckets on ps_partkey (PR 40), on either route
+        assert grown[(("join", "inner"), ("mode", "bucket"))] == 1
         assert grown[(("join", "inner"), ("mode", "folded"))] == 1
+        assert "search" not in modes
+    else:
+        assert "bucket" not in modes
 
 
 def _spans(c, sql, name):

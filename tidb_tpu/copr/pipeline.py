@@ -14,6 +14,7 @@ bottleneck: Q3/Q5 lost all join output to host numpy between operators).
 """
 from __future__ import annotations
 
+import math
 import re
 import threading
 
@@ -66,19 +67,19 @@ def _set_reason(copr, msg):
     _metrics.FUSED_DECLINE.labels(_metrics.reason_code(msg)).inc()
 
 
-def _direct_span(copr, span, nv) -> bool:
+def _direct_span(copr, span, nv, slot_bytes=8) -> bool:
     """May a dimension's build keys be probed through a direct lut
     (`lut[key - lo]`, one gather a probe) rather than a binary search
     over the sorted keys (~70 gathers' time on the chip, PERF.md §7)?
     Two bounds, both from what is observed: the keys are dense enough
-    that the lut is at most four slots a row, and the lut (at most 8
-    bytes a slot, resident like the dimension's columns) fits an eighth
-    of the resident store's budget — 128 Mi slots at the default 8 GiB, so
-    TPC-H's orders (18,000,000 sparse keys at scale 3, 60,000,000 at
+    that the lut is at most four slots a row, and the lut (at most
+    `slot_bytes` a slot, resident like the dimension's columns) fits an
+    eighth of the resident store's budget — 128 Mi slots at the default
+    8 GiB, so TPC-H's orders (18,000,000 sparse keys at scale 3, 60,000,000 at
     10) stay off the binary search, which ran q3, q5 and q10 in 14-16 s
     instead of 1-4 (PERF.md, PR 27)."""
     return span <= max(4 * nv, 1 << 12) and \
-        span * 8 <= copr._dev_store.budget // 8
+        span * slot_bytes <= copr._dev_store.budget // 8
 
 
 def _dim_sort_meta(copr, dim, tbl, read_ts):
@@ -86,15 +87,10 @@ def _dim_sort_meta(copr, dim, tbl, read_ts):
     table" for the build-key column (cached per table version) +
     uniqueness check. -> dict or None when ineligible.
 
-    Two table forms, chosen by key density:
-    - direct: key span fits the budget -> dense position array, probe is
-      ONE gather (pos = lut[key - lo]). TPC-H PKs are dense 1..N, so
-      this is the common case and the TPU-friendly one.
-    - sorted: argsort + binary search (jnp.searchsorted) otherwise.
-
+    The table's form is `_key_table`'s choice from the build keys.
     Composite keys (dim.extra_keys, Q9 partsupp) pack into one int64 by
-    per-column stride before either form; the pack layout ships to the
-    kernel so the probe packs the same way."""
+    per-column stride first; the pack layout ships to the kernel so the
+    probe packs the same way."""
     col_ids = [cid for cid in (_cid_of(dim.dag, sc) for sc in dim.dag.cols)
                if cid != -1]
     arrays, valid = tbl.snapshot(col_ids, read_ts)
@@ -137,37 +133,105 @@ def _dim_sort_meta(copr, dim, tbl, read_ts):
         vidx = np.nonzero(valid)[0]
         keys_v, pack = _packed_keys(arrays, key_cids, n, vidx)
         nv = 0 if keys_v is None else len(keys_v)
-        unique = nv > 0 and len(np.unique(keys_v)) == nv
-        if keys_v is None or nv == 0 or not unique:
+        if nv == 0 or len(np.unique(keys_v)) != nv:
             # dup-key / null-key dims are rejected below on every use:
             # cache a tombstone, don't build the (possibly huge) lut
-            meta = (None, None, None, False, 0, None)
+            meta = (None, None)
         else:
-            lo = int(keys_v.min())
-            hi = int(keys_v.max())
-            span = hi - lo + 1
-            if _direct_span(copr, span, nv):
-                # n == miss
-                lut = np.full(span, n, dtype=dimfold.pos_dtype(n))
-                lut[keys_v - lo] = vidx
-                meta = ("direct", lut, lo, unique, nv, pack)
-            else:
-                o = np.argsort(keys_v, kind="stable")
-                skeys = keys_v[o]
-                meta = ("sorted", (vidx[o], skeys), None, unique, nv, pack)
+            table = _key_table(copr, arrays, key_cids, keys_v, pack, vidx,
+                               n)
+            meta = (table["mode"], table)
         host_cache[hkey] = meta
-    mode, payload, lo, unique, n_sorted, pack = meta
-    if mode is None or not unique:
+    if meta[0] is None:
         _set_reason(copr, f"dim {dim.dag.table_info.name}: build keys "
                     "are duplicated or NULL (non-unique build side)")
         return None
-    out = {"arrays": arrays, "valid": valid, "n": n, "tbl": tbl,
-           "mode": mode, "lo": lo, "n_sorted": n_sorted, "pack": pack}
-    if mode == "direct":
-        out["lut"] = payload
-    else:
-        out["order"], out["skeys"] = payload
-    return out
+    return dict(meta[1], arrays=arrays, valid=valid, n=n, tbl=tbl)
+
+
+def _key_table(copr, arrays, key_cids, keys_v, pack, vidx, n):
+    """The join's "hash table" over a dimension's build keys: `keys_v`,
+    unique and not NULL (packed where the key has several columns), of
+    the rows `vidx` of `n` -> the meta's entries for it. Three forms,
+    chosen from what the keys are observed to be:
+    - direct: the key span is dense enough (`_direct_span`) -> a table
+      of positions, the probe is ONE gather (pos = lut[key - lo], n the
+      miss). TPC-H's primary keys are dense 1..N: the common case.
+    - bucket: a key of several columns whose packed span is not, but
+      one of whose columns is: `_bucket_table`.
+    - sorted: argsort + binary search (jnp.searchsorted) otherwise."""
+    nv = len(keys_v)
+    lo = int(keys_v.min())
+    span = int(keys_v.max()) - lo + 1
+    if _direct_span(copr, span, nv):
+        lut = np.full(span, n, dtype=dimfold.pos_dtype(n))
+        lut[keys_v - lo] = vidx
+        return {"mode": "direct", "lo": lo, "n_sorted": nv, "pack": pack,
+                "lut": lut}
+    bucket = None if pack is None else \
+        _bucket_table(copr, arrays, key_cids, pack, vidx, n)
+    if bucket is not None:
+        return bucket
+    o = np.argsort(keys_v, kind="stable")
+    return {"mode": "sorted", "lo": None, "n_sorted": nv, "pack": pack,
+            "order": vidx[o], "skeys": keys_v[o]}
+
+
+def _bucket_table(copr, arrays, key_cids, pack, vidx, n):
+    """A key of several columns probed through buckets on ONE of them
+    (partsupp's (ps_partkey, ps_suppkey): a part has four suppliers):
+    row `k - lo` of that column holds `m` slots, `m` the most rows that
+    share one value of it: first the `m` rows' other key columns packed
+    (-1 where a slot is empty: no probe packs to it), then their `m`
+    positions (n the miss). The probe gathers its bucket's row ONCE and
+    compares: no search; on the chip a row of eight words costs a third
+    of four words gathered one by one (PERF.md section 7, PR 40). A
+    column may be the bucket column when its table (span x m slots)
+    keeps `_direct_span`'s bounds and a bucket has no more slots than
+    the binary search it replaces has steps; of those the one with the
+    fewest slots a bucket, which is what a probe pays for (partsupp's
+    two columns both give exactly one slot a row: 4 x 200,000 and
+    80 x 10,000), then the smaller table -> the meta's entries, or None
+    (the sorted table). The pack layout it returns has stride 0 at the
+    bucket column: what the kernel packs is a slot's other keys."""
+    los, spans, _strides = pack
+    nv = len(vidx)
+    cols = [arrays[cid][0][:n][vidx].astype(np.int64) - lo
+            for cid, lo in zip(key_cids, los)]
+    packed_span = math.prod(spans)      # `_packed_keys` held it to 62 bits
+    best = None
+    for k, (col, s) in enumerate(zip(cols, spans)):
+        if not _direct_span(copr, s, nv):
+            continue
+        counts = np.bincount(col, minlength=s)
+        m = int(counts.max())
+        # a slot is two words, as narrow as the other keys and `n` fit
+        dt = np.dtype(dimfold.table_dtype(max(
+            (packed_span // s - 1).bit_length(), int(n).bit_length())))
+        if _direct_span(copr, s * m, nv, 2 * dt.itemsize) and \
+                m <= (nv - 1).bit_length() and \
+                (best is None or (m, s) < best[:2]):
+            best = (m, s, k, counts, dt)
+    if best is None:
+        return None
+    m, s, bcol, counts, dt = best
+    rest, acc = [0] * len(spans), 1
+    for k in reversed(range(len(spans))):
+        if k != bcol:
+            rest[k] = acc
+            acc *= spans[k]
+    others = sum(col * st for col, st in zip(cols, rest))
+    o = np.argsort(cols[bcol], kind="stable")
+    b = cols[bcol][o]
+    rank = np.arange(nv) - (np.cumsum(counts) - counts)[b]  # in its bucket
+    btab = np.empty((s, 2 * m), dtype=dt)
+    btab[:, :m] = -1
+    btab[:, m:] = n
+    btab[b, rank] = others[o]
+    btab[b, m + rank] = vidx[o]
+    return {"mode": "bucket", "lo": None, "n_sorted": nv,
+            "pack": (los, spans, tuple(rest)), "bucket": (bcol, m),
+            "btab": btab.reshape(-1)}
 
 
 _VOLATILE_RE = re.compile(
@@ -309,7 +373,7 @@ def _matdim_nbytes(out):
     for d, nl, _sd in out["arrays"].values():
         total += getattr(d, "nbytes", 0)
         total += getattr(nl, "nbytes", 0) if nl is not None else 0
-    for k in ("lut", "order", "skeys"):
+    for k in ("lut", "order", "skeys", "btab"):
         if k in out:
             total += getattr(out[k], "nbytes", 0)
     return total
@@ -442,21 +506,11 @@ def _matdim_meta(copr, ctx, dim, read_ts, seen):
     if keys_v is None or len(np.unique(keys_v)) != n:
         _set_reason(copr, "materialized dim: non-unique or NULL keys")
         return None
-    lo = int(keys_v.min())
-    span = int(keys_v.max()) - lo + 1
-    out = {"arrays": arrays, "valid": valid, "n": n, "tbl": _MatTbl(n),
-           "pack": pack,
-           "dictsig": tuple(sorted(
-               (i, len(sd.values)) for i, (_d, _nl, sd) in arrays.items()
-               if sd is not None))}
-    if _direct_span(copr, span, n):
-        lut = np.full(span, n, dtype=dimfold.pos_dtype(n))
-        lut[keys_v - lo] = vidx
-        out.update(mode="direct", lo=lo, lut=lut, n_sorted=n)
-    else:
-        o = np.argsort(keys_v, kind="stable")
-        out.update(mode="sorted", lo=None, order=vidx[o],
-                   skeys=keys_v[o], n_sorted=n)
+    out = dict(_key_table(copr, arrays, key_cids, keys_v, pack, vidx, n),
+               arrays=arrays, valid=valid, n=n, tbl=_MatTbl(n),
+               dictsig=tuple(sorted(
+                   (i, len(sd.values)) for i, (_d, _nl, sd)
+                   in arrays.items() if sd is not None)))
     if ck is not None:
         lru = _matdim_cache(copr)
         nb = _matdim_nbytes(out)
@@ -627,6 +681,8 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None, want=None,
         _upload_probe_table(meta, args, put, cap,
                             with_valid=not pre and not folded, pack=pack)
     layout = {}
+    if meta["mode"] == "bucket":
+        layout["bucket"] = meta["bucket"]
     if pack is not None:
         layout["pack"] = pack.text
         layout["words"] = tuple(t.dtype.name for t in pack.tables)
@@ -661,7 +717,8 @@ def _upload_probe_table(meta, args, put, cap, with_valid, pack=None):
     time, a folded root its whole chain's: don't upload dead copies
     into the HBM pool), and the direct or the sorted table -- of
     positions, or of a folded root's composed words (`pack`) with the
-    fields' layout as operands beside `lo`."""
+    fields' layout as operands beside `lo` -- or the table of a
+    bucketed composite key (`_bucket_table`)."""
     n = meta["n"]
     if meta.get("pack") is not None:
         # small host values ride the kernel call as numpy operands:
@@ -674,6 +731,15 @@ def _upload_probe_table(meta, args, put, cap, with_valid, pack=None):
     if with_valid:
         args["valid"] = put("valid", meta["valid"], n, cap, False,
                             ts_keyed=True)
+    if meta["mode"] == "bucket":
+        # whole rows: the buckets' count is what is padded to a bucketed
+        # size (a padding row is never addressed)
+        length = len(meta["btab"])
+        row = 2 * meta["bucket"][1]
+        args["bt"] = put("bt", meta["btab"], length,
+                         shape_bucket(length // row) * row, fill=-1,
+                         ts_keyed=True)
+        return
     direct = meta["mode"] == "direct"
     length = len(meta["lut"]) if direct else meta["n_sorted"]
     tcap = shape_bucket(length)
@@ -757,16 +823,17 @@ def _probe_modes(plan, fp, dim_metas):
     """-> [(join type, mode)] a dimension, for
     tidb_tpu_fused_dim_probe_total: what resolves it at fact width.
     `folded`: at its parent's width, no probe of its own; `search`: a
-    binary search over sorted keys, whatever the dimension is; else one
-    gather, of a prefiltered semi table (`exists`), of a materialised
-    aggregate dimension's table (`matdim`) or of its own table of
-    positions or word (`direct`)."""
+    binary search over sorted keys, whatever the dimension is;
+    `bucket`: a composite key's bucket of slots and a compare, whatever
+    the dimension is; else one gather, of a prefiltered semi table
+    (`exists`), of a materialised aggregate dimension's table
+    (`matdim`) or of its own table of positions or word (`direct`)."""
     out = []
     for di, (dim, meta) in enumerate(zip(plan.dims, dim_metas)):
         if fp is not None and fp.parent[di] is not None:
             mode = "folded"
         elif meta["mode"] != "direct":
-            mode = "search"
+            mode = "bucket" if meta["mode"] == "bucket" else "search"
         elif meta.get("pre"):
             mode = "exists"
         elif dim.subplan is not None:
@@ -1132,6 +1199,7 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                     pv = jnp.zeros(cap, dtype=jnp.int64)
                     pnm = jnp.zeros(cap, dtype=bool)
                     inb_pack = jnp.ones(cap, dtype=bool)
+                    kidx = []
                     for ki, (_, pe) in enumerate(dim.all_keys()):
                         v, nl, _ = eval_expr(ctx, pe)
                         if np.isscalar(v) or getattr(v, "ndim", 1) == 0:
@@ -1142,6 +1210,7 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                         inb_pack = inb_pack & (idx >= 0) & \
                             (idx < da["pspan"][ki])
                         idx = jnp.clip(idx, 0, da["pspan"][ki] - 1)
+                        kidx.append(idx)
                         pv = pv + idx * da["pstride"][ki]
                     pnm = pnm | ~inb_pack
                 else:
@@ -1197,6 +1266,26 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                             (da["lut"][jnp.clip(idx, 0, lsize - 1)]
                              .astype(jnp.int64) < dn) & ~pnm
                     if dmask is not None:
+                        hit = hit & dmask[pos]
+                elif "bt" in da:
+                    # a composite key bucketed on one of its columns
+                    # (`_bucket_table`): ONE gather of the bucket's row,
+                    # as narrow as the table. That column's stride is
+                    # 0, so `pv` packs the lane's other keys, which at
+                    # most one of the row's slots holds (the build keys
+                    # are unique; an empty slot holds what nothing
+                    # packs to); the position is that slot's
+                    bcol, slots = layout["bucket"]
+                    row = da["bt"].reshape(-1, 2 * slots)[kidx[bcol]]
+                    eq = row[:, :slots] == pv.astype(row.dtype)[:, None]
+                    pos = jnp.sum(jnp.where(eq, row[:, slots:], 0),
+                                  axis=1, dtype=jnp.int64)
+                    hit = jnp.any(eq, axis=1) & (pos < dn) & ~pnm
+                    pos = jnp.minimum(pos, dcap - 1)
+                    # (the table holds the snapshot's visible rows
+                    # alone: a hit's `valid[pos]` is true, and only the
+                    # dimension's own filters are read at `pos`)
+                    if dim.dag.filters:
                         hit = hit & dmask[pos]
                 else:
                     scap = da["sk"].shape[0]
@@ -2406,7 +2495,8 @@ def _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap, dim_caps,
         (d.dag.table_info.id, d.build_key.col.idx, d.join_type,
          d.probe_expr.fingerprint(), m["mode"],
          (len(m["lut"]), m["lut"].dtype.name) if m["mode"] == "direct"
-         else 0,
+         else m["bucket"] + (len(m["btab"]), m["btab"].dtype.name)
+         if m["mode"] == "bucket" else 0,
          tuple(f.fingerprint() for f in d.dag.filters),
          tuple(sorted((sc.col.idx, sc.name) for sc in d.dag.cols)),
          tuple((sc.col.idx, pe.fingerprint()) for sc, pe in d.extra_keys),

@@ -1008,9 +1008,11 @@ FUSED_DIM_PROBE = REGISTRY.counter(
     "a dimension a statement, at the bind that uploads the dimensions: "
     "folded (resolved into a parent's table or word: no probe of its "
     "own), search (a binary search over sorted keys, whatever the "
-    "dimension), exists (a prefiltered semi table, one gather), matdim "
-    "(a materialised aggregate dimension, one gather), direct (its own "
-    "table of positions or word, one gather)", ("join", "mode"))
+    "dimension), bucket (a key of several columns: the slots of a bucket "
+    "on one of them and a compare), exists (a prefiltered semi table, "
+    "one gather), matdim (a materialised aggregate dimension, one "
+    "gather), direct (its own table of positions or word, one gather)",
+    ("join", "mode"))
 MATDIM = REGISTRY.counter(
     "tidb_tpu_matdim_total",
     "Materialised aggregate dimensions of fused statements (Q17's "
