@@ -6,7 +6,8 @@ and of the compiled HLO's `while` loops.
         --time q5,q3,q10,q18 --hlo q18 --variants control,mask,fold,packed
 
 The data set, its loader and the statements are the benchmark's
-(benchmark/datasets/tpch.py, loaded by path and not edited); the
+(benchmark/datasets/tpch.py, or with `--dataset tpch_set2` the second
+set's Q4, Q9, Q12, Q13, Q17, Q19; loaded by path and not edited); the
 statements run in-process on one session, so a time here is the
 statement's and not the wire's. Variants:
 
@@ -61,9 +62,10 @@ def log(msg):
     print(f"[{time.time() - T0:7.1f}s] {msg}", flush=True)
 
 
-def _dataset():
-    path = os.path.join(ROOT, "benchmark", "datasets", "tpch.py")
-    spec = importlib.util.spec_from_file_location("fold_probe_tpch", path)
+def _dataset(name):
+    path = os.path.join(ROOT, "benchmark", "datasets", f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"fold_probe_{name}",
+                                                  path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -130,6 +132,8 @@ def main():
     ap.add_argument("--variants", default="control,fold")
     ap.add_argument("--runs", type=int, default=5)
     ap.add_argument("--aot", default="")
+    ap.add_argument("--dataset", default="tpch",
+                    choices=("tpch", "tpch_set2"))
     args = ap.parse_args()
     timed = [q for q in args.time.split(",") if q]
     hlo = [q for q in args.hlo.split(",") if q]
@@ -142,6 +146,7 @@ def main():
     import tidb_tpu.copr.pipeline as pl
     from tidb_tpu.session import new_store
     from tidb_tpu.testkit import TestKit
+    from tidb_tpu.utils.metrics import AGG_LOWERING
     dev = jax.devices()[0]
     log(f"device {dev.platform} {dev.device_kind} x{len(jax.devices())}"
         + ("" if dev.platform == "tpu" else
@@ -167,7 +172,7 @@ def main():
             f"for {args.aot} ({topo.devices[0].device_kind}), nothing run "
             "there")
 
-    ds = _dataset()
+    ds = _dataset(args.dataset)
     tk = TestKit(new_store(tempfile.mkdtemp(prefix="fold_probe_")))
     tables = ds.generate(args.scale, args.seed)
     log("data generated")
@@ -237,6 +242,10 @@ def main():
             rec["shapes"])
         return jit.lower(*shapes).compile().as_text()
 
+    def lowering_runs():
+        return {"/".join((lb["site"], lb["kind"], lb["verdict"])): int(v)
+                for _n, lb, v in AGG_LOWERING.sample_rows()}
+
     orig_fold_build = df._build
 
     def timed_build(fp, plan, metas, root):
@@ -278,7 +287,7 @@ def main():
 
     result = {"scale": args.scale, "seed": args.seed,
               "device": f"{dev.platform} {dev.device_kind}", "ms": {},
-              "whiles": {}, "gathers": {}, "sha256": {},
+              "whiles": {}, "gathers": {}, "sha256": {}, "lowering": {},
               "compiled_for": args.aot or f"{dev.platform} {dev.device_kind}"}
     for variant in args.variants.split(","):
         df.fold_plan = variants[variant]
@@ -289,11 +298,21 @@ def main():
                 continue            # the kill criterion is q5's
             del built[:]
             sql = ds.STATEMENTS[q]
+            judged = [lowering_runs()]
             t = time.perf_counter()
             tk.must_query(sql).rows
             log(f"  {q} first run {time.perf_counter() - t:.1f} s")
-            tk.must_query(sql).rows
-            tk.must_query(sql).rows
+            for _ in range(2):
+                judged.append(lowering_runs())
+                tk.must_query(sql).rows
+            judged.append(lowering_runs())
+            # what tidb_tpu_agg_lowering_total grew by, a run: the
+            # third is a steady window's
+            grew = [{k: v - a.get(k, 0) for k, v in b.items()
+                     if v != a.get(k, 0)}
+                    for a, b in zip(judged, judged[1:])]
+            result["lowering"][f"{q}.{variant}"] = grew
+            log(f"  {q} judged runs, first / second / third: {grew}")
             if q in timed:
                 ms = []
                 for _ in range(args.runs):

@@ -7,15 +7,21 @@ directly, on the chip:
     python benchmarks/microbench_tpu.py [section ...]
 
 Sections: io, reduce, group, sort by default; probe (its last rows
-alone: bucket), sort4m, mxu and
+alone: bucket), onehot (PR 43's step 0: how a learned slot table is
+reduced), sort4m, mxu and
 scatter by name (scatter is the slowest to COMPILE on a TPU — run it
 last, with a long timeout).
 
 Design inputs these numbers feed (copr/agg_lowering.py lowering choice):
 - dispatch+fetch round-trip floor
 - masked reductions (no-group aggs)
-- broadcast-compare-reduce (tiny group domains)
-- blocked one-hot matmul (medium dense domains, MXU)
+- broadcast-compare-reduce (tiny group domains; `onehot`: a learned
+  slot table's, linear in the slots: 8.1 ms at 256, 57 at 2,048, 889 at
+  32,768 a 4,194,304-lane block, PR 43)
+- blocked one-hot matmul (medium dense domains, MXU; `onehot`: the
+  program's loop 13.8 / 18.2 / 169.5 ms at those sizes, with the lanes
+  kept minor 3.0 / 8.9 / 110.7; the search for the slot before it,
+  678.5 ms in 256 keys, is what the kind cost)
 - cumsum + boundary extraction (pre-clustered group keys)
 - sort / argsort / top_k (compaction, ordered output)
 - segment_sum scatter (the fallback the others replace)
@@ -240,6 +246,393 @@ def _bucket_rows(rng):
             bench(f"bucket probe {n} lanes {label}", f, jlp, jls)
 
 
+def bench_med(label, fn, *args, reps=5):
+    """Median of `reps` calls, each timed to `block_until_ready` (no
+    fetch: a row's result stays on the device) -> the median in ms. A
+    call of over 3 s is repeated twice, not `reps` times."""
+    t0 = time.time()
+    jax.block_until_ready(fn(*args))
+    print(f"{label}: compile+1st {time.time() - t0:.1f}s", flush=True)
+    ms = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        ms.append((time.perf_counter() - t0) * 1e3)
+        if ms[-1] > 3000 and len(ms) >= 2:
+            break
+    ms.sort()
+    med = ms[len(ms) // 2]
+    print(f"{label}: {med:.3f} ms median of {len(ms)} "
+          f"({ms[0]:.3f}-{ms[-1]:.3f})", flush=True)
+    return med
+
+
+_OH_L10 = tuple(range(0, 63, 7)) + (63,)     # the parent's limb shifts
+# limbs cut from the two 32-bit halves: 7,7,7,7,4 bits of each
+_OH_HALF = (0, 7, 14, 21, 28)
+
+
+def _onehot_rows(rng, scaps, lanes=None):
+    """ISSUE 43's step 0: how a block of fact lanes is reduced into a
+    learned slot table, at q9's sizes: a 4,194,304-lane block and the
+    1,835,008-lane tail, 175 live slots of `scap` 256 (25 nations x 7
+    years packed with spans 26 x 8), one int64 sum within +-2^31, its
+    non-null count and the row count a slot; then the forms that are
+    linear in `scap` again at 2,048 and 32,768 slots (`ONEHOT_MAX`),
+    the full block alone. Every form's sums and counts are checked
+    against numpy's on its first call.
+    (a)  the parent's block loop verbatim (agg_lowering.onehot_agg_body
+         before PR 43: int64 slot compare, int8 one-hot [blk, scap], ten
+         7-bit limbs cut from an s64, dot_general over the rows);
+    (a1) its parts alone: the one-hot build, the limb matrix, the dot
+         over operands that are already in HBM;
+    (a2) the slot: searchsorted of the packed s64 codes in `scap` sorted
+         s64 keys and the compare after it;
+    (b)  the dense kind's broadcast-compare-reduce at [scap, cap], slot
+         ids int32 with dead lanes at -1, sums in int64 (b64: the same
+         with the ids compared as int64, what calling
+         `_dense_agg_states_bcr` with an int32 slot would trace);
+    (c)  the same in blocks: a fori_loop over 8,192 / 65,536 lanes, and
+         a [nblk, blk] reshape reduced twice;
+    (d)  the matmul repaired: int32 compare, one-hot (scap, blk) and
+         limbs (L, blk) with lanes minor and the contraction over them,
+         limbs cut from the 32-bit halves; ten limbs, and five and a
+         sign for a value within 32 bits;
+    (e)  the slot without a search: the packed code itself (a cast and
+         a range check), and one int32 gather from a direct table over
+         the code's range.
+    `lanes`: other widths than q9's, for a rehearsal off the chip."""
+    out = {}
+
+    def check(label, got, want):
+        for g, w, what in zip(got, want, ("sum", "count", "rows")):
+            g = np.asarray(g)[:len(w)]
+            assert np.array_equal(g, w), (label, what, g[:8], w[:8])
+
+    def decode(acc, shifts):
+        """(scap, L) int32 limb accumulator -> sums, counts, rows."""
+        acc = np.asarray(acc).astype(np.int64)
+        n = len(shifts)
+        with np.errstate(over="ignore"):
+            tot = np.zeros(acc.shape[0], dtype=np.int64)
+            for i, sh in enumerate(shifts):
+                tot = tot + np.left_shift(acc[:, i], sh)
+        return tot, acc[:, n], acc[:, n + 1]
+
+    for scap in scaps:
+        nlive = 175 if scap == 256 else (scap * 7) // 10
+        sizes = lanes or ((4_194_304, 1_835_008) if scap == 256
+                          else (4_194_304,))
+        # the learned table: `nlive` codes of a span the power-of-two
+        # cap over which is scap (q9: 26 x 8 = 208 codes under 256)
+        span = 208 if scap == 256 else scap - scap // 8
+        codes = np.sort(rng.choice(np.arange(1, span), nlive,
+                                   replace=False)).astype(np.int64)
+        sk = np.full(scap, np.iinfo(np.int64).max, dtype=np.int64)
+        sk[:nlive] = codes
+        tab = np.full(scap, -1, dtype=np.int32)       # code -> slot
+        tab[codes] = np.arange(nlive, dtype=np.int32)
+        jsk, jtab = jnp.asarray(sk), jnp.asarray(tab)
+        blk = max(512, min(8192, (1 << 25) // scap))
+        for cap in sizes:
+            tag = f"onehot scap {scap} {cap} lanes"
+            slot_h = rng.integers(0, nlive, cap)
+            live_h = rng.random(cap) < 0.054          # q9's part filter
+            nn_h = rng.random(cap) < 0.999            # a value's non-null
+            val_h = rng.integers(-(1 << 31), 1 << 31, cap)
+            ok_h = live_h & nn_h
+            sums = np.zeros(nlive, dtype=np.int64)
+            np.add.at(sums, slot_h[ok_h], val_h[ok_h])
+            want = (sums,
+                    np.bincount(slot_h[ok_h], minlength=nlive),
+                    np.bincount(slot_h[live_h], minlength=nlive))
+            packed = jnp.asarray(codes[slot_h])
+            slot64 = jnp.asarray(slot_h)
+            live, nn = jnp.asarray(live_h), jnp.asarray(nn_h)
+            val = jnp.asarray(val_h)
+            slot32 = jnp.asarray(np.where(live_h, slot_h, -1)
+                                 .astype(np.int32))
+            nblk = cap // blk
+
+            # ---- (a) the parent's loop, verbatim -------------------
+            def vecs_of(live, nn, val):
+                ok = live & nn
+                dv = jnp.where(ok, val, jnp.zeros((), jnp.int64))
+                return [(dv, 10), (ok.astype(jnp.int64), 1),
+                        (live.astype(jnp.int64), 1)]
+
+            def limb_cols(vecs, s):
+                cols8 = []
+                for vec, n in vecs:
+                    vb = jax.lax.dynamic_slice(vec, (s,), (blk,))
+                    if n == 1:
+                        cols8.append((vb & 1).astype(jnp.int8)[:, None])
+                    else:
+                        limbs = [((vb >> (7 * i)) & 0x7F).astype(jnp.int8)
+                                 for i in range(9)]
+                        limbs.append(((vb >> 63) & 1).astype(jnp.int8))
+                        cols8.append(jnp.stack(limbs, axis=1))
+                return jnp.concatenate(cols8, axis=1)        # (blk, L)
+
+            def onehot_of(slot, live, s, sl_ids):
+                sl_b = jax.lax.dynamic_slice(slot, (s,), (blk,))
+                lv_b = jax.lax.dynamic_slice(live, (s,), (blk,))
+                return ((sl_b[:, None] == sl_ids[None, :]) &
+                        lv_b[:, None]).astype(jnp.int8)
+
+            def parent(slot, live, nn, val):
+                vecs = vecs_of(live, nn, val)
+                sl_ids = jnp.arange(scap, dtype=jnp.int64)
+
+                def block(b, acc):
+                    s = b * blk
+                    oh = onehot_of(slot, live, s, sl_ids)
+                    lm = limb_cols(vecs, s)
+                    p = jax.lax.dot_general(
+                        oh, lm, (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.int32)
+                    return acc + p
+                return jax.lax.fori_loop(
+                    0, nblk, block, jnp.zeros((scap, 12), jnp.int32))
+
+            def run(label, fn, *args, dec=None):
+                f = jax.jit(fn)
+                try:
+                    got = f(*args)
+                    if dec is not None:
+                        got = dec(got)
+                    if got is not None:
+                        check(label, got, want)
+                    out[f"{tag} {label}"] = bench_med(
+                        f"{tag} {label}", f, *args)
+                except Exception as e:          # noqa: BLE001
+                    # a form the compiler refuses at this width is a
+                    # reading too: say so and price the others
+                    print(f"{tag} {label}: FAILED "
+                          f"{type(e).__name__}: {str(e)[:300]}", flush=True)
+
+            run(f"(a) the parent's loop, blk {blk} x {nblk}", parent,
+                slot64, live, nn, val, dec=lambda a: decode(a, _OH_L10))
+
+            if scap == 256:
+                # ---- (a1) its parts alone --------------------------
+                def part_onehot(slot, live):
+                    sl_ids = jnp.arange(scap, dtype=jnp.int64)
+
+                    def block(b, acc):
+                        return acc | onehot_of(slot, live, b * blk, sl_ids)
+                    acc = jax.lax.fori_loop(
+                        0, nblk, block, jnp.zeros((blk, scap), jnp.int8))
+                    return jnp.sum(acc.astype(jnp.int32))
+
+                def part_limbs(live, nn, val):
+                    vecs = vecs_of(live, nn, val)
+
+                    def block(b, acc):
+                        return acc | limb_cols(vecs, b * blk)
+                    acc = jax.lax.fori_loop(
+                        0, nblk, block, jnp.zeros((blk, 12), jnp.int8))
+                    return jnp.sum(acc.astype(jnp.int32))
+
+                def make_operands(slot, live, nn, val):
+                    oh = ((slot[:, None] ==
+                           jnp.arange(scap, dtype=jnp.int64)[None, :]) &
+                          live[:, None]).astype(jnp.int8)
+                    vecs = vecs_of(live, nn, val)
+                    lm = jnp.concatenate(
+                        [jnp.stack([((v >> (7 * i)) & 0x7F).astype(jnp.int8)
+                                    for i in range(9)] +
+                                   [((v >> 63) & 1).astype(jnp.int8)],
+                                   axis=1) if n > 1
+                         else (v & 1).astype(jnp.int8)[:, None]
+                         for v, n in vecs], axis=1)
+                    return oh, lm
+
+                def part_dot(oh, lm):
+                    def block(b, acc):
+                        o = jax.lax.dynamic_slice(oh, (b * blk, 0),
+                                                  (blk, scap))
+                        m = jax.lax.dynamic_slice(lm, (b * blk, 0),
+                                                  (blk, 12))
+                        return acc + jax.lax.dot_general(
+                            o, m, (((0,), (0,)), ((), ())),
+                            preferred_element_type=jnp.int32)
+                    return jax.lax.fori_loop(
+                        0, nblk, block, jnp.zeros((scap, 12), jnp.int32))
+
+                run("(a1) the one-hot build alone, int64 compare to "
+                    "int8 [blk, scap]", lambda s, lv: (part_onehot(s, lv),),
+                    slot64, live, dec=lambda g: None)
+                run("(a1) the limb matrix alone, 12 int8 columns of "
+                    "[blk, L]", lambda lv, n_, v: (part_limbs(lv, n_, v),),
+                    live, nn, val, dec=lambda g: None)
+                try:
+                    oh_all, lm_all = jax.jit(make_operands)(
+                        slot64, live, nn, val)
+                    run("(a1) the dot_general alone over operands in HBM",
+                        part_dot, oh_all, lm_all,
+                        dec=lambda a: decode(a, _OH_L10))
+                    del oh_all, lm_all
+                except Exception as e:          # noqa: BLE001
+                    print(f"{tag} (a1) dot operands: FAILED {e}",
+                          flush=True)
+
+                # ---- (a2) the slot by search -----------------------
+                def searched(packed, live):
+                    loc = jnp.searchsorted(jsk, packed)
+                    locc = jnp.minimum(loc, scap - 1)
+                    hit = (jsk[locc] == packed) & (locc < nlive)
+                    return (jnp.sum(jnp.where(live & hit, locc, 0)),
+                            jnp.sum((live & ~hit).astype(jnp.int64)))
+                run("(a2) searchsorted of s64 codes in scap s64 keys + "
+                    "sk[locc] == packed", searched, packed, live,
+                    dec=lambda g: None)
+
+                # ---- (e) the slot without a search -----------------
+                def code_slot(packed, live):
+                    okr = (packed >= 0) & (packed < scap)
+                    sl = jnp.where(live & okr, packed, -1).astype(jnp.int32)
+                    return (jnp.sum(sl.astype(jnp.int64)),
+                            jnp.sum((live & ~okr).astype(jnp.int64)))
+
+                def table_slot(packed, live):
+                    okr = (packed >= 0) & (packed < scap)
+                    sl = jtab[jnp.clip(packed, 0, scap - 1)
+                              .astype(jnp.int32)]
+                    hit = okr & (sl >= 0)
+                    return (jnp.sum(jnp.where(live & hit, sl, 0)
+                                    .astype(jnp.int64)),
+                            jnp.sum((live & ~hit).astype(jnp.int64)))
+                run("(e) the packed code as the slot: a range check and "
+                    "a cast", code_slot, packed, live, dec=lambda g: None)
+                run("(e) one int32 gather from a direct table of scap "
+                    "codes", table_slot, packed, live, dec=lambda g: None)
+
+            # ---- (b) broadcast-compare-reduce, whole width ---------
+            def bcr(slot, nn, val, ids):
+                eq = slot[None, :] == ids[:, None]           # [scap, cap]
+                sel = eq & nn[None, :]
+                z = jnp.zeros((), jnp.int64)
+                return (jnp.sum(jnp.where(sel, val[None, :], z), axis=1),
+                        jnp.sum(sel.astype(jnp.int64), axis=1),
+                        jnp.sum(eq.astype(jnp.int64), axis=1))
+
+            run("(b) compare-reduce [scap, cap], int32 ids, int64 sums",
+                lambda s, n_, v: bcr(s, n_, v,
+                                     jnp.arange(scap, dtype=jnp.int32)),
+                slot32, nn, val)
+            run("(b64) the same, ids compared as int64",
+                lambda s, n_, v: bcr(s, n_, v, jnp.arange(scap)),
+                slot32, nn, val)
+
+            # ---- (c) the same in blocks ----------------------------
+            def bcr_loop(cblk):
+                def fn(slot, nn, val):
+                    ids = jnp.arange(scap, dtype=jnp.int32)
+
+                    def block(b, acc):
+                        s = b * cblk
+                        r = bcr(jax.lax.dynamic_slice(slot, (s,), (cblk,)),
+                                jax.lax.dynamic_slice(nn, (s,), (cblk,)),
+                                jax.lax.dynamic_slice(val, (s,), (cblk,)),
+                                ids)
+                        return tuple(a + x for a, x in zip(acc, r))
+                    z = jnp.zeros(scap, jnp.int64)
+                    return jax.lax.fori_loop(0, cap // cblk, block,
+                                             (z, z, z))
+                return fn
+
+            def bcr_twice(cblk):
+                def fn(slot, nn, val):
+                    ids = jnp.arange(scap, dtype=jnp.int32)
+                    s2 = slot.reshape(-1, cblk)
+                    n2 = nn.reshape(-1, cblk)
+                    v2 = val.reshape(-1, cblk)
+                    eq = s2[None] == ids[:, None, None]
+                    sel = eq & n2[None]
+                    z = jnp.zeros((), jnp.int64)
+
+                    def red(x):
+                        return jnp.sum(jnp.sum(x, axis=2), axis=1)
+                    return (red(jnp.where(sel, v2[None], z)),
+                            red(sel.astype(jnp.int64)),
+                            red(eq.astype(jnp.int64)))
+                return fn
+            for cblk in (8192, 65536):
+                run(f"(c) compare-reduce in a fori_loop of {cblk} lanes",
+                    bcr_loop(cblk), slot32, nn, val)
+            run("(c) compare-reduce over a [nblk, 8192] reshape, "
+                "reduced twice", bcr_twice(8192), slot32, nn, val)
+
+            # ---- (d) the matmul repaired ---------------------------
+            def repaired(wide):
+                nl = 10 if wide else 6
+                L = nl + 2
+
+                def fn(slot, nn, val):
+                    ids = jnp.arange(scap, dtype=jnp.int32)
+                    lo = val.astype(jnp.uint32)
+                    hi = (val >> 32).astype(jnp.uint32)
+                    ok = (slot >= 0) & nn
+
+                    def block(b, acc):
+                        s = b * blk
+                        sl_b = jax.lax.dynamic_slice(slot, (s,), (blk,))
+                        ok_b = jax.lax.dynamic_slice(ok, (s,), (blk,))
+                        lo_b = jnp.where(
+                            ok_b, jax.lax.dynamic_slice(lo, (s,), (blk,)),
+                            jnp.zeros((), jnp.uint32))
+                        oh = (ids[:, None] == sl_b[None, :]
+                              ).astype(jnp.int8)             # (scap, blk)
+                        rows = [((lo_b >> sh) & 0x7F).astype(jnp.int8)
+                                for sh in _OH_HALF[:4]]
+                        if wide:
+                            hi_b = jnp.where(
+                                ok_b,
+                                jax.lax.dynamic_slice(hi, (s,), (blk,)),
+                                jnp.zeros((), jnp.uint32))
+                            rows.append((lo_b >> 28).astype(jnp.int8))
+                            rows += [((hi_b >> sh) & 0x7F).astype(jnp.int8)
+                                     for sh in _OH_HALF[:4]]
+                            rows.append((hi_b >> 28).astype(jnp.int8))
+                        else:
+                            rows.append(((lo_b >> 28) & 7).astype(jnp.int8))
+                            rows.append((lo_b >> 31).astype(jnp.int8))
+                        rows.append(ok_b.astype(jnp.int8))
+                        rows.append((sl_b >= 0).astype(jnp.int8))
+                        lm = jnp.stack(rows, axis=0)         # (L, blk)
+                        p = jax.lax.dot_general(
+                            oh, lm, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.int32)
+                        return acc + p
+                    return jax.lax.fori_loop(
+                        0, nblk, block, jnp.zeros((scap, L), jnp.int32))
+
+                def dec(acc):
+                    acc = np.asarray(acc).astype(np.int64)
+                    with np.errstate(over="ignore"):
+                        if wide:
+                            sh = _OH_HALF + tuple(32 + x for x in _OH_HALF)
+                            tot = sum(np.left_shift(acc[:, i], s_)
+                                      for i, s_ in enumerate(sh))
+                        else:
+                            tot = sum(np.left_shift(acc[:, i], s_)
+                                      for i, s_ in enumerate(_OH_HALF))
+                            tot = tot - np.left_shift(acc[:, 5], 31)
+                    return tot, acc[:, nl], acc[:, nl + 1]
+                return fn, dec
+            for wide in (True, False):
+                fn, dec = repaired(wide)
+                run("(d) the matmul repaired: int32 compare, (scap, blk) x "
+                    "(L, blk) over the lanes, " +
+                    ("ten limbs of the halves" if wide else
+                     "five limbs and a sign"), fn, slot32, nn, val, dec=dec)
+    print("onehot step 0, ms (medians):", flush=True)
+    for k, v in out.items():
+        print(f"  {v:10.3f}  {k}", flush=True)
+    return out
+
+
 def main(sections):
     rng = np.random.default_rng(0)
     v64 = jnp.asarray(rng.integers(0, 1 << 22, N), dtype=jnp.int64)
@@ -389,6 +782,9 @@ def main(sections):
     if "probe" in sections or "bucket" in sections:
         _bucket_rows(rng)
 
+    if "onehot" in sections:
+        _onehot_rows(rng, (256, 2048, 32768))
+
     if "sort4m" in sections:
         n4 = 1 << 22
         w4 = jnp.asarray(rng.integers(0, 1 << 40, n4), dtype=jnp.int64)
@@ -397,9 +793,12 @@ def main(sections):
 
     if "mxu" in sections:
         # exact segment-sum via one-hot int8 matmul: 7-bit value limbs
-        # x one-hot -> int32 MXU accumulation (per-group row count must
-        # stay < 2^24 for exactness of the recombination in f32-free
-        # int32 adds; partitions cap n at 4M so it holds)
+        # x one-hot -> int32 MXU accumulation. A limb column is exact
+        # while lanes x 127 < 2^31 (agg_lowering.ONEHOT_CAP_MAX, 8M
+        # lanes; a block is 4M): int32 adds, no float anywhere, so no
+        # 2^24 line. These two rows feed the einsum whole columns; the
+        # program's own blocked loop, its parts and what replaces it up
+        # to 256 slots are the `onehot` section's rows (PR 43)
         n4 = 1 << 22
         vals = jnp.asarray(rng.integers(0, 1 << 34, n4), dtype=jnp.int64)
         s256 = jnp.asarray(rng.integers(0, 256, n4), dtype=jnp.int64)

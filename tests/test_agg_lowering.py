@@ -153,9 +153,9 @@ def test_a_learned_onehot_table_against_posruns(runs):
     one-hot kind on one chip; the mesh and the per-DAG executor do not
     see it; past ONEHOT_CAP_MAX lanes the sort kind, not posruns."""
     st = _state()
-    st.onehot = {"scap": 128}
+    st.onehot = {"scap": 128, "spans": [26, 8]}
     assert al.Lowering(st, _pos(65), dims=True).choose(CAP) == \
-        ("onehot", (128,), None)
+        ("onehot", (128, "search", "mxu"), None)
     assert al.Lowering(st, _pos(65), dims=True).choose(
         al.ONEHOT_CAP_MAX * 2)[0] == "sort"
     assert al.Lowering(st, _pos(65), site="fused_mpp").choose(CAP)[0] == "sort"
@@ -307,6 +307,56 @@ def test_a_held_run_is_counted_once_by_its_consumer(runs, judged_runs, why):
     assert judged_runs().get(("fused", "posruns", "retry_grow_bucket")) == 1
 
 
+# table -> (slot_by, reducer): the packed code is the slot where every
+# code of the spans is under `scap`, whatever the reducer; a coded table
+# of at most ONEHOT_CMP_MAX slots is reduced by compare
+ONEHOT_FORMS = {
+    "q9_26x8_under_256": ({"scap": 256, "spans": [26, 8]}, "code", "cmp"),
+    "spans_fill_scap": ({"scap": 256, "spans": [32, 8]}, "code", "cmp"),
+    "spans_past_scap": ({"scap": 128, "spans": [26, 8]}, "search", "mxu"),
+    "sparse_keys": ({"scap": 256, "spans": [38105, 6]}, "search", "mxu"),
+    "wide_spans_do_not_overflow": (
+        {"scap": 256, "spans": [1 << 40, 1 << 20]}, "search", "mxu"),
+    "one_key_column": ({"scap": 128, "spans": [100]}, "code", "cmp"),
+    "at_the_crossover": ({"scap": None, "spans": [4]}, "code", "cmp"),
+    "coded_past_the_crossover": ({"scap": None, "spans": [4, 8]},
+                                 "code", "mxu"),
+    "searched_past_the_crossover": ({"scap": None, "spans": [1 << 20]},
+                                    "search", "mxu"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ONEHOT_FORMS))
+def test_onehot_form_follows_the_table(case):
+    table, slot_by, reducer = ONEHOT_FORMS[case]
+    if table["scap"] is None:
+        table = dict(table, scap=al.ONEHOT_CMP_MAX * (
+            1 if case.startswith("at_") else 2))
+    assert al.onehot_form(table) == (slot_by, reducer)
+    st = _state()
+    st.onehot = table
+    assert al.Lowering(st, None, dims=True).choose(CAP)[:2] == \
+        ("onehot", (table["scap"], slot_by, reducer))
+
+
+@pytest.mark.parametrize("form, kind", [
+    (("search", "mxu"), "onehot"), (("code", "mxu"), "onehot"),
+    (("code", "cmp"), "onehot_cmp")], ids="_".join)
+@pytest.mark.parametrize("why", [None, "onehot_miss"])
+def test_judged_names_the_reducer_of_a_onehot_run(runs, judged_runs, form,
+                                                  kind, why):
+    """The matmul keeps the label every earlier reading has, wherever
+    its slot came from; a run reduced by compare, which searches
+    nothing, counts `onehot_cmp`."""
+    low = al.Lowering(_state(), None, dims=True)
+    param = (256, *form)
+    assert low.observe("onehot", param, None, ROWS, ROWS, hold=True) is None
+    assert judged_runs() == {}
+    low.settle("onehot", param, why)
+    assert judged_runs() == {
+        ("fused", kind, "retry_" + why if why else "stands"): 1}
+
+
 def test_state_is_one_per_shape_and_epoch():
     copr = _Copr()
     a, b = _state(copr), _state(copr)
@@ -336,6 +386,7 @@ def test_thresholds_are_constants_not_switches(monkeypatch):
     assert (al.BCR_MAX, al.RUNS_DEGRADE_MIN, al.ONEHOT_MAX,
             al.POS_DENSE_MAX, al.DENSE_MAX) == \
         (64, 65536, 32768, 1 << 22, 1 << 18)
+    assert 256 <= al.ONEHOT_CMP_MAX <= al.ONEHOT_MAX
     monkeypatch.setattr(al, "_FORCE_SEGMENT_IMPL", "hash")
     with pytest.raises(ValueError):
         al.policy()

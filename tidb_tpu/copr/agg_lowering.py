@@ -11,6 +11,8 @@ becomes partials, and their spans.
 """
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 from ..utils import jaxcfg  # noqa: F401
@@ -21,7 +23,7 @@ from ..chunk.device import shape_bucket
 from ..expression import EvalCtx, eval_expr
 from ..expression.vec import materialize_nulls
 from ..utils import metrics as _metrics, tracing as _tracing
-from ..utils.fetch import prefetch, host_array
+from ..utils.fetch import prefetch, host_array, host_int
 
 _I64_MAX = np.iinfo(np.int64).max
 
@@ -32,17 +34,31 @@ _I64_MAX = np.iinfo(np.int64).max
 # Most slots of a dense table reduced by broadcast-compare (the runs
 # policy's only dense form): a [nslots, cap] compare+reduce reads each
 # value column nslots times (q1's 12 slots, q5's 25). The step shows
-# as 0.97 s against 1.41 s in PERF.md finding 4; the value itself: not
-# measured.
+# as 0.97 s against 1.41 s in PERF.md finding 4. The form is linear in
+# the slots, 0.03 ms a slot a 4,194,304-lane block for one int64 sum
+# and two counts (PR 43's step 0, PERF.md section 7: 8.1 ms at 256
+# slots, 57 at 2,048, 889 at 32,768), so 64 slots are about 2 ms; what
+# the scatter past it costs at 65 slots: not measured.
 BCR_MAX = 64
 # The runs lowering calls itself degraded above this many partials AND
 # half the partition's rows (`runs_degraded`). The half was measured
 # (PR 27: at a quarter, q18's subquery sat on the line); this floor: not
 # measured.
 RUNS_DEGRADE_MIN = 65536
-# Most groups of a learned one-hot slot table. Not measured: no cell
-# runs the one-hot kind.
+# Most groups of a learned slot table. At this many the matmul form
+# reads 169.5 ms a 4,194,304-lane block for one sum (PR 43's step 0),
+# five gathers' worth; no cell learns a table of more than 256.
 ONEHOT_MAX = 32768
+# Most slots of a learned table reduced by a compare at the slot and an
+# int64 select-and-sum (`onehot_form`); past it, the int8 matmul. PR
+# 43's step 0, a 4,194,304-lane block, ONE sum and two counts, ms:
+# compare 8.1 / 57.0 / 889.5 at 256 / 2,048 / 32,768 slots (linear: 0.03
+# a slot), the matmul 13.8 / 18.2 / 169.5; they cross near 512 slots,
+# where neither was timed, so the line is at the largest size the compare
+# was seen to win. The compare reads its [scap, cap] tile once a state
+# and the matmul takes more states in the MXU's idle columns: with more
+# sums the crossing is lower, by how much: not measured.
+ONEHOT_CMP_MAX = 256
 # A one-hot limb column accumulates in int32: exact while
 # cap * 127 < 2^31.
 ONEHOT_CAP_MAX = 1 << 23
@@ -240,10 +256,14 @@ def judged(site, kind, param, verdict):
     `tidb_tpu_agg_lowering_total{site, kind, verdict}` and the same on
     the open `consume` span. site: the `Lowering`'s; kind: the lowering
     that ran, the sort kind with its segment impl ("sort_runs",
-    "sort_sorted"; the CPU's "sort_scatter"); verdict: "stands" or why
-    the run is thrown away and run again ("retry_<reason>")."""
+    "sort_sorted"; the CPU's "sort_scatter") and a slot table reduced
+    by compare at its code as "onehot_cmp" ("onehot": the matmul, which
+    as a rule has searched for its slot); verdict: "stands"
+    or why the run is thrown away and run again ("retry_<reason>")."""
     if kind == "sort":
         kind = "sort_" + param[1]
+    elif kind == "onehot" and param[2] == "cmp":
+        kind = "onehot_cmp"
     _metrics.AGG_LOWERING.labels(site, kind, verdict).inc()
     _tracing.tag(lowering=kind, verdict=verdict)
 
@@ -316,9 +336,10 @@ class Lowering:
     def choose(self, cap):
         """-> (agg_kind, agg_param, ecap) for a partition of `cap` lanes
         as learned so far. agg_param: "posdense" (pos_dims, nslots);
-        "dense" sizes; "onehot" (scap,); "sort" / "posruns" (bucket,
-        segment impl / position dims, top-n candidates, late compact
-        capacity). ecap: early compact capacity or None."""
+        "dense" sizes; "onehot" (scap, slot_by, reducer); "sort" /
+        "posruns" (bucket, segment impl / position dims, top-n
+        candidates, late compact capacity). ecap: early compact capacity
+        or None."""
         st, fused = self.state, self.site == "fused"
         if self.pos is not None:
             kind, param = "posdense", (tuple(self.pos[1]), self.pos[2])
@@ -329,7 +350,8 @@ class Lowering:
             kind, param = "dense", tuple(self.sizes)
         elif fused and isinstance(st.onehot, dict) and \
                 cap <= ONEHOT_CAP_MAX:
-            kind, param = "onehot", (st.onehot["scap"],)
+            kind, param = "onehot", (st.onehot["scap"],
+                                     *onehot_form(st.onehot))
         else:
             posruns = self._posruns_on()
             # (a "dense" pin whose layout this statement lacks: the
@@ -654,12 +676,35 @@ def _agg_eval_rows(ctx, a, mask, cap):
     return jnp.ones(cap, dtype=jnp.int64), mask
 
 
-# one-hot MXU segment aggregation (small learned group domains): at
-# most ONEHOT_MAX groups, at most ONEHOT_CAP_MAX lanes. MXU cost is
-# cap*scap*limbs int8 MACs — ~3.4 T-MAC at 4M x 32k x 13 (not measured
-# on this chip); the block size shrinks with scap to bound the
-# materialized one-hot tile at 32MB
+# a learned slot table (small learned group domains): at most
+# ONEHOT_MAX groups, at most ONEHOT_CAP_MAX lanes. Where the slot comes
+# from and how a block is reduced are two decisions (`onehot_form`).
+# What the chip read of them (PR 43's step 0, PERF.md section 7; a
+# 4,194,304-lane block, 256 slots): the search for the slot is what the
+# kind cost, not its reduction. `searchsorted` of the packed codes in
+# 256 sorted s64 keys reads 678.5 ms, the packed code taken as the slot
+# 1; the blocked one-hot int8 matmul (cap*scap*L int8 MACs, its block
+# shrinking with scap to bound the materialized one-hot tile at 32MB)
+# reads 13.8 ms, its one-hot build 9.8, its limbs 2.1, its dot 3.3; a
+# compare of the 32-bit slot id against the slot axis and an exact int64
+# select-and-sum, the dense kind's form, 8.1. Ten limbs against five
+# and a sign is 3.0 against 2.6 ms in a matmul whose operands keep the
+# lanes minor: the limb count is not where the time is.
 _ONEHOT_LIMBS = 10        # 9 x 7-bit limbs (bits 0..62) + the sign bit
+
+
+def onehot_form(table):
+    """-> (slot_by, reducer) of a run over the learned slot `table`, each
+    from what the table holds. slot_by: "code", the packed code itself,
+    when every code of the learned spans is under `scap` and there is
+    nothing to search (q9: 26 x 8 = 208 codes, 175 of them learned,
+    under 256), else "search" in the sorted keys. reducer: "cmp", the
+    compare at a coded slot of at most ONEHOT_CMP_MAX, else "mxu", the
+    int8 matmul."""
+    scap = table["scap"]
+    if math.prod(int(x) for x in table["spans"]) > scap:
+        return "search", "mxu"
+    return "code", "cmp" if scap <= ONEHOT_CMP_MAX else "mxu"
 
 
 def onehot_agg_limb_layout(aggs):
@@ -682,25 +727,55 @@ def onehot_agg_limb_layout(aggs):
     return specs, sum(n for _, _, n in specs)
 
 
-def onehot_agg_body(ctx, mask, group_items, aggs, cap, scap, sargs):
-    """Segment aggregation as ONE one-hot int8 matmul chain on the MXU
-    instead of a device argsort (the sorted lowering's 64-bit sort is
-    the cost it avoids; neither has been measured on this chip).
+def _onehot_cmp_states(ctx, live, aggs, slot, scap, cap):
+    """The compare form's reduction, `_dense_agg_states_bcr`'s for the
+    aggregates a slot table takes: one [scap, cap] compare of int32 slot
+    ids (a dead lane's is -1) that XLA fuses into a reduction a slot,
+    every sum in int64 whatever the column's width -> (states, rowcnt),
+    int64 arrays of `scap` in `_segscan_states`' layout."""
+    eq = slot[None, :] == jnp.arange(scap, dtype=jnp.int32)[:, None]
+    z = jnp.zeros((), jnp.int64)
+    states = []
+    for a in aggs:
+        d, ok = _agg_eval_rows(ctx, a, live, cap)
+        sel = eq & ok[None, :]
+        cnt = jnp.sum(sel.astype(jnp.int64), axis=1)
+        if a.name == "count":
+            states.append([cnt])
+        elif a.name in ("sum", "avg"):
+            states.append([jnp.sum(jnp.where(
+                sel, d.astype(jnp.int64)[None, :], z), axis=1), cnt])
+        else:
+            raise NotImplementedError(f"onehot lowering over {a.name}")
+    return states, jnp.sum(eq.astype(jnp.int64), axis=1)
+
+
+def onehot_agg_body(ctx, mask, group_items, aggs, cap, scap, slot_by,
+                    reducer, sargs):
+    """Segment aggregation into a host-learned slot table instead of a
+    device argsort (the sorted lowering's 64-bit sort is the cost it
+    avoids: 393 s to compile at 4M lanes, PERF.md PR 27; the forms'
+    own prices on the chip are in the header above).
 
     sargs (host-learned slot table, uploaded by the caller):
       skeys (scap,) i64  sorted packed keys, padded with _I64_MAX
       los   (K,)   i64   per-key-column pack offset
       spans (K,)   i64   per-key-column pack span (null code 0 included)
       nslots (1,)  i64   live slot count
-    Exactness: values decompose into 9x7-bit limbs + the sign bit,
-    each limb column accumulates in int32 (cap*127 < 2^31), and the
-    host recombines with arbitrary-precision ints mod 2^64 — bitwise
-    identical to an int64 sum for any input whose true sum fits int64.
+    slot_by, reducer (`onehot_form`): a "code" slot indexes the result by
+    packed code, not by learned slot, and searches nothing: a row on a
+    code the table lacks is the consumer's to find in the row counts
+    (`onehot_states`). "cmp" returns res["states"], int64 arrays of
+    `scap` a state, and res["rowcnt"]; exact as the dense kind's int64
+    sums are. "mxu" returns res["oh_acc"]: values decompose into
+    9x7-bit limbs + the sign bit, each limb column accumulates in int32
+    (cap*127 < 2^31), and the host recombines mod 2^64
+    (`onehot_decode_states`) — bitwise identical to an int64 sum.
     Any probe key missing from the table (new/changed data, span
     drift) is counted in res["miss"]; the caller falls back to the
     sorted lowering and relearns, so staleness can never corrupt a
     result. Keys/states for empty slots are dropped by the caller via
-    the trailing row-count column."""
+    the row count a slot."""
     packed = jnp.zeros(cap, dtype=jnp.int64)
     okr = jnp.ones(cap, dtype=bool)
     for i, g in enumerate(group_items):
@@ -716,14 +791,24 @@ def onehot_agg_body(ctx, mask, group_items, aggs, cap, scap, sargs):
         # register as misses, never as hits
         okr = okr & (code >= 0) & (code < span)
         packed = packed * span + jnp.clip(code, 0, span - 1)
-    sk = sargs["skeys"]
     nslots = sargs["nslots"][0]
-    loc = jnp.searchsorted(sk, packed)
-    locc = jnp.minimum(loc, scap - 1)
-    hit = (sk[locc] == packed) & okr & (locc < nslots)
+    if slot_by == "code":
+        # every code of the spans is under scap: the code is the slot
+        at, hit = packed, okr
+    else:
+        sk = sargs["skeys"]
+        loc = jnp.searchsorted(sk, packed)
+        at = jnp.minimum(loc, scap - 1)
+        hit = (sk[at] == packed) & okr & (at < nslots)
     miss = jnp.sum((mask & ~hit).astype(jnp.int64))
     live = mask & hit
-    slot = jnp.where(live, locc, 0)     # dead rows masked out of the
+    if reducer == "cmp":
+        states, rowcnt = _onehot_cmp_states(
+            ctx, live, aggs, jnp.where(live, at, -1).astype(jnp.int32),
+            scap, cap)
+        return {"states": states, "rowcnt": rowcnt, "miss": miss,
+                "ngroups": nslots}
+    slot = jnp.where(live, at, 0)       # dead rows masked out of the
     #                                     one-hot below, slot value moot
     specs, L = onehot_agg_limb_layout(aggs)
     vecs = []                           # (int64 vector, nlimbs)
@@ -774,10 +859,30 @@ def onehot_agg_body(ctx, mask, group_items, aggs, cap, scap, sargs):
     return {"oh_acc": acc, "miss": miss, "ngroups": nslots}
 
 
+def onehot_states(res, aggs, table, slot_by):
+    """Host side -> (states, rowcnt, miss) over the table's learned
+    slots, from either reducer's result. Under a "code" slot the device
+    indexed by packed code: the slots are read at the table's codes,
+    and the rows that fell on any other code are misses the device
+    could not count."""
+    if "oh_acc" in res:
+        states, every = onehot_decode_states(
+            host_array(res["oh_acc"]), aggs, table["scap"])
+    else:
+        states = [[host_array(s) for s in st] for st in res["states"]]
+        every = host_array(res["rowcnt"])
+    nslots = table["nslots"]
+    at = table["skeys"][:nslots] if slot_by == "code" else slice(nslots)
+    rowcnt = every[at]
+    miss = host_int(res["miss"]) + int(every.sum() - rowcnt.sum())
+    return [[s[at] for s in st] for st in states], rowcnt, miss
+
+
 def onehot_decode_states(acc, aggs, nslots):
-    """Host side: recombine the int32 limb accumulator into exact int64
-    state arrays -> (states, rowcnt). Mirrors _segscan_states' layout
-    (count -> [cnt]; sum/avg -> [s, cnt])."""
+    """Host side of the matmul form: recombine the int32 limb
+    accumulator into exact int64 state arrays -> (states, rowcnt).
+    Mirrors _segscan_states' layout (count -> [cnt]; sum/avg -> [s,
+    cnt])."""
     specs, _l = onehot_agg_limb_layout(aggs)
     states = [[None] * (2 if a.name in ("sum", "avg") else 1)
               for a in aggs]

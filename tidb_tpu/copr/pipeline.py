@@ -32,7 +32,7 @@ from . import dimfold
 from .agg_lowering import (PartialAggResult, capture_agg_dicts,
                            dense_strides, dense_agg_body, dense_agg_states,
                            sort_agg_body, runs_agg_core, onehot_agg_body,
-                           onehot_decode_states, compact_dense,
+                           onehot_states, compact_dense,
                            psum_dense_result)
 from ..utils.fetch import prefetch, host_array, host_int
 from ..utils import failpoint
@@ -1346,11 +1346,10 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                 res["fnvalid"] = fnvalid
             return res
         if agg_kind == "onehot":
-            (scap_oh,) = agg_param
             sargs = dargs[len(dims)]
             with jax.named_scope("group_agg"):
                 res = onehot_agg_body(ctx, mask, group_items, aggs,
-                                      cap, scap_oh, sargs)
+                                      cap, *agg_param, sargs)
                 res["nvalid"] = jnp.sum(mask.astype(jnp.int64))
             if ecap is not None or want_fnvalid:
                 res["fnvalid"] = fnvalid
@@ -2122,7 +2121,10 @@ def fused_partials(copr, plan, read_ts, mesh=None,
             out.append(compact_dense(shim, res, low.sizes, kd, sd))
             return True
         if agg_kind == "onehot":
-            if host_int(res["miss"]) > 0:
+            OH = oh_table
+            states, rowcnt, miss = onehot_states(
+                res, plan.aggs, OH, agg_param[1])
+            if miss > 0:
                 # new/changed keys since the table was learned:
                 # fall back to the sorted lowering and relearn
                 _count("fused_onehot_miss")
@@ -2130,11 +2132,7 @@ def fused_partials(copr, plan, read_ts, mesh=None,
                 del st.onehot
                 return False
             low.settle(agg_kind, agg_param)
-            OH = oh_table
             _count("fused_onehot_agg")
-            acc = host_array(res["oh_acc"])
-            states, rowcnt = onehot_decode_states(
-                acc, plan.aggs, OH["nslots"])
             oh_parts.append((len(out), rowcnt))
             out.append(PartialAggResult(
                 ngroups=OH["nslots"],

@@ -1056,7 +1056,10 @@ AGG_LOWERING = REGISTRY.counter(
     "Device runs of an aggregation program judged by the lowering "
     "(copr/agg_lowering.py), by whose kernel ran (site: fused | fused_mpp "
     "| dag), the lowering that ran (kind: dense, posdense, "
-    "posruns, sort_<segment impl>, onehot) and the verdict: stands, or "
+    "posruns, sort_<segment impl>, onehot: a learned slot table reduced "
+    "by the int8 matmul, onehot_cmp: by a compare at its packed code, "
+    "nothing searched) and the "
+    "verdict: stands, or "
     "why the run was thrown away and run again (retry_early_compact, "
     "retry_compact, retry_pin_sorted, retry_pin_dense, "
     "retry_grow_bucket, "
