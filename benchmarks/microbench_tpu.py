@@ -8,7 +8,8 @@ directly, on the chip:
 
 Sections: io, reduce, group, sort by default; probe (its last rows
 alone: bucket), onehot (PR 43's step 0: how a learned slot table is
-reduced), sort4m, mxu and
+reduced), select (PR 44's step 0: where the k-th set lane of a mask
+is), sort4m, mxu and
 scatter by name (scatter is the slowest to COMPILE on a TPU — run it
 last, with a long timeout).
 
@@ -26,6 +27,7 @@ Design inputs these numbers feed (copr/agg_lowering.py lowering choice):
 - sort / argsort / top_k (compaction, ordered output)
 - segment_sum scatter (the fallback the others replace)
 """
+import os
 import sys
 import time
 
@@ -633,6 +635,166 @@ def _onehot_rows(rng, scaps, lanes=None):
     return out
 
 
+def _select_rows(rng, sizes=None):
+    """ISSUE 44's step 0: where the k-th set lane of a mask is, at the
+    sizes of the fused pipeline's compactions and of the runs lowering:
+    `N` lanes (a 4,194,304-lane block, the 1,835,008-lane tail) x `K`
+    probes (81,920 and 8,192), 1.5 % of the lanes set, so that probes
+    run past the count; and N = K = 81,920 with half the lanes set (the
+    runs lowering after a compaction). Every form's positions are
+    checked against numpy's on its first call.
+    (a) the parent: an s64 cumsum and `jnp.searchsorted`, s64 probes;
+    (b) the same in int32;
+    (c) `agg_lowering.prefix_search` (the program's own): k-ary over
+        rows of block ends, rows of 8, 16, 32 and 128 int32, and one
+        long row (512, 1,024) under a wide top level;
+    (d) `jnp.searchsorted(..., method="sort")` in int32;
+    (h) `lax.top_k` over the set lanes' negated indexes, no count;
+    (e) the cumsum alone, s64 against int32 (ROADMAP S1(e));
+    (f) a run's end: `searchsorted(cs_change, rid + 1)` in s64 as the
+        parent has it, against `agg_lowering.next_flag` (a reverse
+        cummin and one gather);
+    (g) what a row costs: K rows of 1, 8, 16, 32 and 128 int32 gathered
+        at sorted indexes from the N lanes as [N / B, B] and summed
+        along the row, beside their price in 32-bit look-ups of 7.4 ns
+        (PERF.md section 7, PR 37).
+    `sizes`: other (N, K, share set) than the program's, for a
+    rehearsal off the chip."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from tidb_tpu.copr import agg_lowering as al
+    out = {}
+    sizes = sizes or ((4_194_304, 81_920, 0.015), (4_194_304, 8_192, 0.015),
+                      (1_835_008, 81_920, 0.015), (1_835_008, 8_192, 0.015),
+                      (81_920, 81_920, 0.5))
+    LOOKUP_NS = 7.4
+
+    def run(tag, label, fn, args, want):
+        f = jax.jit(fn)
+        try:
+            got = np.asarray(f(*args))
+            if want is not None:
+                assert got.shape == want.shape and \
+                    np.array_equal(got, want), (tag, label, got[:8],
+                                                want[:8])
+            out[f"{tag} {label}"] = bench_med(f"{tag} {label}", f, *args)
+        except Exception as e:          # noqa: BLE001
+            # a form the compiler refuses at this width is a reading
+            # too: say so and price the others
+            print(f"{tag} {label}: FAILED {type(e).__name__}: "
+                  f"{str(e)[:300]}", flush=True)
+
+    for n, k, share in sizes:
+        tag = f"select {n} lanes {k} probes"
+        flags_h = rng.random(n) < share
+        cs_h = np.cumsum(flags_h.astype(np.int64))
+        want = np.searchsorted(cs_h, np.arange(1, k + 1)).astype(np.int64)
+        flags = jnp.asarray(flags_h)
+        print(f"{tag}: {int(cs_h[-1])} set, "
+              f"{int((want >= n).sum())} probes past the count", flush=True)
+
+        def parent(m):
+            return jnp.searchsorted(
+                jnp.cumsum(m.astype(jnp.int64)),
+                jnp.arange(1, k + 1, dtype=jnp.int64))
+
+        def binary32(m):
+            return jnp.searchsorted(
+                jnp.cumsum(m.astype(jnp.int32)),
+                jnp.arange(1, k + 1, dtype=jnp.int32))
+
+        def sort32(m):
+            return jnp.searchsorted(
+                jnp.cumsum(m.astype(jnp.int32)),
+                jnp.arange(1, k + 1, dtype=jnp.int32), method="sort")
+
+        def rows(b, top=al.SELECT_TOP):
+            def fn(m):
+                return al.prefix_search(
+                    al.prefix_count(m), jnp.arange(1, k + 1), row=b,
+                    top=top)
+            return fn
+
+        run(tag, "(a) s64 cumsum + searchsorted, the parent", parent,
+            (flags,), want)
+        run(tag, "(b) int32 cumsum + searchsorted", binary32, (flags,),
+            want)
+        for b in (8, 16, 32, 128):
+            run(tag, f"(c) int32 cumsum + rows of {b} ({b * 4} bytes)",
+                rows(b), (flags,), want)
+        run(tag, "(c) int32 cumsum + rows of 128, at most 2,048 ends on top",
+            rows(128, 2048), (flags,), want)
+        # one gather of a long row under a wide top level
+        run(tag, "(c) int32 cumsum + rows of 512, at most 8,192 ends on top",
+            rows(512, 8192), (flags,), want)
+        run(tag, "(c) int32 cumsum + rows of 1024, at most 4,096 ends on top",
+            rows(1024, 4096), (flags,), want)
+        run(tag, "(d) int32 cumsum + searchsorted method=sort", sort32,
+            (flags,), want)
+        if n > k:
+            # no count at all: the k smallest lane indexes among the set
+            run(tag, "(h) top_k of the set lanes' negated indexes",
+                lambda m: -jax.lax.top_k(
+                    jnp.where(m, -jnp.arange(n, dtype=jnp.int32), -n),
+                    k)[0], (flags,), want.astype(np.int32))
+        if k == 81_920:
+            run(tag, "(e) cumsum alone, s64",
+                lambda m: jnp.cumsum(m.astype(jnp.int64)), (flags,), cs_h)
+            run(tag, "(e) cumsum alone, int32",
+                lambda m: jnp.cumsum(m.astype(jnp.int32)), (flags,),
+                cs_h.astype(np.int32))
+
+            # ---- (f) a run's end -------------------------------------
+            # runs of about 8 lanes; `at` a lane of each of the first k
+            change_h = rng.random(n) < 0.125
+            change_h[0] = True
+            starts = np.flatnonzero(change_h)
+            at_h = np.minimum(starts[:k] + 1, n - 1)
+            at_h = np.concatenate(
+                [at_h, np.full(k - len(at_h), n - 1)]).astype(np.int64)
+            csc_h = np.cumsum(change_h.astype(np.int64))
+            want_re = np.minimum(
+                np.searchsorted(csc_h, csc_h[at_h] + 1), n) - 1
+            change, at = jnp.asarray(change_h), jnp.asarray(at_h)
+
+            def re_search(ch, at):
+                csc = jnp.cumsum(ch.astype(jnp.int64))
+                return jnp.minimum(
+                    jnp.searchsorted(csc, csc[at] + 1), n) - 1
+
+            def re_scan(ch, at):
+                return al.next_flag(ch, at, n) - 1
+
+            run(tag, "(f) a run's end, s64 cumsum + gather + searchsorted",
+                re_search, (change, at), want_re)
+            run(tag, "(f) a run's end, reverse cummin + one gather",
+                re_scan, (change, at), want_re)
+
+            # ---- (g) what a row costs --------------------------------
+            tab_h = rng.integers(0, 1 << 20, n).astype(np.int32)
+            tab = jnp.asarray(tab_h)
+            for b in (1, 8, 16, 32, 128):
+                ridx_h = np.sort(rng.integers(0, n // b, k))
+                want_g = tab_h.reshape(-1, b)[ridx_h].sum(
+                    axis=1, dtype=np.int32)
+                label = (f"(g) {k} rows of {b} int32 ({b * 4} bytes) at "
+                         "sorted indexes, summed")
+                run(tag, label,
+                    lambda t, i, b=b: jnp.sum(
+                        t.reshape(-1, b).at[i].get(
+                            mode="promise_in_bounds"), axis=1),
+                    (tab, jnp.asarray(ridx_h)), want_g)
+                ms = out.get(f"{tag} {label}")
+                if ms is not None:
+                    print(f"{tag} {label}: {ms * 1e6 / k / LOOKUP_NS:.2f} "
+                          f"look-ups of {LOOKUP_NS} ns a row (dispatch "
+                          "included)", flush=True)
+    print("select step 0, ms (medians):", flush=True)
+    for key, v in out.items():
+        print(f"  {v:10.3f}  {key}", flush=True)
+    return out
+
+
 def main(sections):
     rng = np.random.default_rng(0)
     v64 = jnp.asarray(rng.integers(0, 1 << 22, N), dtype=jnp.int64)
@@ -784,6 +946,9 @@ def main(sections):
 
     if "onehot" in sections:
         _onehot_rows(rng, (256, 2048, 32768))
+
+    if "select" in sections:
+        _select_rows(rng)
 
     if "sort4m" in sections:
         n4 = 1 << 22

@@ -162,9 +162,14 @@ def main():
 
     after = snaps[-1]["metrics"]
     kept = {f"{k[0]}|{k[1]}": v for k, v in after.items()
-            if "kernel_stage" in k[0] or "xla_cache" in k[0]}
+            if "kernel_stage" in k[0] or "xla_cache" in k[0]
+            or "prefix_select" in k[0]}
     with open(os.path.join(out_dir, "catalogue.json"), "w") as f:
         json.dump(kept, f, indent=0)
+    # how each program built since the process started inverts its prefix
+    # counts (PR 44): {site, form} -> programs; absent on an older tree
+    log("prefix_select: " + str({k[1]: v for k, v in after.items()
+                                 if k[0] == "tidb_tpu_prefix_select_total"}))
     labels = [k[1] for k in after if k[0] == "tidb_tpu_kernel_stage_ops"]
     log(f"catalogue: {len(labels)} samples, longest label "
         f"{max(map(len, labels), default=0)} bytes; outcomes "

@@ -10,7 +10,7 @@ import pytest
 import tidb_tpu.copr.agg_lowering as al
 import tidb_tpu.copr.pipeline as pl
 from tidb_tpu.chunk.device import shape_bucket
-from tidb_tpu.expression.expr import Column
+from tidb_tpu.expression.expr import AggDesc, Column
 from tidb_tpu.testkit import TestKit
 from tidb_tpu.types.field_type import new_bigint_type
 
@@ -557,3 +557,116 @@ def test_host_unclustered_leaves_what_it_cannot_count_to_the_device():
     floats = np.random.default_rng(7).random(n)
     assert not al.host_unclustered(_Dag(g), {0: (floats, None, None)}, n)
     assert not al.host_unclustered(_Dag(), {}, n)
+
+
+# ---- inverting a prefix count (PR 44) ---------------------------------
+# `prefix_select` is held, position for position, to what it replaced:
+# `jnp.searchsorted` over the int64 prefix count.
+
+def _searched(flags, probes):
+    import jax.numpy as jnp
+    cs = jnp.cumsum(jnp.asarray(flags).astype(jnp.int64))
+    return np.asarray(jnp.searchsorted(cs, jnp.asarray(probes)))
+
+
+# 28,672 and 1,835,008 are 7 x 2^k (a row block's tail), 40,960 is
+# 5 x 2^k (a mesh shard at scale 3): levels `SELECT_ROW` does not divide
+@pytest.mark.parametrize("share", [0.0, 0.015, 1.0])
+@pytest.mark.parametrize("cap", [1, 7, 128, 1000, 16384, 28672, 40960,
+                                 1835008])
+def test_prefix_select_equals_the_search(cap, share):
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng(cap + int(share * 1000))
+    flags = rng.random(cap) < share
+    count = int(flags.sum())
+    sel = jax.jit(lambda f, p: al.prefix_select(f, p))
+    jflags = jnp.asarray(flags)
+    # probes under, at and past the count
+    for k in sorted({max(count // 2, 1), max(count, 1),
+                     min(count + 1000, cap + 7)}):
+        probes = np.arange(1, k + 1)
+        pos, n = sel(jflags, jnp.asarray(probes))
+        assert pos.dtype == np.int32 and n.dtype == np.int64
+        assert int(n) == count
+        want = _searched(flags, probes)
+        assert np.array_equal(np.asarray(pos), want), (cap, share, k)
+        assert k <= count or int(np.asarray(pos)[-1]) == cap
+    # sorted probes that are no arange, as a run's end asks: repeats,
+    # zero, negatives, past the count
+    probes = np.sort(rng.integers(-2, count + 3, min(cap, 4096) + 5))
+    pos, _n = sel(jflags, jnp.asarray(probes))
+    assert np.array_equal(np.asarray(pos), _searched(flags, probes))
+
+
+@pytest.mark.parametrize("row, top", [(8, 16), (16, 512), (128, 2048)])
+def test_prefix_search_levels(row, top):
+    """Other rows and tops than the module's: more and fewer levels
+    over the same count give the same positions."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(row)
+    flags = rng.random(5 * 2 ** 14 + 3) < 0.02
+    probes = np.arange(1, 3000)
+    got = al.prefix_search(al.prefix_count(jnp.asarray(flags)),
+                           jnp.asarray(probes), row=row, top=top)
+    assert np.array_equal(np.asarray(got), _searched(flags, probes))
+
+
+def _runs_case(case, cap=4096):
+    rng = np.random.default_rng(len(case))
+    if case == "clustered":
+        keys = np.sort(rng.integers(0, cap // 6, cap))
+        mask = rng.random(cap) < 0.7
+    elif case == "unclustered":
+        keys = rng.integers(0, 50, cap)
+        mask = rng.random(cap) < 0.7
+    elif case == "masked_runs":
+        # every third run has no visible row, the last run among them
+        keys = np.sort(rng.integers(0, cap // 6, cap))
+        mask = (keys % 3 != 0) & (keys != keys[-1])
+    else:                              # one run, nothing visible
+        keys = np.zeros(cap, dtype=np.int64)
+        mask = np.zeros(cap, dtype=bool)
+    return keys.astype(np.int64), mask
+
+
+@pytest.mark.parametrize("case", ["clustered", "unclustered",
+                                  "masked_runs", "all_masked"])
+def test_run_ends_from_the_scan_equal_the_searched(case):
+    """`next_flag` against the search it replaced, at the lanes
+    `runs_agg_core` asks at (every lane, so every `posc`); then the
+    core's groups against a plain loop over the runs."""
+    import jax.numpy as jnp
+    from tidb_tpu.expression import EvalCtx
+    keys, mask = _runs_case(case)
+    cap = len(keys)
+    change = np.concatenate([[True], keys[1:] != keys[:-1]])
+    cs_change = np.cumsum(change.astype(np.int64))
+    at = np.arange(cap)
+    want = np.minimum(np.searchsorted(cs_change, cs_change[at] + 1), cap)
+    got = al.next_flag(jnp.asarray(change), jnp.asarray(at), cap)
+    assert np.array_equal(np.asarray(got), want)
+
+    vals = np.arange(cap, dtype=np.int64) % 97
+    col = Column(0, new_bigint_type())
+    ctx = EvalCtx(jnp, cap, {0: (jnp.asarray(vals), None, None)},
+                  host=False)
+    aggs = [AggDesc("sum", [col]), AggDesc("count", []),
+            AggDesc("max", [col]), AggDesc("first_row", [col])]
+    res = al.runs_agg_core([jnp.asarray(keys)], None, jnp.asarray(mask),
+                           ctx, aggs, cap, cap)
+    ref = []
+    starts = np.flatnonzero(change)
+    for s, e in zip(starts, list(starts[1:]) + [cap]):
+        m = mask[s:e]
+        if m.any():
+            ref.append((keys[s], vals[s:e][m].sum(), m.sum(),
+                        vals[s:e][m].max(), vals[s:e][m][0]))
+    n = int(res["ngroups"])
+    assert n == len(ref)
+    got = list(zip(np.asarray(res["keys"][0])[:n],
+                   np.asarray(res["states"][0][0])[:n],
+                   np.asarray(res["states"][1][0])[:n],
+                   np.asarray(res["states"][2][0])[:n],
+                   np.asarray(res["states"][3][0])[:n]))
+    assert got == ref

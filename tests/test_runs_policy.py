@@ -14,6 +14,7 @@ import tidb_tpu.copr.agg_lowering as al
 import tidb_tpu.copr.pipeline as pl
 from tidb_tpu.testkit import TestKit
 from tidb_tpu.bench.tpch import load_tpch, ALL_QUERIES, QUERIES
+from tidb_tpu.utils import metrics
 
 
 @pytest.fixture
@@ -412,3 +413,152 @@ def test_posruns_q10_gathers_no_group_payload(tk, runs_impl, kinds,
     assert payload <= set(old)
     # seven payload gathers more, one of the positions' kind's own less
     assert len(old) - len(now) >= 6
+
+
+# ---- where the k-th set lane is: no search left (PR 44) ----------------
+# The compactions and the runs lowering invert a prefix count through
+# `agg_lowering.prefix_select` (rows of block ends, int32) and read a
+# run's end off a scan. Counts of the traced program, never times.
+
+# statement -> the call sites its compacting program counts
+_SELECT_SITES = {
+    "q3": {"late_compact", "runs_pos", "runs_end"},
+    "q10": {"late_compact", "runs_pos", "runs_end"},
+    "q18": {"late_compact", "runs_pos", "runs_end"},
+    "q12": {"early_compact"},
+    "q19": {"early_compact"},
+}
+
+
+def _q(name):
+    # at SF0.003 no order passes q18's HAVING of 300; 244 pass 200
+    return ALL_QUERIES[name].replace("> 300", "> 200")
+
+
+def _body_jaxpr(build, shapes):
+    a, k = build
+    return jax.make_jaxpr(pl._make_pipeline_body(
+        *a, **dict(k, want_fnvalid=True)))(*shapes)
+
+
+def _select_census(build, shapes):
+    """-> (primitives under the `compact` and `group_agg` scopes, the
+    operand types of the row gathers there, the types of the prefix
+    counts a gather under `compact` reads): a loop's body is walked
+    under its eqn's scope."""
+    seen, rows, counts, cums = set(), [], [], {}
+
+    def walk(jaxpr, scope):
+        for e in jaxpr.eqns:
+            at = f"{scope}/{e.source_info.name_stack}"
+            staged = "compact" in at or "group_agg" in at
+            name = e.primitive.name
+            if name == "cumsum" or e.params.get("name") == "cumsum":
+                cums[id(e.outvars[0])] = e.outvars[0].aval.dtype
+            if staged:
+                seen.add(name)
+            if staged and name == "gather":
+                src = e.invars[0]
+                if e.params["slice_sizes"][-1] > 1:
+                    rows.append(src.aval.dtype)
+                if "compact" in at and id(src) in cums:
+                    counts.append(cums[id(src)])
+            for v in e.params.values():
+                for j in v if isinstance(v, (list, tuple)) else [v]:
+                    inner = getattr(j, "jaxpr", j)
+                    if hasattr(inner, "eqns"):
+                        walk(inner, at)
+    walk(_body_jaxpr(build, shapes).jaxpr, "")
+    return seen, rows, counts
+
+
+def _compacting_kernel(tk, kinds, q):
+    """The statement's program once its compaction is learned: the
+    last one built over three runs."""
+    tk.domain.copr._kernel_cache.clear()
+    del kinds[:]
+    tk.domain.copr.use_device = True
+    before = metrics.prefix_selects()
+    for _ in range(3):
+        tk.must_query(_q(q))
+    kind, param, build, shapes = kinds[-1]
+    ecap = build[1].get("ecap")
+    assert (ecap is not None) if kind == "dense" else \
+        (kind == "posruns" and param[3] is not None), (kind, param)
+    return build, shapes, metrics.prefix_selects(before)
+
+
+def _searching(monkeypatch):
+    """The control: the parent's form, a search over the count."""
+    import jax.numpy as jnp
+    monkeypatch.setattr(
+        al, "prefix_search",
+        lambda cs, probes, site=None: jnp.searchsorted(
+            cs.astype(jnp.int64), probes.astype(jnp.int64)))
+
+
+@pytest.mark.parametrize("q", sorted(_SELECT_SITES))
+def test_census_no_search_under_compact_and_group_agg(
+        tk, runs_impl, kinds, monkeypatch, q):
+    """q3, q10, q18 (`posruns` behind a late compaction), q12, q19 (an
+    early compaction) under the chip's policy: no loop under the
+    `compact` and `group_agg` scopes (`searchsorted`'s steps trace as
+    `scan`, the chip's compiler writes them as `while`), the rows of
+    block ends are gathered from int32 and no gather under `compact`
+    reads an int64 count. (The columns a compaction moves and the sums
+    of values stay int64 and are gathered so: once, at no loop's
+    step.) The control, with the search put back, holds the loop."""
+    build, shapes, _grown = _compacting_kernel(tk, kinds, q)
+    seen, rows, counts = _select_census(build, shapes)
+    assert "gather" in seen and "cumsum" in seen
+    assert not {"while", "scan", "sort"} & seen, sorted(seen)
+    assert rows and set(rows) == {np.dtype(np.int32)}, rows
+    assert not [t for t in counts if t == np.int64]
+    _dev_vs_host(tk, _q(q))
+    _searching(monkeypatch)
+    build, shapes, _grown = _compacting_kernel(tk, kinds, q)
+    seen, rows, _counts = _select_census(build, shapes)
+    assert {"while", "scan"} & seen and not rows
+
+
+@pytest.mark.parametrize("q", sorted(_SELECT_SITES))
+def test_prefix_select_counter_names_site_and_form(tk, runs_impl, kinds, q):
+    """One count a built program a call site: `rows` for the searches
+    that were, `scan` for a run's end; the same on the span open while
+    the program is traced."""
+    tk.must_exec("set tidb_tpu_trace_sample_rate = 1")
+    try:
+        _b, _s, grown = _compacting_kernel(tk, kinds, q)
+        tagged = [r[0] for r in tk.must_query(
+            "select attrs from information_schema.tidb_trace_events "
+            "where attrs like '%select_%'").rows]
+    finally:
+        tk.must_exec("set tidb_tpu_trace_sample_rate = 0")
+    want = {(s, "scan" if s == "runs_end" else "rows")
+            for s in _SELECT_SITES[q]}
+    assert set(grown) == want, grown
+    for site, form in want:
+        assert [a for a in tagged if f"select_{site}={form}" in a], tagged
+    # a program found in the kernel cache is not traced again
+    before = metrics.prefix_selects()
+    tk.must_query(_q(q))
+    assert not metrics.prefix_selects(before)
+
+
+@pytest.mark.parametrize("q", ["q1", "q6"])
+def test_scans_select_nothing_and_keep_their_program(
+        tk, runs_impl, kinds, monkeypatch, q):
+    """The dense scans compact nothing and group by no run: the counter
+    stands still and the body is the control's, equation for
+    equation."""
+    tk.domain.copr._kernel_cache.clear()
+    tk.domain.copr.use_device = True
+    before = metrics.prefix_selects()
+    tk.must_query(_q(q))
+    assert kinds and not metrics.prefix_selects(before)
+    bodies = [str(_body_jaxpr(k[2], k[3])) for k in kinds]
+    _searching(monkeypatch)
+    tk.domain.copr._kernel_cache.clear()
+    del kinds[:]
+    tk.must_query(_q(q))
+    assert [str(_body_jaxpr(k[2], k[3])) for k in kinds] == bodies

@@ -968,10 +968,99 @@ def _dense_agg_states_bcr(ctx, mask, aggs, slot, nslots, cap):
             "states": states}
 
 
+# ---- inverting a prefix count ------------------------------------------
+# Where the k-th set lane of a mask is: what a compaction gathers
+# through and where the runs lowering finds a run's first valid row. A
+# look-up is paid per index (7.4 ns a lane on a v5e, PERF.md section 7)
+# and a row at one index costs little more than a scalar, so the count
+# is searched k-ary by rows of block ends, not binary by scalars.
+# Lanes a row of block ends holds, and the most ends the top level
+# compares whole against every probe. Step 0 of PR 44 read them
+# (benchmarks/microbench_tpu.py select).
+SELECT_ROW = 128
+SELECT_TOP = 512
+_I32_MAX = np.iinfo(np.int32).max
+
+
+def _noted_select(site, form):
+    """One count a traced program a call site in
+    `tidb_tpu_prefix_select_total{site, form}`, and `select_<site>` on
+    the span that is open while the program is traced."""
+    if site is None:
+        return
+    _metrics.PREFIX_SELECT.labels(site, form).inc()
+    _tracing.tag(**{"select_" + site: form})
+
+
+def prefix_count(flags):
+    """-> the inclusive count of set lanes, int32: exact under 2^31
+    lanes, which every caller's `cap` is (ONEHOT_CAP_MAX, device_rows,
+    a mesh shard), and one 32-bit gather a look-up where an int64 count
+    is two."""
+    return jnp.cumsum(flags.astype(jnp.int32))
+
+
+def prefix_search(cs, probes, site=None, row=SELECT_ROW, top=SELECT_TOP):
+    """Lanes of the non-decreasing int32 count `cs` that read under each
+    probe: position for position `jnp.searchsorted(cs, probes)`, so with
+    `cs` a mask's `prefix_count` and probe k the lane of its k-th set
+    bit, and `cap` for a probe past the count (callers clamp).
+
+    k-ary: the ends of blocks of `row` lanes, and of blocks of those,
+    until at most `top` are left (a count of at most `top` lanes is its
+    own top level). The top level is a broadcast compare and a count;
+    each level under it one gather of a row [K, row] and a
+    compare-count along it: no loop on any backend. A level `row` does
+    not divide is padded with the type's maximum, which no probe reads
+    under."""
+    probes = probes.astype(jnp.int32)
+    _noted_select(site, "rows")
+    levels, lens = [cs], [cs.shape[0]]
+    while lens[-1] > top:
+        a, n = levels[-1], lens[-1]
+        nb = -(-n // row)
+        if nb * row != n:
+            a = jnp.concatenate(
+                [a, jnp.full(nb * row - n, _I32_MAX, dtype=jnp.int32)])
+        levels[-1] = a.reshape(nb, row)
+        levels.append(levels[-1][:, row - 1])
+        lens.append(nb)
+    p = probes[:, None]
+    c = jnp.sum(levels[-1][None, :] < p, axis=1, dtype=jnp.int32)
+    for rows, n in zip(levels[-2::-1], lens[:0:-1]):
+        # c blocks end under the probe: the next holds it, or the
+        # last when all do (its padding counts for nothing)
+        j = jnp.minimum(c, n - 1)
+        r = rows.at[j].get(mode="promise_in_bounds")
+        c = j * row + jnp.sum(r < p, axis=1, dtype=jnp.int32)
+    return c
+
+
+def prefix_select(flags, probes, site=None):
+    """-> (positions, count): `prefix_search` over the flags' own
+    `prefix_count`, and how many are set (int64, as the results carry
+    it)."""
+    cs = prefix_count(flags)
+    return (prefix_search(cs, probes, site),
+            cs[flags.shape[0] - 1].astype(jnp.int64))
+
+
+def next_flag(flags, at, cap, site=None):
+    """The first set lane after lane `at[k]`, `cap` where none is: a
+    reverse running minimum over the lanes' own indexes and one gather,
+    where the count of `flags` was searched for its next step."""
+    _noted_select(site, "scan")
+    idx = jnp.arange(cap, dtype=jnp.int32)
+    nxt = jax.lax.cummin(jnp.where(flags, idx, cap), reverse=True)
+    a1 = at + 1
+    return jnp.where(a1 < cap, nxt[jnp.minimum(a1, cap - 1)], cap)
+
+
 def runs_agg_core(keys, key_nulls, mask, ctx, aggs, cap, bucket):
     """Contiguous-run partial aggregation: every maximal run of equal
     group keys becomes one partial group, extracted with cumulative
-    sums + monotone searchsorted gathers — no sort, no scatter.
+    sums and gathers at the k-th run's first valid row (`prefix_select`)
+    and its end (`next_flag`) — no sort, no scatter, no search loop.
 
     Exactness: int sums/counts via prefix-sum differences (exact);
     float sums and min/max via a segmented associative scan that resets
@@ -998,19 +1087,18 @@ def runs_agg_core(keys, key_nulls, mask, ctx, aggs, cap, bucket):
     else:
         change = jnp.concatenate([jnp.ones(1, dtype=bool),
                                   jnp.zeros(cap - 1, dtype=bool)])
-    cs_change = jnp.cumsum(change.astype(jnp.int64))      # run ordinal
     run_start = jax.lax.cummax(jnp.where(change, idx, -1))
     mi = mask.astype(jnp.int64)
     mask_cs = jnp.cumsum(mi)
     mask_before_run = (mask_cs - mi)[run_start]
     vstart = mask & (mask_cs == mask_before_run + 1)      # first valid row
-    vcs = jnp.cumsum(vstart.astype(jnp.int64))
-    ngroups = vcs[cap - 1]
-    pos = jnp.searchsorted(vcs, jnp.arange(1, bucket + 1))
+    pos, ngroups = prefix_select(vstart, jnp.arange(1, bucket + 1),
+                                 "runs_pos")
     posc = jnp.minimum(pos, cap - 1)
     rs = run_start[posc]                                  # run start
-    rid = cs_change[posc]
-    re = jnp.minimum(jnp.searchsorted(cs_change, rid + 1), cap) - 1
+    # the run's end is where the next one starts: read off a scan,
+    # nothing searched
+    re = next_flag(change, posc, cap, "runs_end") - 1
 
     out_keys = [k[posc] for k in keys]
     out_key_nulls = [kn[posc] for kn in key_nulls or ()]
@@ -1044,7 +1132,8 @@ def runs_agg_core(keys, key_nulls, mask, ctx, aggs, cap, bucket):
             states.append([s, cnt])
         elif a.name == "first_row":
             ford = (ok_cs - oki)[rs] + 1
-            fpos = jnp.minimum(jnp.searchsorted(ok_cs, ford), cap - 1)
+            fpos = jnp.minimum(prefix_select(ok, ford, "first_row")[0],
+                               cap - 1)
             states.append([d[fpos], cnt])
         else:
             raise NotImplementedError(a.name)

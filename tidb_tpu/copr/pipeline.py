@@ -33,7 +33,7 @@ from .agg_lowering import (PartialAggResult, capture_agg_dicts,
                            dense_strides, dense_agg_body, dense_agg_states,
                            sort_agg_body, runs_agg_core, onehot_agg_body,
                            onehot_states, compact_dense,
-                           psum_dense_result)
+                           psum_dense_result, prefix_select)
 from ..utils.fetch import prefetch, host_array, host_int
 from ..utils import failpoint
 from ..utils import jaxcfg
@@ -1110,8 +1110,9 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
     q14/q19 class: a date-range predicate keeps ~1% of lineitem) make
     every downstream probe gather and agg pass pay full-partition cost
     for mostly-dead lanes. With ecap set, survivors of the FACT-local
-    filters are gathered into an ecap-row buffer (cumsum + searchsorted
-    + gather — the scatter-free kernel policy) and the joins/post
+    filters are gathered into an ecap-row buffer (an int32 prefix count,
+    `prefix_select` for the k-th survivor's lane and gathers — the
+    scatter-free kernel policy) and the joins/post
     filters/aggregation run at ecap instead of fact_cap. The caller
     learns ecap per query shape and verifies fnvalid <= ecap (overflow
     regrows the bucket and reruns — the group_bucket retry pattern).
@@ -1160,10 +1161,8 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                 mask = mask & eval_bool_mask(ctx, f)
         if ecap is not None:
             with jax.named_scope("compact"):
-                csum0 = jnp.cumsum(mask.astype(jnp.int64))
-                fnvalid = csum0[cap - 1]
-                src = jnp.searchsorted(
-                    csum0, jnp.arange(1, ecap + 1, dtype=jnp.int64))
+                src, fnvalid = prefix_select(
+                    mask, jnp.arange(1, ecap + 1), "early_compact")
                 src = jnp.minimum(src, cap - 1)
                 cols = {k: (d[src], None if nl is None else nl[src], sd)
                         for k, (d, nl, sd) in cols.items()}
@@ -1359,23 +1358,25 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
         gb, agg_impl, topn, ccap = agg_param
         pos_dims = agg_impl if posruns else ()
         pkeys = [dim_pos[di] for di in pos_dims]
-        with jax.named_scope("compact"):
-            csum = jnp.cumsum(mask.astype(jnp.int64))
-            nvalid = csum[cap - 1]
         actx, amask, acap = ctx, mask, cap
-        if ccap is not None:
+        if ccap is None:
+            with jax.named_scope("compact"):
+                csum = jnp.cumsum(mask.astype(jnp.int64))
+                nvalid = csum[cap - 1]
+        else:
             # compact-then-aggregate (selective pipelines, the
             # Q18/Q21 class): the sort-based agg pays O(cap log cap)
             # on the FULL padded partition even when a semi/anti dim
             # kills almost every row. Gather the survivors into a
-            # small learned-capacity buffer first — cumsum +
-            # searchsorted + gather only (the scatter-free kernel
-            # policy) — and aggregate that. The caller verifies
+            # small learned-capacity buffer first — an int32 prefix
+            # count, the k-th survivor's lane by rows of block ends
+            # (prefix_select) and gathers only (the scatter-free
+            # kernel policy) — and aggregate that. The caller verifies
             # nvalid <= ccap (an overflow regrows the bucket and
             # reruns, the group_bucket retry pattern).
             with jax.named_scope("compact"):
-                src = jnp.searchsorted(
-                    csum, jnp.arange(1, ccap + 1, dtype=jnp.int64))
+                src, nvalid = prefix_select(
+                    mask, jnp.arange(1, ccap + 1), "late_compact")
                 src = jnp.minimum(src, cap - 1)
                 ok = jnp.arange(ccap, dtype=jnp.int64) < nvalid
                 ccols = {}

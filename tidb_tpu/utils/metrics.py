@@ -1037,11 +1037,14 @@ AGG_MERGE = REGISTRY.counter(
 
 
 def _moved(counter, label, since=None) -> dict:
-    """-> {label value: count} of a one-label counter's samples that
-    have moved, since an earlier reading of this when one is given."""
+    """-> {label value: count} of a counter's samples that have moved,
+    since an earlier reading of this when one is given; keyed by a
+    tuple of values where `label` is a tuple of labels."""
     since = since or {}
+    key = (lambda lb: lb[label]) if isinstance(label, str) else \
+        (lambda lb: tuple(lb[x] for x in label))
     return {k: n for k, n in (
-        (lb[label], int(v) - since.get(lb[label], 0))
+        (key(lb), int(v) - since.get(key(lb), 0))
         for _name, lb, v in counter.sample_rows()) if n}
 
 
@@ -1065,6 +1068,26 @@ AGG_LOWERING = REGISTRY.counter(
     "retry_grow_bucket, "
     "retry_onehot_miss, retry_topn_unproven)",
     ("site", "kind", "verdict"))
+
+
+PREFIX_SELECT = REGISTRY.counter(
+    "tidb_tpu_prefix_select_total",
+    "Inversions of a prefix count traced into a device program "
+    "(agg_lowering.prefix_search / next_flag: where the k-th set lane of "
+    "a mask is), one count a built program a call site, by site "
+    "(late_compact, early_compact: the fused pipeline's compactions; "
+    "runs_pos, runs_end, first_row: the runs lowering's first valid row "
+    "of a run, the run's end, an aggregate's first row) and form (rows: "
+    "k-ary over rows of block ends of an int32 count, a compare at "
+    "every level; scan: read off a reverse running minimum, nothing "
+    "searched)",
+    ("site", "form"))
+
+
+def prefix_selects(since=None) -> dict:
+    """-> {(site, form): count} of `tidb_tpu_prefix_select_total`'s
+    samples that have moved, since an earlier reading of this."""
+    return _moved(PREFIX_SELECT, ("site", "form"), since)
 
 
 SNAPSHOT_FACTS = REGISTRY.counter(
