@@ -15,6 +15,7 @@ import pytest
 import tidb_tpu.copr.agg_lowering as al
 import tidb_tpu.copr.dimfold as df
 import tidb_tpu.copr.pipeline as pl
+import tidb_tpu.copr.probe as probe
 from tidb_tpu.bench.tpch import load_tpch, ALL_QUERIES
 from tidb_tpu.testkit import TestKit
 from tidb_tpu.utils import metrics as mu
@@ -711,7 +712,7 @@ def test_the_words_type_keys_the_kernel_cache(tkc, kinds):
         plan = a[0]
         fact = copr.engine.table(plan.fact_dag.table_info)
         metas = [{"tbl": copr.engine.table(d.dag.table_info),
-                  "mode": "direct", "lut": np.zeros(1, np.int32)}
+                  "probe": probe.ProbeTable.always_miss(1)}
                  for d in plan.dims]
         keys.add(pl._fused_cache_key(
             copr, plan, fact, metas, a[1], tuple(a[3]), tuple(a[4]),
@@ -989,7 +990,7 @@ def test_bind_span_carries_fold_counts(tk):
 # ---- (f) a composite key's table of buckets (PR 40) --------------------
 #
 # A dimension joined on several columns whose packed span is no direct
-# table is probed through buckets on one of them (`pl._bucket_table`):
+# table is probed through buckets on one of them (`probe._bucket_table`):
 # the small tables against a plain join in Python, the census of q9's
 # program, and the first-set statements' programs left as they were.
 
@@ -1001,7 +1002,7 @@ _BQ3 = _BQ + " and fb.c = ps.c"
 def _ps_rows(case):
     """-> [(a, b, c, w)] of the dimension: unique on (a, b) and on
     (a, b, c), spans of 300 x 1000 (x 41) so that no packed key is a
-    direct table at these sizes (`_direct_span`'s floor is 4,096
+    direct table at these sizes (`direct_span`'s floor is 4,096
     slots)."""
     rng = np.random.RandomState(40)
     rows = []
@@ -1112,7 +1113,7 @@ def test_bucket_probe_equals_a_plain_join(kinds, case):
                                  tk.domain.columnar.table(
                                      tk.domain.infoschema().table_by_name(
                                          "test", "ps")), None)
-        rows_ = meta["btab"].reshape(-1, 2 * slots)
+        rows_ = meta["probe"].table.reshape(-1, 2 * slots)
         bk, bp = rows_[:, :slots], rows_[:, slots:]
         assert len(rows_) == max(r[0] for r in ps)
         assert int((bk >= 0).sum()) == int((bp < meta["n"]).sum()) \
@@ -1120,7 +1121,7 @@ def test_bucket_probe_equals_a_plain_join(kinds, case):
         assert (bk[bp == meta["n"]] == -1).all()
         assert rows_.dtype == np.int32 and da["bt"].dtype == np.int32
         assert da["bt"].shape[0] % (2 * slots) == 0     # whole rows
-        assert meta["pack"][2][bcol] == 0       # what the lane packs
+        assert meta["probe"].pack[2][bcol] == 0     # what the lane packs
 
 
 def test_duplicate_composite_keys_are_still_refused():
@@ -1201,7 +1202,7 @@ def test_census_q9_probes_partsupp_without_a_search(tk, runs_impl, kinds,
             if "'cols'" not in p] == [("'bt']", 4)]
     assert [p for p, _ in mine if "'cols'" in p]        # ps_supplycost
     assert not [p for p, _ in census if "'sk'" in p or "'ord'" in p]
-    monkeypatch.setattr(pl, "_bucket_table", lambda *a: None)
+    monkeypatch.setattr(probe, "_bucket_table", lambda *a: None)
     _forget_key_tables(tk)
     searched, old = _loops(*_main_kernel(tk, kinds, _q("q9")))
     _forget_key_tables(tk)
@@ -1232,7 +1233,7 @@ def test_single_column_keys_keep_their_program(tk, runs_impl, kinds,
 
     def unreachable(*a):
         raise AssertionError("a single-column key asked for buckets")
-    monkeypatch.setattr(pl, "_bucket_table", unreachable)
+    monkeypatch.setattr(probe, "_bucket_table", unreachable)
     _forget_key_tables(tk)
     assert _lowered_text(*_main_kernel(tk, kinds, _q(q))) == now
     _forget_key_tables(tk)

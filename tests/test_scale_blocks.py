@@ -25,6 +25,7 @@ import pytest
 
 import tidb_tpu.copr.agg_lowering as al
 import tidb_tpu.copr.pipeline as pl
+import tidb_tpu.copr.probe as probe
 from tidb_tpu.copr.residency import DeviceResidentStore
 from tidb_tpu.errors import MemoryQuotaExceededError
 from tidb_tpu.testkit import TestKit
@@ -243,10 +244,8 @@ def test_part_and_parts_on_dispatch_and_consume(ftk, sql):
 # ---- sizes that pick kernels, past their SF1 steps ---------------------
 
 def _dim_modes(tk):
-    return sorted(v[0] for k, v in tk.domain.copr._host_cache.items()
-                  if isinstance(k, tuple) and "dimcur" not in k and
-                  isinstance(v, tuple) and v and
-                  v[0] in ("direct", "sorted"))
+    return sorted(v.form for v in tk.domain.copr._host_cache.values()
+                  if isinstance(v, probe.ProbeTable))
 
 
 @pytest.mark.parametrize("budget, mode", [(8 << 30, "direct"),
@@ -279,7 +278,7 @@ def test_direct_lut_is_bounded_by_the_store_not_by_a_constant(
     assert [(int(r[0]), int(r[1]), int(r[2])) for r in got] == \
         [(c,) + want[c] for c in sorted(want)]
     assert _dim_modes(ftk) == [mode]
-    assert pl._direct_span(ftk.domain.copr, 18_000_000, 4_500_000) == \
+    assert probe.direct_span(ftk.domain.copr, 18_000_000, 4_500_000) == \
         (budget == 8 << 30)
 
 

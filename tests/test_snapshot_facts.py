@@ -348,8 +348,9 @@ def test_writer_thread_never_changes_a_readers_mask():
     tk = _mk()
     ctab = _ftab(tk)
     stop = threading.Event()
-    errors, held = [], []
+    errors, held, kept = [], [], set()
     deadline = time.time() + 20
+    v0 = ctab.version
 
     def write():
         w = tk.new_session()
@@ -377,7 +378,10 @@ def test_writer_thread_never_changes_a_readers_mask():
                     errors.append(AssertionError("mask and columns"))
                 if stamped is not None and stamped < version:
                     errors.append(AssertionError("facts older than read"))
-                if len(held) < 400:
+                # (a mask not seen before is held past the cap: `held`
+                # may fill before the writer's next commit)
+                if len(held) < 400 or id(valid) not in kept:
+                    kept.add(id(valid))
                     held.append((valid, valid.copy()))
         except Exception as e:                  # noqa: BLE001
             errors.append(e)
@@ -389,7 +393,14 @@ def test_writer_thread_never_changes_a_readers_mask():
     try:
         for t in threads:
             t.start()
-        time.sleep(1.5)
+        # the 1.5 s of before, and on until the writer has committed
+        # three versions and the readers hold the masks of two: on a
+        # loaded machine that can take longer than any fixed sleep
+        soak = time.time() + 1.5
+        while time.time() < deadline and not errors and (
+                time.time() < soak or ctab.version < v0 + 3
+                or len(kept) < 2):
+            time.sleep(0.02)
         stop.set()
         for t in threads:
             t.join(timeout=30)

@@ -213,23 +213,6 @@ def _host_cols(dim, meta):
     return cols
 
 
-def _host_probe(meta, pv, pnm):
-    """The kernel's probe, in numpy: -> (position clipped into the
-    dimension, hit)."""
-    n = meta["n"]
-    if meta["mode"] == "direct":
-        lut = meta["lut"]
-        idx = pv - meta["lo"]
-        inb = (idx >= 0) & (idx < len(lut))
-        raw = lut[np.clip(idx, 0, len(lut) - 1)]
-        return np.minimum(raw, n - 1), inb & (raw < n) & ~pnm
-    sk = meta["skeys"]
-    loc = np.searchsorted(sk, pv)
-    locc = np.minimum(loc, len(sk) - 1)
-    hit = (loc < meta["n_sorted"]) & (sk[locc] == pv) & ~pnm
-    return np.minimum(meta["order"][locc], n - 1), hit
-
-
 _WORD_BITS = 63
 _NARROW_BITS = 31
 
@@ -401,10 +384,10 @@ def _build(fp, plan, metas, root):
                 pv = np.full(n, pv)
             pv = np.asarray(pv).astype(np.int64)
             pnm = np.asarray(materialize_nulls(ectx, pnl))
-            cpos, chit = _host_probe(metas[c], pv, pnm)
+            cpos, chit = metas[c]["probe"].host_probe(pv, pnm)
             passing &= chit
-            if metas[c].get("pre"):
-                continue       # filters and visibility are in its lut
+            if metas[c]["probe"].exists:
+                continue       # filters and visibility are in its table
             passing &= resolve(c)[cpos]
             if dims[c].join_type == "inner":
                 pos_at[(di, c)] = cpos
@@ -418,7 +401,7 @@ def _build(fp, plan, metas, root):
     meta = metas[root]
     n = meta["n"]
     ok = np.append(passing, False)         # the sentinel n stays a miss
-    src = meta["lut"] if meta["mode"] == "direct" else meta["order"]
+    src = meta["probe"].positions
     # (in the physical type of the table it stands in for)
     return np.where(ok[np.minimum(src, n)], src, n) \
         .astype(src.dtype, copy=False), pos_at
@@ -459,7 +442,7 @@ def _chain_sig(fp, plan, metas, root, read_ts):
 def bind_folds(copr, plan, fp, metas, read_ts, ctx):
     """Build or find the folded tables of every root of `fp` and hand
     back the metas the upload and the kernel builder read: a root's
-    copy carries its `Fold` (and the folded table in the lut's place),
+    copy carries its `Fold` (and the folded table in the positions' place),
     a folded child's copy names the root it went under.
     -> (metas, folds, builds)."""
     dirty = txn_dirty(ctx)
@@ -499,7 +482,7 @@ def bind_folds(copr, plan, fp, metas, read_ts, ctx):
             count("cache_hit")
         m = dict(metas[root])
         m["fold"] = fold
-        m["lut" if m["mode"] == "direct" else "order"] = fold.table
+        m["probe"] = m["probe"].with_positions(fold.table)
         m["ukey"] = tuple(m.get("ukey", ())) + ("fold", fold.sig)
         out[root] = m
         for d in fp.descendants(root):

@@ -3,8 +3,8 @@ executor/join/hash_join_v2.go:608 build/probe + tipb partial agg,
 re-designed TPU-first as ONE XLA program).
 
 Design: the fact table streams through in static-shape partitions; each
-dimension join is a binary search into the dimension's SORTED unique key
-column (resident in HBM across queries, version-keyed) followed by a
+dimension join is a probe of the dimension's table over its unique keys
+(copr/probe.py; resident in HBM across queries, version-keyed) and a
 gather of payload columns — no dynamic-shape compaction anywhere: rows
 that fail a filter or miss a join simply clear a validity mask, and the
 partial aggregation at the tail ignores them. This keeps every
@@ -14,7 +14,6 @@ bottleneck: Q3/Q5 lost all join output to host numpy between operators).
 """
 from __future__ import annotations
 
-import math
 import re
 import threading
 
@@ -29,6 +28,8 @@ from ..expression.vec import materialize_nulls
 from ..chunk.device import shape_bucket, shard_lanes
 from . import agg_lowering as _al
 from . import dimfold
+from . import probe
+from .probe import ProbeTable
 from .agg_lowering import (PartialAggResult, capture_agg_dicts,
                            dense_strides, dense_agg_body, dense_agg_states,
                            sort_agg_body, runs_agg_core, onehot_agg_body,
@@ -67,30 +68,14 @@ def _set_reason(copr, msg):
     _metrics.FUSED_DECLINE.labels(_metrics.reason_code(msg)).inc()
 
 
-def _direct_span(copr, span, nv, slot_bytes=8) -> bool:
-    """May a dimension's build keys be probed through a direct lut
-    (`lut[key - lo]`, one gather a probe) rather than a binary search
-    over the sorted keys (~70 gathers' time on the chip, PERF.md §7)?
-    Two bounds, both from what is observed: the keys are dense enough
-    that the lut is at most four slots a row, and the lut (at most
-    `slot_bytes` a slot, resident like the dimension's columns) fits an
-    eighth of the resident store's budget — 128 Mi slots at the default
-    8 GiB, so TPC-H's orders (18,000,000 sparse keys at scale 3, 60,000,000 at
-    10) stay off the binary search, which ran q3, q5 and q10 in 14-16 s
-    instead of 1-4 (PERF.md, PR 27)."""
-    return span <= max(4 * nv, 1 << 12) and \
-        span * slot_bytes <= copr._dev_store.budget // 8
-
-
 def _dim_sort_meta(copr, dim, tbl, read_ts):
     """Host-side per-dimension prep: snapshot arrays + the join "hash
     table" for the build-key column (cached per table version) +
     uniqueness check. -> dict or None when ineligible.
 
-    The table's form is `_key_table`'s choice from the build keys.
-    Composite keys (dim.extra_keys, Q9 partsupp) pack into one int64 by
-    per-column stride first; the pack layout ships to the kernel so the
-    probe packs the same way."""
+    The table and its form are `probe.ProbeTable`'s, from the build
+    keys; composite keys (dim.extra_keys, Q9 partsupp) pack into one
+    int64 first, and the kernel's probe packs by the same layout."""
     col_ids = [cid for cid in (_cid_of(dim.dag, sc) for sc in dim.dag.cols)
                if cid != -1]
     arrays, valid = tbl.snapshot(col_ids, read_ts)
@@ -115,8 +100,8 @@ def _dim_sort_meta(copr, dim, tbl, read_ts):
         # SEMI/ANTI only test key EXISTENCE: fold the dim's filters on
         # the host and dedup, so duplicate keys and filtered dims (Q4's
         # EXISTS, Q22's NOT EXISTS over orders) still ride the fused
-        # probe. The kernel then skips this dim's mask entirely
-        # ("pre" mode).
+        # probe. The kernel then skips this dim's mask entirely (the
+        # table `exists`).
         return _semi_prefiltered_meta(copr, dim, tbl, arrays, valid, n,
                                       key_cids[0], read_ts)
     # built over VALID rows only (old MVCC versions of an updated key
@@ -124,114 +109,20 @@ def _dim_sort_meta(copr, dim, tbl, read_ts):
     # read_ts, so it keys the cache; older versions are evicted
     ck = tuple(key_cids)
     hkey = (tbl.uid, ck, "dim", tbl.version, n, read_ts)
-    meta = host_cache.get(hkey)
-    if meta is None:
-        prev = host_cache.pop((tbl.uid, ck, "dimcur"), None)
-        if prev is not None:
-            host_cache.pop(prev, None)
+    table = host_cache.get(hkey)
+    if table is None:
+        host_cache.pop(host_cache.pop((tbl.uid, ck, "dimcur"), None), None)
         host_cache[(tbl.uid, ck, "dimcur")] = hkey
-        vidx = np.nonzero(valid)[0]
-        keys_v, pack = _packed_keys(arrays, key_cids, n, vidx)
-        nv = 0 if keys_v is None else len(keys_v)
-        if nv == 0 or len(np.unique(keys_v)) != nv:
-            # dup-key / null-key dims are rejected below on every use:
-            # cache a tombstone, don't build the (possibly huge) lut
-            meta = (None, None)
-        else:
-            table = _key_table(copr, arrays, key_cids, keys_v, pack, vidx,
-                               n)
-            meta = (table["mode"], table)
-        host_cache[hkey] = meta
-    if meta[0] is None:
+        # dup-key / null-key dims are rejected below on every use:
+        # cache a tombstone (False), don't build the (possibly huge) table
+        table = host_cache[hkey] = ProbeTable.build(
+            copr, arrays, key_cids, np.nonzero(valid)[0], n) or False
+    if table is False:
         _set_reason(copr, f"dim {dim.dag.table_info.name}: build keys "
                     "are duplicated or NULL (non-unique build side)")
         return None
-    return dict(meta[1], arrays=arrays, valid=valid, n=n, tbl=tbl)
-
-
-def _key_table(copr, arrays, key_cids, keys_v, pack, vidx, n):
-    """The join's "hash table" over a dimension's build keys: `keys_v`,
-    unique and not NULL (packed where the key has several columns), of
-    the rows `vidx` of `n` -> the meta's entries for it. Three forms,
-    chosen from what the keys are observed to be:
-    - direct: the key span is dense enough (`_direct_span`) -> a table
-      of positions, the probe is ONE gather (pos = lut[key - lo], n the
-      miss). TPC-H's primary keys are dense 1..N: the common case.
-    - bucket: a key of several columns whose packed span is not, but
-      one of whose columns is: `_bucket_table`.
-    - sorted: argsort + binary search (jnp.searchsorted) otherwise."""
-    nv = len(keys_v)
-    lo = int(keys_v.min())
-    span = int(keys_v.max()) - lo + 1
-    if _direct_span(copr, span, nv):
-        lut = np.full(span, n, dtype=dimfold.pos_dtype(n))
-        lut[keys_v - lo] = vidx
-        return {"mode": "direct", "lo": lo, "n_sorted": nv, "pack": pack,
-                "lut": lut}
-    bucket = None if pack is None else \
-        _bucket_table(copr, arrays, key_cids, pack, vidx, n)
-    if bucket is not None:
-        return bucket
-    o = np.argsort(keys_v, kind="stable")
-    return {"mode": "sorted", "lo": None, "n_sorted": nv, "pack": pack,
-            "order": vidx[o], "skeys": keys_v[o]}
-
-
-def _bucket_table(copr, arrays, key_cids, pack, vidx, n):
-    """A key of several columns probed through buckets on ONE of them
-    (partsupp's (ps_partkey, ps_suppkey): a part has four suppliers):
-    row `k - lo` of that column holds `m` slots, `m` the most rows that
-    share one value of it: first the `m` rows' other key columns packed
-    (-1 where a slot is empty: no probe packs to it), then their `m`
-    positions (n the miss). The probe gathers its bucket's row ONCE and
-    compares: no search; on the chip a row of eight words costs a third
-    of four words gathered one by one (PERF.md section 7, PR 40). A
-    column may be the bucket column when its table (span x m slots)
-    keeps `_direct_span`'s bounds and a bucket has no more slots than
-    the binary search it replaces has steps; of those the one with the
-    fewest slots a bucket, which is what a probe pays for (partsupp's
-    two columns both give exactly one slot a row: 4 x 200,000 and
-    80 x 10,000), then the smaller table -> the meta's entries, or None
-    (the sorted table). The pack layout it returns has stride 0 at the
-    bucket column: what the kernel packs is a slot's other keys."""
-    los, spans, _strides = pack
-    nv = len(vidx)
-    cols = [arrays[cid][0][:n][vidx].astype(np.int64) - lo
-            for cid, lo in zip(key_cids, los)]
-    packed_span = math.prod(spans)      # `_packed_keys` held it to 62 bits
-    best = None
-    for k, (col, s) in enumerate(zip(cols, spans)):
-        if not _direct_span(copr, s, nv):
-            continue
-        counts = np.bincount(col, minlength=s)
-        m = int(counts.max())
-        # a slot is two words, as narrow as the other keys and `n` fit
-        dt = np.dtype(dimfold.table_dtype(max(
-            (packed_span // s - 1).bit_length(), int(n).bit_length())))
-        if _direct_span(copr, s * m, nv, 2 * dt.itemsize) and \
-                m <= (nv - 1).bit_length() and \
-                (best is None or (m, s) < best[:2]):
-            best = (m, s, k, counts, dt)
-    if best is None:
-        return None
-    m, s, bcol, counts, dt = best
-    rest, acc = [0] * len(spans), 1
-    for k in reversed(range(len(spans))):
-        if k != bcol:
-            rest[k] = acc
-            acc *= spans[k]
-    others = sum(col * st for col, st in zip(cols, rest))
-    o = np.argsort(cols[bcol], kind="stable")
-    b = cols[bcol][o]
-    rank = np.arange(nv) - (np.cumsum(counts) - counts)[b]  # in its bucket
-    btab = np.empty((s, 2 * m), dtype=dt)
-    btab[:, :m] = -1
-    btab[:, m:] = n
-    btab[b, rank] = others[o]
-    btab[b, m + rank] = vidx[o]
-    return {"mode": "bucket", "lo": None, "n_sorted": nv,
-            "pack": (los, spans, tuple(rest)), "bucket": (bcol, m),
-            "btab": btab.reshape(-1)}
+    return {"probe": table, "arrays": arrays, "valid": valid, "n": n,
+            "tbl": tbl}
 
 
 _VOLATILE_RE = re.compile(
@@ -373,10 +264,7 @@ def _matdim_nbytes(out):
     for d, nl, _sd in out["arrays"].values():
         total += getattr(d, "nbytes", 0)
         total += getattr(nl, "nbytes", 0) if nl is not None else 0
-    for k in ("lut", "order", "skeys", "btab"):
-        if k in out:
-            total += getattr(out[k], "nbytes", 0)
-    return total
+    return total + out["probe"].nbytes
 
 
 _MAT_SEQ = [0]
@@ -500,14 +388,12 @@ def _matdim_meta(copr, ctx, dim, read_ts, seen):
         if ksdict is not None or kdata.dtype.kind == "f":
             _set_reason(copr, "materialized dim: non-int64 join key")
             return None
-    valid = np.ones(n, dtype=bool)
-    vidx = np.arange(n)
-    keys_v, pack = _packed_keys(arrays, key_cids, n, vidx)
-    if keys_v is None or len(np.unique(keys_v)) != n:
+    table = ProbeTable.build(copr, arrays, key_cids, np.arange(n), n)
+    if table is None:
         _set_reason(copr, "materialized dim: non-unique or NULL keys")
         return None
-    out = dict(_key_table(copr, arrays, key_cids, keys_v, pack, vidx, n),
-               arrays=arrays, valid=valid, n=n, tbl=_MatTbl(n),
+    out = dict(probe=table, arrays=arrays, valid=np.ones(n, dtype=bool),
+               n=n, tbl=_MatTbl(n),
                dictsig=tuple(sorted(
                    (i, len(sd.values)) for i, (_d, _nl, sd)
                    in arrays.items() if sd is not None)))
@@ -525,97 +411,27 @@ def _matdim_meta(copr, ctx, dim, read_ts, seen):
     return out
 
 
-def _packed_keys(arrays, key_cids, n, vidx):
-    """-> (packed int64 key per valid row, pack layout) or (None, None).
-    Single keys pass through (pack=None). Composite keys pack as
-    sum((k_i - lo_i) * stride_i); the layout is (los, spans, strides),
-    rejected when the combined span overflows int63 or any key is
-    NULL."""
-    if len(key_cids) == 1:
-        kdata, knulls, _ = arrays[key_cids[0]]
-        if knulls is not None and knulls[:n][vidx].any():
-            return None, None
-        return kdata[:n][vidx], None
-    cols = []
-    for cid in key_cids:
-        kdata, knulls, _ = arrays[cid]
-        if knulls is not None and knulls[:n][vidx].any():
-            return None, None
-        cols.append(kdata[:n][vidx].astype(np.int64))
-    if len(cols[0]) == 0:
-        return None, None
-    los = [int(c.min()) for c in cols]
-    spans = [int(c.max()) - lo + 1 for c, lo in zip(cols, los)]
-    total = 1
-    for s in spans:
-        total *= s
-        if total > (1 << 62):
-            return None, None
-    strides = []
-    acc = 1
-    for s in reversed(spans):
-        strides.append(acc)
-        acc *= s
-    strides = list(reversed(strides))
-    packed = np.zeros(len(cols[0]), dtype=np.int64)
-    for c, lo, st in zip(cols, los, strides):
-        packed += (c - lo) * st
-    return packed, (tuple(los), tuple(spans), tuple(strides))
-
-
 def _semi_prefiltered_meta(copr, dim, tbl, arrays, valid, n, key_cid,
                            read_ts):
     fps = tuple(f.fingerprint() for f in dim.dag.filters)
     hkey = (tbl.uid, key_cid, "semidim", tbl.version, n, read_ts, fps)
-    meta = copr._host_cache.get(hkey)
+    cache = copr._host_cache
+    meta = cache.get(hkey)
     if meta is None:
-        prev = copr._host_cache.pop((tbl.uid, key_cid, "semicur"), None)
-        if prev is not None:
-            copr._host_cache.pop(prev, None)
-        copr._host_cache[(tbl.uid, key_cid, "semicur")] = hkey
+        cache.pop(cache.pop((tbl.uid, key_cid, "semicur"), None), None)
+        cache[(tbl.uid, key_cid, "semicur")] = hkey
         mask = valid.copy()
-        if dim.dag.filters:
-            cols = {}
-            for sc in dim.dag.cols:
-                cid = _cid_of(dim.dag, sc)
-                if cid == -1:
-                    continue
-                d, nl, sd = arrays[cid]
-                cols[sc.col.idx] = (d, nl, sd)
-            ectx = EvalCtx(np, n, cols, host=True)
-            for f in dim.dag.filters:
-                mask &= np.asarray(eval_bool_mask(ectx, f))
+        ectx = EvalCtx(np, n, dimfold._host_cols(
+            dim, {"n": n, "arrays": arrays}), host=True)
+        for f in dim.dag.filters:
+            mask &= np.asarray(eval_bool_mask(ectx, f))
         kdata, knulls, _ = arrays[key_cid]
         if knulls is not None:
             mask &= ~knulls[:n]
-        keys = np.unique(kdata[:n][mask])
-        nv = len(keys)
-        if nv == 0:
-            # nothing passes: a 1-slot always-miss lut (the kernel's hit
-            # test is lut[idx] < n, so the sentinel must be n itself —
-            # any smaller value is a false hit for probe key == lo)
-            meta = ("direct", np.array([n], dtype=dimfold.pos_dtype(n)),
-                    0, True, 0)
-        else:
-            lo = int(keys.min())
-            span = int(keys.max()) - lo + 1
-            if _direct_span(copr, span, nv):
-                lut = np.full(span, n, dtype=dimfold.pos_dtype(n))
-                lut[keys - lo] = 0       # any representative: hit test
-                meta = ("direct", lut, lo, True, nv)
-            else:
-                meta = ("sorted", (np.zeros(nv, dtype=np.int64), keys),
-                        None, True, nv)
-        copr._host_cache[hkey] = meta
-    mode, payload, lo, _unique, n_sorted = meta
-    out = {"arrays": arrays, "valid": valid, "n": n, "tbl": tbl,
-           "mode": mode, "lo": lo, "n_sorted": n_sorted, "pre": True,
-           "ukey": ("pre",) + fps}
-    if mode == "direct":
-        out["lut"] = payload
-    else:
-        out["order"], out["skeys"] = payload
-    return out
+        meta = cache[hkey] = ProbeTable.build_exists(
+            copr, np.unique(kdata[:n][mask]), n)
+    return {"probe": meta, "arrays": arrays, "valid": valid, "n": n,
+            "tbl": tbl, "ukey": ("pre",) + fps}
 
 
 def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None, want=None,
@@ -630,10 +446,8 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None, want=None,
     at its width (None: all of them, a dimension that folds nothing;
     of a folded one only the device top-n's ordering column), and no
     `valid` (its probe table holds the hit); a dimension resolved under
-    a root takes no probe table at all. A root through whose position
-    something but the position is read takes `pack` (dimfold.Packed):
-    the composed words go up where the table of positions would, keyed
-    by the field set too."""
+    a root takes no probe table at all; `pack`: the composed words of a
+    root through whose position something but the position is read."""
     tbl = meta["tbl"]
     n = meta["n"]
     ver = tbl.version
@@ -675,20 +489,11 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None, want=None,
             pad_fill=fill, mesh=mesh,
             spec="local" if mesh is None else "replicated")
 
-    pre = bool(meta.get("pre"))
-    args = {"cols": {}}
+    pre = meta["probe"].exists
+    args, layout = {"cols": {}}, {}
     if probed:
-        _upload_probe_table(meta, args, put, cap,
-                            with_valid=not pre and not folded, pack=pack)
-    layout = {}
-    if meta["mode"] == "bucket":
-        layout["bucket"] = meta["bucket"]
-    if pack is not None:
-        layout["pack"] = pack.text
-        layout["words"] = tuple(t.dtype.name for t in pack.tables)
-        nullable = {idx for kind, idx, _w, _dt in pack.text if kind == "null"}
-        for idx, sdict in pack.sdicts.items():
-            layout[idx] = (idx in nullable, sdict)
+        layout = meta["probe"].upload(
+            args, put, cap, None if pre or folded else meta["valid"], pack)
     if not pre:
         for sc in dim.dag.cols:
             cid = _cid_of(dim.dag, sc)
@@ -708,59 +513,6 @@ def _upload_dim(copr, dim, meta, cap, read_ts, mesh=None, want=None,
             args["cols"][sc.col.idx] = (jd, jn)
             layout[sc.col.idx] = (nulls is not None, sdict)
     return args, layout
-
-
-def _upload_probe_table(meta, args, put, cap, with_valid, pack=None):
-    """What the kernel's probe of one dimension reads: the composite
-    key's pack layout, `valid` unless the table holds the mask
-    (prefiltered semi dims fold visibility+filters into the lut at meta
-    time, a folded root its whole chain's: don't upload dead copies
-    into the HBM pool), and the direct or the sorted table -- of
-    positions, or of a folded root's composed words (`pack`) with the
-    fields' layout as operands beside `lo` -- or the table of a
-    bucketed composite key (`_bucket_table`)."""
-    n = meta["n"]
-    if meta.get("pack") is not None:
-        # small host values ride the kernel call as numpy operands:
-        # jnp.asarray of a scalar or a list is a device program of its
-        # own (`jit_convert_element_type`) on every statement
-        los, spans, strides = meta["pack"]
-        args["plo"] = np.asarray(los, dtype=np.int64)
-        args["pspan"] = np.asarray(spans, dtype=np.int64)
-        args["pstride"] = np.asarray(strides, dtype=np.int64)
-    if with_valid:
-        args["valid"] = put("valid", meta["valid"], n, cap, False,
-                            ts_keyed=True)
-    if meta["mode"] == "bucket":
-        # whole rows: the buckets' count is what is padded to a bucketed
-        # size (a padding row is never addressed)
-        length = len(meta["btab"])
-        row = 2 * meta["bucket"][1]
-        args["bt"] = put("bt", meta["btab"], length,
-                         shape_bucket(length // row) * row, fill=-1,
-                         ts_keyed=True)
-        return
-    direct = meta["mode"] == "direct"
-    length = len(meta["lut"]) if direct else meta["n_sorted"]
-    tcap = shape_bucket(length)
-    if pack is not None:
-        args["pk"] = [put(("pk", pack.fields, wi), t, length, tcap,
-                          fill=0 if wi else dimfold.miss(t.dtype),
-                          ts_keyed=True)
-                      for wi, t in enumerate(pack.tables)]
-        args["fshift"], args["fmask"], args["flo"] = \
-            pack.shift, pack.mask, pack.lo
-    elif direct:
-        args["lut"] = put("lut", meta["lut"], length, tcap, fill=n,
-                          ts_keyed=True)
-    else:
-        args["ord"] = put("ord", meta["order"], length, tcap,
-                          ts_keyed=True)
-    if direct:
-        args["lo"] = np.asarray(meta["lo"], dtype=np.int64)
-    else:
-        args["sk"] = put("sk", meta["skeys"], length, tcap, fill=_I64_MAX,
-                         ts_keyed=True)
 
 
 def _topn_group_col(plan):
@@ -810,38 +562,10 @@ def _upload_dims(copr, plan, fp, dim_metas, dim_caps, read_ts, mesh,
                     outcomes.append("packed_spill")
         da, layout = _upload_dim(copr, dim, meta, dcap, read_ts, mesh,
                                  want, pack)
-        # what a fact lane gathers from by key: the words, or the `lut`
-        probed = da["pk"] if "pk" in da else [da["lut"]] if "lut" in da \
-            else []
-        outcomes += ["word32"] * sum(t.dtype == np.int32 for t in probed)
+        outcomes += ["word32"] * layout.get("words", ()).count("int32")
         dim_args.append(da)
         dim_layouts.append(layout)
     return dim_args, dim_layouts, outcomes
-
-
-def _probe_modes(plan, fp, dim_metas):
-    """-> [(join type, mode)] a dimension, for
-    tidb_tpu_fused_dim_probe_total: what resolves it at fact width.
-    `folded`: at its parent's width, no probe of its own; `search`: a
-    binary search over sorted keys, whatever the dimension is;
-    `bucket`: a composite key's bucket of slots and a compare, whatever
-    the dimension is; else one gather, of a prefiltered semi table
-    (`exists`), of a materialised aggregate dimension's table
-    (`matdim`) or of its own table of positions or word (`direct`)."""
-    out = []
-    for di, (dim, meta) in enumerate(zip(plan.dims, dim_metas)):
-        if fp is not None and fp.parent[di] is not None:
-            mode = "folded"
-        elif meta["mode"] != "direct":
-            mode = "bucket" if meta["mode"] == "bucket" else "search"
-        elif meta.get("pre"):
-            mode = "exists"
-        elif dim.subplan is not None:
-            mode = "matdim"
-        else:
-            mode = "direct"
-        out.append((dim.join_type, mode))
-    return out
 
 
 def _fused_topn_state(plan, fact_tbl, state, kd, sd):
@@ -1099,8 +823,7 @@ def _compact_pos_dense(plan, res, group_map, pos_dims, dim_metas, sd):
 
 def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                         dim_sns, dim_layouts, agg_kind, agg_param,
-                        dim_pres=(), ecap=None, want_fnvalid=False,
-                        fold=None):
+                        ecap=None, want_fnvalid=False, fold=None):
     """The traced pipeline: filter fact -> dim probes/gathers -> residual
     filters -> partial agg. fact_cap is the (local, for MPP shards) fact
     partition capacity; dim_ns = full dim row counts, dim_sns = valid
@@ -1178,11 +901,12 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                 continue               # resolved at its root's width
             masked = fold is not None and fold.masked[dim_i]
             with jax.named_scope("dim_probe"):
-                pre = bool(dim_pres[dim_i]) if dim_i < len(dim_pres) else False
-                if pre or masked:
-                    dmask = None       # filters/visibility folded at meta
-                                       # time (prefiltered semi dims,
-                                       # folded roots)
+                if layout["exists"] or masked or (
+                        layout.get("visible") and not dim.dag.filters):
+                    # nothing to read at the position: its filters and
+                    # visibility are in the table (`exists`, a folded
+                    # root), or a hit is a visible row and none filters
+                    dmask = None
                 else:
                     dcols = {}
                     for idx, (jd, jn) in da["cols"].items():
@@ -1218,24 +942,15 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                         pv = jnp.full(cap, pv)
                     pv = pv.astype(jnp.int64)
                     pnm = materialize_nulls(ctx, pnl)
+                    kidx = None
+                pos, hit = probe.resolve(da, layout, pv, kidx, pnm, dn, dsn,
+                                         dcap, masked)
                 pk = layout.get("pack")
                 if pk is not None:
-                    # a folded root's composed words: ONE gather a word
-                    # by key slot (or sorted rank), every field a shift
-                    # and a mask of it; the sign bit is the miss. A word
-                    # is gathered as narrow as its table holds it and
-                    # widened after: never the table before the gather
-                    if "lo" in da:
-                        lsize = da["pk"][0].shape[0]
-                        idx = pv - da["lo"]
-                        at = jnp.clip(idx, 0, lsize - 1)
-                        hit = (idx >= 0) & (idx < lsize)
-                    else:
-                        scap = da["sk"].shape[0]
-                        loc = jnp.searchsorted(da["sk"], pv)
-                        at = jnp.minimum(loc, scap - 1)
-                        hit = (da["sk"][at] == pv) & (loc < dsn)
-                    words = [t[at].astype(jnp.int64) for t in da["pk"]]
+                    # a folded root's composed words came back in the
+                    # position's place: every field a shift and a mask
+                    # of one; the sign bit of word 0 is the miss
+                    words = pos
                     mask = mask & hit & (words[0] >= 0) & ~pnm
                     got = {(kind, ident): dimfold.unpack_field(
                         words[wi], da["fshift"][fi], da["fmask"][fi],
@@ -1249,55 +964,8 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                             dim_pos[dim_i if kind == "pos" else ident] = v
                     ctx = EvalCtx(jnp, cap, cols, host=False)
                     continue
-                if "lut" in da:
-                    # dense key domain: the join is ONE gather, of a
-                    # table as narrow as its positions (widened after)
-                    lsize = da["lut"].shape[0]
-                    idx = pv - da["lo"]
-                    inb = (idx >= 0) & (idx < lsize)
-                    pos = da["lut"][jnp.clip(idx, 0, lsize - 1)] \
-                        .astype(jnp.int64)
-                    if masked:
-                        hit = inb & (pos < dn) & ~pnm
-                    pos = jnp.minimum(pos, dcap - 1)
-                    if not masked:
-                        hit = inb & \
-                            (da["lut"][jnp.clip(idx, 0, lsize - 1)]
-                             .astype(jnp.int64) < dn) & ~pnm
-                    if dmask is not None:
-                        hit = hit & dmask[pos]
-                elif "bt" in da:
-                    # a composite key bucketed on one of its columns
-                    # (`_bucket_table`): ONE gather of the bucket's row,
-                    # as narrow as the table. That column's stride is
-                    # 0, so `pv` packs the lane's other keys, which at
-                    # most one of the row's slots holds (the build keys
-                    # are unique; an empty slot holds what nothing
-                    # packs to); the position is that slot's
-                    bcol, slots = layout["bucket"]
-                    row = da["bt"].reshape(-1, 2 * slots)[kidx[bcol]]
-                    eq = row[:, :slots] == pv.astype(row.dtype)[:, None]
-                    pos = jnp.sum(jnp.where(eq, row[:, slots:], 0),
-                                  axis=1, dtype=jnp.int64)
-                    hit = jnp.any(eq, axis=1) & (pos < dn) & ~pnm
-                    pos = jnp.minimum(pos, dcap - 1)
-                    # (the table holds the snapshot's visible rows
-                    # alone: a hit's `valid[pos]` is true, and only the
-                    # dimension's own filters are read at `pos`)
-                    if dim.dag.filters:
-                        hit = hit & dmask[pos]
-                else:
-                    scap = da["sk"].shape[0]
-                    loc = jnp.searchsorted(da["sk"], pv)
-                    locc = jnp.minimum(loc, scap - 1)
-                    pos = da["ord"][locc]
-                    hit = (da["sk"][locc] == pv) & ~pnm & (loc < dsn)
-                    if masked:
-                        # a folded row order holds the miss sentinel
-                        hit = hit & (pos < dn)
-                        pos = jnp.minimum(pos, dcap - 1)
-                    if dmask is not None:
-                        hit = hit & dmask[pos]
+                if dmask is not None:
+                    hit = hit & dmask[pos]
                 if dim.join_type == "left":
                     # preserved side: misses keep the row, payload is NULL
                     for idx, (jd, jn) in da["cols"].items():
@@ -1418,11 +1086,11 @@ def _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
 
 def _build_fused_kernel(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
                         dim_sns, dim_layouts, agg_kind, agg_param,
-                        dim_pres=(), ecap=None, fold=None):
+                        ecap=None, fold=None):
     body = _make_pipeline_body(plan, fact_cap, fact_sdicts, dim_caps,
                                dim_ns, dim_sns, dim_layouts, agg_kind,
-                               agg_param, dim_pres, ecap=ecap,
-                               want_fnvalid=True, fold=fold)
+                               agg_param, ecap=ecap, want_fnvalid=True,
+                               fold=fold)
     # donate the fact validity mask: per-dispatch scratch rebuilt by
     # _pad_upload every call; dim args and fact columns ride the
     # resident pool and must never be donated
@@ -1433,7 +1101,7 @@ def _build_fused_kernel(plan, fact_cap, fact_sdicts, dim_caps, dim_ns,
 
 def _build_fused_kernel_mpp(plan, local_cap, fact_sdicts, dim_caps,
                             dim_ns, dim_sns, dim_layouts, agg_kind,
-                            agg_param, mesh, dim_pres=(), fold=None):
+                            agg_param, mesh, fold=None):
     """The fused pipeline as ONE shard_map program: fact shards ride the
     'dp' mesh axis (PassThrough exchange from the scan), dims are
     replicated (Broadcast exchange), and the partial aggregation merges
@@ -1444,7 +1112,7 @@ def _build_fused_kernel_mpp(plan, local_cap, fact_sdicts, dim_caps,
 
     body = _make_pipeline_body(plan, local_cap, fact_sdicts, dim_caps,
                                dim_ns, dim_sns, dim_layouts, agg_kind,
-                               agg_param, dim_pres, fold=fold)
+                               agg_param, fold=fold)
     aggs = list(plan.aggs)
     dense = agg_kind in ("dense", "posdense")
 
@@ -1780,9 +1448,7 @@ def _bind_tables(copr, plan, read_ts, ctx, fp=None, sp=None):
                                None, tbl.dicts.get(cid))
             dim_metas.append({
                 "arrays": arrays, "valid": np.zeros(1, dtype=bool),
-                "n": 1, "tbl": tbl, "mode": "direct",
-                "lut": np.array([1], dtype=dimfold.pos_dtype(1)), "lo": 0,
-                "n_sorted": 0, "pack": None,
+                "n": 1, "tbl": tbl, "probe": ProbeTable.always_miss(1),
                 # arrays are fabricated 1-row placeholders, NOT the
                 # table's append-only columns: they must never enter
                 # the delta-maintained append seam under this uid
@@ -1855,8 +1521,7 @@ def fused_partials(copr, plan, read_ts, mesh=None,
 
     dim_caps = [shape_bucket(m["n"]) for m in dim_metas]
     dim_ns = [m["n"] for m in dim_metas]
-    dim_sns = [m["n_sorted"] for m in dim_metas]
-    dim_pres = tuple(bool(m.get("pre")) for m in dim_metas)
+    dim_sns = [m["probe"].n_sorted for m in dim_metas]
     dim_up = {}
 
     def _dims_for(pos_grouped):
@@ -1878,12 +1543,14 @@ def fused_partials(copr, plan, read_ts, mesh=None,
                     sp.attrs["packed_roots"] = outcomes.count("packed")
                     sp.attrs["word32"] = outcomes.count("word32")
                 if not dim_up:      # once a statement, not a lowering
-                    probes = _probe_modes(plan, fp, dim_metas)
-                    for join, mode in probes:
-                        _metrics.FUSED_DIM_PROBE.labels(join, mode).inc()
+                    # what resolves each dimension at fact width
+                    probes = [m["probe"].label(d, "folded_under" in m)
+                              for d, m in zip(plan.dims, dim_metas)]
+                    for d, mode in zip(plan.dims, probes):
+                        _metrics.FUSED_DIM_PROBE.labels(d.join_type,
+                                                        mode).inc()
                     if sp is not None:
-                        sp.attrs["probes"] = "+".join(
-                            m for _j, m in probes)
+                        sp.attrs["probes"] = "+".join(probes)
                 dim_up[pos_grouped] = up
         return dim_up[pos_grouped]
 
@@ -1986,8 +1653,7 @@ def fused_partials(copr, plan, read_ts, mesh=None,
         return _run_fused_mpp(
             copr, plan, mesh, fact_tbl, fact_arrays, fact_valid, n,
             handles, dim_args, dim_metas, dim_caps, dim_ns, dim_sns,
-            dim_layouts, fact_sdicts, low, shim, kd, sd, read_ts,
-            dim_pres, fp)
+            dim_layouts, fact_sdicts, low, shim, kd, sd, read_ts, fp)
     # which group items identify a "sort" / "posruns" partial's groups
     ident = _ident_items(plan)
     # the lowering the first row block will take: its operands go up
@@ -2034,7 +1700,7 @@ def fused_partials(copr, plan, read_ts, mesh=None,
             kern = _build_fused_kernel(
                 plan, cap, fact_sdicts, tuple(dim_caps),
                 tuple(dim_ns), tuple(dim_sns), tuple(dim_layouts),
-                agg_kind, agg_param, dim_pres, ecap=ecap, fold=fp)
+                agg_kind, agg_param, ecap=ecap, fold=fp)
             kern = copr._kernel_cache.put(key, kern)
         with phase.bind_span():
             fjc_full, fvv = copr._pad_upload(cols, v, m, cap,
@@ -2375,7 +2041,7 @@ def _try_fused_shuffle(copr, plan, mesh, dim_metas, fact_tbl, fact_arrays,
 def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
                    n, handles, dim_args, dim_metas, dim_caps, dim_ns,
                    dim_sns, dim_layouts, fact_sdicts, low, shim, kd, sd,
-                   read_ts, dim_pres=(), fold=None):
+                   read_ts, fold=None):
     """Mesh execution: ONE shard_map call over the whole fact table."""
     from ..mpp.exec import exchange_observed, tree_nbytes
     from .delta import append_key
@@ -2425,7 +2091,7 @@ def _run_fused_mpp(copr, plan, mesh, fact_tbl, fact_arrays, fact_valid,
             kern = _build_fused_kernel_mpp(
                 plan, local, fact_sdicts, tuple(dim_caps), tuple(dim_ns),
                 tuple(dim_sns), tuple(dim_layouts), agg_kind, agg_param,
-                mesh, dim_pres, fold)
+                mesh, fold)
             kern = copr._kernel_cache.put(key, kern)
         # tpulint: disable=unguarded-dispatch — supervised by
         # executors.FusedPipeline's guarded_dispatch site="fused/mpp"
@@ -2492,10 +2158,7 @@ def _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap, dim_caps,
     fps = tuple(f.fingerprint() for f in plan.fact_dag.filters)
     dimsig = tuple(
         (d.dag.table_info.id, d.build_key.col.idx, d.join_type,
-         d.probe_expr.fingerprint(), m["mode"],
-         (len(m["lut"]), m["lut"].dtype.name) if m["mode"] == "direct"
-         else m["bucket"] + (len(m["btab"]), m["btab"].dtype.name)
-         if m["mode"] == "bucket" else 0,
+         d.probe_expr.fingerprint(), m["probe"].signature(),
          tuple(f.fingerprint() for f in d.dag.filters),
          tuple(sorted((sc.col.idx, sc.name) for sc in d.dag.cols)),
          tuple((sc.col.idx, pe.fingerprint()) for sc, pe in d.extra_keys),
@@ -2509,7 +2172,6 @@ def _fused_cache_key(copr, plan, fact_tbl, dim_metas, cap, dim_caps,
     return ("fused", fact_tbl.uid, cap, dim_caps, dim_ns, dim_sns, fps,
             dimsig, postfps, gfps, afps, tuple(dict_vers), colsig,
             agg_kind, agg_param, ecap, _al.policy(),
-            tuple(bool(m.get("pre")) for m in dim_metas),
             None if fold is None else fold.sig(),
             # which field of a composed word is read where, and the
             # physical type a word (like a `lut`) is gathered in, is
